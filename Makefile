@@ -52,7 +52,8 @@ trace-smoke:
 slo-smoke:
 	JAX_PLATFORMS=cpu python scripts/slo_smoke.py
 
-# perf-regression sentinel over BENCH_r*.json / BENCH_serving.json:
+# perf-regression sentinel over the committed BENCH_serving*.json /
+# BENCH_generate.json (and any BENCH_r<NN>.json wrappers in --dir):
 # trajectory table + exit 1 when the newest round regressed >10%
 # vs the best comparable (same-lineage) prior value (docs/slo.md)
 perf-sentinel:
@@ -119,8 +120,7 @@ fleet-smoke:
 
 # populate the persistent autotune cache for the bench shapes
 # (ZOO_TPU_AUTOTUNE=1 sweeps on first sight; docs/autotune.md), then
-# print the decision table. chip_session.sh runs this before the
-# benches and commits the refreshed v5e defaults table.
+# print the decision table.
 autotune:
 	ZOO_TPU_AUTOTUNE=1 python scripts/autotune_report.py --sweep
 

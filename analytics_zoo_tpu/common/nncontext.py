@@ -30,6 +30,7 @@ from analytics_zoo_tpu.common.config import (
     ZooTpuConf,
     parse_axes,
 )
+from analytics_zoo_tpu.common.device import setup_compile_cache
 from analytics_zoo_tpu.version import __version__
 
 logger = logging.getLogger("analytics_zoo_tpu")
@@ -211,6 +212,7 @@ def init_nncontext(
     """
     global _current
     _maybe_init_distributed(multi_host)
+    setup_compile_cache()
     conf = ZooTpuConf.from_env(conf)
     if app_name is not None:
         conf.app_name = app_name

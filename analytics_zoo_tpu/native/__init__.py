@@ -4,19 +4,23 @@
 The reference ships native code as JNI `.so`s in `zoo-core-dist-all`
 (SURVEY.md §2.11); here the C++ ships as package data (`native/src/`) and is
 built on first use with g++ (no pybind11 in the image — plain C ABI +
-ctypes). Every consumer has a pure-Python fallback, so the framework
-degrades gracefully where a toolchain is missing.
+ctypes). The library is built from the shipped sources or not used: a
+binary older than its sources is rebuilt, never loaded. Every
+consumer has a pure-Python fallback for where a toolchain is missing.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional
 
 import numpy as np
+
+logger = logging.getLogger("analytics_zoo_tpu")
 
 # sources ship as package data (src/); the .so is built next to them
 # on first use, so pip-installed copies work without a build step
@@ -29,41 +33,52 @@ _lib_lock = threading.Lock()
 _build_failed = False
 
 
+_SRCS = [os.path.join(_NATIVE_DIR, f)
+         for f in ("host_arena.cpp", "serving_queue.cpp",
+                   "serving_http.cpp")]
+
+
 def _build() -> bool:
-    srcs = [os.path.join(_NATIVE_DIR, f)
-            for f in ("host_arena.cpp", "serving_queue.cpp",
-                      "serving_http.cpp")]
+    """Compile the sources into ``_SO_PATH``; atomic (tmp + rename),
+    so a concurrent process never loads a half-written binary."""
+    tmp = f"{_SO_PATH}.tmp.{os.getpid()}"
     cmd = ["g++", "-O2", "-fPIC", "-std=c++17", "-shared", "-o",
-           _SO_PATH] + srcs + ["-lpthread"]
+           tmp] + _SRCS + ["-lpthread"]
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        subprocess.run(cmd, check=True, capture_output=True,
+                       timeout=120)
+        os.replace(tmp, _SO_PATH)
         return True
-    except Exception:
+    except (OSError, subprocess.SubprocessError) as e:
+        logger.warning("native: build failed (%s: %s) %s",
+                       type(e).__name__, e,
+                       getattr(e, "stderr", b"") or b"")
+        if os.path.exists(tmp):
+            os.unlink(tmp)
         return False
 
 
+def _stale() -> bool:
+    """No binary, or one older than any of its sources."""
+    if not os.path.exists(_SO_PATH):
+        return True
+    built = os.path.getmtime(_SO_PATH)
+    return any(os.path.getmtime(src) > built for src in _SRCS)
+
+
 def load_native() -> Optional[ctypes.CDLL]:
-    """Load (building if needed) the native library; None if unavailable."""
+    """Load (building if needed) the native library; None if
+    unavailable. A binary older than its sources is never loaded: it
+    is rebuilt, and if that fails the library is not used."""
     global _lib, _build_failed
     with _lib_lock:
         if _lib is not None:
             return _lib
         if _build_failed:
             return None
-        srcs = [os.path.join(_NATIVE_DIR, f)
-                for f in ("host_arena.cpp", "serving_queue.cpp",
-                          "serving_http.cpp")]
-        stale = os.path.exists(_SO_PATH) and any(
-            os.path.getmtime(s) > os.path.getmtime(_SO_PATH)
-            for s in srcs if os.path.exists(s))
-        if (not os.path.exists(_SO_PATH) or stale) and not _build():
-            if not os.path.exists(_SO_PATH):   # stale-but-present is usable
-                _build_failed = True
-                return None
-            import logging
-            logging.getLogger("analytics_zoo_tpu").warning(
-                "native: rebuild failed; loading STALE %s (sources are "
-                "newer than the binary)", _SO_PATH)
+        if _stale() and not _build():
+            _build_failed = True
+            return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError:
@@ -107,13 +122,10 @@ def load_native() -> Optional[ctypes.CDLL]:
         lib.zoo_http_respond.argtypes = [
             ctypes.c_void_p, ctypes.c_long, ctypes.c_int,
             ctypes.c_char_p, ctypes.c_long]
-        try:  # absent from a stale pre-tracing .so — optional
-            lib.zoo_http_respond_hdr.restype = ctypes.c_int
-            lib.zoo_http_respond_hdr.argtypes = [
-                ctypes.c_void_p, ctypes.c_long, ctypes.c_int,
-                ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p]
-        except AttributeError:
-            pass
+        lib.zoo_http_respond_hdr.restype = ctypes.c_int
+        lib.zoo_http_respond_hdr.argtypes = [
+            ctypes.c_void_p, ctypes.c_long, ctypes.c_int,
+            ctypes.c_char_p, ctypes.c_long, ctypes.c_char_p]
         lib.zoo_http_destroy.argtypes = [ctypes.c_void_p]
         _lib = lib
         return _lib
@@ -266,7 +278,7 @@ class NativeHttpServer:
         None on timeout, or raises StopIteration after close().
         ``trace_id`` is the request's X-Zoo-Trace-Id header when the
         C++ side captured one (it rides the path buffer after a
-        ``\\n``; a stale pre-tracing .so simply never sends it).
+        ``\\n``).
         Buffers are per-THREAD (reused across polls — no 16MB alloc
         churn), so concurrent worker pulls never share a buffer."""
         if not self._handle:
@@ -290,7 +302,7 @@ class NativeHttpServer:
                 trace_id: "Optional[str]" = None) -> bool:
         if not self._handle:
             return False
-        if trace_id and hasattr(self._lib, "zoo_http_respond_hdr"):
+        if trace_id:
             return self._lib.zoo_http_respond_hdr(
                 self._handle, req_id, status, body, len(body),
                 trace_id.encode()) == 0

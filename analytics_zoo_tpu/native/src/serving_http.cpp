@@ -17,8 +17,7 @@
 //       -> body length >=0, -1 timeout, -2 shutdown
 //       (when the request carried an X-Zoo-Trace-Id header, the path
 //        buffer holds "path\ntrace_id" — '\n' never appears in a
-//        request line, and an old .so simply never emits it, so the
-//        Python side degrades gracefully against a stale binary)
+//        request line)
 //   zoo_http_respond(h, req_id, status, body, len) -> 0 ok
 //   zoo_http_respond_hdr(h, req_id, status, body, len, trace)
 //       -> same, echoing trace as an X-Zoo-Trace-Id response header

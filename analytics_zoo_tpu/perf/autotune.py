@@ -29,7 +29,7 @@ Sweeping is opt-in: ``ZOO_TPU_AUTOTUNE=1`` sweeps a bounded
 candidate set on first sight of a key (compile time excluded via
 ``diagnostics.expected_compiles()``), ``2`` force-resweeps each key
 once per process, unset/``0`` never times anything. Sweeps never run
-inside an active jax trace (``jax.core.trace_state_clean``) — a
+inside an active jax trace (``jax.core.trace_ctx.is_top_level``) — a
 decision needed mid-trace falls back to cache/defaults/heuristic and
 ``make autotune`` populates the cache ahead of time at the bench
 shapes. The heuristic config always competes in its own sweep and
@@ -52,10 +52,13 @@ directions).
 from __future__ import annotations
 
 import json
+import logging
 import os
 import threading
 import time
 from typing import Any, Callable, Dict, List, Optional
+
+logger = logging.getLogger("analytics_zoo_tpu")
 
 __all__ = [
     "SCHEMA_VERSION", "OVERRIDE_FLAGS", "OpSpec", "AutotuneCache",
@@ -249,6 +252,10 @@ def _count(which: str):
         obs.counter("zoo_tpu_autotune_misses_total",
                     help="autotune decisions with no cached entry "
                          "(heuristic served unless a sweep ran)").inc()
+    elif which == "candidate_failure":
+        obs.counter("zoo_tpu_autotune_candidate_failures_total",
+                    help="sweep candidates that failed to compile "
+                         "or run and were skipped").inc()
     else:
         obs.counter("zoo_tpu_autotune_sweeps_total",
                     help="candidate sweeps executed and "
@@ -374,7 +381,9 @@ class AutotuneCache:
             try:
                 with obs.span("autotune/sweep", op=op, key=key):
                     for cfg in cands:
-                        ms = self._time_candidate(spec, params, cfg)
+                        ms = self._time_candidate(
+                            spec, op, params, cfg,
+                            is_heuristic=cfg == heur)
                         if ms is not None:
                             timed.append({"config": cfg, "ms": ms})
             finally:
@@ -401,27 +410,38 @@ class AutotuneCache:
             self._note("sweep")
             return entry["config"]
 
-    def _time_candidate(self, spec: OpSpec, params: dict,
-                        cfg: dict) -> Optional[float]:
+    def _time_candidate(self, spec: OpSpec, op: str, params: dict,
+                        cfg: dict, is_heuristic: bool
+                        ) -> Optional[float]:
         """Best-of-``SWEEP_REPS`` wall ms of the spec's probe, with
         the compile excluded (the warm-up call runs inside an
         ``expected_compiles`` bracket so deliberate sweep compiles
-        never read as a recompile storm)."""
+        never read as a recompile storm). None when the runner
+        declines the candidate here. A candidate whose first call
+        fails (the compiler refused it) is counted, logged and
+        skipped — unless it is the heuristic, the config every call
+        site falls back to: then the sweep raises."""
         from analytics_zoo_tpu.common import diagnostics
+        fn = spec.runner(params, cfg)
+        if fn is None:
+            return None
         try:
-            fn = spec.runner(params, cfg)
-            if fn is None:
-                return None
             with diagnostics.expected_compiles():
                 fn()                       # compile + warm
-            best = float("inf")
-            for _ in range(SWEEP_REPS):
-                t0 = time.perf_counter()
-                fn()
-                best = min(best, time.perf_counter() - t0)
-            return best * 1e3
-        except Exception:
-            return None        # infeasible candidate: skip, not fatal
+        except Exception as e:
+            _count("candidate_failure")
+            logger.warning(
+                "autotune: op %s config %s failed to compile or run "
+                "(%s: %s)", op, cfg, type(e).__name__, e)
+            if is_heuristic:
+                raise
+            return None
+        best = float("inf")
+        for _ in range(SWEEP_REPS):
+            t0 = time.perf_counter()
+            fn()
+            best = min(best, time.perf_counter() - t0)
+        return best * 1e3
 
     # -- persistence ----------------------------------------------------
 
@@ -477,10 +497,7 @@ class AutotuneCache:
 
 def _trace_clean() -> bool:
     import jax
-    try:
-        return bool(jax.core.trace_state_clean())
-    except AttributeError:
-        return False
+    return jax.core.trace_ctx.is_top_level()
 
 
 _cache: Optional[AutotuneCache] = None

@@ -1,10 +1,7 @@
 """Live goodput / MFU ledger for the training loop.
 
-PERF.md's MFU numbers were hand-computed after each bench round —
-and went dark when rounds 3–5 lost chip access. This module makes
-the roofline chase (ROADMAP item 5, 0.45 MFU) a *live* signal
-instead: every Estimator step feeds a :class:`GoodputLedger`, which
-maintains
+Makes the roofline chase (0.45 MFU, BASELINE.md) a *live* signal:
+every Estimator step feeds a :class:`GoodputLedger`, which maintains
 
 - ``zoo_tpu_mfu`` — executed-semantics FLOPs per step (from
   :mod:`analytics_zoo_tpu.perf.flops`, the same counter behind
@@ -22,8 +19,7 @@ maintains
 
 Per-epoch summaries (:meth:`GoodputLedger.epoch_summary`) land in the
 Estimator's training history and — via
-``bench_common.attach_metrics_snapshot`` — in every bench artifact,
-so the perf trajectory stays measurable even on CPU fallback.
+``bench_common.attach_metrics_snapshot`` — in every bench artifact.
 
 ``ZOO_TPU_GOODPUT=0`` disables the ledger entirely;
 ``ZOO_TPU_GOODPUT_FLOPS=0`` skips the one-off train-step lowering
@@ -53,12 +49,14 @@ __all__ = [
     "flops_enabled",
 ]
 
-# Per-chip dense peak FLOP/s at the dtype the train step actually
-# runs (bf16 on TPU). Matched by lowercase substring against
-# ``jax.devices()[0].device_kind``; first hit wins, most specific
-# first. The CPU entry is a deliberately honest single-core figure so
-# fallback MFU numbers stay comparable round-over-round rather than
-# flattering.
+# THE peaks table: per-chip dense peak FLOP/s at the dtype the train
+# step actually runs (bf16 on TPU; Google Cloud TPU documentation,
+# per-generation system-architecture pages). Matched by lowercase
+# substring against ``jax.devices()[0].device_kind``; first hit wins,
+# most specific first. A kind that is not here is an error, never a
+# default. The CPU entry is a nominal single-core figure that keeps
+# the ledger's arithmetic defined under the CPU tests; an MFU against
+# it is not a device metric.
 PEAK_FLOPS_BY_DEVICE_KIND = (
     ("v5p", 459e12),
     ("v5e", 197e12),
@@ -75,8 +73,6 @@ PEAK_FLOPS_BY_DEVICE_KIND = (
 # the shares always sum to 1.0.
 COMPONENTS = ("compute", "data_wait", "dispatch", "checkpoint")
 
-_DEFAULT_PEAK = 197e12  # unrecognized accelerator: assume v5e
-
 
 def enabled() -> bool:
     return os.environ.get("ZOO_TPU_GOODPUT", "1") != "0"
@@ -88,24 +84,22 @@ def flops_enabled() -> bool:
     return os.environ.get("ZOO_TPU_GOODPUT_FLOPS", "1") != "0"
 
 
-def resolve_peak_flops(device_kind: str,
-                       platform: str = "") -> float:
-    """Peak FLOP/s for a device-kind string.
-    ``ZOO_TPU_PEAK_TFLOPS`` (the same knob bench.py uses for its MFU
-    denominator) overrides the table."""
+def resolve_peak_flops(device_kind: str) -> float:
+    """Peak FLOP/s for a ``device_kind`` string, from
+    :data:`PEAK_FLOPS_BY_DEVICE_KIND`. ``ZOO_TPU_PEAK_TFLOPS``
+    overrides the table. A kind the table does not know raises
+    ``ValueError``: no peak is ever assumed."""
     raw = os.environ.get("ZOO_TPU_PEAK_TFLOPS")
     if raw:
-        try:
-            return float(raw) * 1e12
-        except ValueError:
-            pass
+        return float(raw) * 1e12
     kind = (device_kind or "").lower()
     for sub, peak in PEAK_FLOPS_BY_DEVICE_KIND:
         if sub in kind:
             return peak
-    if (platform or "").lower() == "cpu":
-        return dict(PEAK_FLOPS_BY_DEVICE_KIND)["cpu"]
-    return _DEFAULT_PEAK
+    raise ValueError(
+        f"no peak FLOP/s known for device_kind {device_kind!r}: add "
+        "it to perf.goodput.PEAK_FLOPS_BY_DEVICE_KIND with its "
+        "source, or set ZOO_TPU_PEAK_TFLOPS")
 
 
 class GoodputLedger:
@@ -114,11 +108,10 @@ class GoodputLedger:
     it, but `/debug` surfaces may read concurrently)."""
 
     def __init__(self, peak_flops: Optional[float] = None,
-                 device_kind: str = "", platform: str = "",
-                 n_devices: int = 1,
+                 device_kind: str = "", n_devices: int = 1,
                  registry: "Optional[obs.MetricsRegistry]" = None):
         if peak_flops is None:
-            peak_flops = resolve_peak_flops(device_kind, platform)
+            peak_flops = resolve_peak_flops(device_kind)
         self.peak_flops = float(peak_flops) * max(1, int(n_devices))
         self.device_kind = device_kind
         self.flops_per_step: Optional[float] = None
@@ -219,8 +212,7 @@ class GoodputLedger:
 
 
 # Recent epoch summaries, process-wide: bench_common attaches these
-# to every artifact so CPU-fallback rounds still carry a goodput
-# trajectory.
+# to every artifact.
 _summaries_lock = threading.Lock()
 _summaries: "deque" = deque(maxlen=32)
 
@@ -240,17 +232,11 @@ def ledger_for_backend(
         registry: "Optional[obs.MetricsRegistry]" = None
 ) -> Optional[GoodputLedger]:
     """A ledger sized for the current jax backend (device kind, peak
-    FLOPs, local device count); None when ``ZOO_TPU_GOODPUT=0`` or
-    jax is unavailable."""
+    FLOPs, local device count); None when ``ZOO_TPU_GOODPUT=0``. An
+    unknown ``device_kind`` raises (see :func:`resolve_peak_flops`)."""
     if not enabled():
         return None
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        kind = getattr(dev, "device_kind", "") or ""
-        platform = getattr(dev, "platform", "") or ""
-        n = jax.local_device_count()
-    except Exception:
-        return None
-    return GoodputLedger(device_kind=kind, platform=platform,
-                         n_devices=n, registry=registry)
+    import jax
+    return GoodputLedger(
+        device_kind=jax.local_devices()[0].device_kind,
+        n_devices=jax.local_device_count(), registry=registry)

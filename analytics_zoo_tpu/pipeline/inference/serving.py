@@ -1325,7 +1325,8 @@ def make_inference_server(model: InferenceModel, port: int = 0,
                           prefer_native: bool = True,
                           batcher="auto", gen_batcher="auto"):
     """Native C++ front-end when the toolchain built it, else the
-    stdlib ThreadingHTTPServer — same endpoints either way.
+    stdlib ThreadingHTTPServer (with a warning that says why) — same
+    endpoints either way.
     ``batcher``: ``"auto"`` (env-configured dynamic batching),
     ``None`` (per-request), or a :class:`DynamicBatcher`.
     ``gen_batcher``: same trio for /generate — ``"auto"`` mounts a
@@ -1336,7 +1337,10 @@ def make_inference_server(model: InferenceModel, port: int = 0,
             return NativeInferenceServer(model, port=port,
                                          batcher=batcher,
                                          gen_batcher=gen_batcher)
-        except (RuntimeError, OSError):
-            pass
+        except (RuntimeError, OSError) as e:
+            from analytics_zoo_tpu.common.nncontext import logger
+            logger.warning(
+                "native HTTP front-end unavailable (%s: %s); serving "
+                "from the stdlib front-end", type(e).__name__, e)
     return InferenceServer(model, port=port, batcher=batcher,
                            gen_batcher=gen_batcher)

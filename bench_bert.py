@@ -120,12 +120,14 @@ def measure(batch: int = 32, steps: int = 10, seq_len: int = 128,
 
 
 def main():
-    from bench_common import attach_metrics_snapshot
+    from bench_common import attach_metrics_snapshot, select_device
+    device = select_device()
     rec = measure(
         batch=int(os.environ.get("ZOO_TPU_BENCH_BERT_BATCH", "32")),
         steps=int(os.environ.get("ZOO_TPU_BENCH_STEPS", "10")),
         hidden=int(os.environ.get("ZOO_TPU_BENCH_BERT_HIDDEN", "768")),
         blocks=int(os.environ.get("ZOO_TPU_BENCH_BERT_BLOCKS", "4")))
+    rec["device"] = device
     print(json.dumps(attach_metrics_snapshot(rec)), flush=True)
 
 

@@ -76,6 +76,7 @@ import os
 import sys
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
@@ -179,7 +180,7 @@ def _run_clients(submit, clients: int, duration_s: float):
             t0 = time.perf_counter()
             try:
                 out = submit(prompts[n], max_new)
-            except Exception:
+            except Exception:  # load generator: count, keep going
                 with lock:
                     errors[0] += 1
                 continue
@@ -207,6 +208,7 @@ def _run_ttft_probe(submit, duration_s: float) -> dict:
     prompts = {n: rs.randint(1, VOCAB, size=n).tolist()
                for n in (PROBE_SHORT, PROBE_LONG)}
     samples = {PROBE_SHORT: [], PROBE_LONG: []}
+    errors = [0]
     lock = threading.Lock()
     stop_at = time.perf_counter() + duration_s
     shapes = (PROBE_SHORT, PROBE_LONG)
@@ -219,7 +221,9 @@ def _run_ttft_probe(submit, duration_s: float) -> dict:
             t0 = time.perf_counter()
             try:
                 submit(prompts[n], 1)
-            except Exception:
+            except Exception:  # load generator: count, keep probing
+                with lock:
+                    errors[0] += 1
                 continue
             dt = (time.perf_counter() - t0) * 1e3
             with lock:
@@ -231,9 +235,13 @@ def _run_ttft_probe(submit, duration_s: float) -> dict:
         t.start()
     for t in ts:
         t.join()
-    out = {}
+    out = {"ttft_probe_errors": errors[0]}
     for n, name in ((PROBE_SHORT, "short"), (PROBE_LONG, "long")):
-        arr = np.asarray(samples[n]) if samples[n] else np.zeros((1,))
+        if not samples[n]:  # a failed probe is not a 0 ms TTFT
+            raise RuntimeError(
+                f"TTFT probe: no {name} request succeeded "
+                f"({errors[0]} errors)")
+        arr = np.asarray(samples[n])
         out[f"ttft_{name}_p50_ms"] = round(
             float(np.percentile(arr, 50)), 2)
         out[f"ttft_{name}_p99_ms"] = round(
@@ -345,16 +353,15 @@ def measure(mode: str, im, clients: int, duration_s: float,
         spec0 = (engine.spec_proposed, engine.spec_accepted) \
             if getattr(engine, "spec_k", 0) else None
         t0 = time.perf_counter()
-        if stream and probe_ttft:
-            probe = {}
-            pt = threading.Thread(target=lambda: probe.update(
-                _run_ttft_probe(submit, duration_s)))
-            pt.start()
-        tokens, lat, errors = _run_clients(submit, clients,
-                                           duration_s)
-        if stream and probe_ttft:
-            pt.join()
-            probe_rec = probe
+        # the probe runs beside the mix clients; result() re-raises
+        # what a bare thread would only print
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            fut = pool.submit(_run_ttft_probe, submit, duration_s) \
+                if stream and probe_ttft else None
+            tokens, lat, errors = _run_clients(submit, clients,
+                                               duration_s)
+            if fut is not None:
+                probe_rec = fut.result()
         window = time.perf_counter() - t0
         d_tok = _counter_value(
             "zoo_tpu_serving_gen_tokens_total") - tok0
@@ -363,7 +370,10 @@ def measure(mode: str, im, clients: int, duration_s: float,
     finally:
         if cb is not None:
             cb.stop()
-    lat_ms = np.asarray(lat) * 1e3 if lat else np.zeros((1,))
+    if not lat:  # a window in which nothing finished is no result
+        raise RuntimeError(
+            f"[{mode}] no request succeeded ({errors} errors)")
+    lat_ms = np.asarray(lat) * 1e3
     rec = {
         "mode": mode,
         "clients": clients,

@@ -145,7 +145,7 @@ def _run_clients(port: int, clients: int, duration_s: float):
                         urllib.request.Request(url, data=bodies[n]),
                         timeout=60) as r:
                     r.read()
-            except Exception:
+            except Exception:  # load generator: count, keep going
                 with lock:
                     errors[0] += 1
                 continue
@@ -179,6 +179,9 @@ def measure(mode: str, clients: int, duration_s: float,
         window = time.perf_counter() - t0
     finally:
         srv.stop()
+    if not lat:  # a window in which nothing finished is no result
+        raise RuntimeError(
+            f"[{mode}] no request succeeded ({errors} errors)")
     lat_ms = np.asarray(lat) * 1e3
     rec = {
         "mode": mode,

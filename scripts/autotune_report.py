@@ -8,11 +8,9 @@ Three jobs, one process (one backend init):
 - ``--sweep``: populate the cache for the bench shapes (the ResNet
   1x1 matmuls, the attention crossover key lengths, the conv_bn
   backward gate) by routing each through ``autotune.decide`` with
-  ``ZOO_TPU_AUTOTUNE=1`` semantics — the one-time search cost
-  ROADMAP item 4 budgets for a chip session;
+  ``ZOO_TPU_AUTOTUNE=1`` semantics — the one-time search cost;
 - ``--emit-defaults``: freeze the current entries into the committed
-  per-device table ``perf/autotune_defaults/<device>.json`` (what
-  scripts/chip_session.sh commits on the first healthy chip session),
+  per-device table ``perf/autotune_defaults/<device>.json``,
   stamping ``--round`` into the table header.
 
 Usage:

@@ -73,6 +73,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:  # `python scripts/fleet_smoke.py`
     sys.path.insert(0, ROOT)
 
+# A CPU smoke by construction: this process and every worker it
+# spawns each initialise JAX, and a chip belongs to one process.
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=2")
 

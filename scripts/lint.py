@@ -56,7 +56,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TARGETS = ["analytics_zoo_tpu", "tests", "scripts", "apps",
            "bench.py", "bench_ncf.py", "bench_bert.py",
            "bench_common.py", "bench_serving.py",
-           "bench_generate.py", "__graft_entry__.py"]
+           "bench_generate.py", "chip_smoke.py",
+           "__graft_entry__.py"]
 MAX_LEN = 79
 
 

@@ -256,6 +256,86 @@ def test_sweep_counters_and_span(tmp_path, monkeypatch):
     autotune.reset_cache()
 
 
+def _toy_spec(fail):
+    """A two-candidate op whose ``fail``-named configs raise on their
+    first (compiling) call, the way a refused kernel does."""
+    def runner(params, cfg):
+        def run():
+            if cfg["name"] in fail:
+                raise RuntimeError(f"RESOURCE_EXHAUSTED: vmem "
+                                   f"({cfg['name']})")
+        return run
+    return autotune.OpSpec(
+        "toy_failing_op", heuristic=lambda p: {"name": "heur"},
+        candidates=lambda p: [{"name": "heur"}, {"name": "other"}],
+        runner=runner)
+
+
+def test_candidate_that_fails_to_compile_is_reported(
+        tmp_path, monkeypatch, caplog):
+    # counter + a log line naming op, config and error; the sweep
+    # carries on with the candidates that did compile
+    from analytics_zoo_tpu.common import observability as obs
+    monkeypatch.setenv("ZOO_TPU_AUTOTUNE_CACHE",
+                       str(tmp_path / "at.json"))
+    monkeypatch.setenv("ZOO_TPU_AUTOTUNE", "1")
+    autotune.reset_cache()
+    autotune.register(_toy_spec(fail={"other"}))
+    try:
+        with caplog.at_level("WARNING", logger="analytics_zoo_tpu"):
+            cfg = autotune.decide("toy_failing_op", {"n": 1})
+        assert cfg == {"name": "heur"}
+        assert autotune.get_cache().sweeps == 1
+        failures = obs.counter(
+            "zoo_tpu_autotune_candidate_failures_total",
+            help="x").value
+        assert failures == 1
+        [line] = [r.getMessage() for r in caplog.records
+                  if "failed to compile" in r.getMessage()]
+        assert "toy_failing_op" in line
+        assert "'other'" in line
+        assert "RESOURCE_EXHAUSTED" in line
+    finally:
+        autotune._SPECS.pop("toy_failing_op", None)
+        autotune.reset_cache()
+
+
+def test_failing_heuristic_config_raises(tmp_path, monkeypatch):
+    # every call site falls back to the heuristic config: if the
+    # compiler refuses THAT one the sweep must not swallow it
+    monkeypatch.setenv("ZOO_TPU_AUTOTUNE_CACHE",
+                       str(tmp_path / "at.json"))
+    monkeypatch.setenv("ZOO_TPU_AUTOTUNE", "1")
+    autotune.reset_cache()
+    autotune.register(_toy_spec(fail={"heur"}))
+    try:
+        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+            autotune.decide("toy_failing_op", {"n": 1})
+        # and the sweep state is left usable afterwards
+        assert autotune._tls.in_sweep is False
+    finally:
+        autotune._SPECS.pop("toy_failing_op", None)
+        autotune.reset_cache()
+
+
+def test_trace_clean_asks_jax_and_never_defaults():
+    # jax 0.9.0 dropped jax.core.trace_state_clean; the replacement
+    # is asked directly — inside a trace it says so, outside it does
+    # not, and nothing answers "not clean" by default
+    import jax
+    assert autotune._trace_clean() is True
+    seen = []
+
+    @jax.jit
+    def f(x):
+        seen.append(autotune._trace_clean())
+        return x
+    f(1.0)
+    assert seen == [False]
+    assert "except" not in __import__("inspect").getsource(
+        autotune._trace_clean)
+
+
 def test_stats_block_shape(tuner):
     s = autotune.stats()
     assert set(s) == {"enabled", "cache_hits", "cache_misses",

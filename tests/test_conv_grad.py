@@ -142,7 +142,7 @@ def test_conv_bn_stride2_phase_matches_dilated(rng, monkeypatch):
 def _conv_params(jaxpr, out):
     """All conv_general_dilated eqn params, recursing into sub-
     jaxprs (scan/cond/custom_vjp bodies)."""
-    from jax import core
+    from jax.extend import core
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "conv_general_dilated":
             out.append(eqn.params)

@@ -26,18 +26,26 @@ def _gauges(reg):
 
 # -- peak resolution --------------------------------------------------------
 
-@pytest.mark.parametrize("kind,platform,expect", [
-    ("TPU v5p", "", 459e12),
-    ("TPU v5e", "", 197e12),
-    ("TPU v5 lite", "", 197e12),
-    ("TPU v4", "", 275e12),
-    ("TPU v3", "", 123e12),
-    ("cpu", "cpu", 1e11),
-    ("Golden Gate", "cpu", 1e11),       # platform fallback
-    ("Golden Gate", "", 197e12),        # unknown accelerator
+@pytest.mark.parametrize("kind,expect", [
+    ("TPU v5p", 459e12),
+    ("TPU v5e", 197e12),
+    ("TPU v5 lite", 197e12),
+    ("TPU v4", 275e12),
+    ("TPU v3", 123e12),
+    ("cpu", 1e11),
 ])
-def test_resolve_peak_flops(kind, platform, expect):
-    assert resolve_peak_flops(kind, platform) == expect
+def test_resolve_peak_flops(kind, expect):
+    assert resolve_peak_flops(kind) == expect
+
+
+@pytest.mark.parametrize("kind", ["Golden Gate", "", "TPU v9"])
+def test_unknown_device_kind_raises(kind):
+    # an unknown device is an error, never an assumed v5e
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        resolve_peak_flops(kind)
+    with pytest.raises(ValueError, match="no peak FLOP/s known"):
+        GoodputLedger(device_kind=kind,
+                      registry=obs.MetricsRegistry())
 
 
 def test_peak_env_override(monkeypatch):

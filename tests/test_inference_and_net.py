@@ -569,3 +569,35 @@ def test_inference_model_aot_path_accepts_device_arrays(rng):
     np.testing.assert_allclose(
         np.asarray(im.predict([committed])),
         np.asarray(im.predict([x])), rtol=1e-6)
+
+
+def test_executables_take_the_weights_as_an_argument(rng):
+    """A plain model's executables (the AOT one and every batch
+    bucket's) share the resident weights instead of embedding a copy
+    each: a bucket executable serializes to a fraction of the weights'
+    size, and answers exactly like the net."""
+    import jax
+    from jax.experimental import serialize_executable as se
+
+    from analytics_zoo_tpu.pipeline.api.keras import (
+        Sequential, layers as L)
+    from analytics_zoo_tpu.pipeline.inference import InferenceModel
+    m = Sequential()
+    m.add(L.Dense(1024, activation="relu", input_shape=(512,)))
+    m.add(L.Dense(8))
+    m.compile(optimizer="sgd", loss="mse")
+    x = rng.randn(4, 512).astype(np.float32)
+    im = InferenceModel()
+    im.load_keras_net(m, example_inputs=[x])
+    params = m.estimator.params
+    weight_bytes = sum(leaf.nbytes
+                       for leaf in jax.tree_util.tree_leaves(params))
+    assert weight_bytes > 2_000_000
+    want = np.asarray(m.forward(params, x, training=False))
+    np.testing.assert_allclose(im.predict([x]), want, rtol=1e-6)
+    bucket = im.lower_for(
+        [jax.ShapeDtypeStruct((2, 512), np.float32)])
+    np.testing.assert_allclose(np.asarray(bucket(x[:2])), want[:2],
+                               rtol=1e-6)
+    payload, _, _ = se.serialize(bucket.func)
+    assert len(payload) < weight_bytes / 10, len(payload)

@@ -1,0 +1,221 @@
+"""Chip compiles kept among the tests: every Pallas kernel of
+chip_smoke.py's phase 4, at its real ResNet-50-b128 / T=4096 shape,
+compiled by the TPU compiler for a *described* v5e (no chip attached,
+nothing runs). Interpret mode cannot see what this sees: slices not
+aligned to the tiling, a kernel that wants more VMEM than it may use.
+
+The one file that does this (a second file could land on another
+xdist worker, whose fixture would then skip every test): the
+topology is described inside a module-scoped fixture — never at
+import, in a ``skipif`` or in ``parametrize`` — because only one
+process at a time may hold the TPU library.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from analytics_zoo_tpu.ops import conv_bn as cb
+from analytics_zoo_tpu.ops import flash_attention as fa
+from analytics_zoo_tpu.ops import kv_cache as kvc
+from analytics_zoo_tpu.perf import autotune
+
+BF, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A described v5e device; the persistent compilation cache is off
+    around these compiles (an entry written for a described chip
+    cannot be read back without one, and warns)."""
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(autouse=True)
+def _v5e_tile_tables(monkeypatch):
+    """Tile decisions as the chip makes them: the code asks
+    `jax.devices()`, which is the CPU here, so point the autotuner at
+    the committed v5e table."""
+    monkeypatch.setattr(autotune, "_device", "v5e")
+    autotune.reset_cache()
+    yield
+    autotune.reset_cache()
+
+
+# ---------------------------------------------------------------------
+# the kernels: name -> (function, [(shape, dtype), ...])
+# ---------------------------------------------------------------------
+
+_ATT = (4, 4096, 16, 64)          # B, T, H, D
+_DEC = dict(s=8, t=4096, h=12, d=64)
+
+
+def _flash(q, k, v):
+    return fa.flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _flash_grads(q, k, v):
+    def loss(q, k, v):
+        return jnp.sum(_flash(q, k, v).astype(F32) ** 2)
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+def _flash_masked(q, k, v, mask):
+    return fa.flash_attention(q, k, v, key_mask=mask, interpret=False)
+
+
+def _flash_partial(q, k, v):
+    return fa.flash_block_partial(q, k, v, jnp.int32(0), causal=True,
+                                  scale=0.125, interpret=False)
+
+
+def _decode(q, k, v, mask, ks=None, vs=None):
+    return fa.flash_decode_attention(
+        q, k, v, mask, scale=0.125, interpret=False, k_scales=ks,
+        v_scales=vs)
+
+
+def _matmul(x, w, s, t, sh, r=None):
+    return cb.matmul_bn(x, w, in_scale=s, in_shift=t, relu_in=True,
+                        stat_shift=sh, in_residual=r, interpret=False)
+
+
+def _matmul_grads(x, w, s, t, sh):
+    def loss(x, w):
+        y, su, sq = _matmul(x, w, s, t, sh)
+        return jnp.sum(y.astype(F32)) + jnp.sum(su) + jnp.sum(sq)
+    return jax.grad(loss, argnums=(0, 1))(x, w)
+
+
+def _matmul_apply(x, w, s, t, os_):
+    return cb.matmul_bn_apply(x, w, in_scale=s, in_shift=t,
+                              relu_in=True, out_scale=os_,
+                              out_shift=os_, relu_out=True,
+                              interpret=False)
+
+
+def _conv3(x, w, s, t, sh, stride):
+    return cb.conv3x3_bn(x, w, in_scale=s, in_shift=t, relu_in=True,
+                         stat_shift=sh, stride=stride, interpret=False)
+
+
+def _conv3_apply(x, w, s, t, os_, stride):
+    return cb.conv3x3_bn_apply(x, w, in_scale=s, in_shift=t,
+                               relu_in=True, out_scale=os_,
+                               out_shift=os_, relu_out=True,
+                               stride=stride, interpret=False)
+
+
+def _mm_args(m, k, n):
+    return [((m, k), BF), ((k, n), BF), ((k,), F32), ((k,), F32),
+            ((n,), F32)]
+
+
+def _conv_args(shape, cout):
+    cin = shape[-1]
+    return [(shape, BF), ((3, 3, cin, cout), BF), ((cin,), F32),
+            ((cin,), F32), ((cout,), F32)]
+
+
+_QKV = [(_ATT, BF)] * 3
+_HALF = [((4, 2048, 16, 64), BF)] * 3
+_DQKV = [((_DEC["s"], _DEC["h"], _DEC["d"]), BF)] + \
+    [((_DEC["s"], _DEC["t"], _DEC["h"], _DEC["d"]), BF)] * 2
+_DMASK = [((_DEC["s"], _DEC["t"]), jnp.bool_)]
+_DSCALES = [((_DEC["s"], _DEC["t"], _DEC["h"]), F32)] * 2
+_M0 = (128 * 56 * 56, 64, 256)    # stage-0 c3, the longest M
+
+KERNELS = {
+    "flash_fwd": (_flash, _QKV),
+    "flash_fwd_bwd": (_flash_grads, _QKV),
+    "flash_masked_fwd": (_flash_masked, _QKV + [((4, 4096), F32)]),
+    "flash_block_partial": (_flash_partial, _HALF),
+    "flash_decode_bf16": (_decode, _DQKV + _DMASK),
+    "flash_decode_int8": (
+        _decode,
+        [_DQKV[0]] + [(_DQKV[1][0], jnp.int8)] * 2 + _DMASK + _DSCALES),
+    "matmul_bn_fwd": (_matmul, _mm_args(*_M0)),
+    "matmul_bn_bwd": (_matmul_grads, _mm_args(*_M0)),
+    "matmul_bn_bwd_stage3": (_matmul_grads,
+                             _mm_args(128 * 7 * 7, 2048, 512)),
+    "matmul_bn_in_residual": (
+        _matmul, _mm_args(128 * 56 * 56, 256, 64) +
+        [((128 * 56 * 56, 256), BF)]),
+    "matmul_bn_apply": (_matmul_apply, _mm_args(*_M0)),
+    # the two planes the v5e compiler refused (scoped VMEM 18.17M and
+    # 50.15M against 16M) before _conv3_batch_tile counted lane
+    # padding and double buffers and the kernels named their limit
+    "conv3x3_bn_56x56x64_s1": (
+        functools.partial(_conv3, stride=1),
+        _conv_args((128, 56, 56, 64), 64)),
+    "conv3x3_bn_56x56x128_s2": (
+        functools.partial(_conv3, stride=2),
+        _conv_args((128, 56, 56, 128), 128)),
+    "conv3x3_bn_14x14x256_s1": (
+        functools.partial(_conv3, stride=1),
+        _conv_args((128, 14, 14, 256), 256)),
+    "conv3x3_bn_apply_56x56x64_s1": (
+        functools.partial(_conv3_apply, stride=1),
+        _conv_args((128, 56, 56, 64), 64)),
+    "conv3x3_bn_apply_14x14x512_s2": (
+        functools.partial(_conv3_apply, stride=2),
+        _conv_args((128, 14, 14, 512), 512)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, name):
+    fn, specs = KERNELS[name]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{name}: the Pallas kernel is not in the compiled program"
+
+
+def test_int8_decode_operands_match_the_cache_codec():
+    # the int8 case above feeds (int8 rows, f32 per-(token, head)
+    # scales): exactly what the cache's own quantizer emits
+    k = jnp.ones((2, 128, 2, 64), BF)
+    q, scale = kvc.quantize_rows(k)
+    assert q.dtype == jnp.int8 and scale.dtype == F32
+    assert q.shape == k.shape and scale.shape == k.shape[:-1]
+
+
+@pytest.mark.parametrize("shape,cout,stride,tile", [
+    ((128, 56, 56, 64), 64, 1, 1),
+    ((128, 56, 56, 128), 128, 2, 1),
+    ((128, 28, 28, 128), 128, 1, 4),
+    ((128, 28, 28, 256), 256, 2, 2),
+    ((128, 14, 14, 256), 256, 1, 8),
+    ((128, 14, 14, 512), 512, 2, 2),
+    ((128, 7, 7, 512), 512, 1, 4),
+    # a plane that cannot fit even one image: the XLA reference route
+    ((128, 112, 112, 64), 64, 1, None),
+])
+def test_conv3_batch_tile_counts_padding_and_double_buffers(
+        shape, cout, stride, tile):
+    # every ResNet-50 b128 3x3 plane gets a Pallas tile under the
+    # 16 MiB budget, counted with lane/sublane padding (a 64-channel
+    # bf16 row occupies 128 lanes; 56 columns occupy 64 sublanes)
+    assert cb._conv3_batch_tile(shape, cout, 2, stride) == tile
+    assert cb._vmem_bytes((56, 56, 64), 2) == 56 * 64 * 128 * 2
+    assert cb._vmem_bytes((56, 56, 64), 4) == 56 * 56 * 128 * 4
