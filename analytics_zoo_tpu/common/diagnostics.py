@@ -43,6 +43,7 @@ from collections import deque
 from typing import Optional
 
 from analytics_zoo_tpu.common import observability as obs
+from analytics_zoo_tpu.common import tracing
 
 __all__ = [
     "anomaly",
@@ -203,6 +204,13 @@ class RecompileMonitor:
     def _listener(self, event_name: str, duration: float, **kw):
         # jax stamps e.g. ".../jax_backend_compile_duration".
         if event_name.endswith("backend_compile_duration"):
+            # one ``xla/compile`` record a compile, under the span
+            # that triggered it when there is one: a compile inside a
+            # measured window shows by name, not as a slow step
+            tracing.record_span(
+                tracing.current() or (tracing.new_trace_id(), None),
+                "xla/compile", time.time() - duration, duration,
+                expected=compiles_expected())
             self.note()
 
     def install(self) -> "RecompileMonitor":

@@ -30,10 +30,16 @@ from analytics_zoo_tpu.common.config import (
     ZooTpuConf,
     parse_axes,
 )
+from analytics_zoo_tpu.common import tracing
 from analytics_zoo_tpu.common.device import setup_compile_cache
 from analytics_zoo_tpu.version import __version__
 
 logger = logging.getLogger("analytics_zoo_tpu")
+
+# one clock: every span the program opens is also a ``zoo:<name>``
+# annotation in any profiler session (tracing stays stdlib-only, so
+# the module that imports jax hands it the factory)
+tracing.set_annotation_hook(jax.profiler.TraceAnnotation)
 
 _lock = threading.RLock()
 _current: "NNContext | None" = None

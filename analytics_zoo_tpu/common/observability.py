@@ -521,8 +521,12 @@ def _event_sink():
 
 def event(name: str, **fields):
     """Append one structured JSONL event to the ``ZOO_TPU_EVENT_LOG``
-    sink (no-op when the env var is unset). Non-JSON-able field
-    values are stringified rather than dropped."""
+    sink (no-op when the env var is unset: it returns before the
+    process-wide lock, so spans on several threads never contend for
+    a sink nobody asked for). Non-JSON-able field values are
+    stringified rather than dropped."""
+    if not os.environ.get("ZOO_TPU_EVENT_LOG"):
+        return
     with _event_lock:
         fh = _event_sink()
         if fh is None:
@@ -579,15 +583,18 @@ class Span:
     ``ZOO_TPU_EVENT_LOG`` is set. ``fields`` go to the event log only
     — never to metric labels (unbounded values like step indices must
     not explode label cardinality). ``elapsed`` holds the duration in
-    seconds after exit.
+    seconds after exit; :meth:`annotate` adds fields measured inside
+    the block.
 
     When an ambient trace is open (see
     :mod:`~analytics_zoo_tpu.common.tracing`) the span also joins it
     as a child, and the emitted event carries the trace/span ids so
-    the event log stays joinable per trace."""
+    the event log stays joinable per trace. Unless ``ZOO_TPU_TRACE=0``
+    the span is also a ``zoo:<name>`` annotation on the profiler's
+    clock (`tracing.set_annotation_hook`)."""
 
     __slots__ = ("name", "fields", "elapsed", "_t0", "_registry",
-                 "_trace_tok")
+                 "_trace_tok", "_ann")
 
     def __init__(self, name: str, registry: MetricsRegistry,
                  fields: Dict[str, Any]):
@@ -597,31 +604,41 @@ class Span:
         self._t0 = 0.0
         self._registry = registry
         self._trace_tok = None
+        self._ann = None
+
+    def annotate(self, **fields):
+        """Add fields measured inside the block (None is skipped)."""
+        for k, v in fields.items():
+            if v is not None:
+                self.fields[k] = v
 
     def __enter__(self) -> "Span":
         self._trace_tok = _tracing.span_start(self.name)
+        self._ann = _tracing.annotation_start(self.name)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.elapsed = time.perf_counter() - self._t0
+        _tracing.annotation_end(self._ann)
         metric = "zoo_tpu_" + _sanitize(self.name) + "_seconds"
         self._registry.histogram(
             metric, help=f"wall time of {self.name} spans").observe(
             self.elapsed)
-        fields = dict(self.fields)
-        fields["dur_s"] = round(self.elapsed, 6)
-        if exc_type is not None:
-            fields["error"] = exc_type.__name__
         if self._trace_tok is not None:
-            _tok, tid, sid, parent, t0_wall = self._trace_tok
             _tracing.span_end(self._trace_tok, self.name,
                               self.elapsed, self.fields)
-            fields["trace_id"] = tid
-            fields["span_id"] = sid
-            fields["parent_id"] = parent
-            fields["t_start"] = round(t0_wall, 6)
-        event(self.name, **fields)
+        if os.environ.get("ZOO_TPU_EVENT_LOG"):
+            fields = dict(self.fields)
+            fields["dur_s"] = round(self.elapsed, 6)
+            if exc_type is not None:
+                fields["error"] = exc_type.__name__
+            if self._trace_tok is not None:
+                _tok, tid, sid, parent, t0_wall = self._trace_tok
+                fields.update(trace_id=tid, span_id=sid,
+                              parent_id=parent,
+                              t_start=round(t0_wall, 6))
+            event(self.name, **fields)
         return False  # never swallow exceptions
 
 

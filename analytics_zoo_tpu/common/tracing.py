@@ -26,13 +26,26 @@ Three moving parts:
   (``ph: "X"`` complete events, one process per trace) loadable at
   https://ui.perfetto.dev.
 
+- **one clock with the profiler** — every span opened here
+  (:func:`trace`, ``observability.span``, :func:`annotate`) also
+  opens a profiler annotation named ``zoo:<span name>`` for its
+  duration, through the factory jax-importing code registers with
+  :func:`set_annotation_hook` (``jax.profiler.TraceAnnotation``;
+  `common/nncontext.py` installs it). Inside any profiler session
+  (``Estimator.set_profile``, ``/debug/profile``) the program's spans
+  then lie on the host plane of the ``.xplane.pb`` beside the
+  device's operations; with no session an annotation costs
+  nanoseconds. Already-timed :func:`record_span` records stay
+  store-only.
+
 ``ZOO_TPU_TRACE=0`` disables the whole layer: :func:`span_start`
-returns ``None`` before touching the context var and :func:`trace`
-yields a no-op handle, so the serving hot path pays nothing.
+returns ``None`` before touching the context var, :func:`trace`
+yields a no-op handle and no annotation is opened, so the serving hot
+path pays nothing.
 
 Stdlib-only on purpose (observability imports *us*, never the other
-way around); event-log integration is inverted through
-:func:`set_event_hook`.
+way around); event-log and profiler integration are inverted through
+:func:`set_event_hook` and :func:`set_annotation_hook`.
 """
 
 from __future__ import annotations
@@ -66,6 +79,11 @@ __all__ = [
     "chrome_events",
     "to_chrome_trace",
     "set_event_hook",
+    "ANNOTATION_PREFIX",
+    "set_annotation_hook",
+    "annotation_start",
+    "annotation_end",
+    "annotate",
 ]
 
 # HTTP header carrying the trace id across the serving front door.
@@ -262,6 +280,56 @@ def set_event_hook(hook):
     _event_hook = hook
 
 
+# Every profiler annotation the program opens carries this prefix, so
+# a trace reduction can tell the program's spans from the runtime's.
+ANNOTATION_PREFIX = "zoo:"
+
+# jax-importing code registers ``jax.profiler.TraceAnnotation`` here
+# (`common/nncontext.py`); None = spans open no annotation.
+_annotation_hook = None
+
+
+def set_annotation_hook(factory):
+    """``factory(name)`` returns a context manager that marks the
+    span on the profiler's clock (``jax.profiler.TraceAnnotation``)."""
+    global _annotation_hook
+    _annotation_hook = factory
+
+
+def annotation_start(name: str):
+    """Open the profiler annotation ``zoo:<name>``; returns the handle
+    for :func:`annotation_end`, or None (disabled, no hook)."""
+    hook = _annotation_hook
+    if hook is None or not enabled():
+        return None
+    try:
+        ann = hook(ANNOTATION_PREFIX + name)
+        ann.__enter__()
+        return ann
+    except Exception:
+        return None  # telemetry must never take down the traced path
+
+
+def annotation_end(ann):
+    """Close what :func:`annotation_start` returned (None is fine)."""
+    if ann is not None:
+        try:
+            ann.__exit__(None, None, None)
+        except Exception:
+            pass
+
+
+@contextmanager
+def annotate(name: str):
+    """Annotation only — no store record, no histogram: for a wait
+    whose duration a field of another span already carries."""
+    ann = annotation_start(name)
+    try:
+        yield
+    finally:
+        annotation_end(ann)
+
+
 def _emit(rec: SpanRecord):
     hook = _event_hook
     if hook is None:
@@ -308,15 +376,17 @@ def trace(name: str = "trace", trace_id: Optional[str] = None,
     tid = sanitize_trace_id(trace_id) or new_trace_id()
     sid = _new_span_id()
     tok = _ctx.set((tid, sid))
+    ann = annotation_start(name)
     t0_wall = time.time()
     t0 = time.perf_counter()
     handle = Trace(tid, sid, dict(fields))
     try:
         yield handle
     finally:
+        dur_s = time.perf_counter() - t0
+        annotation_end(ann)
         _ctx.reset(tok)
-        rec = SpanRecord(tid, sid, None, name, t0_wall,
-                         time.perf_counter() - t0,
+        rec = SpanRecord(tid, sid, None, name, t0_wall, dur_s,
                          threading.current_thread().name,
                          handle.fields)
         _STORE.add(rec)

@@ -81,6 +81,7 @@ def decode_flash_profitable(tk: int) -> bool:
                                 {"tk": tk})["use_flash"])
 
 
+@jax.named_scope("zoo:decode/attention")
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      seq_lens: jnp.ndarray,
                      scale: Optional[float] = None,
@@ -142,6 +143,7 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("sht,sthd->shd", probs, v)
 
 
+@jax.named_scope("zoo:decode/chunk_attention")
 def chunk_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     q_positions: jnp.ndarray,
                     scale: Optional[float] = None,

@@ -195,6 +195,7 @@ def _matmul_bn_fwd_pallas(x, w, s, t, sh, r, relu_in, affine_in,
         operands.append(r)
     y, ssum, ssq = pl.pallas_call(
         kernel,
+        name="zoo_matmul_bn_fwd",
         grid=(n_m, n_k),
         in_specs=in_specs,
         out_specs=[
@@ -516,6 +517,7 @@ def _bwd_pallas(x, w, s, t, sh, y, dy, dsum, dsq, relu_in, affine_in,
                           out_dtype=jnp.dtype(x.dtype),
                           res_dtype=jnp.dtype(r.dtype) if has_res
                           else None),
+        name="zoo_matmul_bn_bwd_dx",
         grid=(n_m,),
         in_specs=dx_specs,
         out_specs=dx_out_specs,
@@ -547,6 +549,7 @@ def _bwd_pallas(x, w, s, t, sh, y, dy, dsum, dsq, relu_in, affine_in,
     dw = pl.pallas_call(
         functools.partial(_dw_kernel, n_m=n_m, relu_in=relu_in,
                           affine_in=affine_in, has_res=has_res),
+        name="zoo_matmul_bn_bwd_dw",
         grid=(n // bn_w, n_m),
         in_specs=dw_specs,
         out_specs=pl.BlockSpec((k, bn_w), lambda ni, mi: (0, ni)),
@@ -741,6 +744,7 @@ def _matmul_apply(x, w, s, t, os_, ot, res, relu_in, affine_in,
         operands.append(res)
     y = pl.pallas_call(
         kernel,
+        name="zoo_matmul_apply",
         grid=(n_m, n_k),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, n), lambda mi, ki: (mi, 0)),
@@ -1001,6 +1005,7 @@ def _conv3_apply(x, w, s, t, os_, ot, relu_in, affine_in, relu_out,
         functools.partial(_conv3_apply_kernel, relu_in=relu_in,
                           affine_in=affine_in, relu_out=relu_out,
                           out_dtype=jnp.dtype(x.dtype), stride=stride),
+        name="zoo_conv3x3_apply",
         grid=(b // bb,),
         in_specs=[
             pl.BlockSpec((bb, h, wd, cin), lambda bi: (bi, 0, 0, 0)),
@@ -1154,6 +1159,7 @@ def _conv3_fwd_pallas(x, w, s, t, sh, relu_in, affine_in, stride,
                           affine_in=affine_in,
                           out_dtype=jnp.dtype(x.dtype),
                           stride=stride),
+        name="zoo_conv3x3_bn_fwd",
         grid=(b // bb,),
         in_specs=[
             pl.BlockSpec((bb, h, wd, cin), lambda bi: (bi, 0, 0, 0)),

@@ -180,6 +180,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, interpret,
         args.append(_kmask8(key_mask, tk))
     return pl.pallas_call(
         kernel,
+        name="zoo_flash_fwd",
         grid=(b, h, nq, nk),
         in_specs=in_specs,
         out_specs=blk(block_q, lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -418,6 +419,7 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, g):
         functools.partial(
             _bwd_dkdv_kernel_masked if masked else _bwd_dkdv_kernel,
             **common),
+        name="zoo_flash_bwd_dkdv",
         grid=(b, h, tk // block_k, tq // block_q),
         in_specs=in_specs_kv,
         out_specs=[
@@ -452,6 +454,7 @@ def _flash_vjp_bwd(scale, causal, block_q, block_k, interpret, res, g):
         functools.partial(
             _bwd_dq_kernel_masked if masked else _bwd_dq_kernel,
             **common),
+        name="zoo_flash_bwd_dq",
         grid=(b, h, tq // block_q, tk // block_k),
         in_specs=in_specs_q,
         out_specs=blk(block_q, lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
@@ -539,6 +542,7 @@ def _block_partials(qt, kt, vt, qk_offset, causal, scale,
         args.append(_kmask8(key_mask, tk))
     acc, m, l = pl.pallas_call(
         kernel,
+        name="zoo_flash_block_partial",
         grid=(b, h, tq // block_q, tk // block_k),
         in_specs=in_specs,
         out_specs=[
@@ -650,6 +654,7 @@ def flash_decode_attention(q: jnp.ndarray, k: jnp.ndarray,
     blk = lambda bs, im: pl.BlockSpec((1, 1, bs, d), im)
     out = pl.pallas_call(
         kernel,
+        name="zoo_flash_decode",
         grid=(s, h, 1, t // bk),
         in_specs=[
             blk(8, lambda bi, hi, qi, ki: (bi, hi, 0, 0)),

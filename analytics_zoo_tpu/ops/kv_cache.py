@@ -49,6 +49,7 @@ from __future__ import annotations
 import base64
 from typing import NamedTuple, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -178,6 +179,7 @@ def _quantize_for(pages, x):
     return x.astype(pages.dtype), None
 
 
+@jax.named_scope("zoo:kv_cache/append")
 def append_layer(k_pages, v_pages, page_table, seq_lens,
                  k_new, v_new, active=None,
                  k_scales=None, v_scales=None):
@@ -210,6 +212,7 @@ def append_layer(k_pages, v_pages, page_table, seq_lens,
     return k_pages, v_pages, k_scales, v_scales
 
 
+@jax.named_scope("zoo:kv_cache/write_prompt")
 def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
                        k_seq, v_seq, start=None,
                        k_scales=None, v_scales=None):
@@ -249,6 +252,7 @@ def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
     return k_pages, v_pages, k_scales, v_scales
 
 
+@jax.named_scope("zoo:kv_cache/gather")
 def gather_layer(pages, page_table, t_max: int):
     """Page-table gather back to a dense (S, t_max, H, D) view of one
     layer's cache (positions past a slot's ``seq_len`` hold stale/zero

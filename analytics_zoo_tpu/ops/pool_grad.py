@@ -41,6 +41,7 @@ def mask_bwd_enabled() -> bool:
     return os.environ.get("ZOO_TPU_MAXPOOL_MASK_BWD") != "0"
 
 
+@jax.named_scope("zoo:pool/maxpool")
 def _reduce_max(x, window, strides, pads4):
     init = jnp.array(-jnp.inf, x.dtype)
     return jax.lax.reduce_window(
@@ -59,6 +60,7 @@ def _maxpool2d_fwd(x, window, strides, pads):
     return y, (x, y)
 
 
+@jax.named_scope("zoo:pool/maxpool_bwd")
 def _maxpool2d_bwd(window, strides, pads, res, g):
     x, y = res
     invocations["bwd_mask"] += 1

@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("zoo:decode/sampling")
 def sample_tokens(rng, logits, temperature, top_k: int = 0):
     """Next-token ids for a batch of slots.
 

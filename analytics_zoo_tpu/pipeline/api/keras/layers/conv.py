@@ -104,6 +104,7 @@ class _ConvND(KerasLayer):
             params["bias"] = jnp.zeros((self.nb_filter,), jnp.float32)
         return params
 
+    @jax.named_scope("zoo:conv/convolve")
     def _convolve(self, x, kernel):
         # strided NHWC 2-D convs route through ops.conv_grad.conv2d:
         # same forward, but the backward is gated between jax's

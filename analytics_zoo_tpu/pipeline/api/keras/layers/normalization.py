@@ -101,25 +101,28 @@ class BatchNormalization(KerasLayer):
             # single pass over x: both reductions fuse into one
             # multi-output kernel reading x once (profiling showed BN
             # reductions, not convs, dominate the ResNet-50 step)
-            shift0 = self._reshape_stat(
-                jax.lax.stop_gradient(state["moving_mean"]), x)
-            xf = x.astype(jnp.float32) - shift0
-            count = float(np.prod([x.shape[a] for a in reduce_axes]))
-            mean, var, updates = bn_batch_stats(
-                jnp.sum(xf, axis=reduce_axes),
-                jnp.sum(jnp.square(xf), axis=reduce_axes),
-                count, state, self.momentum)
+            with jax.named_scope("zoo:bn/stats"):
+                shift0 = self._reshape_stat(
+                    jax.lax.stop_gradient(state["moving_mean"]), x)
+                xf = x.astype(jnp.float32) - shift0
+                count = float(np.prod([x.shape[a]
+                                       for a in reduce_axes]))
+                mean, var, updates = bn_batch_stats(
+                    jnp.sum(xf, axis=reduce_axes),
+                    jnp.sum(jnp.square(xf), axis=reduce_axes),
+                    count, state, self.momentum)
         else:
             mean, var = state["moving_mean"], state["moving_var"]
             updates = {}
         # fold (x-mean)*inv*gamma+beta into one per-element FMA: the
         # per-channel scale/shift vectors are computed in f32 off the
         # hot path, so the activation tensor is read once, written once
-        scale, shift = bn_fold(
-            mean, var, params["gamma"] if self.scale else None,
-            params["beta"] if self.center else None, self.epsilon)
-        y = x * self._reshape_stat(scale, x).astype(x.dtype) + \
-            self._reshape_stat(shift, x).astype(x.dtype)
+        with jax.named_scope("zoo:bn/apply"):
+            scale, shift = bn_fold(
+                mean, var, params["gamma"] if self.scale else None,
+                params["beta"] if self.center else None, self.epsilon)
+            y = x * self._reshape_stat(scale, x).astype(x.dtype) + \
+                self._reshape_stat(shift, x).astype(x.dtype)
         return y, updates
 
     def call(self, params, x, *, training=False, rng=None):

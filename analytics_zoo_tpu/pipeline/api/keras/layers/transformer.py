@@ -455,10 +455,12 @@ class TransformerLayer(KerasLayer):
         h0 = self._embed(params, token_ids)
         causal = not self.bidirectional
 
+        @jax.named_scope("zoo:prefill/layer")
         def block(x, p):
             q, k, v = self._split_qkv(p, x)
-            attn = dot_product_attention(q, k, v, causal=causal,
-                                         impl=self.attention_impl)
+            with jax.named_scope("zoo:prefill/attention"):
+                attn = dot_product_attention(
+                    q, k, v, causal=causal, impl=self.attention_impl)
             attn = attn.reshape(s, t, self.hidden_size)
             return self._block_tail(p, x, attn), (k, v)
 
@@ -469,8 +471,10 @@ class TransformerLayer(KerasLayer):
         cache = cache._replace(
             seq_lens=jnp.where(prompt_lens > 0, prompt_lens,
                                cache.seq_lens))
-        last = final[jnp.arange(s), jnp.maximum(prompt_lens - 1, 0)]
-        logits = last @ params["tok_embed"].astype(last.dtype).T
+        with jax.named_scope("zoo:prefill/lm_head"):
+            last = final[jnp.arange(s),
+                         jnp.maximum(prompt_lens - 1, 0)]
+            logits = last @ params["tok_embed"].astype(last.dtype).T
         return cache, logits
 
     def _write_prompt_all(self, cache, k_all, v_all, total_lens,
@@ -522,6 +526,7 @@ class TransformerLayer(KerasLayer):
         seq_lens = cache.seq_lens
         lens_after = seq_lens + active.astype(jnp.int32)
 
+        @jax.named_scope("zoo:decode/layer")
         def block(x, xs):
             p, kp, vp, ks, vs = xs
             q, k_new, v_new = self._split_qkv(p, x)
@@ -554,7 +559,8 @@ class TransformerLayer(KerasLayer):
         cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
                                k_scales=k_scales, v_scales=v_scales,
                                seq_lens=lens_after)
-        logits = final @ params["tok_embed"].astype(final.dtype).T
+        with jax.named_scope("zoo:decode/lm_head"):
+            logits = final @ params["tok_embed"].astype(final.dtype).T
         return cache, logits
 
     def forward_chunk(self, params, cache, token_ids, starts, n_new,
@@ -597,6 +603,7 @@ class TransformerLayer(KerasLayer):
         t_max = cache.max_context
         table = cache.page_table
 
+        @jax.named_scope("zoo:prefill/chunk_layer")
         def block(x, xs):
             p, kp, vp, ks, vs = xs
             q, k_new, v_new = self._split_qkv(p, x)

@@ -334,10 +334,67 @@ def _timed_iter(it):
     while True:
         t0 = time.perf_counter()
         try:
-            item = next(it)
+            # annotation only: train/step's data_wait_s carries it
+            with tracing.annotate("train/data_wait"):
+                item = next(it)
         except StopIteration:
             return
         yield time.perf_counter() - t0, item
+
+
+def _traced_gather(it):
+    """The train input path's first half, under its own name: each
+    ``next()`` on the dataset's batch iterator (the fancy-index
+    gather) is one ``train/input_gather`` span. Runs wherever the
+    iterator is pulled — the prefetch worker. Yields ``(trace_id,
+    batch)``: the batch's trace id is minted here and travels with it
+    through `_traced_place` and the queue to the consumer's
+    ``train/step``, so one batch reads gather -> place -> step."""
+    it = iter(it)
+    done = object()
+    while True:
+        t0_wall, t0 = time.time(), time.perf_counter()
+        with tracing.annotate("train/input_gather"):
+            batch = next(it, done)
+        dur_s = time.perf_counter() - t0
+        if batch is done:
+            return
+        tid = tracing.new_trace_id()
+        tracing.record_span((tid, None), "train/input_gather",
+                            t0_wall, dur_s,
+                            rows=_batch_dim(batch[0]),
+                            bytes=_nbytes(batch))
+        yield tid, batch
+
+
+def _traced_place(place):
+    """``place`` (relayout + ``device_put``) as the batch's
+    ``train/input_place`` span; takes and returns ``(trace_id, …)``."""
+    def traced(item):
+        tid, batch = item
+        with tracing.trace("train/input_place", trace_id=tid,
+                           bytes=_nbytes(batch)):
+            return tid, place(batch)
+    return traced
+
+
+class _EpochTurn:
+    """``train/epoch_turn``: from an epoch's last dispatch to the next
+    epoch's first batch being in hand (or the run's end) — the
+    prefetch worker's shutdown, the losses' ``device_get`` (``fetch_s``),
+    gauges and summaries, the new worker's first gather and place."""
+
+    def __init__(self, epoch: int):
+        self.fields = {"epoch": epoch}
+        self._ann = tracing.annotation_start("train/epoch_turn")
+        self._t0_wall, self._t0 = time.time(), time.perf_counter()
+
+    def close(self):
+        tracing.annotation_end(self._ann)
+        tracing.record_span((tracing.new_trace_id(), None),
+                            "train/epoch_turn", self._t0_wall,
+                            time.perf_counter() - self._t0,
+                            **self.fields)
 
 
 def _prefetch_depth() -> int:
@@ -674,16 +731,19 @@ class Estimator:
 
             def compute_loss(p):
                 out, state_upd = model.apply(p, x, training=True, rng=rng)
-                if mixed:  # loss in f32 for numeric stability
-                    out = _cast_floats(out, jnp.float32)
-                loss = _apply_loss(loss_fn, y, out)
-                loss = loss + model.regularization_loss(p)
+                with jax.named_scope("zoo:train/loss"):
+                    if mixed:  # loss in f32 for numeric stability
+                        out = _cast_floats(out, jnp.float32)
+                    loss = _apply_loss(loss_fn, y, out)
+                    loss = loss + model.regularization_loss(p)
                 return loss, state_upd
 
             (loss, state_upd), grads = jax.value_and_grad(
                 compute_loss, has_aux=True)(params)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("zoo:train/optimizer"):
+                updates, opt_state = tx.update(grads, opt_state,
+                                               params)
+                params = optax.apply_updates(params, updates)
             if state_upd:
                 params = Estimator._merge_updates(params, state_upd)
             return params, opt_state, loss
@@ -793,10 +853,11 @@ class Estimator:
         decomposition live."""
         try:
             from analytics_zoo_tpu.perf import flops as flops_lib
-            lowered = self._train_step.lower(
-                self.params, self.opt_state, rng, xb, yb)
-            return flops_lib.executed_flops(
-                flops_lib.hlo_text(lowered))
+            with obs.span("train/flops_lowering"):
+                lowered = self._train_step.lower(
+                    self.params, self.opt_state, rng, xb, yb)
+                return flops_lib.executed_flops(
+                    flops_lib.hlo_text(lowered))
         except Exception:
             return None
 
@@ -840,11 +901,7 @@ class Estimator:
         # each env-gated (ZOO_TPU_SLO / ZOO_TPU_GOODPUT)
         slo_lib.ensure_default_slos("training")
         ledger = goodput_lib.ledger_for_backend()
-        # ZOO_TPU_TRACE_SYNC=1 adds a block_until_ready per step so
-        # step traces carry true device time — a per-step sync, so
-        # opt-in (it caps dispatch pipelining)
-        trace_sync = os.environ.get(
-            "ZOO_TPU_TRACE_SYNC", "0") == "1"
+        turn: "Optional[_EpochTurn]" = None
 
         try:
             for epoch in range(1, nb_epoch + 1):
@@ -865,18 +922,23 @@ class Estimator:
                 # otherwise pin depth+1 device-resident batches
                 # (notebook OOM-retry trap)
                 batches = _prefetch_iter(
-                    ds.iter_batches(batch_size, shuffle=True,
-                                    seed=epoch),
-                    _place, _prefetch_depth())
+                    _traced_gather(ds.iter_batches(
+                        batch_size, shuffle=True, seed=epoch)),
+                    _traced_place(_place), _prefetch_depth())
                 ep_span = obs.span("train/epoch", epoch=epoch,
                                    step=self.step)
                 with ep_span:
                     try:
                         t_prev = time.perf_counter()
                         t_led_prev = t_prev
-                        for wait_s, (xb, yb) in _timed_iter(batches):
+                        for wait_s, (tid, (xb, yb)) in \
+                                _timed_iter(batches):
+                            if turn is not None:
+                                turn.close()
+                                turn = None
                             with tracing.trace(
-                                      "train/step", step=self.step + 1,
+                                      "train/step", trace_id=tid,
+                                      step=self.step + 1,
                                       epoch=epoch) as tr:
                                 rng = jax.random.fold_in(base_rng,
                                                          self.step)
@@ -898,12 +960,6 @@ class Estimator:
                                 dispatch_s = (time.perf_counter()
                                               - t_disp)
                                 self.step += 1
-                                device_s = None
-                                if trace_sync:
-                                    t_dev = time.perf_counter()
-                                    jax.block_until_ready(loss)
-                                    device_s = (time.perf_counter()
-                                                - t_dev)
                                 if first_step:
                                     # includes XLA compile when this call
                                     # traced a fresh step fn; the one-time
@@ -958,7 +1014,6 @@ class Estimator:
                                 tr.annotate(
                                     data_wait_s=round(wait_s, 6),
                                     dispatch_s=round(dispatch_s, 6),
-                                    device_s=device_s,
                                     checkpoint_s=ckpt_s)
                                 if ledger is not None:
                                     # ledger wall is iteration-to-
@@ -975,6 +1030,7 @@ class Estimator:
                                         epoch - 1, self.step, False):
                                     stop = True
                                     break
+                        turn = _EpochTurn(epoch)
                     finally:
                         # break/exception must stop the worker thread
                         # NOW, not at GC — a retained traceback would
@@ -982,10 +1038,13 @@ class Estimator:
                         # batches (notebook OOM-retry trap)
                         batches.close()
 
+                    t_fetch = time.perf_counter()
                     losses_np = ([float(v) for v in
                                   jax.device_get(
                                       [v for _, v in pending])]
                                  if pending else [])
+                    turn.fields["fetch_s"] = round(
+                        time.perf_counter() - t_fetch, 6)
                 dt = max(ep_span.elapsed, 1e-9)
                 if tb is not None:
                     for (s, _), lf in zip(pending, losses_np):
@@ -1051,6 +1110,8 @@ class Estimator:
                                      if k.startswith("val_")})):
                     break
         finally:
+            if turn is not None:  # the run's last epoch, or an error
+                turn.close()
             if self._profiling:  # run ended inside the trace window
                 jax.profiler.stop_trace()
                 self._profiling = False
@@ -1396,6 +1457,11 @@ def _accepts_mask(metric) -> bool:
         return "mask" in inspect.signature(metric.batch_stats).parameters
     except (TypeError, ValueError):
         return False
+
+
+def _nbytes(tree) -> int:
+    return sum(int(getattr(a, "nbytes", 0))
+               for a in jax.tree_util.tree_leaves(tree))
 
 
 def _batch_dim(x) -> int:

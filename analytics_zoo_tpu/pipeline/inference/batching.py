@@ -691,11 +691,15 @@ class ContinuousBatcher:
     (`GenerationEngine.can_admit`), so an admitted sequence always
     runs to completion.
 
-    Telemetry: `decode/admit` / `decode/step` / `decode/retire` spans
-    (the PR 5 trace vocabulary), slot-occupancy + free-page gauges,
-    a tokens counter and a time-to-first-token histogram
-    (docs/observability.md). ``ZOO_TPU_GEN_QUEUE_DEPTH`` bounds the
-    wait queue (default 64; full → :class:`QueueFullError` → 503),
+    Telemetry (docs/observability.md): one `decode/iteration` trace a
+    pass of the loop, whose children `decode/prefill`, `decode/step`
+    and `decode/release` time the engine calls; per request, the
+    already-timed `decode/queue_wait`, `decode/admit` (submit to
+    first token) and `decode/retire` (submit to last) records under
+    the request's own trace; slot-occupancy + free-page gauges, a
+    tokens counter and a time-to-first-token histogram.
+    ``ZOO_TPU_GEN_QUEUE_DEPTH`` bounds the wait queue (default 64;
+    full → :class:`QueueFullError` → 503),
     ``ZOO_TPU_GEN_MAX_NEW`` caps any request's decode budget
     (default 256).
     """
@@ -917,7 +921,7 @@ class ContinuousBatcher:
 
     # -- the decode loop ----------------------------------------------------
     def _finish(self, e: "_GenEntry", now: float):
-        with obs.span("decode/retire", slot=e.slot,
+        with obs.span("decode/release", slot=e.slot,
                       tokens=len(e.tokens)):
             self.engine.release(e.slot)
         dur = now - e.t_enq
@@ -930,7 +934,7 @@ class ContinuousBatcher:
         """Prefill-side retirement: export the slot's cache state
         (which reclaims its pages immediately) and resolve the future
         with the blob. The entry never joins the decode set."""
-        with obs.span("decode/handoff_export", slot=e.slot):
+        with obs.span("decode/page_export", slot=e.slot):
             blob = self.engine.export_handoff(e.slot)
         obs.counter(
             "zoo_tpu_serving_gen_handoffs_total",
@@ -952,7 +956,7 @@ class ContinuousBatcher:
         engine = self.engine
         for e in entries:
             try:
-                with obs.span("decode/handoff_admit"):
+                with obs.span("decode/page_import"):
                     slot = engine.admit_from_handoff(e.blob,
                                                      e.max_new)
             except Exception as exc:
@@ -1035,8 +1039,6 @@ class ContinuousBatcher:
 
     def _run(self):
         engine = self.engine
-        chunked = getattr(engine, "prefill_chunk", 0) > 0
-        spec_k = int(getattr(engine, "spec_k", 0))
         while True:
             with self._cond:
                 while not self._q and not self._active \
@@ -1046,179 +1048,210 @@ class ContinuousBatcher:
                     return
                 fresh = ([] if self._draining
                          else self._admit_locked_pop())
-            try:
-                now = time.monotonic()
-                done: "list[_GenEntry]" = []
-
-                def chunk_step():
-                    # advance every mid-prefill slot by one chunk
-                    # and emit first tokens for prompts whose final
-                    # chunk just landed
-                    with obs.span(
-                            "decode/prefill_chunk",
-                            n=len(engine.prefilling_slots)):
-                        firsts = engine.prefill_step()
-                    t = time.monotonic()
-                    obs.counter(
-                        "zoo_tpu_serving_gen_prefill_chunks_total",
-                        help="prompt chunks written by chunked "
-                             "prefill").inc()
-                    if firsts:
-                        by_slot = {e.slot: e
-                                   for e in self._active}
-                        for slot, tok in firsts:
-                            e = by_slot[slot]
-                            e.prefilling = False
-                            if e.handoff == "out":
-                                self._token_out(e, tok, t)
-                                self._active.remove(e)
-                                self._finish_handoff_out(e, t)
-                            elif self._token_out(e, tok, t):
-                                done.append(e)
-                                self._active.remove(e)
-                if fresh:
-                    hand_in = [e for e in fresh
-                               if e.handoff == "in"]
-                    if hand_in:
-                        fresh = [e for e in fresh
-                                 if e.handoff != "in"]
-                        self._admit_handoffs(hand_in, done)
-                if fresh:
-                    # chunked admission only pays off past one
-                    # chunk: a prompt that fits in a single chunk
-                    # would run the full-width chunk program padded,
-                    # where the classic bucket-padded prefill runs
-                    # one right-sized call — so short prompts keep
-                    # the direct path even when chunking is on
-                    long_p = [e for e in fresh if chunked
-                              and len(e.ids) > engine.prefill_chunk]
-                    short_p = [e for e in fresh if e not in long_p]
-                    if long_p:
-                        # claim slots + pages only; the prompt is
-                        # written chunk-by-chunk below, interleaved
-                        # with decode steps of resident slots
-                        reqs = [(e.ids, e.max_new, e.temperature)
-                                for e in long_p]
-                        with obs.span("decode/admit",
-                                      n=len(long_p)):
-                            slots = engine.admit_partial(reqs)
-                        now = time.monotonic()
-                        for e, slot in zip(long_p, slots):
-                            e.slot = slot
-                            e.prefilling = True
-                            tracing.record_span(
-                                e.trace, "decode/admit",
-                                e.t_enq_wall, now - e.t_enq,
-                                slot=slot, prompt_len=len(e.ids))
-                            self._active.append(e)
-                        # kickoff: land the fresh prompts' first
-                        # chunk in the iteration that admitted them
-                        # rather than waiting a full loop pass —
-                        # one bounded extra chunk call, mirroring
-                        # how short prompts prefill inline at admit
-                        chunk_step()
-                    if short_p:
-                        reqs = [(e.ids, e.max_new, e.temperature)
-                                for e in short_p]
-                        with obs.span("decode/admit",
-                                      n=len(short_p)):
-                            first = engine.admit(reqs)
-                        now = time.monotonic()
-                        for e, (slot, tok) in zip(short_p, first):
-                            e.slot = slot
-                            tracing.record_span(
-                                e.trace, "decode/admit",
-                                e.t_enq_wall, now - e.t_enq,
-                                slot=slot, prompt_len=len(e.ids))
-                            if e.handoff == "out":
-                                self._token_out(e, tok, now)
-                                self._finish_handoff_out(e, now)
-                            elif self._token_out(e, tok, now):
-                                done.append(e)
-                            else:
-                                self._active.append(e)
-                if chunked and engine.prefilling_slots:
-                    chunk_step()
-                    now = time.monotonic()
-                spec: "list[_GenEntry]" = []
-                regular: "list[_GenEntry]" = []
-                for e in self._active:
-                    if e.prefilling:
-                        continue
-                    if spec_k > 0 and self._spec_eligible(e):
-                        spec.append(e)
-                    else:
-                        regular.append(e)
-                emitted = 0
-                if spec:
-                    active = np.zeros((engine.max_slots,),
-                                      np.bool_)
-                    for e in spec:
-                        active[e.slot] = True
-                    prev_acc = engine.spec_accepted
-                    with obs.span("decode/spec_step",
-                                  n=len(spec)):
-                        out, n_emit = engine.spec_step(active)
-                    now = time.monotonic()
-                    obs.counter(
-                        "zoo_tpu_serving_gen_spec_proposed_total",
-                        help="draft tokens proposed for "
-                             "verification").inc(
-                        spec_k * len(spec))
-                    obs.counter(
-                        "zoo_tpu_serving_gen_spec_accepted_total",
-                        help="draft tokens accepted by the "
-                             "target model").inc(
-                        engine.spec_accepted - prev_acc)
-                    for e in spec:
-                        fin = False
-                        for j in range(int(n_emit[e.slot])):
-                            emitted += 1
-                            if self._token_out(
-                                    e, int(out[e.slot, j]), now):
-                                fin = True
-                                break
-                        if fin:
-                            done.append(e)
-                            self._active.remove(e)
-                if regular:
-                    active = np.zeros((engine.max_slots,),
-                                      np.bool_)
-                    for e in regular:
-                        active[e.slot] = True
-                    with obs.span("decode/step",
-                                  n=len(regular)):
-                        toks = engine.step(active)
-                    now = time.monotonic()
-                    for e in regular:
-                        emitted += 1
-                        if self._token_out(e, int(toks[e.slot]),
-                                           now):
-                            done.append(e)
-                            self._active.remove(e)
-                if spec or regular:
-                    obs.counter(
-                        "zoo_tpu_serving_gen_tokens_total",
-                        help="tokens generated").inc(emitted)
-                    obs.counter(
-                        "zoo_tpu_serving_gen_steps_total",
-                        help="decode iterations executed").inc()
-                for e in done:
-                    self._finish(e, now)
-            except Exception as exc:
-                # a device/step failure must fail its requests, not
-                # the loop thread; slots are reclaimed so the batch
-                # keeps serving whoever comes next
-                failing = {id(e): e
-                           for e in fresh + self._active}
-                for e in failing.values():
-                    if e.slot >= 0:
-                        engine.release(e.slot)
-                    _fail_entry(e, exc)
-                self._active = []
-                logger.warning("generation batcher error: %s", exc)
+            # one pass of the loop body (never the idle wait above)
+            # is one trace: the engine calls below are its children
+            with tracing.trace("decode/iteration") as it:
+                try:
+                    self._iterate(fresh, it)
+                except Exception as exc:
+                    # a device/step failure must fail its requests,
+                    # not the loop thread; slots are reclaimed so the
+                    # batch keeps serving whoever comes next
+                    failing = {id(e): e
+                               for e in fresh + self._active}
+                    for e in failing.values():
+                        if e.slot >= 0:
+                            engine.release(e.slot)
+                        _fail_entry(e, exc)
+                    self._active = []
+                    logger.warning("generation batcher error: %s",
+                                   exc)
             self._slots_gauge().set(engine.slots_active)
             self._pages_gauge().set(engine.free_pages)
+
+    def _prefill_span(self, entries, bucket: int):
+        """The ``decode/prefill`` span round one admission call;
+        ``bucket``: the padded length its program runs at."""
+        return obs.span(
+            "decode/prefill", n=len(entries), bucket=bucket,
+            prompt_tokens=sum(len(e.ids) for e in entries))
+
+    def _iterate(self, fresh: "list[_GenEntry]", it):
+        """One pass: admit ``fresh``, advance chunked prefills, step
+        the resident slots, retire what finished."""
+        engine = self.engine
+        chunked = getattr(engine, "prefill_chunk", 0) > 0
+        spec_k = int(getattr(engine, "spec_k", 0))
+        now = time.monotonic()
+        done: "list[_GenEntry]" = []
+        for e in fresh:
+            tracing.record_span(e.trace, "decode/queue_wait",
+                                e.t_enq_wall, now - e.t_enq)
+
+        def chunk_step():
+            # advance every mid-prefill slot by one chunk
+            # and emit first tokens for prompts whose final
+            # chunk just landed
+            with obs.span(
+                    "decode/prefill_chunk",
+                    n=len(engine.prefilling_slots)):
+                firsts = engine.prefill_step()
+            t = time.monotonic()
+            obs.counter(
+                "zoo_tpu_serving_gen_prefill_chunks_total",
+                help="prompt chunks written by chunked "
+                     "prefill").inc()
+            if firsts:
+                by_slot = {e.slot: e
+                           for e in self._active}
+                for slot, tok in firsts:
+                    e = by_slot[slot]
+                    e.prefilling = False
+                    if e.handoff == "out":
+                        self._token_out(e, tok, t)
+                        self._active.remove(e)
+                        self._finish_handoff_out(e, t)
+                    elif self._token_out(e, tok, t):
+                        done.append(e)
+                        self._active.remove(e)
+        admitted = len(fresh)
+        if fresh:
+            hand_in = [e for e in fresh
+                       if e.handoff == "in"]
+            if hand_in:
+                fresh = [e for e in fresh
+                         if e.handoff != "in"]
+                self._admit_handoffs(hand_in, done)
+        if fresh:
+            # chunked admission only pays off past one
+            # chunk: a prompt that fits in a single chunk
+            # would run the full-width chunk program padded,
+            # where the classic bucket-padded prefill runs
+            # one right-sized call — so short prompts keep
+            # the direct path even when chunking is on
+            long_p = [e for e in fresh if chunked
+                      and len(e.ids) > engine.prefill_chunk]
+            short_p = [e for e in fresh if e not in long_p]
+            if long_p:
+                # claim slots + pages only; the prompt is
+                # written chunk-by-chunk below, interleaved
+                # with decode steps of resident slots
+                reqs = [(e.ids, e.max_new, e.temperature)
+                        for e in long_p]
+                with self._prefill_span(long_p,
+                                        engine.prefill_chunk):
+                    slots = engine.admit_partial(reqs)
+                now = time.monotonic()
+                for e, slot in zip(long_p, slots):
+                    e.slot = slot
+                    e.prefilling = True
+                    tracing.record_span(
+                        e.trace, "decode/admit",
+                        e.t_enq_wall, now - e.t_enq,
+                        slot=slot, prompt_len=len(e.ids))
+                    self._active.append(e)
+                # kickoff: land the fresh prompts' first
+                # chunk in the iteration that admitted them
+                # rather than waiting a full loop pass —
+                # one bounded extra chunk call, mirroring
+                # how short prompts prefill inline at admit
+                chunk_step()
+            if short_p:
+                reqs = [(e.ids, e.max_new, e.temperature)
+                        for e in short_p]
+                with self._prefill_span(
+                        short_p, engine.prompt_bucket(
+                            max(len(e.ids) for e in short_p))):
+                    first = engine.admit(reqs)
+                now = time.monotonic()
+                for e, (slot, tok) in zip(short_p, first):
+                    e.slot = slot
+                    tracing.record_span(
+                        e.trace, "decode/admit",
+                        e.t_enq_wall, now - e.t_enq,
+                        slot=slot, prompt_len=len(e.ids))
+                    if e.handoff == "out":
+                        self._token_out(e, tok, now)
+                        self._finish_handoff_out(e, now)
+                    elif self._token_out(e, tok, now):
+                        done.append(e)
+                    else:
+                        self._active.append(e)
+        if chunked and engine.prefilling_slots:
+            chunk_step()
+            now = time.monotonic()
+        spec: "list[_GenEntry]" = []
+        regular: "list[_GenEntry]" = []
+        for e in self._active:
+            if e.prefilling:
+                continue
+            if spec_k > 0 and self._spec_eligible(e):
+                spec.append(e)
+            else:
+                regular.append(e)
+        emitted = 0
+        if spec:
+            active = np.zeros((engine.max_slots,),
+                              np.bool_)
+            for e in spec:
+                active[e.slot] = True
+            prev_acc = engine.spec_accepted
+            with obs.span("decode/spec_step",
+                          n=len(spec)):
+                out, n_emit = engine.spec_step(active)
+            now = time.monotonic()
+            obs.counter(
+                "zoo_tpu_serving_gen_spec_proposed_total",
+                help="draft tokens proposed for "
+                     "verification").inc(
+                spec_k * len(spec))
+            obs.counter(
+                "zoo_tpu_serving_gen_spec_accepted_total",
+                help="draft tokens accepted by the "
+                     "target model").inc(
+                engine.spec_accepted - prev_acc)
+            for e in spec:
+                fin = False
+                for j in range(int(n_emit[e.slot])):
+                    emitted += 1
+                    if self._token_out(
+                            e, int(out[e.slot, j]), now):
+                        fin = True
+                        break
+                if fin:
+                    done.append(e)
+                    self._active.remove(e)
+        if regular:
+            active = np.zeros((engine.max_slots,),
+                              np.bool_)
+            for e in regular:
+                active[e.slot] = True
+            with obs.span("decode/step",
+                          n=len(regular)) as sp:
+                toks = engine.step(active)
+                # the step's two waits, timed inside the engine:
+                # the compiled call returning, then the tokens
+                dispatch_s, fetch_s = engine.step_times
+                sp.annotate(dispatch_s=round(dispatch_s, 6),
+                            fetch_s=round(fetch_s, 6))
+            now = time.monotonic()
+            for e in regular:
+                emitted += 1
+                if self._token_out(e, int(toks[e.slot]),
+                                   now):
+                    done.append(e)
+                    self._active.remove(e)
+        if spec or regular:
+            obs.counter(
+                "zoo_tpu_serving_gen_tokens_total",
+                help="tokens generated").inc(emitted)
+            obs.counter(
+                "zoo_tpu_serving_gen_steps_total",
+                help="decode iterations executed").inc()
+        for e in done:
+            self._finish(e, now)
+        it.annotate(admitted=admitted, active=len(self._active),
+                    emitted=emitted, retired=len(done))
 
     # -- introspection ------------------------------------------------------
     def stats(self) -> dict:

@@ -76,6 +76,7 @@ step + buckets used to fire a spurious ``recompile_storm``).
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional, Sequence
 
 import numpy as np
@@ -243,6 +244,9 @@ class GenerationEngine:
         self.prompt_buckets = bucket_ladder(
             min(self.max_context, int(net.seq_len)))
 
+        # (dispatch_s, fetch_s) of the latest `step`: the compiled call
+        # returning, then blocked on the tokens (`decode/step` fields)
+        self.step_times = (0.0, 0.0)
         self._compiled_step = None
         self._compiled_prefill: dict = {}
         self._compiled_chunk = None
@@ -637,6 +641,10 @@ class GenerationEngine:
         return bool(self.free_slots) and self.allocator.can_alloc(
             self.pages_for(prompt_len, max_new))
 
+    def prompt_bucket(self, prompt_len: int) -> int:
+        """The padded length a prompt of ``prompt_len`` prefills at."""
+        return next(b for b in self.prompt_buckets if b >= prompt_len)
+
     def admit(self, requests: "Sequence[tuple]") -> "list[tuple]":
         """Admit ``[(prompt_ids, max_new, temperature), ...]`` into
         free slots of the LIVE batch: assign pages, write the table
@@ -655,8 +663,7 @@ class GenerationEngine:
                 raise ValueError(
                     f"prompt length {len(prompt_ids)} outside [1, "
                     f"{self.max_context - 1}]")
-        tp = max(len(r[0]) for r in requests)
-        tp = next(b for b in self.prompt_buckets if b >= tp)
+        tp = self.prompt_bucket(max(len(r[0]) for r in requests))
         ids_arr = np.zeros((self.max_slots, tp), np.int32)
         plens = np.zeros((self.max_slots,), np.int32)
         admitted = []
@@ -808,11 +815,14 @@ class GenerationEngine:
         _STEP_FAULT.fire()
         fn = self._get_step()
         active = np.asarray(active, np.bool_)
+        t0 = time.perf_counter()
         self.cache, toks = fn(self.cache, self.params,
                               self._last_tok, active, self._temps,
                               self._rng, np.int32(self._step_id))
+        t1 = time.perf_counter()
         self._step_id += 1
         toks = np.asarray(toks)
+        self.step_times = (t1 - t0, time.perf_counter() - t1)
         self._last_tok = np.where(active, toks, self._last_tok
                                   ).astype(np.int32)
         return toks

@@ -86,13 +86,12 @@ def step_timeline(events, out=sys.stdout):
     if not steps:
         return
     print("  step  epoch   total_ms    wait_ms   dispatch_ms  "
-          "device_ms    ckpt_ms", file=out)
+          "  ckpt_ms", file=out)
     for e in steps:
         print(f"  {e.get('step', '?'):>4}  {e.get('epoch', '?'):>5}"
               f"  {_fmt_ms(e.get('dur_s')):>9}"
               f"  {_fmt_ms(e.get('data_wait_s')):>9}"
               f"  {_fmt_ms(e.get('dispatch_s')):>11}"
-              f"  {_fmt_ms(e.get('device_s')):>9}"
               f"  {_fmt_ms(e.get('checkpoint_s')):>9}", file=out)
 
 

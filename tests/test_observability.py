@@ -555,3 +555,88 @@ def test_histogram_quantile_method():
     import math
     empty = histogram("zoo_tpu_q2_seconds", buckets=(1.0,))
     assert math.isnan(empty.quantile(0.5))
+
+
+# -- PR 27: one clock, no needless lock, no jax ----------------------------
+
+def test_observability_and_tracing_import_without_jax():
+    """The two modules run inside Spark executors' pickled closures
+    and the native front-end's worker threads: stdlib only. The
+    package's ``__init__`` pulls in jax, so load them under stand-in
+    parent packages with jax made unimportable."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = f"""
+import sys, types
+sys.modules["jax"] = None            # any `import jax` now raises
+for name, sub in (("analytics_zoo_tpu", ""),
+                  ("analytics_zoo_tpu.common", "common")):
+    pkg = types.ModuleType(name)
+    pkg.__path__ = [{root!r} + "/analytics_zoo_tpu/" + sub]
+    sys.modules[name] = pkg
+from analytics_zoo_tpu.common import observability as obs, tracing
+with tracing.trace("unit/root") as tr:
+    with obs.span("unit/child") as sp:
+        sp.annotate(k=1)
+assert len(tracing.get_store().spans(tr.trace_id)) == 2
+assert tracing._annotation_hook is None
+assert not [m for m in sys.modules if m.split(".")[0] in
+            ("jax", "jaxlib", "numpy") and sys.modules[m] is not None]
+print("ok")
+"""
+    out = subprocess.run([sys.executable, "-c", code],
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0 and out.stdout.strip() == "ok", \
+        out.stderr
+
+
+@pytest.mark.parametrize("log_set", [False, True])
+def test_event_takes_no_lock_without_a_sink(monkeypatch, tmp_path,
+                                            log_set):
+    from analytics_zoo_tpu.common import observability as obs
+
+    class Watched:
+        taken = 0
+
+        def __enter__(self):
+            Watched.taken += 1
+
+        def __exit__(self, *exc):
+            return False
+
+    if log_set:
+        monkeypatch.setenv("ZOO_TPU_EVENT_LOG",
+                           str(tmp_path / "events.jsonl"))
+    else:
+        monkeypatch.delenv("ZOO_TPU_EVENT_LOG", raising=False)
+    monkeypatch.setattr(obs, "_event_lock", Watched())
+    with span("unit/x", step=1):
+        pass
+    obs.event("unit/y")
+    assert Watched.taken == (2 if log_set else 0)
+    if log_set:
+        monkeypatch.undo()      # the real lock, to close the sink
+        lines = [json.loads(ln) for ln in
+                 (tmp_path / "events.jsonl").read_text().splitlines()]
+        assert [e["event"] for e in lines] == ["unit/x", "unit/y"]
+        assert lines[0]["step"] == 1 and "dur_s" in lines[0]
+
+
+def test_span_annotate_reaches_store_and_event_log(monkeypatch,
+                                                   tmp_path):
+    from analytics_zoo_tpu.common import tracing
+    monkeypatch.setenv("ZOO_TPU_EVENT_LOG",
+                       str(tmp_path / "events.jsonl"))
+    with tracing.trace("unit/root") as tr:
+        with span("unit/child", n=2) as sp:
+            sp.annotate(fetch_s=0.25, skipped=None)
+    child = [r for r in tracing.get_store().spans(tr.trace_id)
+             if r.name == "unit/child"][0]
+    assert child.fields == {"n": 2, "fetch_s": 0.25}
+    reset_metrics()
+    first = json.loads(
+        (tmp_path / "events.jsonl").read_text().splitlines()[0])
+    assert first["event"] == "unit/child" and first["fetch_s"] == 0.25
+    assert first["trace_id"] == tr.trace_id

@@ -345,3 +345,389 @@ def test_evaluate_traced(rng):
              and r.trace_id == runs[0].trace_id]
     assert len(evals) == 1
     assert evals[0].parent_id == runs[0].span_id
+
+
+# -- PR 27: the program's spans where the work happens, on one clock -------
+
+def _dicts(records):
+    return [r.to_dict() for r in records]
+
+
+def _toy_convnet():
+    from analytics_zoo_tpu.pipeline.api.keras.layers import (
+        BatchNormalization, Convolution2D, Dense, Flatten,
+        MaxPooling2D)
+    from analytics_zoo_tpu.pipeline.api.keras.models import Sequential
+    m = Sequential()
+    m.add(Convolution2D(4, 3, 3, border_mode="same", bias=False,
+                        input_shape=(8, 8, 3)))
+    m.add(BatchNormalization())
+    m.add(MaxPooling2D(pool_size=(2, 2)))
+    m.add(Flatten())
+    m.add(Dense(3))
+    return m
+
+
+@pytest.fixture(scope="module")
+def train_records():
+    """Every span record of a two-epoch toy `Estimator.train`."""
+    import analytics_zoo_tpu as zoo
+    from analytics_zoo_tpu.pipeline.estimator import Estimator
+    tracing.reset_tracing()
+    ctx = zoo.init_nncontext(seed=0, log_level="WARNING")
+    est = Estimator(_toy_convnet(), optimizer="sgd", loss="mse",
+                    ctx=ctx)
+    rs = np.random.RandomState(0)
+    x = rs.rand(32, 8, 8, 3).astype(np.float32)
+    y = rs.rand(32, 3).astype(np.float32)
+    est.train(x, y, batch_size=8, nb_epoch=2)
+    return _dicts(tracing.get_store().records())
+
+
+@pytest.mark.parametrize("name,count,fields", [
+    ("train/input_gather", 8, {"rows", "bytes"}),
+    ("train/input_place", 8, {"bytes"}),
+    ("train/step", 8, {"step", "epoch", "data_wait_s", "dispatch_s"}),
+    ("train/epoch_turn", 2, {"epoch", "fetch_s"}),
+    ("train/flops_lowering", 1, set()),
+    ("xla/compile", None, {"expected"}),
+])
+def test_train_span_in_store_with_fields(train_records, name, count,
+                                         fields):
+    recs = [r for r in train_records if r["name"] == name]
+    assert recs, f"no {name} record"
+    if count is not None:
+        assert len(recs) == count
+    for r in recs:
+        assert fields <= set(r["fields"]), r
+        assert r["dur_s"] >= 0
+    if name == "train/input_gather":
+        assert recs[0]["fields"]["rows"] == 8
+        assert recs[0]["fields"]["bytes"] == 8 * (8 * 8 * 3 + 3) * 4
+        assert {r["thread"] for r in recs} == {"zoo-tpu-prefetch"}
+
+
+def test_train_batch_reads_gather_place_step(train_records):
+    by_trace = {}
+    for r in train_records:
+        by_trace.setdefault(r["trace_id"], []).append(r["name"])
+    steps = [names for names in by_trace.values()
+             if "train/step" in names]
+    assert len(steps) == 8
+    for names in steps:
+        assert {"train/input_gather", "train/input_place",
+                "train/step"} <= set(names)
+        assert names.index("train/input_gather") < \
+            names.index("train/input_place") < \
+            names.index("train/step")
+    # annotation only, and the removed per-step sync's field is gone
+    assert not [r for r in train_records
+                if r["name"] == "train/data_wait"]
+    assert not [r for r in train_records if "device_s" in r["fields"]]
+    # steady state writes three records a step (the ring holds 4096)
+    steady = [n for names in steps[1:] for n in names]
+    assert len(steady) == 3 * 7
+
+
+def _toy_engine():
+    import jax
+    from analytics_zoo_tpu import init_nncontext
+    from analytics_zoo_tpu.pipeline.api.keras.layers.transformer \
+        import TransformerLayer
+    from analytics_zoo_tpu.pipeline.inference.generation import \
+        GenerationEngine
+    init_nncontext(seed=0, log_level="WARNING")
+    net = TransformerLayer(n_block=2, hidden_size=32, n_head=2,
+                           seq_len=32, vocab=61, hidden_p_drop=0.0,
+                           attn_p_drop=0.0, embed_p_drop=0.0)
+    params = net.build(jax.random.key(0), (32,))
+    return GenerationEngine(net, params, max_slots=2, max_context=32,
+                            page_size=8)
+
+
+@pytest.fixture(scope="module")
+def decode_records():
+    """Every span record of a toy `ContinuousBatcher` run: five
+    traced requests over two slots."""
+    from analytics_zoo_tpu.pipeline.inference.batching import \
+        ContinuousBatcher
+    eng = _toy_engine()
+    cb = ContinuousBatcher(eng, queue_depth=16).start()
+    tracing.reset_tracing()
+    try:
+        futs = []
+        for n, m in [(3, 6), (7, 4), (2, 8), (5, 5), (4, 7)]:
+            with tracing.trace("serving/request"):
+                futs.append(cb.submit(list(range(1, n + 1)),
+                                      max_new_tokens=m))
+        for f in futs:
+            f.result(timeout=60)
+    finally:
+        cb.stop()
+    return _dicts(tracing.get_store().records())
+
+
+@pytest.mark.parametrize("name,fields", [
+    ("decode/iteration", {"admitted", "active", "emitted",
+                          "retired"}),
+    ("decode/prefill", {"n", "bucket", "prompt_tokens"}),
+    ("decode/step", {"n", "dispatch_s", "fetch_s"}),
+    ("decode/release", {"slot", "tokens"}),
+    ("decode/queue_wait", set()),
+    ("decode/admit", {"slot", "prompt_len"}),
+    ("decode/retire", {"slot", "tokens"}),
+])
+def test_decode_span_in_store_with_fields(decode_records, name,
+                                          fields):
+    recs = [r for r in decode_records if r["name"] == name]
+    assert recs, f"no {name} record"
+    for r in recs:
+        assert fields <= set(r["fields"]), r
+    if name in ("decode/queue_wait", "decode/admit", "decode/retire",
+                "decode/release"):
+        assert len(recs) == 5       # one a request
+    if name == "decode/step":
+        for r in recs:
+            f = r["fields"]
+            assert 0 <= f["dispatch_s"] and 0 <= f["fetch_s"]
+            assert f["dispatch_s"] + f["fetch_s"] <= r["dur_s"] + 1e-4
+    if name == "decode/prefill":
+        assert sum(r["fields"]["prompt_tokens"] for r in recs) == \
+            3 + 7 + 2 + 5 + 4
+        assert all(r["fields"]["bucket"] in (1, 2, 4, 8, 16, 32)
+                   for r in recs)
+    if name == "decode/iteration":
+        assert sum(r["fields"]["admitted"] for r in recs) == 5
+        assert sum(r["fields"]["retired"] for r in recs) == 5
+        # every token but each request's first comes from a step
+        assert sum(r["fields"]["emitted"] for r in recs) == \
+            6 + 4 + 8 + 5 + 7 - 5
+
+
+def test_decode_children_of_one_iteration(decode_records):
+    its = {r["span_id"]: r for r in decode_records
+           if r["name"] == "decode/iteration"}
+    for name in ("decode/prefill", "decode/step", "decode/release"):
+        for r in decode_records:
+            if r["name"] == name:
+                parent = its[r["parent_id"]]
+                assert parent["trace_id"] == r["trace_id"]
+                assert parent["t_start"] <= r["t_start"] + 1e-3
+    both = [it for it in its.values() if {"decode/prefill",
+            "decode/step"} <= {r["name"] for r in decode_records
+                               if r["parent_id"] == it["span_id"]}]
+    assert both, "no iteration holds both a prefill and a step"
+
+
+def test_no_span_name_means_two_things(decode_records):
+    # the per-request records keep their names and meaning: the
+    # benchmark's readers index fields["prompt_len"] of every one
+    for r in decode_records:
+        if r["name"] == "decode/admit":
+            assert "prompt_len" in r["fields"]
+            assert r["parent_id"] is not None
+    loop = {r["name"] for r in decode_records
+            if r["thread"] == "zoo-tpu-gen-batcher"
+            and r["trace_id"] in {i["trace_id"] for i in decode_records
+                                  if i["name"] == "decode/iteration"}}
+    per_request = {"decode/queue_wait", "decode/admit",
+                   "decode/retire"}
+    assert not loop & per_request
+    assert obs.snapshot().get("zoo_tpu_decode_admit_seconds") is None
+
+
+class _FakeAnnotation:
+    opened = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        _FakeAnnotation.opened.append(self.name)
+        return self
+
+    def __exit__(self, *exc):
+        _FakeAnnotation.opened.append("/" + self.name)
+        return False
+
+
+_OPENERS = {
+    "obs.span": lambda: obs.span("unit/x", k=1),
+    "obs.span in trace": lambda: obs.span("unit/x"),
+    "tracing.trace": lambda: tracing.trace("unit/x"),
+    "tracing.annotate": lambda: tracing.annotate("unit/x"),
+}
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+@pytest.mark.parametrize("opener", sorted(_OPENERS))
+def test_annotation_hook_once_per_span(monkeypatch, opener, enabled):
+    monkeypatch.setattr(tracing, "_annotation_hook", _FakeAnnotation)
+    if not enabled:
+        monkeypatch.setenv("ZOO_TPU_TRACE", "0")
+    _FakeAnnotation.opened = []
+    if opener == "obs.span in trace":
+        with tracing.trace("unit/outer"):
+            _FakeAnnotation.opened = []
+            with _OPENERS[opener]():
+                pass
+            seen = list(_FakeAnnotation.opened)
+    else:
+        with _OPENERS[opener]():
+            pass
+        seen = list(_FakeAnnotation.opened)
+    assert seen == (["zoo:unit/x", "/zoo:unit/x"] if enabled else [])
+    # the already-timed cross-thread form stays store-only
+    _FakeAnnotation.opened = []
+    tracing.record_span(("t1", "s1"), "unit/recorded", time.time(),
+                        0.1)
+    assert _FakeAnnotation.opened == []
+
+
+def test_jax_installs_the_profiler_annotation():
+    import jax
+    import analytics_zoo_tpu  # noqa: F401 — nncontext installs it
+    assert tracing._annotation_hook is jax.profiler.TraceAnnotation
+    assert tracing.ANNOTATION_PREFIX == "zoo:"
+
+
+def test_failing_annotation_hook_never_breaks_the_span(monkeypatch):
+    def boom(name):
+        raise RuntimeError("profiler gone")
+    monkeypatch.setattr(tracing, "_annotation_hook", boom)
+    with tracing.trace("unit/root") as tr:
+        with obs.span("unit/child"):
+            pass
+    assert len(tracing.get_store().spans(tr.trace_id)) == 2
+
+
+def test_xla_compile_record_under_the_triggering_span():
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.common import diagnostics
+    diagnostics.install_recompile_monitor()
+    x = jnp.ones(7).block_until_ready()
+    tracing.reset_tracing()
+    with tracing.trace("unit/root") as tr:
+        jax.jit(lambda a: a * 3 + 1)(x).block_until_ready()
+    with diagnostics.expected_compiles():
+        jax.jit(lambda a: a * 5 - 2)(x).block_until_ready()
+    recs = [r for r in tracing.get_store().records()
+            if r.name == "xla/compile"]
+    assert [r.fields["expected"] for r in recs] == [False, True]
+    assert recs[0].trace_id == tr.trace_id and \
+        recs[0].parent_id == tr.span_id
+    assert recs[1].parent_id is None and recs[1].dur_s > 0
+
+
+# -- device work under the program's names ---------------------------------
+
+class _NoScope:
+    """`jax.named_scope` with the name taken away, as context manager
+    and as decorator."""
+
+    def __init__(self, name):
+        pass
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def __call__(self, fn):
+        return fn
+
+
+def _unscope(monkeypatch):
+    """The programs as they were before the scopes: ``with`` and
+    nested-decorator sites through ``jax.named_scope``, module-level
+    decorated functions through ``__wrapped__``."""
+    import jax
+    from analytics_zoo_tpu.ops import attention, kv_cache, sampling
+    from analytics_zoo_tpu.pipeline.api.keras.layers import conv
+    monkeypatch.setattr(jax, "named_scope", _NoScope)
+    for mod, names in ((kv_cache, ("append_layer", "gather_layer",
+                                   "write_prompt_layer")),
+                       (attention, ("decode_attention",
+                                    "chunk_attention")),
+                       (sampling, ("sample_tokens",)),
+                       (conv._ConvND, ("_convolve",))):
+        for n in names:
+            monkeypatch.setattr(mod, n, getattr(mod, n).__wrapped__)
+
+
+def _train_step_and_args():
+    import jax
+    import analytics_zoo_tpu as zoo
+    from analytics_zoo_tpu.pipeline.estimator import Estimator
+    ctx = zoo.init_nncontext(seed=0, log_level="WARNING")
+    est = Estimator(_toy_convnet(), optimizer="sgd", loss="mse",
+                    ctx=ctx)
+    est._ensure_initialized()
+    rs = np.random.RandomState(1)
+    args = (est.params, est.opt_state, jax.random.key(0),
+            rs.rand(8, 8, 8, 3).astype(np.float32),
+            rs.rand(8, 3).astype(np.float32))
+    return est._build_train_step(est._tx()), args
+
+
+def _decode_step_and_args():
+    import jax
+    eng = _toy_engine()
+    eng.admit([([5, 9, 2], 4, 0.0)])
+    active = np.array([True, False])
+    args = (eng.cache, eng.params, eng._last_tok, active, eng._temps,
+            eng._rng, np.int32(1))
+    return jax.jit(eng._step_fn), args
+
+
+_PROGRAMS = {
+    "train_step": (_train_step_and_args, [
+        "zoo:bn/stats", "zoo:bn/apply", "zoo:conv/convolve",
+        "zoo:pool/maxpool_bwd", "zoo:train/loss",
+        "zoo:train/optimizer"]),
+    "decode_step": (_decode_step_and_args, [
+        "zoo:kv_cache/append", "zoo:kv_cache/gather",
+        "zoo:decode/attention", "zoo:decode/sampling",
+        "zoo:decode/layer", "zoo:decode/lm_head"]),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_PROGRAMS))
+def test_scopes_in_hlo_and_outputs_bit_identical(monkeypatch,
+                                                 program):
+    import jax
+    build, scopes = _PROGRAMS[program]
+    fn, args = build()
+    text = fn.lower(*args).as_text(debug_info=True)
+    for scope in scopes:
+        assert scope in text, f"{scope} not in the lowered {program}"
+    # donated arguments die with the call: run on copies
+    copy = lambda t: jax.tree_util.tree_map(
+        lambda a: a.copy() if hasattr(a, "copy") else a, t)
+    scoped = jax.device_get(fn(*copy(args)))
+    _unscope(monkeypatch)
+    plain_fn, _ = build()
+    assert "zoo:" not in plain_fn.lower(*args).as_text(
+        debug_info=True).replace("zoo:pool/", "")
+    plain = jax.device_get(plain_fn(*copy(args)))
+    for a, b in zip(jax.tree_util.tree_leaves(scoped),
+                    jax.tree_util.tree_leaves(plain)):
+        a, b = np.asarray(a), np.asarray(b)
+        if a.dtype.kind not in "biufc":   # typed PRNG keys
+            a, b = jax.random.key_data(a), jax.random.key_data(b)
+        assert a.tobytes() == b.tobytes()
+
+
+def test_prefill_program_carries_its_scopes():
+    import jax
+    eng = _toy_engine()
+    ids = np.zeros((2, 8), np.int32)
+    text = jax.jit(eng._prefill_fn).lower(
+        eng.cache, eng.params, ids, np.array([3, 0], np.int32),
+        eng._temps, eng._rng, np.int32(0)).as_text(debug_info=True)
+    for scope in ("zoo:kv_cache/write_prompt", "zoo:prefill/layer",
+                  "zoo:prefill/attention", "zoo:prefill/lm_head",
+                  "zoo:decode/sampling"):
+        assert scope in text
