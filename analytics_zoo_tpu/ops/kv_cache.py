@@ -4,27 +4,50 @@ vLLM's PagedAttention (SOSP '23) insight, applied to this stack:
 instead of one contiguous (B, T_max, H, D) K/V buffer per layer —
 whose T axis either reallocates as sequences grow (recompile) or pads
 every sequence to the worst case (HBM waste) — K/V live in a
-fixed-size pool of small pages, `(max_pages, page_size, heads,
-head_dim)` per layer, preallocated once. A per-slot page table maps
+fixed-size pool of small pages, `(max_pages, page_size, row)` per
+layer, preallocated once. A per-slot page table maps
 logical token positions to physical pages, so sequence growth only
-ever writes one (heads, head_dim) row into an existing page (or walks
-onto a freshly assigned one) and NO array shape ever changes: the
-whole decode loop stays one compiled program regardless of how many
-sequences join, leave, or how long they run.
+ever writes one row into an existing page (or walks onto a freshly
+assigned one) and NO array shape ever changes: the whole decode loop
+stays one compiled program regardless of how many sequences join,
+leave, or how long they run.
+
+A token's row holds every head side by side, `heads * head_dim`
+padded up to whole 128-lane tiles (`ROW_ALIGN`), because the device
+tiles an array's two minor axes (8 or 16 x 128 on a TPU) and picks
+the layout that pads least: with `(25, 64)` or `(16, 1600)` minor,
+the v5e lays the PAGE axis out minor-most, where no page is
+contiguous, and its compiler re-lays the whole pool out around every
+gather and scatter by page. With `(page_size, 1664)` minor the layout
+is row-major, a page is one contiguous block, and pages are gathered
+from and rows scattered into the pool where it lies
+(tests/test_tpu_compile.py holds the compiler to it). The views
+handed to attention are split back to `(heads, head_dim)`.
 
 Everything device-side here is shape-static and jit-safe:
 
 - :func:`init_cache` — allocate the pool (zeros) + identity tables;
-- :func:`append_layer` — scatter one new token's K/V per slot into
-  one layer's pool (inactive slots are routed out-of-range and
-  dropped, so padded batch slots never corrupt live pages);
+- :func:`decode_view` — one layer's dense (S, T, H, D) context for a
+  decode step: ONE gather straight out of the stacked pool through
+  (layer, page table), with the new token's row laid over position
+  ``seq_lens[s]`` of every writing slot. The pools are only read, so
+  they stay loop-invariant in the layer scan (scanning them as
+  ``xs``/``ys`` made XLA copy every layer's slab, and the whole
+  stack, in every step);
+- :func:`append_rows` — the step's one write: scatter every layer's
+  new rows `(L, S, row)` into the stacked pools after the scan, in
+  place when the cache is donated (inactive slots and full contexts
+  are routed out-of-range and dropped, so padded batch slots never
+  corrupt live pages);
 - :func:`write_prompt_layer` — bulk-scatter a whole (right-padded)
   prompt's K/V at prefill (pad rows land in pages past `seq_len` and
   are never gathered — the length mask owns validity); a per-slot
   ``start`` offset writes a partial chunk of the prompt instead, the
   primitive chunked prefill is built on;
 - :func:`gather_layer` / :func:`length_mask` — page-table gather back
-  to a dense (S, T, H, D) view + key-validity mask for attention.
+  to a dense (S, T, row) view + key-validity mask for attention, from
+  one layer's pool or, given ``layer``, from the stacked pool;
+  :func:`split_heads` makes it (S, T, H, D).
 
 Int8 pages (``ZOO_TPU_KV_DTYPE=int8``): the pool stores int8 rows
 plus a per-row-per-head scale array of the same page geometry
@@ -32,7 +55,8 @@ plus a per-row-per-head scale array of the same page geometry
 stored alongside the pages"). :func:`quantize_rows` computes the
 symmetric scale `max|x| / 127` over ``head_dim`` at every write
 (append and prompt scatter share the coordinate math, so the scale
-rows land exactly where their K/V rows do), and
+rows land exactly where their K/V rows do; a decode step quantizes
+its row once, and the overlay and the append use that same row), and
 :func:`dequantize_rows` restores values at the gather before
 attention — roughly 2x resident-sequence capacity for a bounded,
 tested accuracy cost (tests/test_generate.py's kv-dtype conformance
@@ -57,8 +81,9 @@ import numpy as np
 class PagedKVCache(NamedTuple):
     """The device-side cache state threaded through the decode loop.
 
-    ``k_pages``/``v_pages``: (num_layers, max_pages, page_size,
-    heads, head_dim) — the preallocated pools.
+    ``k_pages``/``v_pages``: (num_layers, max_pages, page_size, W)
+    — the preallocated pools, W = ``heads * head_dim`` rounded up to
+    ``ROW_ALIGN``.
     ``page_table``: (max_slots, pages_per_slot) int32 physical page
     ids (logical page j of slot s lives in ``page_table[s, j]``).
     ``seq_lens``: (max_slots,) int32 tokens currently cached per slot
@@ -93,6 +118,11 @@ class PagedKVCache(NamedTuple):
         return self.k_scales is not None
 
 
+# K/V rows are padded to whole lane tiles: what keeps the device's
+# layout of a pool row-major (see the module docstring)
+ROW_ALIGN = 128
+
+
 def init_cache(num_layers: int, max_slots: int, max_context: int,
                heads: int, head_dim: int, page_size: int = 16,
                max_pages: int = 0,
@@ -110,7 +140,8 @@ def init_cache(num_layers: int, max_slots: int, max_context: int,
             f"max_pages {max_pages} < max_slots*pages_per_slot "
             f"{max_slots * pages_per_slot}; the identity table "
             f"would alias pages")
-    shape = (num_layers, max_pages, page_size, heads, head_dim)
+    shape = (num_layers, max_pages, page_size,
+             -(-heads * head_dim // ROW_ALIGN) * ROW_ALIGN)
     table = np.arange(max_slots * pages_per_slot, dtype=np.int32)
     quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
     scale_shape = (num_layers, max_pages, page_size, heads)
@@ -171,45 +202,125 @@ def _scatter_coords(page_table, seq_lens, positions, page_size,
     return phys, offset
 
 
-def _quantize_for(pages, x):
-    """Route a write through :func:`quantize_rows` when the pool is
-    int8; (values, scales-or-None) otherwise."""
+def _pool_rows(pages, x):
+    """K/V rows ``(…, heads, head_dim)`` as the pool stores them:
+    ``(…, W)``, heads side by side and zero-padded to the pool's row,
+    in its dtype — through :func:`quantize_rows` with ``(…, heads)``
+    scales when the pool is int8 (None otherwise)."""
     if pages.dtype == jnp.int8:
-        return quantize_rows(x)
-    return x.astype(pages.dtype), None
+        x, scales = quantize_rows(x)
+    else:
+        x, scales = x.astype(pages.dtype), None
+    x = x.reshape(x.shape[:-2] + (-1,))
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, pages.shape[-1] - x.shape[-1])]
+    return jnp.pad(x, pad), scales
+
+
+def split_heads(view, heads: int, head_dim: int):
+    """A gathered K/V view ``(S, T, W)`` as attention takes it:
+    ``(S, T, heads, head_dim)``, the row's padding cut off."""
+    return view[..., :heads * head_dim].reshape(
+        view.shape[:-1] + (heads, head_dim))
+
+
+def _decode_writes(cache, active):
+    """(S,) bool: the slots whose new row lands this step — active
+    (``None`` = every slot) and not yet at ``max_context``."""
+    room = cache.seq_lens < cache.max_context
+    return room if active is None else jnp.logical_and(active, room)
+
+
+def decode_view(cache: PagedKVCache, layer, k_new, v_new,
+                active=None):
+    """One layer's attention operands for a decode step, the pools
+    only read.
+
+    ``layer``: scalar index into the stacked pools (traced inside the
+    layer scan); ``k_new``/``v_new``: (S, H, D) — the new token of
+    every slot. Gathers the layer's dense context through the page
+    table and lays each writing slot's row (quantized for int8 pools,
+    cast to the pool's dtype otherwise) over position
+    ``seq_lens[s]``, so attention sees exactly what a gather after
+    the append would hold. Returns ``(ctx, rows)``: ``ctx =
+    (k_ctx, v_ctx, k_sctx, v_sctx)``, (S, T, H, D) views in the
+    pool's dtype and their (S, T, H) scale views (None for float
+    pools); ``rows = (k_row, v_row, k_srow, v_srow)``, (S, W) and
+    (S, H): what :func:`append_rows` writes once every layer's are
+    stacked."""
+    t_max = cache.max_context
+    writes = _decode_writes(cache, active)
+
+    def view(pages, scales, new):
+        row, srow = _pool_rows(pages, new)
+        ctx = _lay_rows(
+            gather_layer(pages, cache.page_table, t_max, layer),
+            cache.seq_lens, row, writes)
+        ctx = split_heads(ctx, *new.shape[1:])
+        if scales is None:
+            return ctx, None, row, None
+        sctx = _lay_rows(
+            gather_layer(scales, cache.page_table, t_max, layer),
+            cache.seq_lens, srow, writes)
+        return ctx, sctx, row, srow
+
+    k_ctx, k_sctx, k_row, k_srow = view(cache.k_pages, cache.k_scales,
+                                        k_new)
+    v_ctx, v_sctx, v_row, v_srow = view(cache.v_pages, cache.v_scales,
+                                        v_new)
+    return (k_ctx, v_ctx, k_sctx, v_sctx), (k_row, v_row, k_srow,
+                                            v_srow)
+
+
+@jax.named_scope("zoo:kv_cache/gather")
+def _lay_rows(ctx, seq_lens, rows, writes):
+    """``ctx`` (S, T, W) with ``rows[s]`` (W,) at position
+    ``seq_lens[s]`` of every slot in ``writes``."""
+    at = jnp.arange(ctx.shape[1], dtype=jnp.int32)[None, :]
+    hit = jnp.logical_and(at == seq_lens[:, None], writes[:, None])
+    return jnp.where(hit[:, :, None], rows[:, None], ctx)
 
 
 @jax.named_scope("zoo:kv_cache/append")
-def append_layer(k_pages, v_pages, page_table, seq_lens,
-                 k_new, v_new, active=None,
-                 k_scales=None, v_scales=None):
-    """Scatter one decode step's K/V into one layer's pool.
+def append_rows(cache: PagedKVCache, rows, active=None):
+    """Scatter one decode step's rows of EVERY layer into the stacked
+    pools: the step's only write to them.
 
-    k_pages/v_pages: (P, page, H, D); k_new/v_new: (S, H, D) — the new
-    token of every slot, written at position ``seq_lens[s]``. Slots
-    with ``active == False`` (or ``seq_lens == 0`` when active is
-    None... callers pass the done-mask) are dropped, not written.
-    Returns the updated (k_pages, v_pages), plus the updated
-    (k_scales, v_scales) when scale pools are passed (int8 pages:
-    values are quantized per row and the scale rows scatter through
-    the SAME coordinates, so drop semantics match exactly).
-    Shape-static; safe inside scan/while_loop."""
-    page_size = k_pages.shape[1]
-    if active is None:
-        active = jnp.ones(seq_lens.shape, jnp.bool_)
-    max_ctx = page_table.shape[1] * page_size
-    active = jnp.logical_and(active, seq_lens < max_ctx)
-    phys, offset = _scatter_coords(page_table, seq_lens, seq_lens,
-                                   page_size, active)
-    k_new, k_s = _quantize_for(k_pages, k_new)
-    v_new, v_s = _quantize_for(v_pages, v_new)
-    k_pages = k_pages.at[phys, offset].set(k_new, mode="drop")
-    v_pages = v_pages.at[phys, offset].set(v_new, mode="drop")
-    if k_scales is None:
-        return k_pages, v_pages
-    k_scales = k_scales.at[phys, offset].set(k_s, mode="drop")
-    v_scales = v_scales.at[phys, offset].set(v_s, mode="drop")
-    return k_pages, v_pages, k_scales, v_scales
+    ``rows``: :func:`decode_view`'s second result with a leading
+    layer axis — k/v (L, S, W) in the pool's dtype, scale rows
+    (L, S, H) for int8 pools — written at position ``seq_lens[s]``
+    of every slot. Slots with ``active == False`` and slots already
+    at ``max_context`` are routed out of range and dropped, not
+    written; the scale rows scatter through the SAME coordinates, so
+    drop semantics match exactly. Returns the cache with the pools
+    replaced (``seq_lens`` is the caller's to advance). Shape-static;
+    one in-place scatter a pool when the cache is donated."""
+    phys, offset = _scatter_coords(
+        cache.page_table, cache.seq_lens, cache.seq_lens,
+        cache.page_size, _decode_writes(cache, active))
+    k_row, v_row, k_srow, v_srow = rows
+    cache = cache._replace(
+        k_pages=_put_rows(cache.k_pages, phys, offset, k_row),
+        v_pages=_put_rows(cache.v_pages, phys, offset, v_row))
+    if not cache.quantized:
+        return cache
+    return cache._replace(
+        k_scales=_put_rows(cache.k_scales, phys, offset, k_srow),
+        v_scales=_put_rows(cache.v_scales, phys, offset, v_srow))
+
+
+def _put_rows(pages, phys, offset, rows):
+    """Scatter ``rows`` (..., W) to (page, in-page offset) ``phys`` /
+    ``offset`` (...) of one layer's pool (P, page, W); out-of-range
+    pages drop. A stacked pool (L, P, page, W) takes rows with a
+    leading layer axis at the same coordinates in every layer — each
+    (W,) row its own update: a window over the layer axis makes the
+    TPU compiler re-lay the whole pool out around the scatter."""
+    if pages.ndim == 3:
+        return pages.at[phys, offset].set(rows, mode="drop")
+    layers = jnp.arange(pages.shape[0], dtype=jnp.int32).reshape(
+        (-1,) + (1,) * phys.ndim)
+    return pages.at[layers, phys[None], offset[None]].set(
+        rows, mode="drop")
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
@@ -219,7 +330,9 @@ def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
     """Bulk prefill scatter for one layer: k_seq/v_seq (S, T, H, D)
     hold the (right-padded) prompt K/V; positions past
     ``prompt_lens[s]`` are dropped (never written), so pad tokens
-    cannot leak into pages a later admit might reuse.
+    cannot leak into pages a later admit might reuse. Stacked pools
+    (L, P, page, W) with k_seq/v_seq (L, S, T, H, D) write every
+    layer at once (whole-prompt prefill, after its layer scan).
 
     ``start`` (S,) int32 shifts each slot's write window: row j of
     k_seq lands at position ``start[s] + j`` (still gated by
@@ -228,10 +341,10 @@ def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
     partial-prompt primitive chunked prefill interleaves with decode
     steps — each chunk is one bounded scatter at its offset, and a
     slot not being chunk-prefilled passes ``prompt_lens == 0`` and is
-    untouched. Scale pools (int8) behave as in
-    :func:`append_layer`."""
-    s, t = k_seq.shape[0], k_seq.shape[1]
-    page_size = k_pages.shape[1]
+    untouched. Scale pools (int8) take the scale rows through the
+    same coordinates, as in :func:`append_rows`."""
+    s, t = k_seq.shape[-4], k_seq.shape[-3]
+    page_size = k_pages.shape[-2]
     positions = jnp.broadcast_to(
         jnp.arange(t, dtype=jnp.int32)[None, :], (s, t))
     if start is not None:
@@ -241,32 +354,39 @@ def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
                              positions < max_ctx)
     phys, offset = _scatter_coords(page_table, prompt_lens, positions,
                                    page_size, active)
-    k_seq, k_s = _quantize_for(k_pages, k_seq)
-    v_seq, v_s = _quantize_for(v_pages, v_seq)
-    k_pages = k_pages.at[phys, offset].set(k_seq, mode="drop")
-    v_pages = v_pages.at[phys, offset].set(v_seq, mode="drop")
+    k_seq, k_s = _pool_rows(k_pages, k_seq)
+    v_seq, v_s = _pool_rows(v_pages, v_seq)
+    k_pages = _put_rows(k_pages, phys, offset, k_seq)
+    v_pages = _put_rows(v_pages, phys, offset, v_seq)
     if k_scales is None:
         return k_pages, v_pages
-    k_scales = k_scales.at[phys, offset].set(k_s, mode="drop")
-    v_scales = v_scales.at[phys, offset].set(v_s, mode="drop")
+    k_scales = _put_rows(k_scales, phys, offset, k_s)
+    v_scales = _put_rows(v_scales, phys, offset, v_s)
     return k_pages, v_pages, k_scales, v_scales
 
 
 @jax.named_scope("zoo:kv_cache/gather")
-def gather_layer(pages, page_table, t_max: int):
-    """Page-table gather back to a dense (S, t_max, H, D) view of one
-    layer's cache (positions past a slot's ``seq_len`` hold stale/zero
-    rows — :func:`length_mask` owns validity). ``t_max`` is static and
-    must be a whole number of pages."""
-    page_size = pages.shape[1]
+def gather_layer(pages, page_table, t_max: int, layer=None):
+    """Page-table gather back to a dense (S, t_max, W) view of one
+    layer's cache, W the pool's row: the padded ``heads * head_dim``
+    of a K/V pool, ``heads`` of a scale pool (positions past a slot's
+    ``seq_len`` hold stale/zero rows — :func:`length_mask` owns
+    validity). ``t_max`` is static and must be a whole number of
+    pages. ``pages`` is one layer's pool (P, page, W), or with
+    ``layer`` (a scalar, traced or not) the stacked pool
+    (L, P, page, W): the gather then indexes (layer, page) at once
+    and no layer's slab is sliced out first."""
+    page_size = pages.shape[-2]
     if t_max % page_size:
         raise ValueError(f"t_max {t_max} not a multiple of page_size "
                          f"{page_size}")
-    n = t_max // page_size
-    picked = jnp.take(pages, page_table[:, :n], axis=0,
-                      mode="clip")                 # (S, n, page, H, D)
-    s = page_table.shape[0]
-    return picked.reshape((s, t_max) + pages.shape[2:])
+    ids = page_table[:, :t_max // page_size]
+    if layer is None:
+        picked = jnp.take(pages, ids, axis=0, mode="clip")
+    else:
+        picked = pages.at[layer, ids].get(mode="clip")
+    # (S, n, page, W) -> (S, t_max, W)
+    return picked.reshape(ids.shape[0], t_max, pages.shape[-1])
 
 
 def length_mask(seq_lens, t: int):
@@ -293,7 +413,7 @@ def gather_slot_pages(cache: PagedKVCache, page_ids):
     row, fixed width (entries past the used prefix may repeat a real
     page; the caller slices the used prefix host-side). Returns
     ``(k, v, k_scales, v_scales)`` with k/v shaped
-    ``(num_layers, P, page_size, heads, head_dim)`` and scales
+    ``(num_layers, P, page_size, W)`` and scales
     ``(num_layers, P, page_size, heads)`` (None for float pools)."""
     k = jnp.take(cache.k_pages, page_ids, axis=1, mode="clip")
     v = jnp.take(cache.v_pages, page_ids, axis=1, mode="clip")
@@ -338,7 +458,7 @@ def scatter_slot_pages(cache: PagedKVCache, page_ids, active, slot,
 # the decode-resume state. Array fields (below) are np arrays sliced to
 # the used page count; everything else is plain scalars, so the wire
 # codec round-trips through JSON for the HTTP hop.
-HANDOFF_VERSION = 1
+HANDOFF_VERSION = 2
 _WIRE_ARRAYS = ("k", "v", "k_scales", "v_scales")
 
 

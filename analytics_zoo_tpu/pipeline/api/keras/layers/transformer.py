@@ -479,28 +479,18 @@ class TransformerLayer(KerasLayer):
 
     def _write_prompt_all(self, cache, k_all, v_all, total_lens,
                           start=None):
-        """vmap the per-layer prompt scatter over the block stack
-        (k_all/v_all: (L, S, T, nh, hd)); quantized caches thread
-        their scale pools through the same coordinates. Returns the
-        cache with pages (and scales) replaced — ``seq_lens`` is the
-        caller's to update."""
+        """Scatter every block's prompt K/V (k_all/v_all:
+        (L, S, T, nh, hd)) into the stacked pools; quantized caches
+        thread their scale pools through the same coordinates.
+        Returns the cache with pages (and scales) replaced —
+        ``seq_lens`` is the caller's to update."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        if cache.quantized:
-            write = jax.vmap(
-                lambda kp, vp, ks, vs, k, v: kvc.write_prompt_layer(
-                    kp, vp, cache.page_table, total_lens, k, v,
-                    start=start, k_scales=ks, v_scales=vs))
-            kp, vp, ks, vs = write(cache.k_pages, cache.v_pages,
-                                   cache.k_scales, cache.v_scales,
-                                   k_all, v_all)
-            return cache._replace(k_pages=kp, v_pages=vp,
-                                  k_scales=ks, v_scales=vs)
-        write = jax.vmap(
-            lambda kp, vp, k, v: kvc.write_prompt_layer(
-                kp, vp, cache.page_table, total_lens, k, v,
-                start=start))
-        kp, vp = write(cache.k_pages, cache.v_pages, k_all, v_all)
-        return cache._replace(k_pages=kp, v_pages=vp)
+        pools = kvc.write_prompt_layer(
+            cache.k_pages, cache.v_pages, cache.page_table,
+            total_lens, k_all, v_all, start=start,
+            k_scales=cache.k_scales, v_scales=cache.v_scales)
+        return cache._replace(**dict(zip(
+            ("k_pages", "v_pages", "k_scales", "v_scales"), pools)))
 
     def decode_step(self, params, cache, token_ids, active=None):
         """One decode step for every slot: consume ``token_ids`` (S,)
@@ -521,44 +511,30 @@ class TransformerLayer(KerasLayer):
         x = jnp.take(params["tok_embed"],
                      token_ids.astype(jnp.int32), axis=0) + \
             jnp.take(params["pos_embed"], pos, axis=0)
-        t_max = cache.max_context
-        table = cache.page_table
-        seq_lens = cache.seq_lens
-        lens_after = seq_lens + active.astype(jnp.int32)
+        lens_after = cache.seq_lens + active.astype(jnp.int32)
 
+        # the pools are closed over, not scanned: the body only reads
+        # them, and the step's rows are written once after the scan
         @jax.named_scope("zoo:decode/layer")
         def block(x, xs):
-            p, kp, vp, ks, vs = xs
+            p, layer = xs
             q, k_new, v_new = self._split_qkv(p, x)
-            if ks is None:
-                kp, vp = kvc.append_layer(
-                    kp, vp, table, seq_lens, k_new, v_new,
-                    active=active)
-                sk = sv = None
-            else:
-                kp, vp, ks, vs = kvc.append_layer(
-                    kp, vp, table, seq_lens, k_new, v_new,
-                    active=active, k_scales=ks, v_scales=vs)
-                sk = kvc.gather_layer(ks, table, t_max)
-                sv = kvc.gather_layer(vs, table, t_max)
-            k_ctx = kvc.gather_layer(kp, table, t_max)
-            v_ctx = kvc.gather_layer(vp, table, t_max)
-            if ks is None:
+            (k_ctx, v_ctx, sk, sv), rows = kvc.decode_view(
+                cache, layer, k_new, v_new, active=active)
+            if sk is None:
                 k_ctx = k_ctx.astype(x.dtype)
                 v_ctx = v_ctx.astype(x.dtype)
             attn = decode_attention(q, k_ctx, v_ctx, lens_after,
                                     impl=self.attention_impl,
                                     k_scales=sk, v_scales=sv)
             attn = attn.reshape(s, self.hidden_size)
-            return self._block_tail(p, x, attn), (kp, vp, ks, vs)
+            return self._block_tail(p, x, attn), rows
 
-        final, (k_pages, v_pages, k_scales, v_scales) = jax.lax.scan(
-            block, x, (params["blocks"], cache.k_pages,
-                       cache.v_pages, cache.k_scales,
-                       cache.v_scales))
-        cache = cache._replace(k_pages=k_pages, v_pages=v_pages,
-                               k_scales=k_scales, v_scales=v_scales,
-                               seq_lens=lens_after)
+        final, rows = jax.lax.scan(
+            block, x, (params["blocks"],
+                       jnp.arange(self.n_block, dtype=jnp.int32)))
+        cache = kvc.append_rows(cache, rows, active=active)._replace(
+            seq_lens=lens_after)
         with jax.named_scope("zoo:decode/lm_head"):
             logits = final @ params["tok_embed"].astype(final.dtype).T
         return cache, logits
@@ -617,8 +593,10 @@ class TransformerLayer(KerasLayer):
                     k_scales=ks, v_scales=vs)
                 sk = kvc.gather_layer(ks, table, t_max)
                 sv = kvc.gather_layer(vs, table, t_max)
-            k_ctx = kvc.gather_layer(kp, table, t_max)
-            v_ctx = kvc.gather_layer(vp, table, t_max)
+            k_ctx = kvc.split_heads(
+                kvc.gather_layer(kp, table, t_max), *k_new.shape[2:])
+            v_ctx = kvc.split_heads(
+                kvc.gather_layer(vp, table, t_max), *v_new.shape[2:])
             if ks is None:
                 k_ctx = k_ctx.astype(x.dtype)
                 v_ctx = v_ctx.astype(x.dtype)

@@ -536,13 +536,15 @@ class GenerationEngine:
         handoff width ``pages_per_slot`` — both handoff programs are
         shape-static over the full width (unused entries masked/
         dropped), so each compiles exactly once per engine."""
-        lyr, _, page, h, d = self.cache.k_pages.shape
+        lyr, _, page, width = self.cache.k_pages.shape
         p = self.pages_per_slot
-        rows = self._shape(lyr, p, page, h, d,
+        rows = self._shape(lyr, p, page, width,
                            dtype=self.cache.k_pages.dtype)
         if self.cache.k_scales is None:
             return rows, None
-        return rows, self._shape(lyr, p, page, h, dtype=np.float32)
+        return rows, self._shape(lyr, p, page,
+                                 self.cache.k_scales.shape[3],
+                                 dtype=np.float32)
 
     def _get_handoff_export(self):
         if self._compiled_handoff_export is None:
@@ -904,8 +906,7 @@ class GenerationEngine:
             "page_size": self.page_size,
             "kv_dtype": np.dtype(self.cache.k_pages.dtype).name,
             "num_layers": int(self.cache.k_pages.shape[0]),
-            "heads": int(self.cache.k_pages.shape[3]),
-            "head_dim": int(self.cache.k_pages.shape[4]),
+            "row_width": int(self.cache.k_pages.shape[3]),
             "last_token": int(self._last_tok[slot]),
             "temperature": float(self._temps[slot]),
             "k": np.asarray(k)[:, :n_used].copy(),
@@ -928,8 +929,7 @@ class GenerationEngine:
             "page_size": self.page_size,
             "kv_dtype": np.dtype(self.cache.k_pages.dtype).name,
             "num_layers": int(self.cache.k_pages.shape[0]),
-            "heads": int(self.cache.k_pages.shape[3]),
-            "head_dim": int(self.cache.k_pages.shape[4]),
+            "row_width": int(self.cache.k_pages.shape[3]),
         }
         for key, want in mine.items():
             if blob.get(key) != want:

@@ -770,3 +770,204 @@ def test_generate_route_over_http_sequential_path():
         assert gen["max_slots"] == 2
     finally:
         srv.stop()
+
+
+# -- decode step: the page pools stay out of the layer scan -------------------
+
+_KV_DTYPES = ["f32", "bf16", "int8"]
+
+
+def _prefilled(kv, table=None, prompts=((7, 3, 11, 2, 19), (5, 9, 2))):
+    """A two-slot cache with both prompts written, its net and params,
+    and each slot's first sampled token."""
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.pipeline.inference.generation import \
+        resolve_kv_dtype
+    net, params = _toy_transformer()
+    cache = net.init_kv_cache(len(prompts), SEQ, page_size=8,
+                              dtype=resolve_kv_dtype(kv))
+    if table is not None:
+        cache = cache._replace(page_table=jnp.asarray(table, jnp.int32))
+    width = max(len(p) for p in prompts)
+    ids = np.zeros((len(prompts), width), np.int32)
+    for i, p in enumerate(prompts):
+        ids[i, :len(p)] = p
+    plens = jnp.asarray([len(p) for p in prompts], jnp.int32)
+    cache, logits = net.prefill(params, cache, jnp.asarray(ids), plens)
+    return net, params, cache, jnp.argmax(logits, -1).astype(jnp.int32)
+
+
+def _pools(cache):
+    return [np.asarray(a) for a in (cache.k_pages, cache.v_pages,
+                                    cache.k_scales, cache.v_scales)
+            if a is not None]
+
+
+def _layer_scan(jaxpr, length):
+    """The scan over ``length`` layers, searched through nested
+    jaxprs; (eqn, consts, xs, ys) with the scan's operands split."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan" and \
+                eqn.params["length"] == length:
+            nc, ncar = eqn.params["num_consts"], eqn.params["num_carry"]
+            return (eqn, eqn.invars[:nc], eqn.invars[nc + ncar:],
+                    eqn.outvars[ncar:])
+        for sub in eqn.params.values():
+            inner = getattr(sub, "jaxpr", sub)
+            if hasattr(inner, "eqns"):
+                found = _layer_scan(inner, length)
+                if found:
+                    return found
+    return None
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_decode_step_scans_no_page_pool(kv):
+    """The pools are loop-invariant operands of the layer scan, never
+    its scanned inputs or stacked outputs (as ``xs``/``ys`` XLA copied
+    every layer's slab, and the whole stack, in every step)."""
+    import jax
+    net, params, cache, tok = _prefilled(kv)
+    jaxpr = jax.make_jaxpr(net.decode_step)(
+        params, cache, tok, np.array([True, True]))
+    _, consts, xs, ys = _layer_scan(jaxpr.jaxpr, net.n_block)
+    pooled = {a.shape for a in (cache.k_pages, cache.k_scales)
+              if a is not None}
+    slabs = {s[1:] for s in pooled}
+    for v in list(xs) + list(ys):
+        assert v.aval.shape not in pooled, v.aval
+        assert v.aval.shape[1:] not in slabs, v.aval
+    closed = [v.aval.shape for v in consts]
+    n_pools = 2 * len(pooled)
+    assert sum(s in pooled for s in closed) == n_pools, closed
+    # what the scan stacks is one row a slot a layer
+    rows = sorted(v.aval.shape for v in ys)
+    assert len(rows) == n_pools
+    assert all(s[:2] == (net.n_block, 2) and len(s) == 3 for s in rows)
+
+
+def test_step_program_donates_the_pools():
+    """`_step_fn` is compiled with the cache donated, and the program
+    writes the pools in place: every cache leaf of the output aliases
+    its input, and the buffers handed in are gone after the call."""
+    import jax
+    eng = _engine()
+    (slot, _), = eng.admit([([4, 19, 7], 4, 0.0)])
+    compiled = eng._get_step()
+    n_leaves = len(jax.tree_util.tree_leaves(eng.cache))
+    header = compiled.as_text().split("\n", 1)[0]
+    if "input_output_alias" in header:     # the backend reports it
+        aliased = header.split("input_output_alias={", 1)[1]
+        for i in range(n_leaves):
+            assert f"{{{i}}}: ({i}, " in aliased, (i, header)
+    before = eng.cache
+    active = np.zeros((eng.max_slots,), np.bool_)
+    active[slot] = True
+    eng.step(active)
+    assert before.k_pages.is_deleted() and before.v_pages.is_deleted()
+    assert not eng.cache.k_pages.is_deleted()
+
+
+@pytest.mark.parametrize("kv", _KV_DTYPES)
+def test_decode_step_freezes_inactive_slot(kv):
+    """An inactive slot's pages and seq_lens are bit-identical after
+    a step; the active neighbour gains exactly one row a layer."""
+    net, params, cache, tok = _prefilled(kv)
+    before = _pools(cache)
+    after, _ = net.decode_step(params, cache, tok,
+                               np.array([True, False]))
+    assert np.asarray(after.seq_lens).tolist() == [6, 3]
+    # identity table, 4 pages a slot: slot 1 owns pages 4..7
+    for b, a in zip(before, _pools(after)):
+        np.testing.assert_array_equal(a[:, 4:], b[:, 4:])
+        changed = np.argwhere((a != b).reshape(a.shape[:3] + (-1,))
+                              .any(-1))
+        # slot 0's position 5 = page 0, offset 5, in every layer
+        assert {tuple(c[1:]) for c in changed} == {(0, 5)}
+        assert len(changed) == net.n_block
+
+
+@pytest.mark.parametrize("kv", _KV_DTYPES)
+def test_decode_step_full_context_writes_nothing(kv):
+    """A slot already at ``max_context`` stays active but has no room:
+    its row is dropped, never wrapped onto a live page."""
+    import jax.numpy as jnp
+    net, params, cache, tok = _prefilled(kv)
+    cache = cache._replace(
+        seq_lens=jnp.asarray([cache.max_context, 3], jnp.int32))
+    before = _pools(cache)
+    after, logits = net.decode_step(params, cache, tok,
+                                    np.array([True, True]))
+    assert np.isfinite(np.asarray(logits, np.float32)).all()
+    for b, a in zip(before, _pools(after)):
+        np.testing.assert_array_equal(a[:, :4], b[:, :4])
+        assert (a[:, 4:] != b[:, 4:]).any()     # slot 1 did append
+
+
+@pytest.mark.parametrize("kv", _KV_DTYPES)
+def test_appended_row_is_the_row_attention_saw(kv):
+    """The view handed to attention (gather + the new row laid over
+    it) equals a gather from the pools after the post-scan append."""
+    import jax
+    import jax.numpy as jnp
+    from analytics_zoo_tpu.ops import kv_cache as kvc
+    net, params, cache, _ = _prefilled(kv)
+    active = jnp.asarray([True, False])
+    heads, hd = net.n_head, net.hidden_size // net.n_head
+    views, rows = [], []
+    for layer in range(net.n_block):
+        k_new, v_new = jax.random.normal(
+            jax.random.key(layer), (2, 2, heads, hd), jnp.float32)
+        ctx, row = kvc.decode_view(cache, jnp.int32(layer), k_new,
+                                   v_new, active=active)
+        views.append(ctx)
+        rows.append(row)
+    stacked = tuple(None if r[0] is None else jnp.stack(r)
+                    for r in zip(*rows))
+    after = kvc.append_rows(cache, stacked, active=active)
+    t = cache.max_context
+    for layer, (k_ctx, v_ctx, k_sctx, v_sctx) in enumerate(views):
+        assert k_ctx.shape == (2, t, heads, hd)
+        assert k_ctx.dtype == cache.k_pages.dtype
+        for seen, pool in ((k_ctx, after.k_pages),
+                           (v_ctx, after.v_pages)):
+            got = kvc.split_heads(kvc.gather_layer(
+                pool, after.page_table, t, layer), heads, hd)
+            np.testing.assert_array_equal(np.asarray(seen),
+                                          np.asarray(got))
+        for seen, pool in ((k_sctx, after.k_scales),
+                           (v_sctx, after.v_scales)):
+            assert (seen is None) == (pool is None)
+            if seen is not None:
+                np.testing.assert_array_equal(
+                    np.asarray(seen), np.asarray(kvc.gather_layer(
+                        pool, after.page_table, t, layer)))
+    # the active slot's row is new, the frozen slot's view is the old
+    assert (np.asarray(views[0][0][0, 5]) != 0).any()
+    np.testing.assert_array_equal(
+        np.asarray(views[0][0][1]),
+        np.asarray(kvc.split_heads(kvc.gather_layer(
+            cache.k_pages, cache.page_table, t, 0), heads, hd)[1]))
+
+
+@pytest.mark.parametrize("kv", _KV_DTYPES)
+def test_shuffled_page_table_same_tokens(kv):
+    """Pages handed out by `PageAllocator` in shuffled order give the
+    logits the identity table gives, bit for bit."""
+    from analytics_zoo_tpu.ops.kv_cache import PageAllocator
+    alloc = PageAllocator(8)
+    pages = np.asarray(alloc.alloc(8))
+    np.random.RandomState(3).shuffle(pages)
+    table = pages.reshape(2, 4)
+    assert table.tolist() != np.arange(8).reshape(2, 4).tolist()
+    streams = []
+    for tbl in (None, table):
+        net, params, cache, tok = _prefilled(kv, table=tbl)
+        out = []
+        for _ in range(5):      # slot 0 walks onto its second page
+            cache, logits = net.decode_step(params, cache, tok,
+                                            np.array([True, True]))
+            out.append(np.asarray(logits, np.float32))
+            tok = logits.argmax(-1).astype(np.int32)
+        streams.append(np.stack(out))
+    np.testing.assert_array_equal(streams[0], streams[1])

@@ -219,3 +219,73 @@ def test_conv3_batch_tile_counts_padding_and_double_buffers(
     assert cb._conv3_batch_tile(shape, cout, 2, stride) == tile
     assert cb._vmem_bytes((56, 56, 64), 2) == 56 * 64 * 128 * 2
     assert cb._vmem_bytes((56, 56, 64), 4) == 56 * 56 * 128 * 4
+
+
+# ---------------------------------------------------------------------
+# the generation engine's programs at GPT-2-XL's widths: what the v5e
+# compiler does with the KV page pools (nothing runs, nothing is timed)
+# ---------------------------------------------------------------------
+
+_XL = dict(hidden_size=1600, n_head=25, seq_len=1024, vocab=50257,
+           intermediate_size=6400)
+_XL_BLOCKS, _XL_SLOTS, _XL_PAGE = 4, 8, 16    # the scan body compiles once
+
+
+def _xl_program(one_chip, program):
+    from analytics_zoo_tpu.pipeline.api.keras.layers.transformer \
+        import TransformerLayer
+    net = TransformerLayer(n_block=_XL_BLOCKS, hidden_p_drop=0.0,
+                           attn_p_drop=0.0, embed_p_drop=0.0, **_XL)
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, dtype if dtype and a.dtype == F32 else a.dtype,
+                sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: net.build(jax.random.key(0), (_XL["seq_len"],))), BF)
+    cache = on_chip(jax.eval_shape(lambda: net.init_kv_cache(
+        _XL_SLOTS, _XL["seq_len"], page_size=_XL_PAGE, dtype=BF)))
+    s = _XL_SLOTS
+    if program == "step":
+        def fn(cache, params, tok, active):
+            return net.decode_step(params, cache, tok, active=active)
+        args = [jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((s,), jnp.bool_, sharding=one_chip)]
+    else:
+        def fn(cache, params, ids, plens):
+            return net.prefill(params, cache, ids, plens)
+        args = [jax.ShapeDtypeStruct((s, 128), jnp.int32,
+                                     sharding=one_chip),
+                jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip)]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        cache, params, *args).compile()
+    return cache, compiled.as_text()
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_generation_programs_leave_the_pools_in_place(one_chip,
+                                                      program):
+    """The device lays a K/V pool out row-major (a page is one
+    contiguous block: rows are `heads * head_dim` padded to whole
+    lane tiles, or the runtime picks a layout with the page axis
+    minor-most), and neither the decode step nor a prefill copies,
+    transposes or slices anything of a pool's or a layer slab's
+    shape: the only operations that produce a pool are the two
+    in-place scatters."""
+    import re
+    cache, hlo = _xl_program(one_chip, program)
+    pool = ",".join(map(str, cache.k_pages.shape))
+    slab = ",".join(map(str, cache.k_pages.shape[1:]))
+    assert cache.k_pages.shape[-1] % kvc.ROW_ALIGN == 0
+    entry = hlo.split("entry_computation_layout={(", 1)[1]
+    assert entry.startswith(f"bf16[{pool}]{{3,2,1,0:"), entry[:80]
+    made = re.findall(
+        r"= bf16\[(?:1,)?(?:%s|%s)\]\S* ([\w\-]+)\(" % (pool, slab),
+        hlo)
+    moved = [op for op in made if op in (
+        "copy", "transpose", "dynamic-slice", "dynamic-update-slice")]
+    assert not moved, moved
+    # donated and written in place: output pools alias input pools
+    assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in hlo

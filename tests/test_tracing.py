@@ -647,7 +647,8 @@ def _unscope(monkeypatch):
     from analytics_zoo_tpu.ops import attention, kv_cache, sampling
     from analytics_zoo_tpu.pipeline.api.keras.layers import conv
     monkeypatch.setattr(jax, "named_scope", _NoScope)
-    for mod, names in ((kv_cache, ("append_layer", "gather_layer",
+    for mod, names in ((kv_cache, ("append_rows", "gather_layer",
+                                   "_lay_rows",
                                    "write_prompt_layer")),
                        (attention, ("decode_attention",
                                     "chunk_attention")),
