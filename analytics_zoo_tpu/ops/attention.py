@@ -143,6 +143,37 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("sht,sthd->shd", probs, v)
 
 
+@jax.named_scope("zoo:decode/mla_attention")
+def mla_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
+                         ctx: jnp.ndarray, seq_lens: jnp.ndarray,
+                         scale: float) -> jnp.ndarray:
+    """Single-query latent (MLA) attention in the absorbed form: the
+    128 query heads of a slot all attend to ONE latent row a cached
+    token, and no per-head key or value is ever formed from the
+    cache.
+
+    ``q_lat``: (S, H, R) — each head's content query already carried
+    into the latent space (``q_nope @ W_kvb[key part]ᵀ``); ``q_pe``:
+    (S, H, P) its rotated part; ``ctx``: (S, T, W) gathered latent
+    rows, ``[c_kv (R) | k_pe (P) | padding]`` (the view from
+    `ops.kv_cache.latent_decode_view`); ``seq_lens`` (S,) masks
+    positions ``>= seq_lens[s]``. Scores are ``(q_lat·c_kv +
+    q_pe·k_pe) * scale``, softmax in f32. Returns the latent outputs
+    ``P c_kv`` (S, H, R); the caller carries them out through
+    ``W_kvb[value part]``."""
+    r, p = q_lat.shape[-1], q_pe.shape[-1]
+    t = ctx.shape[1]
+    ctx = ctx.astype(q_lat.dtype)
+    c_kv, k_pe = ctx[..., :r], ctx[..., r:r + p]
+    f32 = dict(preferred_element_type=jnp.float32)
+    logits = (jnp.einsum("shr,str->sht", q_lat, c_kv, **f32) +
+              jnp.einsum("shp,stp->sht", q_pe, k_pe, **f32)) * scale
+    valid = (jnp.arange(t, dtype=jnp.int32)[None, None, :] <
+             seq_lens[:, None, None])
+    probs = jax.nn.softmax(jnp.where(valid, logits, -1e30), axis=-1)
+    return jnp.einsum("sht,str->shr", probs.astype(q_lat.dtype), c_kv)
+
+
 @jax.named_scope("zoo:decode/chunk_attention")
 def chunk_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     q_positions: jnp.ndarray,

@@ -62,6 +62,17 @@ attention — roughly 2x resident-sequence capacity for a bounded,
 tested accuracy cost (tests/test_generate.py's kv-dtype conformance
 matrix).
 
+Latent pages (:class:`LatentPagedCache`, multi-head latent
+attention): a token's row is ONE vector a layer, shared by every
+head — the normalised KV latent with the rotated key part beside it
+— so there is one pool, not a K and a V pool, and no per-head
+anything: :func:`init_latent_cache`, :func:`latent_decode_view`,
+:func:`append_latent_rows` and :func:`write_latent_prompt` are the
+four operations above over that one pool, through the same
+coordinates, gather and in-place scatters. Int8 latent pages are
+refused by name (the scales are per (token, head) and a latent row
+has no heads), and so is the handoff codec, which carries K and V.
+
 The host-side :class:`PageAllocator` is the bookkeeping half: a free
 list of physical page ids for the continuous batcher, which assigns
 pages at admission / token-boundary growth and reclaims them at
@@ -117,6 +128,32 @@ class PagedKVCache(NamedTuple):
     def quantized(self) -> bool:
         return self.k_scales is not None
 
+    @property
+    def num_pages(self) -> int:
+        return self.k_pages.shape[1]
+
+    @property
+    def pool_dtype(self):
+        return self.k_pages.dtype
+
+
+class LatentPagedCache(NamedTuple):
+    """A paged cache whose rows are latent vectors: ``pages``
+    (num_layers, max_pages, page_size, W), W the latent width
+    rounded up to ``ROW_ALIGN``; ``page_table`` and ``seq_lens`` as
+    in :class:`PagedKVCache`, whose geometry properties it shares so
+    that the engine's allocator and programs take either."""
+
+    pages: jnp.ndarray
+    page_table: jnp.ndarray
+    seq_lens: jnp.ndarray
+
+    page_size = property(lambda self: self.pages.shape[2])
+    max_context = PagedKVCache.max_context
+    max_slots = PagedKVCache.max_slots
+    num_pages = property(lambda self: self.pages.shape[1])
+    pool_dtype = property(lambda self: self.pages.dtype)
+
 
 # K/V rows are padded to whole lane tiles: what keeps the device's
 # layout of a pool row-major (see the module docstring)
@@ -133,29 +170,58 @@ def init_cache(num_layers: int, max_slots: int, max_context: int,
     the identity mapping — the compiled-loop `generate()` path uses it
     as-is; the continuous batcher overwrites tables from its
     :class:`PageAllocator` as sequences come and go."""
-    pages_per_slot = -(-int(max_context) // int(page_size))
-    max_pages = int(max_pages) or int(max_slots) * pages_per_slot
-    if max_pages < max_slots * pages_per_slot:
-        raise ValueError(
-            f"max_pages {max_pages} < max_slots*pages_per_slot "
-            f"{max_slots * pages_per_slot}; the identity table "
-            f"would alias pages")
-    shape = (num_layers, max_pages, page_size,
-             -(-heads * head_dim // ROW_ALIGN) * ROW_ALIGN)
-    table = np.arange(max_slots * pages_per_slot, dtype=np.int32)
+    shape, table = _pool_geometry(num_layers, max_slots, max_context,
+                                  heads * head_dim, page_size,
+                                  max_pages)
     quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
-    scale_shape = (num_layers, max_pages, page_size, heads)
+    scale_shape = shape[:3] + (heads,)
     return PagedKVCache(
         k_pages=jnp.zeros(shape, dtype),
         v_pages=jnp.zeros(shape, dtype),
-        page_table=jnp.asarray(
-            table.reshape(max_slots, pages_per_slot)),
-        seq_lens=jnp.zeros((max_slots,), jnp.int32),
+        page_table=table,
+        seq_lens=jnp.zeros((int(max_slots),), jnp.int32),
         k_scales=jnp.zeros(scale_shape, jnp.float32)
         if quantized else None,
         v_scales=jnp.zeros(scale_shape, jnp.float32)
         if quantized else None,
     )
+
+
+def _pool_geometry(num_layers, max_slots, max_context, width,
+                   page_size, max_pages):
+    """A pool's shape, rows padded to ``ROW_ALIGN``, and the identity
+    page table."""
+    max_slots, page_size = int(max_slots), int(page_size)
+    pages_per_slot = -(-int(max_context) // page_size)
+    max_pages = int(max_pages) or max_slots * pages_per_slot
+    if max_pages < max_slots * pages_per_slot:
+        raise ValueError(
+            f"max_pages {max_pages} < max_slots*pages_per_slot "
+            f"{max_slots * pages_per_slot}; the identity table "
+            f"would alias pages")
+    shape = (int(num_layers), max_pages, page_size,
+             -(-int(width) // ROW_ALIGN) * ROW_ALIGN)
+    table = np.arange(max_slots * pages_per_slot, dtype=np.int32)
+    return shape, jnp.asarray(table.reshape(max_slots, pages_per_slot))
+
+
+def init_latent_cache(num_layers: int, max_slots: int,
+                      max_context: int, width: int,
+                      page_size: int = 16, max_pages: int = 0,
+                      dtype=jnp.float32) -> LatentPagedCache:
+    """Allocate a latent pool of ``width``-value rows (the KV latent
+    and the rotated key part side by side); geometry and table as
+    :func:`init_cache`."""
+    if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
+        raise ValueError(
+            "int8 pages for a latent cache (LatentPagedCache): the "
+            "int8 scales are per (token, head) and a latent row has "
+            "no heads; use f32 or bf16")
+    shape, table = _pool_geometry(num_layers, max_slots, max_context,
+                                  width, page_size, max_pages)
+    return LatentPagedCache(
+        pages=jnp.zeros(shape, dtype), page_table=table,
+        seq_lens=jnp.zeros((int(max_slots),), jnp.int32))
 
 
 # int8 pages: symmetric per-(token, head) quantization over head_dim.
@@ -323,6 +389,55 @@ def _put_rows(pages, phys, offset, rows):
         rows, mode="drop")
 
 
+def _latent_rows(pages, x):
+    """Latent rows ``(…, width)`` as the pool stores them: its dtype,
+    zero-padded to its row."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, pages.shape[-1] - x.shape[-1])]
+    return jnp.pad(x.astype(pages.dtype), pad)
+
+
+def latent_decode_view(cache: LatentPagedCache, layer, new,
+                       active=None):
+    """:func:`decode_view` for a latent pool. ``new``: (S, width),
+    the new token's latent row of every slot. Returns ``(ctx, row)``:
+    the layer's (S, T, W) context in the pool's dtype with each
+    writing slot's row at position ``seq_lens[s]``, and the (S, W)
+    row :func:`append_latent_rows` writes."""
+    row = _latent_rows(cache.pages, new)
+    ctx = _lay_rows(
+        gather_layer(cache.pages, cache.page_table, cache.max_context,
+                     layer),
+        cache.seq_lens, row, _decode_writes(cache, active))
+    return ctx, row
+
+
+@jax.named_scope("zoo:kv_cache/append")
+def append_latent_rows(cache: LatentPagedCache, rows, active=None):
+    """:func:`append_rows` for a latent pool: ``rows`` (L, S, W),
+    every layer's new row, scattered in place at ``seq_lens[s]``."""
+    phys, offset = _scatter_coords(
+        cache.page_table, cache.seq_lens, cache.seq_lens,
+        cache.page_size, _decode_writes(cache, active))
+    return cache._replace(
+        pages=_put_rows(cache.pages, phys, offset, rows))
+
+
+@jax.named_scope("zoo:kv_cache/write_prompt")
+def write_latent_prompt(cache: LatentPagedCache, prompt_lens, rows):
+    """:func:`write_prompt_layer` for a latent pool: ``rows``
+    (L, S, T, width) hold every layer's (right-padded) prompt rows;
+    positions past ``prompt_lens[s]`` are dropped."""
+    s, t = rows.shape[1], rows.shape[2]
+    positions = jnp.broadcast_to(
+        jnp.arange(t, dtype=jnp.int32)[None, :], (s, t))
+    active = jnp.logical_and(positions < prompt_lens[:, None],
+                             positions < cache.max_context)
+    phys, offset = _scatter_coords(cache.page_table, prompt_lens,
+                                   positions, cache.page_size, active)
+    return cache._replace(pages=_put_rows(
+        cache.pages, phys, offset, _latent_rows(cache.pages, rows)))
+
+
 @jax.named_scope("zoo:kv_cache/write_prompt")
 def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
                        k_seq, v_seq, start=None,
@@ -406,6 +521,16 @@ def length_mask(seq_lens, t: int):
 # once and reuses it for every handoff regardless of sequence length.
 
 
+def refuse_latent_handoff(cache):
+    """Raise, by name, for a cache the handoff codec does not
+    carry."""
+    if isinstance(cache, LatentPagedCache):
+        raise TypeError(
+            "the KV-page handoff carries K and V pools (blob version "
+            f"{HANDOFF_VERSION}); a latent cache (LatentPagedCache) "
+            "is not carried: serve it with role='both'")
+
+
 def gather_slot_pages(cache: PagedKVCache, page_ids):
     """Gather one slot's pages out of every layer's pool.
 
@@ -415,6 +540,7 @@ def gather_slot_pages(cache: PagedKVCache, page_ids):
     ``(k, v, k_scales, v_scales)`` with k/v shaped
     ``(num_layers, P, page_size, W)`` and scales
     ``(num_layers, P, page_size, heads)`` (None for float pools)."""
+    refuse_latent_handoff(cache)
     k = jnp.take(cache.k_pages, page_ids, axis=1, mode="clip")
     v = jnp.take(cache.v_pages, page_ids, axis=1, mode="clip")
     if cache.k_scales is None:
@@ -438,6 +564,7 @@ def scatter_slot_pages(cache: PagedKVCache, page_ids, active, slot,
     int8 pools) are the :func:`gather_slot_pages` outputs, zero-padded
     to width P. Returns the updated cache; the caller owns writing the
     destination page-table row (host-side bookkeeping)."""
+    refuse_latent_handoff(cache)
     max_pages = cache.k_pages.shape[1]
     phys = jnp.where(active, page_ids, max_pages + 2 ** 20)
     k_pages = cache.k_pages.at[:, phys].set(k_rows, mode="drop")

@@ -29,7 +29,10 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.advanced_activations \
 from analytics_zoo_tpu.pipeline.api.keras.layers.noise import (
     GaussianNoise, GaussianDropout, SpatialDropout1D, SpatialDropout2D,
     SpatialDropout3D)
-from analytics_zoo_tpu.pipeline.api.keras.layers.moe import MoE
+from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
+    MoE, GatedMLP, GroupLimitedMoE)
+from analytics_zoo_tpu.pipeline.api.keras.layers.decoder import (
+    YarnRope, LatentAttention, PatternDecoder, deepseek_v2_decoder)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
     MultiHeadAttention, TransformerLayer, BERT)
 from analytics_zoo_tpu.pipeline.api.keras.layers.elementwise import (
@@ -79,6 +82,8 @@ __all__ = [
     "SpatialDropout2D", "SpatialDropout3D",
     # transformer
     "MultiHeadAttention", "TransformerLayer", "MoE", "BERT",
+    "GatedMLP", "GroupLimitedMoE", "YarnRope", "LatentAttention",
+    "PatternDecoder", "deepseek_v2_decoder",
     # elementwise / tensor utilities
     "AddConstant", "MulConstant", "CAdd", "CMul", "Mul", "Scale", "Power",
     "Negative", "Exp", "Log", "Sqrt", "Square", "Identity",

@@ -17,6 +17,15 @@ Transformer formulation:
 
 Aux load-balancing loss (Switch eq. 4) is exposed via
 ``regularization_loss`` so the Estimator adds it automatically.
+
+:class:`GroupLimitedMoE` is the serving-side expert layer of the
+DeepSeek-V2 family, a feed-forward *part* of
+`layers.decoder.PatternDecoder`: top-k of all experts by
+group-limited greedy routing, shared experts always on, no capacity
+and no dropped token, and a layer that is TOLD WHICH EXPERTS IT
+HOLDS: it routes over all of them and computes the part of the
+result its own experts give (what expert parallelism asks of a
+layer; the exchange is the caller's).
 """
 
 from __future__ import annotations
@@ -28,6 +37,204 @@ import jax.numpy as jnp
 
 from analytics_zoo_tpu.ops import activations, initializers
 from analytics_zoo_tpu.pipeline.api.keras.engine import KerasLayer, Shape
+from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import \
+    _normal
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """SwiGLU: ``(silu(x W_g) * (x W_u)) W_d``."""
+    dt = x.dtype
+    return (jax.nn.silu(x @ w_gate.astype(dt)) *
+            (x @ w_up.astype(dt))) @ w_down.astype(dt)
+
+
+class GatedMLP:
+    """Dense SwiGLU feed-forward part of width ``width``."""
+
+    step_counters = ()
+
+    def __init__(self, hidden_size: int, width: int):
+        self.hidden_size, self.width = int(hidden_size), int(width)
+
+    def build(self, rng, stddev: float) -> dict:
+        h, m = self.hidden_size, self.width
+        k = jax.random.split(rng, 3)
+        return {"gate": _normal(k[0], (h, m), stddev),
+                "up": _normal(k[1], (h, m), stddev),
+                "down": _normal(k[2], (m, h), stddev)}
+
+    def __call__(self, p, x, valid=None, scope="decode"):
+        """``x``: (N, hidden) tokens. Returns ``(y, None)``."""
+        del valid
+        with jax.named_scope(f"zoo:{scope}/mlp"):
+            return gated_mlp(x, p["gate"], p["up"], p["down"]), None
+
+
+class GroupLimitedMoE:
+    """Expert feed-forward part: ``n_experts`` routed SwiGLU experts
+    of width ``width`` in ``n_group`` groups, ``top_k`` a token
+    chosen among the ``topk_group`` best groups (a group's score is
+    its best expert's), weights the softmax scores themselves (not
+    renormalised) times ``routed_scaling``; ``n_shared`` shared
+    experts (one SwiGLU of ``n_shared * width``) always on.
+
+    ``experts_held = (first, count)``: the experts whose weights
+    this layer holds. It routes over all ``n_experts`` and adds, for
+    each token, only what its chosen experts in ``[first, first +
+    count)`` give; what the others would add is left out. The routed
+    part is a grouped matrix product over the held experts
+    (`jax.lax.ragged_dot` on the assignments sorted by expert): an
+    expert no token chose is not read, and tokens marked not
+    ``valid`` (padding, idle slots) choose none.
+    """
+
+    # per call, as int32: (token, expert) assignments of valid
+    # tokens, those that fell on held experts, the busiest held
+    # expert's
+    step_counters = ("zoo_tpu_moe_assignments_total",
+                     "zoo_tpu_moe_assignments_held_total",
+                     "zoo_tpu_moe_expert_load_max_total")
+
+    @staticmethod
+    def record(counts):
+        """Add a decode step's three counts (summed over the expert
+        layers) to the counters of :attr:`step_counters`."""
+        from analytics_zoo_tpu.common import observability as obs
+        total, held, busiest = (int(c) for c in counts)
+        obs.counter(
+            "zoo_tpu_moe_assignments_total",
+            help="(token, expert) assignments of active slots in "
+            "decode steps, over the expert layers").inc(total)
+        obs.counter(
+            "zoo_tpu_moe_assignments_held_total",
+            help="assignments that fell on experts held "
+            "here").inc(held)
+        obs.counter(
+            "zoo_tpu_moe_expert_load_max_total",
+            help="the busiest held expert's assignments, a layer "
+            "and step").inc(busiest)
+
+    def __init__(self, hidden_size: int, width: int, n_experts: int,
+                 top_k: int, n_group: int = 1, topk_group: int = 1,
+                 n_shared: int = 0, routed_scaling: float = 1.0,
+                 experts_held: "Optional[tuple]" = None,
+                 token_block: int = 2048):
+        self.hidden_size, self.width = int(hidden_size), int(width)
+        self.n_experts, self.top_k = int(n_experts), int(top_k)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.n_shared = int(n_shared)
+        self.routed_scaling = float(routed_scaling)
+        first, count = experts_held or (0, self.n_experts)
+        self.first, self.n_held = int(first), int(count)
+        self.token_block = int(token_block)
+        if self.n_experts % self.n_group:
+            raise ValueError("n_experts must divide by n_group")
+        if not 0 <= self.first <= self.first + self.n_held \
+                <= self.n_experts:
+            raise ValueError(f"experts_held {experts_held} outside "
+                             f"[0, {self.n_experts})")
+        if self.top_k > self.topk_group * (self.n_experts //
+                                           self.n_group):
+            raise ValueError("top_k exceeds the experts of the "
+                             "groups kept")
+
+    def build(self, rng, stddev: float) -> dict:
+        h, m, e = self.hidden_size, self.width, self.n_held
+        k = jax.random.split(rng, 7)
+        n = lambda key, shape: _normal(key, shape, stddev)
+        out = {"router": n(k[0], (h, self.n_experts)),
+               "experts_gate": n(k[1], (e, h, m)),
+               "experts_up": n(k[2], (e, h, m)),
+               "experts_down": n(k[3], (e, m, h))}
+        if self.n_shared:
+            ms = self.n_shared * m
+            out.update(shared_gate=n(k[4], (h, ms)),
+                       shared_up=n(k[5], (h, ms)),
+                       shared_down=n(k[6], (ms, h)))
+        return out
+
+    def route(self, p, x):
+        """``(experts (N, top_k) int32, weights (N, top_k) f32)`` of
+        tokens ``x`` (N, hidden), over all ``n_experts``. The router
+        runs in float32 at the highest matmul precision: it is tiny,
+        and a rounded score moves a token to another expert."""
+        scores = jax.nn.softmax(jnp.dot(
+            x.astype(jnp.float32), p["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST), axis=-1)
+        n, g = scores.shape[0], self.n_group
+        if g > 1:
+            best = scores.reshape(n, g, -1).max(axis=-1)
+            _, keep = jax.lax.top_k(best, self.topk_group)
+            kept = jnp.zeros((n, g), jnp.bool_).at[
+                jnp.arange(n)[:, None], keep].set(True)
+            scores = jnp.where(jnp.repeat(
+                kept, self.n_experts // g, axis=1), scores, 0.0)
+        weights, experts = jax.lax.top_k(scores, self.top_k)
+        return experts.astype(jnp.int32), \
+            weights * self.routed_scaling
+
+    def routed(self, p, x, experts, weights, valid):
+        """The held experts' part for tokens ``x`` (N, hidden) and
+        the three counts of :attr:`step_counters`."""
+        n, k = experts.shape
+        local = experts - self.first
+        held = jnp.logical_and(local >= 0, local < self.n_held)
+        held = jnp.logical_and(held, valid[:, None]).reshape(-1)
+        # held assignments first, by expert; the rest behind them,
+        # where no group reaches
+        key = jnp.where(held, local.reshape(-1), self.n_held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((self.n_held + 1,), jnp.int32).at[key].add(
+            1)[:self.n_held]
+        rows = jnp.take(x, order // k, axis=0)
+        dt = x.dtype
+        act = jax.nn.silu(jax.lax.ragged_dot(
+            rows, p["experts_gate"].astype(dt), sizes)) * \
+            jax.lax.ragged_dot(rows, p["experts_up"].astype(dt),
+                               sizes)
+        out = jax.lax.ragged_dot(act, p["experts_down"].astype(dt),
+                                 sizes)
+        # back to (token, choice) order; a row past the last group is
+        # whatever the kernel left there, and counts for nothing
+        back = jnp.argsort(order)
+        out = jnp.where(held[:, None], jnp.take(out, back, axis=0), 0)
+        w = weights.reshape(-1, 1).astype(jnp.float32)
+        y = (out.astype(jnp.float32) * w).reshape(n, k, -1).sum(axis=1)
+        counts = jnp.stack([k * jnp.sum(valid, dtype=jnp.int32),
+                            jnp.sum(held, dtype=jnp.int32),
+                            jnp.max(sizes, initial=0)])
+        return y.astype(dt), counts
+
+    def __call__(self, p, x, valid=None, scope="decode"):
+        """``x``: (N, hidden) tokens; ``valid`` (N,) bool. Returns
+        ``(y (N, hidden), counts (3,) int32)``. Past ``token_block``
+        tokens the routed part runs block by block, so that its
+        sorted copies of the tokens stay small."""
+        n = x.shape[0]
+        if valid is None:
+            valid = jnp.ones((n,), jnp.bool_)
+
+        def block(xb, vb):
+            with jax.named_scope(f"zoo:{scope}/moe_route"):
+                experts, weights = self.route(p, xb)
+            with jax.named_scope(f"zoo:{scope}/moe_experts"):
+                return self.routed(p, xb, experts, weights, vb)
+
+        tb = self.token_block
+        if n > tb and n % tb == 0:
+            y, counts = jax.lax.map(
+                lambda a: block(*a), (x.reshape(n // tb, tb, -1),
+                                      valid.reshape(n // tb, tb)))
+            y = y.reshape(n, -1)
+            counts = jnp.stack([counts[:, 0].sum(), counts[:, 1].sum(),
+                                counts[:, 2].max()])
+        else:
+            y, counts = block(x, valid)
+        if self.n_shared:
+            with jax.named_scope(f"zoo:{scope}/moe_shared"):
+                y = y + gated_mlp(x, p["shared_gate"], p["shared_up"],
+                                  p["shared_down"])
+        return y, counts
 
 
 class MoE(KerasLayer):

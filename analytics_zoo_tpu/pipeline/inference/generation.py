@@ -121,10 +121,17 @@ class GenerationEngine:
 
     ``net`` must expose the decode surface the transformer layer
     defines: ``init_kv_cache / prefill / decode_step / forward_chunk
-    / generate`` and ``seq_len`` / ``vocab`` attributes (duck-typed —
-    any net with those methods serves). A ``drafter`` (same surface,
-    same vocab, typically far fewer blocks) plus ``spec_k > 0`` turns
-    on speculative decoding.
+    / generate`` and ``seq_len`` (the most positions it takes) /
+    ``vocab`` attributes (duck-typed — any net with those methods
+    serves: `TransformerLayer` with its K/V pools, `PatternDecoder`
+    with its one latent pool). A net without ``forward_chunk`` serves
+    whole-prompt prefill only: ``prefill_chunk > 0`` and
+    ``spec_k > 0`` are refused for it here. A net that names
+    ``step_counters`` has ``decode_step(..., stats=True)`` return
+    their per-step counts, which come back in the tokens' fetch and
+    go to its ``record_step_counts``. A ``drafter`` (same
+    surface, same vocab, typically far fewer blocks) plus
+    ``spec_k > 0`` turns on speculative decoding.
     """
 
     def __init__(self, net, params, *,
@@ -156,8 +163,14 @@ class GenerationEngine:
             spec_k = int(env.get("ZOO_TPU_SPEC_K", 0))
         if max_context > net.seq_len:
             raise ValueError(
-                f"max_context {max_context} exceeds the net's "
-                f"position table ({net.seq_len})")
+                f"max_context {max_context} exceeds the positions "
+                f"the net takes (seq_len {net.seq_len})")
+        if not hasattr(net, "forward_chunk") and (
+                int(prefill_chunk) > 0 or int(spec_k) > 0):
+            raise ValueError(
+                f"{type(net).__name__} has no forward_chunk: chunked "
+                "prefill (prefill_chunk > 0) and speculative verify "
+                "(spec_k > 0) are not available for it; use 0")
         self.net = net
         self.params = params
         self.max_slots = int(max_slots)
@@ -198,7 +211,9 @@ class GenerationEngine:
             (self.max_slots, self.pages_per_slot), np.int32)
         self.cache = cache._replace(
             page_table=jax.numpy.asarray(self._table))
-        self.allocator = kvc.PageAllocator(cache.k_pages.shape[1])
+        self.allocator = kvc.PageAllocator(cache.num_pages)
+        if role != "both":
+            kvc.refuse_latent_handoff(cache)
         self._slot_pages: "dict[int, list]" = {}
         self.free_slots = set(range(self.max_slots))
 
@@ -262,12 +277,16 @@ class GenerationEngine:
     def _step_fn(self, cache, params, tok, active, temps, rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
-        cache, logits = self.net.decode_step(params, cache, tok,
-                                             active=active)
+        counted = bool(getattr(self.net, "step_counters", ()))
+        cache, logits, *counts = self.net.decode_step(
+            params, cache, tok, active=active,
+            **({"stats": True} if counted else {}))
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        return cache, nxt
+        # the step's counts ride behind the tokens: one fetch
+        return cache, jax.numpy.concatenate([nxt] + counts) \
+            if counts else nxt
 
     def _prefill_fn(self, cache, params, ids, plens, temps, rng,
                     step):
@@ -596,6 +615,9 @@ class GenerationEngine:
         bucket-warm discipline). Returns the number of programs
         compiled this call. Idempotent."""
         n0 = self._warmed()
+        # the first push of the page table compiles its conversion:
+        # here, not under the first admission
+        self._push_table()
         # role-gated: a prefill-pool engine never decodes (its only
         # steady-state programs are prefill/chunk + handoff export);
         # a decode-pool engine never sees a raw prompt (step + handoff
@@ -825,6 +847,9 @@ class GenerationEngine:
         self._step_id += 1
         toks = np.asarray(toks)
         self.step_times = (t1 - t0, time.perf_counter() - t1)
+        if len(toks) > self.max_slots:
+            self.net.record_step_counts(toks[self.max_slots:])
+            toks = toks[:self.max_slots]
         self._last_tok = np.where(active, toks, self._last_tok
                                   ).astype(np.int32)
         return toks
@@ -887,6 +912,7 @@ class GenerationEngine:
         resume decode token-exactly with NO forward pass."""
         import jax
         from analytics_zoo_tpu.ops import kv_cache as kvc
+        kvc.refuse_latent_handoff(self.cache)
         if slot in self._pending_prompts:
             raise ValueError(
                 f"slot {slot} is still mid-chunked-prefill")
@@ -921,6 +947,7 @@ class GenerationEngine:
 
     def _check_handoff_blob(self, blob: dict):
         from analytics_zoo_tpu.ops import kv_cache as kvc
+        kvc.refuse_latent_handoff(self.cache)
         if int(blob.get("version", -1)) != kvc.HANDOFF_VERSION:
             raise ValueError(
                 f"handoff version {blob.get('version')!r} != "
@@ -1057,7 +1084,7 @@ class GenerationEngine:
             "total_pages": self.allocator.max_pages,
             "prompt_buckets": list(self.prompt_buckets),
             "warmed_programs": self._warmed(),
-            "kv_dtype": np.dtype(self.cache.k_pages.dtype).name,
+            "kv_dtype": np.dtype(self.cache.pool_dtype).name,
             "prefill_chunk": self.prefill_chunk,
             "spec_k": self.spec_k,
         }

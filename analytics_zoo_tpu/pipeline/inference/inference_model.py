@@ -503,9 +503,10 @@ class InferenceModel:
     # -- generation (pipeline/inference/generation.py) ----------------------
     def load_generator(self, net, params=None, **engine_kwargs):
         """Attach an autoregressive decode engine for ``net`` (a
-        transformer-style stack exposing ``init_kv_cache / prefill /
-        decode_step / generate`` — `pipeline/api/keras/layers/
-        transformer.py`). Orthogonal to the ``load_*`` predict path:
+        decoder exposing ``init_kv_cache / prefill / decode_step /
+        generate``: `layers.TransformerLayer`, or a
+        `layers.PatternDecoder` such as `deepseek_v2_decoder` builds).
+        Orthogonal to the ``load_*`` predict path:
         a model can serve ``/predict`` and ``/generate`` at once, and
         loading a generator does not invalidate warmed predict
         buckets. ``engine_kwargs`` forward to

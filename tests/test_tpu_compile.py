@@ -289,3 +289,102 @@ def test_generation_programs_leave_the_pools_in_place(one_chip,
     assert not moved, moved
     # donated and written in place: output pools alias input pools
     assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in hlo
+
+
+# ---------------------------------------------------------------------
+# DeepSeek-V2 as the benchmark's configuration holds it (one chip's
+# share of four: 5 layers, 40 of 160 experts, 25600 rows of the
+# vocabulary; every width as published): the decode step and the
+# largest prefill bucket fit the chip beside the weights, and the one
+# latent pool stays where it lies
+# ---------------------------------------------------------------------
+
+_V5E_BYTES = 15.75e9          # what the v5e's compiler allows a program
+
+
+def _ds_config():
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        "deepseek-v2-ep4.json")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+def _ds_program(one_chip, program):
+    from analytics_zoo_tpu.pipeline.api.keras.layers import \
+        deepseek_v2_decoder
+    cfg = _ds_config()
+    eng = cfg["engine"]
+    first, end = cfg["held"]["experts"]
+    # "auto" asks jax.devices(), the CPU here: name the kernel the
+    # chip's "auto" takes from 1024 keys up
+    net = deepseek_v2_decoder(
+        dict(cfg, n_routed_experts=cfg["published"]["n_routed_experts"]),
+        n_layer=cfg["n_layer"], experts_held=(first, end - first),
+        attention_impl="flash")
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, dtype if dtype and a.dtype == F32 else a.dtype,
+                sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: net.build(jax.random.key(0), (16,))), BF)
+    s, ctx = eng["max_slots"], eng["max_context"]
+    cache = on_chip(jax.eval_shape(lambda: net.init_kv_cache(
+        s, ctx, page_size=eng["page_size"], dtype=BF)))
+    if program == "step":
+        def fn(cache, params, tok, active):
+            return net.decode_step(params, cache, tok, active=active,
+                                   stats=True)
+        args = [jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
+                jax.ShapeDtypeStruct((s,), jnp.bool_, sharding=one_chip)]
+    else:
+        def fn(cache, params, ids, plens):
+            return net.prefill(params, cache, ids, plens)
+        args = [jax.ShapeDtypeStruct((s, ctx), jnp.int32,
+                                     sharding=one_chip),
+                jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip)]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        cache, params, *args).compile()
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    return cache, weights, compiled
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_deepseek_v2_programs_fit_the_chip_and_leave_the_pool(
+        one_chip, program):
+    """At the published widths and the configuration's depth, slots
+    and context: 10.33 GB of weights, the program's arguments,
+    results and temporaries inside 15.75 GB; the latent pool
+    (rows of 576 padded to 640) row-major, produced by nothing but
+    its one in-place scatter, and aliased to its input."""
+    import re
+    cache, weights, compiled = _ds_program(one_chip, program)
+    assert abs(weights - 10.33e9) < 0.005e9, weights
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
+        mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < _V5E_BYTES, held
+    hlo = compiled.as_text()
+    assert cache.pages.shape == (5, 2048, 16, 640)
+    pool = ",".join(map(str, cache.pages.shape))
+    slab = ",".join(map(str, cache.pages.shape[1:]))
+    entry = hlo.split("entry_computation_layout={(", 1)[1]
+    assert entry.startswith(f"bf16[{pool}]{{3,2,1,0:"), entry[:80]
+    made = re.findall(
+        r"= bf16\[(?:1,)?(?:%s|%s)\]\S* ([\w\-]+)\(" % (pool, slab),
+        hlo)
+    moved = [op for op in made if op in (
+        "copy", "transpose", "dynamic-slice", "dynamic-update-slice")]
+    assert not moved, moved
+    assert "{0}: (0, {}, may-alias)" in hlo
+    # the routed experts are the compiler's grouped matrix product:
+    # an expert no token chose is not read
+    assert "ragged-dot" in hlo
+    if program == "prefill":
+        assert "zoo_flash_fwd" in hlo
