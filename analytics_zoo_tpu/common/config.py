@@ -92,6 +92,13 @@ class MeshConf:
 @dataclass
 class ZooTpuConf:
     """Top-level configuration for :func:`init_nncontext`.
+    ``ingest_threads`` is a ceiling, not a demand: `Estimator.train`
+    copies the rows of one batch on up to that many threads (and no
+    more than the host's cores), one for every 8 MiB the batch's
+    largest column holds, so a batch of ids or tokens stays on the
+    prefetch thread and a batch of images splits
+    (`feature.feature_set.ingest_width`; the ``threads`` field of the
+    ``train/input_gather`` span says what a batch got).
 
     Analog of the SparkConf + `spark-analytics-zoo.conf` overlay
     (reference `Z/common/NNContext.scala:132-207`): perf-relevant defaults
@@ -109,7 +116,8 @@ class ZooTpuConf:
     check_batch_divisibility: bool = True
     log_level: str = "INFO"
     version_check: bool = False
-    # host data-ingest workers (FeatureSet prefetch threads)
+    # host data-ingest workers: the most threads that copy one train
+    # batch's rows into its host buffer (see the docstring)
     ingest_threads: int = 4
     # default checkpoint root
     checkpoint_dir: str = ""

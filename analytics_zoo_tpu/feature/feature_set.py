@@ -25,6 +25,7 @@ from __future__ import annotations
 import enum
 import os
 import tempfile
+import threading
 from typing import Any, Iterable, Iterator, Optional, Tuple
 
 import numpy as np
@@ -84,6 +85,104 @@ def normalize_labels(y):
         if all(getattr(c, "ndim", 0) >= 1 for c in y):
             return [np.asarray(c) for c in y], True
     return [np.asarray(y)], False
+
+
+# a slice of a batch under this many bytes does not repay the hand-off
+# to a thread of its own: token and id batches (KBs to a few MB) stay
+# on the calling thread, image batches (tens of MB and up) split
+_MIN_SLICE_BYTES = 8 << 20
+
+
+def ingest_width(nbytes: int, rows: int, ceiling: int) -> int:
+    """How many threads copy one column of one batch: as many whole
+    `_MIN_SLICE_BYTES` slices as it holds, at most ``ceiling``
+    (`ZooTpuConf.ingest_threads`), the host's cores and its rows."""
+    return max(1, min(int(ceiling), os.cpu_count() or 1, rows,
+                      nbytes // _MIN_SLICE_BYTES))
+
+
+def batch_selections(n: int, batch_size: int, shuffle: bool, seed: int,
+                     drop_last: bool, sort: bool = False
+                     ) -> "Iterator[np.ndarray]":
+    """Row indices of each batch of one epoch: the per-epoch index
+    permutation (the reference's reshuffle via a shuffled index array,
+    `FeatureSet.scala:216-296`). ``sort`` orders each batch's rows
+    (the PMEM tier reads its memmap front to back)."""
+    idx = np.arange(n)
+    if shuffle:
+        np.random.RandomState(seed).shuffle(idx)
+    end = (n - n % batch_size) if drop_last else n
+    for start in range(0, end, batch_size):
+        sel = idx[start:start + batch_size]
+        yield np.sort(sel) if sort else sel
+
+
+def _take(a, sel, out):
+    # mode="clip": the default "raise" copies through a temporary;
+    # ``sel`` comes from `batch_selections` and is always in range
+    np.take(a, sel, axis=0, out=out, mode="clip")
+
+
+def _take_split(a, sel, out, width: int):
+    """``out[i] = a[sel[i]]`` with the rows split over ``width``
+    threads (this one among them), each writing its own contiguous
+    slice of ``out`` (numpy releases the GIL in the copy). The threads
+    live for this call only; what one raised re-raises here."""
+    n = len(sel)
+    cuts = [n * i // width for i in range(width + 1)]
+    errors: "list[BaseException]" = []
+
+    def work(lo, hi):
+        try:
+            _take(a, sel[lo:hi], out[lo:hi])
+        except BaseException as e:  # noqa: BLE001 — re-raised below
+            errors.append(e)
+
+    workers = [threading.Thread(target=work, args=(lo, hi), daemon=True,
+                                name=f"zoo-tpu-ingest-{i}")
+               for i, (lo, hi) in enumerate(zip(cuts[1:-1], cuts[2:]), 1)]
+    for t in workers:
+        t.start()
+    work(cuts[0], cuts[1])
+    for t in workers:
+        t.join()
+    if errors:
+        raise errors[0]
+
+
+def gather_rows(columns, sel, out=None, threads: int = 1
+                ) -> "list[np.ndarray]":
+    """Rows ``sel`` of every column, bit for bit ``a[sel]``.
+
+    ``out=None``: fresh arrays the caller may keep. ``out``: one
+    buffer per column, of the batch's shape and dtype, that the rows
+    are copied INTO and that is returned — the caller owns its
+    lifetime (`Estimator.train` recycles page-warm buffers this way).
+    ``threads`` is a ceiling: each column is split over
+    `ingest_width` threads, one for a small batch."""
+    got = []
+    for a, buf in zip(columns, out or [None] * len(columns)):
+        width = ingest_width(a.nbytes // max(len(a), 1) * len(sel),
+                             len(sel), threads)
+        if buf is None and width == 1:
+            got.append(np.asarray(a[sel]))
+            continue
+        if buf is None:
+            buf = np.empty((len(sel),) + a.shape[1:], a.dtype)
+        _take_split(a, sel, buf, width)
+        got.append(buf)
+    return got
+
+
+def gather_batch(x_cols, y_cols, multi_y: bool, sel, out=None,
+                 threads: int = 1):
+    """One ``(xb, yb)`` batch as `iter_batches` yields it: a single
+    input or label column bare, several as a list, no labels None.
+    ``out`` is the flat list of buffers, inputs then labels."""
+    cols = gather_rows(list(x_cols) + list(y_cols), sel, out, threads)
+    xb, yb = cols[:len(x_cols)], cols[len(x_cols):]
+    return (xb[0] if len(xb) == 1 else xb,
+            None if not yb else yb if multi_y else yb[0])
 
 
 class FeatureSet:
@@ -259,28 +358,26 @@ class FeatureSet:
     def num_samples(self) -> int:
         return self._n
 
+    def batch_selections(self, batch_size: int, shuffle: bool = True,
+                         seed: int = 0, drop_last: bool = True
+                         ) -> "Iterator[np.ndarray]":
+        """Row indices of each batch of one epoch, in its order."""
+        return batch_selections(
+            self._n, batch_size, shuffle, seed, drop_last,
+            sort=self.memory_type == MemoryType.PMEM)
+
+    def gather(self, sel, out=None, threads: int = 1):
+        """Rows ``sel`` as one ``(xb, yb)`` batch (`gather_batch`)."""
+        return gather_batch(self._x, self._y_cols, self._multi_y, sel,
+                            out, threads)
+
     def iter_batches(self, batch_size: int, shuffle: bool = True,
                      seed: int = 0, drop_last: bool = True
                      ) -> Iterator[Tuple[Any, Any]]:
-        """Per-epoch index permutation (the reference's reshuffle via
-        shuffled index array, `FeatureSet.scala:216-296`)."""
-        idx = np.arange(self._n)
-        if shuffle:
-            np.random.RandomState(seed).shuffle(idx)
-        end = (self._n - self._n % batch_size) if drop_last else self._n
-        for start in range(0, end, batch_size):
-            sel = np.sort(idx[start:start + batch_size]) if \
-                self.memory_type == MemoryType.PMEM else \
-                idx[start:start + batch_size]
-            xb = [np.asarray(c[sel]) for c in self._x]
-            xb = xb[0] if len(xb) == 1 else xb
-            if not self._y_cols:
-                yb = None
-            elif self._multi_y:
-                yb = [np.asarray(c[sel]) for c in self._y_cols]
-            else:
-                yb = np.asarray(self._y_cols[0][sel])
-            yield xb, yb
+        """Fresh arrays each batch: the caller may keep them."""
+        for sel in self.batch_selections(batch_size, shuffle, seed,
+                                         drop_last):
+            yield self.gather(sel)
 
     def __len__(self):
         return self._n

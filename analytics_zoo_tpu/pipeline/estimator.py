@@ -36,6 +36,7 @@ from analytics_zoo_tpu.common import observability as obs
 from analytics_zoo_tpu.common import slo as slo_lib
 from analytics_zoo_tpu.common import tracing
 from analytics_zoo_tpu.common.device import on_tpu
+from analytics_zoo_tpu.feature import feature_set
 from analytics_zoo_tpu.perf import goodput as goodput_lib
 from analytics_zoo_tpu.common.nncontext import NNContext, get_nncontext, \
     logger
@@ -210,20 +211,18 @@ class ArrayDataset:
     """
 
     def __init__(self, x, y=None):
-        from analytics_zoo_tpu.feature.feature_set import \
-            normalize_labels
         self.x = x if isinstance(x, (list, tuple)) else [x]
         self.x = [np.asarray(a) for a in self.x]
         # normalize_labels is the one decision point for single-array
         # vs multi-output label lists (scalar lists stay one array)
-        y_cols, self._multi_y = normalize_labels(y)
-        self.y = (y_cols if self._multi_y
-                  else y_cols[0] if y_cols else None)
+        self._y_cols, self._multi_y = feature_set.normalize_labels(y)
+        self.y = (self._y_cols if self._multi_y
+                  else self._y_cols[0] if self._y_cols else None)
         n = self.x[0].shape[0]
         for a in self.x:
             if a.shape[0] != n:
                 raise ValueError("inconsistent sample counts in x")
-        for a in y_cols:
+        for a in self._y_cols:
             if a.shape[0] != n:
                 raise ValueError("x and y sample counts differ")
         self._n = n
@@ -232,23 +231,24 @@ class ArrayDataset:
     def num_samples(self) -> int:
         return self._n
 
+    def batch_selections(self, batch_size: int, shuffle: bool = True,
+                         seed: int = 0, drop_last: bool = True):
+        """Row indices of each batch of one epoch, in its order."""
+        return feature_set.batch_selections(
+            self._n, batch_size, shuffle, seed, drop_last)
+
+    def gather(self, sel, out=None, threads: int = 1):
+        """Rows ``sel`` as one ``(xb, yb)`` batch
+        (`feature_set.gather_batch`)."""
+        return feature_set.gather_batch(
+            self.x, self._y_cols, self._multi_y, sel, out, threads)
+
     def iter_batches(self, batch_size: int, shuffle: bool = True,
                      seed: int = 0, drop_last: bool = True):
-        idx = np.arange(self._n)
-        if shuffle:
-            np.random.RandomState(seed).shuffle(idx)
-        end = (self._n - self._n % batch_size) if drop_last else self._n
-        for start in range(0, end, batch_size):
-            sel = idx[start:start + batch_size]
-            xb = [a[sel] for a in self.x]
-            xb = xb[0] if len(xb) == 1 else xb
-            if self.y is None:
-                yb = None
-            elif self._multi_y:
-                yb = [a[sel] for a in self.y]
-            else:
-                yb = self.y[sel]
-            yield xb, yb
+        """Fresh arrays each batch: the caller may keep them."""
+        for sel in self.batch_selections(batch_size, shuffle, seed,
+                                         drop_last):
+            yield self.gather(sel)
 
 
 def to_dataset(data, y=None):
@@ -342,39 +342,125 @@ def _timed_iter(it):
         yield time.perf_counter() - t0, item
 
 
+class _HostRing:
+    """One ``train()`` call's host side of the input path: each batch
+    is gathered INTO the next of ``length`` recycled buffers (page-warm
+    after their first use; a fresh 77 MB destination costs as much in
+    page faults as the copy itself), its rows split over the ingest
+    threads where the batch is large enough (`feature_set.ingest_width`).
+
+    **A buffer is free only when the runtime has read it**: `placed`
+    takes the device arrays made from batch k's buffer and, before
+    gather k+1 writes the ring's oldest buffer, waits for the arrays
+    that were placed from THAT one (``device_put`` returns at once and
+    the runtime lays the batch out and copies it on its own threads).
+    Where the placed arrays ARE the host buffer (the CPU backend
+    aliases an aligned numpy array, zero-copy, for the array's whole
+    life) nothing is recycled: every batch gets a fresh destination.
+
+    A dataset without ``gather`` (a foreign `iter_batches`) keeps its
+    own batches: the ring cannot know who else holds them."""
+
+    def __init__(self, ds, batch_size: int, length: int, threads: int):
+        self._ds, self._batch_size = ds, batch_size
+        self._threads = threads
+        self._buffers: "list[Optional[list]]" = [None] * length
+        self._placed: "list[Any]" = [None] * length
+        self._k = 0  # batches placed so far
+        self._recycle = self._gathers = hasattr(ds, "gather")
+
+    def epoch(self, seed: int):
+        """``(batch, span fields)`` of one shuffled epoch, drop-last."""
+        ds, size = self._ds, self._batch_size
+        if not self._gathers:
+            for batch in ds.iter_batches(size, shuffle=True, seed=seed):
+                yield batch, {"threads": 1, "recycled": False}
+            return
+        for sel in ds.batch_selections(size, shuffle=True, seed=seed):
+            slot = self._k % len(self._buffers)
+            recycled = self._recycle
+            batch = ds.gather(sel, out=self._buffers[slot],
+                              threads=self._threads)
+            leaves = jax.tree_util.tree_leaves(batch)
+            if recycled:
+                self._buffers[slot] = leaves
+            yield batch, {
+                "threads": max(feature_set.ingest_width(
+                    a.nbytes, len(sel), self._threads) for a in leaves),
+                "recycled": recycled}
+
+    def placed(self, arrays) -> float:
+        """Batch k is placed as ``arrays``; returns the seconds waited
+        for the buffer gather k+1 writes to come free (> 0 in steady
+        state: the ring is too short for the runtime's copy)."""
+        if self._recycle and self._k == 0 and _on_host(arrays):
+            self._recycle = False
+            self._buffers = [None] * len(self._buffers)
+        if not self._recycle:
+            return 0.0
+        n = len(self._placed)
+        self._placed[self._k % n] = arrays
+        self._k += 1
+        oldest, self._placed[self._k % n] = self._placed[self._k % n], None
+        if oldest is None:
+            return 0.0
+        t0 = time.perf_counter()
+        jax.block_until_ready(oldest)
+        return time.perf_counter() - t0
+
+    def close(self):
+        """Let go of the buffers and of the device arrays (a retained
+        traceback must not pin them)."""
+        self._buffers = [None] * len(self._buffers)
+        self._placed = [None] * len(self._placed)
+
+
+def _on_host(arrays) -> bool:
+    """Whether any placed array lives on a CPU device, where it may
+    alias the numpy buffer it was placed from."""
+    return any(d.platform == "cpu"
+               for a in jax.tree_util.tree_leaves(arrays)
+               if isinstance(a, jax.Array) for d in a.devices())
+
+
 def _traced_gather(it):
     """The train input path's first half, under its own name: each
-    ``next()`` on the dataset's batch iterator (the fancy-index
-    gather) is one ``train/input_gather`` span. Runs wherever the
-    iterator is pulled — the prefetch worker. Yields ``(trace_id,
-    batch)``: the batch's trace id is minted here and travels with it
-    through `_traced_place` and the queue to the consumer's
-    ``train/step``, so one batch reads gather -> place -> step."""
+    ``next()`` on `_HostRing.epoch` (the gather of one batch's rows)
+    is one ``train/input_gather`` span. Runs wherever the iterator is
+    pulled — the prefetch worker. Yields ``(trace_id, batch)``: the
+    batch's trace id is minted here and travels with it through
+    `_traced_place` and the queue to the consumer's ``train/step``,
+    so one batch reads gather -> place -> step."""
     it = iter(it)
     done = object()
     while True:
         t0_wall, t0 = time.time(), time.perf_counter()
         with tracing.annotate("train/input_gather"):
-            batch = next(it, done)
+            item = next(it, done)
         dur_s = time.perf_counter() - t0
-        if batch is done:
+        if item is done:
             return
+        batch, fields = item
         tid = tracing.new_trace_id()
         tracing.record_span((tid, None), "train/input_gather",
                             t0_wall, dur_s,
                             rows=_batch_dim(batch[0]),
-                            bytes=_nbytes(batch))
+                            bytes=_nbytes(batch), **fields)
         yield tid, batch
 
 
-def _traced_place(place):
-    """``place`` (relayout + ``device_put``) as the batch's
-    ``train/input_place`` span; takes and returns ``(trace_id, …)``."""
+def _traced_place(place, ring: _HostRing):
+    """``place`` (``device_put``; the runtime's relayout and copy follow
+    on its own threads) and the ring's wait for its next buffer as the
+    batch's ``train/input_place`` span; takes and returns
+    ``(trace_id, …)``."""
     def traced(item):
         tid, batch = item
         with tracing.trace("train/input_place", trace_id=tid,
-                           bytes=_nbytes(batch)):
-            return tid, place(batch)
+                           bytes=_nbytes(batch)) as tr:
+            placed = place(batch)
+            tr.annotate(reuse_wait_s=round(ring.placed(placed), 6))
+            return tid, placed
     return traced
 
 
@@ -902,6 +988,10 @@ class Estimator:
         slo_lib.ensure_default_slos("training")
         ledger = goodput_lib.ledger_for_backend()
         turn: "Optional[_EpochTurn]" = None
+        depth = _prefetch_depth()
+        # one buffer being written, `depth` placed and in the queue
+        ring = _HostRing(ds, batch_size, max(depth, 0) + 1,
+                         self.ctx.conf.ingest_threads)
 
         try:
             for epoch in range(1, nb_epoch + 1):
@@ -922,9 +1012,8 @@ class Estimator:
                 # otherwise pin depth+1 device-resident batches
                 # (notebook OOM-retry trap)
                 batches = _prefetch_iter(
-                    _traced_gather(ds.iter_batches(
-                        batch_size, shuffle=True, seed=epoch)),
-                    _traced_place(_place), _prefetch_depth())
+                    _traced_gather(ring.epoch(seed=epoch)),
+                    _traced_place(_place, ring), depth)
                 ep_span = obs.span("train/epoch", epoch=epoch,
                                    step=self.step)
                 with ep_span:
@@ -1110,6 +1199,7 @@ class Estimator:
                                      if k.startswith("val_")})):
                     break
         finally:
+            ring.close()
             if turn is not None:  # the run's last epoch, or an error
                 turn.close()
             if self._profiling:  # run ended inside the trace window

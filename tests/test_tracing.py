@@ -385,8 +385,8 @@ def train_records():
 
 
 @pytest.mark.parametrize("name,count,fields", [
-    ("train/input_gather", 8, {"rows", "bytes"}),
-    ("train/input_place", 8, {"bytes"}),
+    ("train/input_gather", 8, {"rows", "bytes", "threads", "recycled"}),
+    ("train/input_place", 8, {"bytes", "reuse_wait_s"}),
     ("train/step", 8, {"step", "epoch", "data_wait_s", "dispatch_s"}),
     ("train/epoch_turn", 2, {"epoch", "fetch_s"}),
     ("train/flops_lowering", 1, set()),
@@ -405,6 +405,8 @@ def test_train_span_in_store_with_fields(train_records, name, count,
         assert recs[0]["fields"]["rows"] == 8
         assert recs[0]["fields"]["bytes"] == 8 * (8 * 8 * 3 + 3) * 4
         assert {r["thread"] for r in recs} == {"zoo-tpu-prefetch"}
+        # 2 KB a batch: one thread, whatever `ingest_threads` allows
+        assert {r["fields"]["threads"] for r in recs} == {1}
 
 
 def test_train_batch_reads_gather_place_step(train_records):
