@@ -6,7 +6,7 @@
 IMAGE ?= analytics-zoo-tpu
 
 .PHONY: test docker-build docker-test docker-test-spark dist docs \
-    lint obs-smoke fused-conformance flops-audit serving-smoke \
+    lint obs-smoke flops-audit serving-smoke \
     bench-serving bench-serving-fleet trace-smoke trace-report \
     slo-smoke perf-sentinel fleet-smoke generate-smoke \
     bench-generate chaos-smoke autotune autotune-smoke \
@@ -27,14 +27,6 @@ test:
 	$(MAKE) autotune-smoke
 	$(MAKE) dashboard-smoke
 	python scripts/perf_sentinel.py --advisory
-
-# conv+BN (+ residual-epilogue) conformance: the exact Pallas kernel
-# code paths the fused ResNet runs on chip, exercised under the
-# interpreter on the host CPU — values, gradients (Pallas vs XLA
-# backward), moving state, bf16, padded grids, DP sharding. Tier-1
-# safe; documented next to the MFU roofline in PERF.md.
-fused-conformance:
-	JAX_PLATFORMS=cpu python -m pytest tests/test_conv_bn.py -q
 
 # telemetry end-to-end: 2 train steps + 1 served request, then assert
 # the /metrics exposition carries every layer (docs/observability.md)
@@ -65,8 +57,8 @@ EVENTS ?= /tmp/zoo_tpu_trace_smoke.events.jsonl
 trace-report:
 	python scripts/trace_report.py --events $(EVENTS)
 
-# executed-FLOPs audit of the ResNet-50 train step, phase backward
-# off vs on (lowering only — CPU-safe, no chip; docs/perf_flags.md)
+# executed-FLOPs audit of the ResNet-50 train step (lowering only —
+# CPU-safe, no chip; docs/perf_flags.md)
 flops-audit:
 	JAX_PLATFORMS=cpu python scripts/flops_audit.py --image 96
 

@@ -7,8 +7,8 @@ SGD + warmup + poly decay, checkpoint every epoch) rebuilt TPU-first:
 - augmentation ON DEVICE inside the jitted train step
   (`feature/image/device_transforms`): Inception-style
   random-resized crop, hflip, color jitter, normalize;
-- model: `resnet50(space_to_depth=..., fused=...)` — the Pallas
-  fused conv+BN bottleneck path when enabled/measured;
+- model: `resnet50(space_to_depth=...)`, the space-to-depth stem
+  for even crop sizes;
 - training: Estimator over the mesh's ``data`` axis (bf16 activations
   on TPU by default), SGD momentum + warmup→poly schedule, epoch
   checkpoints (async write capable via ZOO_TPU_ASYNC_CKPT=1).
@@ -40,9 +40,6 @@ def main(argv=None):
     p.add_argument("--classes", type=int, default=10)
     p.add_argument("--lr", type=float, default=0.1)
     p.add_argument("--checkpoint", default=None)
-    p.add_argument("--fused", default="auto",
-                   choices=["auto", "0", "1", "defer"],
-                   help="Pallas fused conv+BN path")
     args = p.parse_args(argv)
 
     import jax
@@ -97,10 +94,8 @@ def main(argv=None):
                     (58.393, 57.12, 57.375)))
 
     # -- model + recipe ------------------------------------------------
-    fused = {"0": False, "1": True, "defer": "defer"}.get(
-        args.fused, "auto")
     model = resnet50(input_shape=(s, s, 3), classes=classes,
-                     space_to_depth=(s % 2 == 0), fused=fused)
+                     space_to_depth=(s % 2 == 0))
     steps_per_epoch = max(1, (len(x) // batch))
     total_steps = steps_per_epoch * args.epochs
     warm = max(1, total_steps // 20)
@@ -114,8 +109,7 @@ def main(argv=None):
         est.set_checkpoint(args.checkpoint, trigger=EveryEpoch())
 
     res = est.train(x, y, batch_size=batch, nb_epoch=args.epochs)
-    print(f"devices={n} crop={s} batch={batch} fused={args.fused} "
-          f"steps={est.step}")
+    print(f"devices={n} crop={s} batch={batch} steps={est.step}")
     print(f"final epoch loss={res.history[-1]['loss']:.4f} "
           f"throughput={res.history[-1]['throughput']:.1f} img/s")
     return res.history

@@ -31,6 +31,13 @@ class ZooModel:
         """Constructor kwargs needed to rebuild this model."""
         return {}
 
+    @classmethod
+    def from_hyper_parameters(cls, hp: dict) -> "ZooModel":
+        """Rebuild from a saved config's ``hyper_parameters``; a
+        model whose saved configs outlived a constructor argument
+        checks them here."""
+        return cls(**hp)
+
     # -- common surface -----------------------------------------------------
     @property
     def model(self) -> KerasNet:
@@ -106,7 +113,7 @@ class ZooModel:
             raise ValueError(
                 f"{state['module']}.{state['class']} is not a ZooModel "
                 "subclass (tampered file?)")
-        inst = klass(**state["hyper_parameters"])
+        inst = klass.from_hyper_parameters(state["hyper_parameters"])
         inst.compile()  # default compile; caller may re-compile
         est = inst.model.estimator
         _check_params_compatible(inst.model, state["params"])

@@ -4,40 +4,19 @@ registry): a ZooModel dispatching to named architectures."""
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 from analytics_zoo_tpu.models.common import ZooModel
 
 
-def _fused_resnet() -> bool:
-    """ZOO_TPU_FUSED_RESNET: "1"/"0" pin the fused Pallas conv+BN
-    bottlenecks (`ops/conv_bn.py`) on/off; "auto" (the default) routes
-    fused on a TPU backend once `conv_bn.fused_profitable()` reports a
-    measured on-chip win — the same policy shape as attention's
-    flash "auto" (`ops/attention.py:33-61`)."""
-    import os
-    mode = os.environ.get("ZOO_TPU_FUSED_RESNET", "auto")
-    if mode == "auto":
-        from analytics_zoo_tpu.ops.conv_bn import fused_profitable
-        return fused_profitable()
-    return mode == "1"
-
-
-def _build_resnet(depth, s, c, fused=False):
-    from analytics_zoo_tpu.models.image.imageclassification.resnet \
-        import ResNet
-    return ResNet(depth).build(s, c, fused=fused)
-
-
 def _builders():
     """Single name→builder registry; ARCHS derives from its keys so the
-    validation tuple and the dispatch can never drift. ResNet builders
-    accept ``fused=`` (the rest are fixed-layout)."""
-    import functools
-
+    validation tuple and the dispatch can never drift."""
     from analytics_zoo_tpu.models.image.imageclassification import archs
     from analytics_zoo_tpu.models.image.imageclassification.lenet import \
         lenet5
+    from analytics_zoo_tpu.models.image.imageclassification.resnet \
+        import ResNet
     reg = {
         "lenet-5": lenet5,
         "vgg-16": archs.vgg16,
@@ -49,7 +28,7 @@ def _builders():
         "squeezenet": archs.squeezenet,
     }
     for d in (50, 101, 152):
-        reg[f"resnet-{d}"] = functools.partial(_build_resnet, d)
+        reg[f"resnet-{d}"] = ResNet(d).build
     return reg
 
 
@@ -69,13 +48,7 @@ class ImageClassifier(ZooModel):
 
     def __init__(self, model_name: str = "resnet-50",
                  input_shape: Tuple[int, int, int] = (224, 224, 3),
-                 classes: int = 1000,
-                 fused: Optional[bool] = None):
-        """``fused``: ResNets only — build with the fused Pallas
-        conv+BN bottlenecks. None resolves the ``ZOO_TPU_FUSED_RESNET``
-        env default AT CONSTRUCTION and the resolved value persists in
-        ``hyper_parameters`` (a checkpoint reloads the architecture it
-        was saved with, regardless of the loading process's env)."""
+                 classes: int = 1000):
         super().__init__()
         name = model_name.lower()
         if name not in _builders():
@@ -84,65 +57,31 @@ class ImageClassifier(ZooModel):
         self.model_name = name
         self.input_shape = tuple(input_shape)
         self.classes = int(classes)
-        if fused is None:
-            fused = name.startswith("resnet-") and _fused_resnet()
-        self.fused = bool(fused)
-        if self.fused and not name.startswith("resnet-"):
-            raise ValueError(f"fused=True is ResNet-only, not {name}")
-
-    def load_weights(self, path: str):
-        """Load a ``save_weights`` ``.npz``; for ResNets a checkpoint
-        saved in a DIFFERENT layout (unfused ↔ per-block fused ↔
-        stage) is converted on the fly via `convert_resnet_params` —
-        the checkpoint-portability leg of the fused "auto" default:
-        existing unfused checkpoints load into the fused TPU runtime
-        without user action."""
-        try:
-            return super().load_weights(path)
-        except KeyError:
-            if not self.model_name.startswith("resnet-"):
-                raise
-        import jax
-        import numpy as np
-
-        from analytics_zoo_tpu.models.image.imageclassification \
-            .resnet import convert_resnet_params
-        est = self.model.estimator
-        if est.params is None:
-            est._ensure_initialized()
-        nested: dict = {}
-        with np.load(path) as data:
-            for key in data.files:
-                parts = key.split("/")
-                d = nested
-                for p in parts[:-1]:
-                    d = d.setdefault(p, {})
-                d[parts[-1]] = data[key]
-        target = jax.device_get(est.params)
-        converted = convert_resnet_params(nested, target)
-        for (kp1, l1), (kp2, l2) in zip(
-                jax.tree_util.tree_leaves_with_path(converted),
-                jax.tree_util.tree_leaves_with_path(target)):
-            if tuple(np.shape(l1)) != tuple(np.shape(l2)):
-                raise ValueError(
-                    f"shape mismatch at {kp2}: saved "
-                    f"{np.shape(l1)} vs model {np.shape(l2)}")
-        est.params = jax.device_put(converted)
-        est._train_step = None
-        return self
 
     def hyper_parameters(self):
         return {"model_name": self.model_name,
                 "input_shape": self.input_shape,
-                "classes": self.classes,
-                "fused": self.fused}
+                "classes": self.classes}
+
+    @classmethod
+    def from_hyper_parameters(cls, hp: dict):
+        """Configs saved before the fused conv+BN ResNet was deleted
+        carry ``"fused"``: false loads as it always did; true names
+        a parameter layout no builder makes any more, and is
+        refused."""
+        hp = dict(hp)
+        if hp.pop("fused", False):
+            raise ValueError(
+                f"this {hp.get('model_name', 'ResNet')} config was "
+                "saved with fused=True: the fused Pallas conv+BN "
+                "bottlenecks were deleted (PR 32: 2.8x slower a step "
+                "than the XLA graph on the v5e, PERF.md §6) and no "
+                "builder makes their parameter layout any more")
+        return cls(**hp)
 
     def build_model(self):
-        builder = _builders()[self.model_name]
-        if self.model_name.startswith("resnet-"):
-            return builder(self.input_shape, self.classes,
-                           fused=self.fused)
-        return builder(self.input_shape, self.classes)
+        return _builders()[self.model_name](self.input_shape,
+                                            self.classes)
 
     @classmethod
     def load_model(cls, path_or_name: str, weights_path=None,

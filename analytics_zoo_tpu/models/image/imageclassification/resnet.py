@@ -1,6 +1,11 @@
 """ResNet v1.5 for image classification — the nnframes ResNet-50/ImageNet
-headline workload (BASELINE.json: ≥45% MFU on v5e; reference recipe
-`examples/inception/Train.scala` is the equivalent CNN training recipe).
+headline workload (reference recipe `examples/inception/Train.scala`
+is the equivalent CNN training recipe).
+
+One graph, all XLA: every block is `_bottleneck` (Convolution2D +
+BatchNormalization layers); the Pallas fused conv+BN bottlenecks and
+the phase-decomposed strided backward that used to sit beside it ran
+slower on the v5e and were deleted (PERF.md §6, PR 32).
 
 TPU-first choices:
 - NHWC layout end-to-end (native TPU conv layout).
@@ -27,12 +32,6 @@ from analytics_zoo_tpu.pipeline.api.keras.layers import (
 
 def conv_bn(x, filters, kernel, stride=1, activation="relu",
              name=None):
-    # strided convs (the stem 7x7 s2, stage-transition 3x3 s2 and
-    # 1x1 s2 shortcuts of the unfused graph) inherit the gated
-    # phase-decomposed backward through Convolution2D._convolve
-    # (ops.conv_grad, ZOO_TPU_PHASE_BWD) — their input-dilated
-    # transpose-rule dx is the executed-FLOPs excess PERF.md round 6
-    # pinned
     x = Convolution2D(filters, kernel, kernel, subsample=stride,
                       border_mode="same", bias=False, name=name)(x)
     x = BatchNormalization(name=None if name is None else name + "_bn")(x)
@@ -129,233 +128,6 @@ def s2d_stem_kernel(k7: np.ndarray) -> np.ndarray:
     return np.ascontiguousarray(k2d.reshape(4, 4, 4 * c, f))
 
 
-class FusedBottleneck(KerasLayer):
-    """v1.5 bottleneck with the Pallas fused matmul+BN kernel
-    (`ops.conv_bn.matmul_bn`) on the 1×1 convs.
-
-    Same math as the `_bottleneck` subgraph (conv → BatchNorm with
-    moving-mean-shifted single-pass batch statistics → ReLU, residual
-    add), restructured for HBM traffic: the 1×1 convs run as matmuls
-    whose prologue applies the previous BN+ReLU in VMEM and whose
-    epilogue accumulates this BN's Σy/Σy² while writing the output —
-    per fused conv the activation tensor is written once instead of
-    written + read (stats) + read/written (apply). Every block's 3×3
-    — stride 1 AND the stage-transition stride 2 — runs through the
-    fused `conv3x3_bn` Pallas kernel (bn1's normalized activation
-    never exists in HBM; round 4 added the strided taps).
-
-    Params: ``c1/c2/c3[/down]`` HWIO kernels + ``bn1/bn2/bn3[/bnd]``
-    groups each ``{gamma, beta, _state:{moving_mean, moving_var}}`` —
-    the per-layer content of the unfused block, so weights can be
-    copied across layouts.
-
-    Eval mode: the Pallas kernels' stats epilogues still run but cost
-    no HBM traffic — they reduce the f32 accumulator already in VMEM.
-    """
-
-    def __init__(self, filters: int, stride: int = 1,
-                 downsample: bool = False, epsilon: float = 1e-3,
-                 momentum: float = 0.99, init="glorot_uniform",
-                 input_shape=None, name=None, **kwargs):
-        super().__init__(input_shape=input_shape, name=name, **kwargs)
-        self.filters = int(filters)
-        self.stride = int(stride)
-        self.downsample = bool(downsample)
-        self.epsilon = float(epsilon)
-        self.momentum = float(momentum)
-        self.kernel_init = initializers.get(init)
-
-    def _bn_init(self, n):
-        return {"gamma": jnp.ones((n,), jnp.float32),
-                "beta": jnp.zeros((n,), jnp.float32),
-                "_state": {"moving_mean": jnp.zeros((n,), jnp.float32),
-                           "moving_var": jnp.ones((n,), jnp.float32)}}
-
-    def build(self, rng, input_shape):
-        c = input_shape[-1]
-        f = self.filters
-        ks = jax.random.split(rng, 4)
-        params = {
-            "c1": self.kernel_init(ks[0], (1, 1, c, f)),
-            "c2": self.kernel_init(ks[1], (3, 3, f, f)),
-            "c3": self.kernel_init(ks[2], (1, 1, f, 4 * f)),
-            "bn1": self._bn_init(f),
-            "bn2": self._bn_init(f),
-            "bn3": self._bn_init(4 * f),
-        }
-        if self.downsample:
-            params["down"] = self.kernel_init(ks[3], (1, 1, c, 4 * f))
-            params["bnd"] = self._bn_init(4 * f)
-        return params
-
-    def _bn_vectors(self, bn, ssum, ssq, count, training):
-        """(scale, shift, updates) via the SHARED BatchNorm scheme
-        (`normalization.bn_batch_stats`/`bn_fold` — the same code the
-        unfused layer runs, so the two layouts cannot drift)."""
-        from analytics_zoo_tpu.pipeline.api.keras.layers \
-            .normalization import bn_batch_stats, bn_fold
-        state = bn["_state"]
-        if training:
-            mean, var, upd = bn_batch_stats(ssum, ssq, count, state,
-                                            self.momentum)
-        else:
-            mean, var = state["moving_mean"], state["moving_var"]
-            upd = {}
-        scale, shift = bn_fold(mean, var, bn["gamma"], bn["beta"],
-                               self.epsilon)
-        return scale, shift, upd
-
-    def apply(self, params, x, *, training=False, rng=None):
-        if not training:
-            return self._apply_eval(params, x), {}
-        return self._apply_train(params, x)
-
-    def _apply_train(self, params, x, *, pending_in=None,
-                     defer_out=False):
-        """Training forward. ``pending_in``/``defer_out`` implement
-        the DEFERRED-APPLY scheme (`fused_stage_forward`): a pending
-        value is ``(y3, scale3, shift3, sc)`` representing the
-        previous block's unmaterialized output
-        ``relu(y3·scale3+shift3 + sc)``. With ``pending_in``, this
-        block's c1 consumes it in the kernel prologue
-        (`matmul_bn(in_residual=)`) — the previous block's output
-        never gets its own whole-tensor pass; the block's own
-        shortcut re-derives it as a fused 3-input elementwise. With
-        ``defer_out`` (stride-1, no downsample only) this block
-        returns its own pending tuple instead of materializing."""
-        from analytics_zoo_tpu.ops.conv_bn import conv1x1_bn, conv3x3_bn
-        if pending_in is not None and self.downsample:
-            raise ValueError("pending input requires an identity "
-                             "shortcut (no downsample)")
-        if defer_out and (self.stride != 1 or self.downsample):
-            raise ValueError("defer_out requires a stride-1 "
-                             "identity-shortcut block")
-        updates = {}
-        mm = lambda bn: jax.lax.stop_gradient(
-            params[bn]["_state"]["moving_mean"])
-
-        # c1: 1×1 matmul + bn1 stats epilogue (with a pending input,
-        # the previous bn3 apply + residual + relu fold into the
-        # prologue)
-        if pending_in is None:
-            y1, s1, q1 = conv1x1_bn(x, params["c1"],
-                                    stat_shift=mm("bn1"))
-        else:
-            y3p, s3p, t3p, scp = pending_in
-            y1, s1, q1 = conv1x1_bn(
-                y3p, params["c1"], in_scale=s3p, in_shift=t3p,
-                relu_in=True, in_residual=scp, stat_shift=mm("bn1"))
-            # the block's own shortcut: re-derive the previous output
-            # (XLA fuses this 3-input elementwise into its consumer —
-            # cheaper than materializing out_prev with its own pass)
-            x = jnp.maximum(
-                y3p * s3p.astype(y3p.dtype) + t3p.astype(y3p.dtype) +
-                scp.astype(y3p.dtype), 0)
-        n1 = float(np.prod(y1.shape[:-1]))
-        scale1, shift1, upd1 = self._bn_vectors(
-            params["bn1"], s1, q1, n1, True)
-        if upd1:
-            updates["bn1"] = upd1
-
-        # c2: fused Pallas 3×3 at either stride — bn1 apply+relu in
-        # the prologue (the normalized activation never exists in
-        # HBM), bn2 stats in the epilogue. Round 3 kept the strided
-        # blocks on an XLA conv (+ a separate apply pass and stats
-        # reduction); the stride-2 kernel (VERDICT r4 lever) removes
-        # those three whole-tensor transfers.
-        y2, s2, q2 = conv3x3_bn(
-            y1, params["c2"], in_scale=scale1, in_shift=shift1,
-            relu_in=True, stat_shift=mm("bn2"), stride=self.stride)
-        n2 = float(np.prod(y2.shape[:-1]))
-        scale2, shift2, upd2 = self._bn_vectors(
-            params["bn2"], s2, q2, n2, True)
-        if upd2:
-            updates["bn2"] = upd2
-
-        # c3: bn2-apply+relu prologue, 1×1 matmul, bn3 stats epilogue
-        y3, s3, q3 = conv1x1_bn(
-            y2, params["c3"], in_scale=scale2, in_shift=shift2,
-            relu_in=True, stat_shift=mm("bn3"))
-        n3 = float(np.prod(y3.shape[:-1]))
-        scale3, shift3, upd3 = self._bn_vectors(
-            params["bn3"], s3, q3, n3, True)
-        if upd3:
-            updates["bn3"] = upd3
-
-        if self.downsample:
-            # the strided 1x1 shortcut slices x[::2, ::2] BEFORE the
-            # matmul (conv1x1_bn), so its backward is a cheap
-            # zero-pad — it never had the input-dilated conv the
-            # phase backward (ops.conv_grad) removes from the
-            # stage-transition 3x3 above and from the unfused graph
-            ysc, sd, qd = conv1x1_bn(x, params["down"],
-                                     stride=self.stride,
-                                     stat_shift=mm("bnd"))
-            nd = float(np.prod(ysc.shape[:-1]))
-            scaled, shiftd, updd = self._bn_vectors(
-                params["bnd"], sd, qd, nd, True)
-            if updd:
-                updates["bnd"] = updd
-            shortcut = ysc * scaled.astype(ysc.dtype) + \
-                shiftd.astype(ysc.dtype)
-        else:
-            shortcut = x
-        if defer_out:
-            # hand (y3, scale3, shift3, sc) to the next block's c1
-            # prologue instead of materializing the output
-            return (y3, scale3, shift3, shortcut), updates
-        # bn3 apply + residual add + relu: one elementwise pass
-        out = jnp.maximum(
-            y3 * scale3.astype(y3.dtype) + shift3.astype(y3.dtype) +
-            shortcut.astype(y3.dtype), 0)
-        return out, updates
-
-    def _apply_eval(self, params, x):
-        """Eval: every BN is a known moving-stats fold, so the whole
-        block runs in three kernels with NO whole-tensor elementwise
-        pass — c3's epilogue applies bn3 + residual + ReLU while the
-        output writes (`matmul_bn_apply`), and the downsample shortcut
-        folds bnd the same way. The raw y3 never exists in HBM
-        (round-4 inference lever; the training path cannot do this —
-        bn3's batch statistics only exist after the matmul)."""
-        from analytics_zoo_tpu.ops.conv_bn import (
-            conv1x1_bn_apply, conv3x3_bn_apply)
-        none = (None,) * 3
-        scale1, shift1, _ = self._bn_vectors(params["bn1"], *none,
-                                             training=False)
-        scale2, shift2, _ = self._bn_vectors(params["bn2"], *none,
-                                             training=False)
-        scale3, shift3, _ = self._bn_vectors(params["bn3"], *none,
-                                             training=False)
-        # every epilogue applies its BN fold directly — no statistics
-        # computed anywhere, no whole-tensor elementwise pass
-        z1 = conv1x1_bn_apply(x, params["c1"], out_scale=scale1,
-                              out_shift=shift1, relu_out=True)
-        z2 = conv3x3_bn_apply(z1, params["c2"], out_scale=scale2,
-                              out_shift=shift2, relu_out=True,
-                              stride=self.stride)
-        if self.downsample:
-            scaled, shiftd, _ = self._bn_vectors(params["bnd"], *none,
-                                                 training=False)
-            shortcut = conv1x1_bn_apply(
-                x, params["down"], stride=self.stride,
-                out_scale=scaled, out_shift=shiftd)
-        else:
-            shortcut = x
-        return conv1x1_bn_apply(
-            z2, params["c3"], out_scale=scale3, out_shift=shift3,
-            residual=shortcut, relu_out=True)
-
-    def call(self, params, x, *, training=False, rng=None):
-        y, _ = self.apply(params, x, training=training, rng=rng)
-        return y
-
-    def compute_output_shape(self, input_shape):
-        h, w, _ = input_shape
-        s = self.stride
-        return ((h + s - 1) // s, (w + s - 1) // s, 4 * self.filters)
-
-
 class ResNet:
     """Builder; `ResNet(depth).build(input_shape, classes)` → keras Model."""
 
@@ -369,17 +141,10 @@ class ResNet:
         self.depth = depth
 
     def build(self, input_shape=(224, 224, 3), classes: int = 1000,
-              space_to_depth: bool = False,
-              fused=False) -> Model:
-        """``fused=True`` uses :class:`FusedBottleneck` (the Pallas
-        matmul+BN kernel on the 1×1 convs) — same math, less HBM
-        traffic; ``fused="defer"`` additionally runs each stage as
-        one :class:`FusedStage` with the chained deferred-apply
-        scheme. Weights are per-conv/per-BN in every layout
-        (`convert_resnet_params` maps between them)."""
-        if fused not in (False, True, "defer"):
-            raise ValueError(f"fused must be False/True/'defer', "
-                             f"got {fused!r}")
+              space_to_depth: bool = False) -> Model:
+        """Stem (7x7/s2, or its space-to-depth form), 3x3/s2 max
+        pool, the stages of `_bottleneck` blocks, global average
+        pool and the classifier."""
         blocks = self.DEPTH_BLOCKS[self.depth]
         inp = Input(input_shape, name="image")
         if space_to_depth:
@@ -396,213 +161,18 @@ class ResNet:
         filters = 64
         for stage, n_blocks in enumerate(blocks):
             first_stride = 2 if stage > 0 else 1
-            if fused == "defer":
-                x = FusedStage(filters, n_blocks,
-                               first_stride=first_stride,
-                               name=f"s{stage}")(x)
-            else:
-                for b in range(n_blocks):
-                    stride = first_stride if b == 0 else 1
-                    if fused:
-                        x = FusedBottleneck(filters, stride=stride,
-                                            downsample=(b == 0),
-                                            name=f"s{stage}b{b}")(x)
-                    else:
-                        x = _bottleneck(x, filters, stride=stride,
-                                        downsample=(b == 0),
-                                        name=f"s{stage}b{b}")
+            for b in range(n_blocks):
+                stride = first_stride if b == 0 else 1
+                x = _bottleneck(x, filters, stride=stride,
+                                downsample=(b == 0),
+                                name=f"s{stage}b{b}")
             filters *= 2
         x = GlobalAveragePooling2D()(x)
         out = Dense(classes, name="fc")(x)
         return Model(inp, out, name=f"resnet{self.depth}")
 
 
-class FusedStage(KerasLayer):
-    """One ResNet stage as a SINGLE layer running its
-    `FusedBottleneck` blocks through `fused_stage_forward` (the
-    chained deferred-apply scheme — `resnet50(fused="defer")`).
-    Params nest per block: ``{"b0": <FusedBottleneck params>, ...}``,
-    so `convert_resnet_params` maps them to/from the other layouts by
-    name."""
-
-    def __init__(self, filters: int, n_blocks: int,
-                 first_stride: int = 1, epsilon: float = 1e-3,
-                 momentum: float = 0.99, init="glorot_uniform",
-                 input_shape=None, name=None, **kwargs):
-        super().__init__(input_shape=input_shape, name=name, **kwargs)
-        self.filters = int(filters)
-        self.n_blocks = int(n_blocks)
-        self.first_stride = int(first_stride)
-        self.blocks = [
-            FusedBottleneck(filters,
-                            stride=first_stride if b == 0 else 1,
-                            downsample=(b == 0), epsilon=epsilon,
-                            momentum=momentum, init=init,
-                            name=f"b{b}")
-            for b in range(self.n_blocks)]
-
-    def build(self, rng, input_shape):
-        params = {}
-        shape = input_shape
-        for b, blk in enumerate(self.blocks):
-            params[f"b{b}"] = blk.build(
-                jax.random.fold_in(rng, b), shape)
-            shape = blk.compute_output_shape(shape)
-        return params
-
-    def apply(self, params, x, *, training=False, rng=None):
-        out, upds = fused_stage_forward(
-            self.blocks, [params[f"b{b}"]
-                          for b in range(self.n_blocks)],
-            x, training=training)
-        updates = {f"b{b}": u for b, u in enumerate(upds) if u}
-        return out, updates
-
-    def call(self, params, x, *, training=False, rng=None):
-        y, _ = self.apply(params, x, training=training, rng=rng)
-        return y
-
-    def compute_output_shape(self, input_shape):
-        shape = input_shape
-        for blk in self.blocks:
-            shape = blk.compute_output_shape(shape)
-        return shape
-
-
-def fused_stage_forward(blocks, params_list, x, training=True):
-    """Run a stage of `FusedBottleneck` blocks with CHAINED deferred
-    apply (the round-5/6 HBM-traffic lever, exercised here for
-    conformance ahead of the on-chip measurement that decides whether
-    the ResNet builder adopts it):
-
-    EVERY eligible block (stride-1 identity shortcut, not the last)
-    defers its final bn3+residual+ReLU pass; the NEXT block consumes
-    the pending ``(y3, scale3, shift3, sc)`` in its c1 kernel
-    prologue (`matmul_bn(in_residual=)`), re-derives its own shortcut
-    as a fused elementwise, and — when itself eligible — defers its
-    own tail in turn. Per deferred block, one whole-tensor write (and
-    its read-back) of the stage's widest tensor disappears; in a
-    stage of B blocks all B−1 interior tails ride their successor's
-    kernel (the round-5 scheme alternated, saving only ⌊(B−1)/2⌋).
-    Same math as running the blocks sequentially; eval mode just
-    chains the (already optimal) eval folds.
-
-    ``blocks``/``params_list``: the stage's `FusedBottleneck` layers
-    and their param dicts. Returns ``(out, updates_per_block)``."""
-    if len(blocks) != len(params_list):
-        raise ValueError(f"{len(blocks)} blocks but "
-                         f"{len(params_list)} param dicts")
-    if not training:
-        out, upds = x, []
-        for blk, p in zip(blocks, params_list):
-            out, u = blk.apply(p, out, training=False)
-            upds.append(u)
-        return out, upds
-    updates_per_block = []
-    pending = None
-    for i, (blk, p) in enumerate(zip(blocks, params_list)):
-        eligible = (blk.stride == 1 and not blk.downsample)
-        # chain: a block consuming a pending may defer its own tail
-        # too — only the next block's ability to CONSUME gates it
-        defer = (eligible
-                 and i + 1 < len(blocks)
-                 and blocks[i + 1].stride == 1
-                 and not blocks[i + 1].downsample)
-        out, upd = blk._apply_train(
-            p, x if pending is None else None,
-            pending_in=pending, defer_out=defer)
-        updates_per_block.append(upd)
-        if defer:
-            pending = out
-        else:
-            pending = None
-            x = out
-    return x, updates_per_block
-
-
-# fused param-group name ↔ unfused layer-name suffix, per block
-_FUSED_PARTS = [("c1", "_c1", "kernel"), ("c2", "_c2", "kernel"),
-                ("c3", "_c3", "kernel"), ("down", "_down", "kernel"),
-                ("bn1", "_c1_bn", None), ("bn2", "_c2_bn", None),
-                ("bn3", "_c3_bn", None), ("bnd", "_down_bn", None)]
-
-
-def convert_resnet_params(src_params: dict, dst_params: dict) -> dict:
-    """Translate a ResNet params dict BETWEEN the fused and unfused
-    layouts (same depth/stem/classes): a `FusedBottleneck` layer
-    ``s{i}b{j}`` groups exactly the per-conv/per-BN entries the
-    unfused graph keeps as separate ``s{i}b{j}_c1`` /
-    ``s{i}b{j}_c1_bn`` / … layers, so pretrained weights move across
-    layouts losslessly in either direction (the checkpoint-portability
-    contract behind the ``fused`` construction flag — an unfused-saved
-    `.model` loads into the fused TPU runtime and vice versa).
-    The stage layout (`fused="defer"`: one ``s{i}`` layer with nested
-    ``b{j}`` block groups) converts to/from both as well. Non-block
-    layers (stem, fc) copy by name. Returns a params dict shaped like
-    ``dst_params``."""
-    import re
-
-    def src_block(flat):
-        """The fused param group for flat block name ``s{i}b{j}``,
-        from a per-block-fused, stage, or unfused source."""
-        if flat in src_params:
-            return src_params[flat]
-        msb = re.fullmatch(r"(s\d+)(b\d+)", flat)
-        if msb and msb.group(1) in src_params and \
-                msb.group(2) in src_params[msb.group(1)]:
-            return src_params[msb.group(1)][msb.group(2)]
-        return None
-
-    def gather_unfused(flat, like):
-        grp = {}
-        for key, suffix, leaf in _FUSED_PARTS:
-            if key not in like:
-                continue
-            layer = src_params[flat + suffix]
-            grp[key] = layer[leaf] if leaf else layer
-        return grp
-
-    out = {}
-    for name, sub in dst_params.items():
-        if not jax.tree_util.tree_leaves(sub):
-            out[name] = sub     # parameterless (Activation, pooling)
-        elif name in src_params:
-            out[name] = src_params[name]            # same layout
-        elif isinstance(sub, dict) and "bn1" in sub and "c1" in sub:
-            # dst per-block fused ← src stage or unfused
-            grp = src_block(name)
-            out[name] = grp if grp is not None else \
-                gather_unfused(name, sub)
-        elif isinstance(sub, dict) and all(
-                re.fullmatch(r"b\d+", k) for k in sub):
-            # dst STAGE ← src per-block fused or unfused
-            stage = {}
-            for bkey, bsub in sub.items():
-                flat = name + bkey
-                grp = src_block(flat)
-                stage[bkey] = grp if grp is not None else \
-                    gather_unfused(flat, bsub)
-            out[name] = stage
-        elif "_c" in name or "_down" in name:
-            # dst unfused ← src per-block fused or stage
-            base, _, suffix = name.partition("_")
-            key = next(k for k, sfx, _ in _FUSED_PARTS
-                       if sfx == "_" + suffix)
-            leaf = dict(
-                (k, l) for k, _, l in _FUSED_PARTS)[key]
-            grp = src_block(base)
-            if grp is None:
-                raise KeyError(f"no source block for {base!r}")
-            out[name] = {"kernel": grp[key]} if leaf else grp[key]
-        else:
-            raise KeyError(
-                f"layer {name!r} has no counterpart in the source "
-                "params (different depth/stem?)")
-    return out
-
-
 def resnet50(input_shape=(224, 224, 3), classes: int = 1000,
-             space_to_depth: bool = False,
-             fused=False) -> Model:
+             space_to_depth: bool = False) -> Model:
     return ResNet(50).build(input_shape, classes,
-                            space_to_depth=space_to_depth, fused=fused)
+                            space_to_depth=space_to_depth)

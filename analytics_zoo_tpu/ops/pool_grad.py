@@ -1,10 +1,11 @@
 """Mask-based backward for 2-D max pooling.
 
-PERF.md carries the stem maxpool backward as an open small lever
-(~1.5% of the ResNet step): jax differentiates `reduce_window(max)`
-through XLA's `select_and_scatter`, a sequential window scan that
-lowers poorly on TPU. The backward here is dense vector work
-instead: re-extract the k^2 strided window patches of the (padded)
+jax differentiates `reduce_window(max)` through XLA's
+`select_and_scatter`, a sequential window scan. The backward here,
+the default of every `MaxPooling2D`, is dense vector work instead
+(23.3% of the ResNet-50 step's device time on the v5e, PERF.md §5;
+it has not been paired against `select_and_scatter` on the chip:
+ROADMAP D12): re-extract the k^2 strided window patches of the (padded)
 input, mask each against the pooled output (``patch == y``), and
 distribute the cotangent by mask / tie-count — k^2 compares, one
 count, k^2 pad-shifted adds, all trivially fusable element-wise HLO.
@@ -23,15 +24,37 @@ from __future__ import annotations
 
 import functools
 import os
-from typing import Tuple
+from typing import Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops.conv_grad import normalize_padding
-
-# test observability, like ops.conv_grad.invocations
+# test observability: traces through each side of the custom VJP
 invocations = {"fwd": 0, "bwd_mask": 0}
+
+
+def _same_pads(size: int, k: int, stride: int) -> Tuple[int, int]:
+    out = -(-size // stride)
+    total = max((out - 1) * stride + k - size, 0)
+    lo = total // 2
+    return lo, total - lo
+
+
+def normalize_padding(padding, x_spatial: Sequence[int],
+                      k_spatial: Sequence[int],
+                      stride: Sequence[int]
+                      ) -> Tuple[Tuple[int, int], ...]:
+    """Resolve "SAME"/"VALID"/explicit padding to per-dim (lo, hi)
+    pairs (jax's own SAME algebra: lo = total // 2)."""
+    if isinstance(padding, str):
+        p = padding.upper()
+        if p == "VALID":
+            return tuple((0, 0) for _ in x_spatial)
+        if p == "SAME":
+            return tuple(_same_pads(sz, k, s) for sz, k, s in
+                         zip(x_spatial, k_spatial, stride))
+        raise ValueError(f"padding must be SAME|VALID, got {padding}")
+    return tuple((int(lo), int(hi)) for lo, hi in padding)
 
 
 def mask_bwd_enabled() -> bool:

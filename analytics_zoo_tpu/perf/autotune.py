@@ -1,12 +1,11 @@
 """Persistent kernel autotuner: sweep once, memoize to disk.
 
 Every Pallas crossover in the tree used to be a hand-measured
-constant — the `_pick_blocks` heuristics in ``ops/conv_bn.py`` and
-``ops/flash_attention.py``, the dense-vs-flash gates in
-``ops/attention.py``, the ``ZOO_TPU_CONV_BN_PALLAS_BWD`` backward
-toggle. This module replaces those constants with a search-and-
-memoize layer in the AutoTVM/Ansor mold: measured configs beat
-analytic heuristics, and a persistent cache makes the search a
+constant — the `_pick_blocks` heuristic in
+``ops/flash_attention.py`` and the dense-vs-flash gates in
+``ops/attention.py``. This module replaces those constants with a
+search-and-memoize layer in the AutoTVM/Ansor mold: measured configs
+beat analytic heuristics, and a persistent cache makes the search a
 one-time cost.
 
 Decisions are keyed by ``(op, shape-signature, dtype, device-kind)``
@@ -89,12 +88,8 @@ NOISE_MARGIN = 0.02
 OVERRIDE_FLAGS = {
     "ZOO_TPU_FLASH_MIN_T": "attn_crossover",
     "ZOO_TPU_DECODE_FLASH_MIN_T": "decode_crossover",
-    "ZOO_TPU_CONV_BN_PALLAS_BWD": "conv_bn_bwd",
     "ZOO_TPU_ATTENTION": "attn_crossover:pin",
     "ZOO_TPU_FLASH_FORCE_INTERPRET": "attn_crossover:pin",
-    "ZOO_TPU_FUSED_WIN": "conv_bn_blocks:pin",
-    "ZOO_TPU_CONV3_BWD_F32": "conv_bn_bwd:pin",
-    "ZOO_TPU_PHASE_BWD": "conv_phase_bwd:pin",
     "ZOO_TPU_MAXPOOL_MASK_BWD": "maxpool_bwd:pin",
 }
 

@@ -6,10 +6,9 @@ input-dilated backward conv (jax's transpose rule for a strided
 conv's dx) is costed as if the hardware skipped the zeros. A
 systolic conv unit does not skip them — it executes
 ``out_elems x window_taps x Cin`` MACs regardless of what the taps
-read. That gap is exactly the executed-FLOPs excess PERF.md round 6
-pinned (~1.95x model on ResNet-50), and it is invisible to
-`cost_analysis()`; these counters make it visible so the
-phase-decomposition lever (ops.conv_grad) is measurable on CPU.
+read. That gap (~1.95x model on ResNet-50, a count and not a time)
+is invisible to `cost_analysis()`; these counters make it visible.
+The Estimator's goodput ledger reads them.
 
 Counting rules (MXU ops only — vector/elementwise work is excluded,
 which understates absolute FLOPs but leaves conv/dot ratios exact):
@@ -20,7 +19,7 @@ which understates absolute FLOPs but leaves conv/dot ratios exact):
   produces the FULL-resolution gradient with the full kernel at
   every position — the s^2 waste), and `rhs_dilate` inflates the
   effective window to (size-1)*d+1 per dim (a dilated dw slides
-  the full dilated footprint — the waste phase_dw eliminates).
+  the full dilated footprint).
 - ``dot``: 2 x out_elems x prod(lhs contracting extents).
 
 FLOPs here are 2 x MACs (one multiply + one add). Beware the

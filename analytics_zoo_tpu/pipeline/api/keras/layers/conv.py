@@ -19,8 +19,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from analytics_zoo_tpu.ops import (activations, conv_grad,
-                                   initializers, regularizers)
+from analytics_zoo_tpu.ops import (activations, initializers,
+                                   regularizers)
 from analytics_zoo_tpu.pipeline.api.keras.engine import KerasLayer, Shape
 
 
@@ -106,18 +106,6 @@ class _ConvND(KerasLayer):
 
     @jax.named_scope("zoo:conv/convolve")
     def _convolve(self, x, kernel):
-        # strided NHWC 2-D convs route through ops.conv_grad.conv2d:
-        # same forward, but the backward is gated between jax's
-        # transpose rule and the phase decomposition (which never
-        # materializes a dilated operand — the executed-FLOPs lever;
-        # ZOO_TPU_PHASE_BWD, trace-time)
-        if (self.ndim == 2 and self.groups == 1
-                and self.dilation == (1, 1)
-                and self.dim_ordering == "tf"
-                and max(self.subsample) > 1):
-            return conv_grad.conv2d(
-                x, kernel.astype(x.dtype), stride=self.subsample,
-                padding=self.border_mode)
         return jax.lax.conv_general_dilated(
             x, kernel.astype(x.dtype),
             window_strides=self.subsample,

@@ -25,9 +25,8 @@ from analytics_zoo_tpu.pipeline.api.keras.engine import KerasLayer, Shape
 
 def bn_batch_stats(ssum, ssq, count, state, momentum):
     """Batch mean/var from moving-mean-SHIFTED sums ``Σ(x−mm)`` /
-    ``Σ(x−mm)²`` plus the moving-average update — the single copy of
-    the scheme, shared by :class:`BatchNormalization` and the fused
-    ResNet bottleneck (`models/.../resnet.py`). The shift keeps
+    ``Σ(x−mm)²`` plus the moving-average update, for
+    :class:`BatchNormalization`. The shift keeps
     E[x²]−E[x]² from cancelling when |mean| ≫ std; the moving mean is
     stop-gradded (it is frozen state, not a differentiable input)."""
     mm = jax.lax.stop_gradient(state["moving_mean"])

@@ -114,8 +114,8 @@ class KerasNet(KerasLayer):
                         for inner in lyr.layers if inner.name in sub}
             def mask_sub(node):
                 # "_state" subtrees are non-trainable at ANY nesting
-                # depth (composite layers like FusedBottleneck keep
-                # per-BN state under params["bn1"]["_state"], ...)
+                # depth (a composite layer may keep per-BN state
+                # under params["bn1"]["_state"], ...)
                 if isinstance(node, dict):
                     return {k: (jax.tree_util.tree_map(
                                     lambda _: False, v)

@@ -3,8 +3,8 @@
 One process, one device, no fallback: it measures on the device JAX
 names and prints JSON lines {"metric", "value", "unit", "vs_baseline",
 "device", ...}; the last line is the complete record. It exits
-non-zero when the platform is not ``tpu``, or when any variant, the
-NCF leg or the BERT leg raises. ``ZOO_TPU_BENCH_PLATFORM=cpu`` runs
+non-zero when the platform is not ``tpu``, or when the ResNet step,
+the NCF leg or the BERT leg raises. ``ZOO_TPU_BENCH_PLATFORM=cpu`` runs
 the same code on the CPU for the tests; the record then says so
 (``device.platform`` and ``smoke``) and is not a measurement.
 
@@ -38,8 +38,7 @@ def _emit() -> None:
 
 
 def _resnet_train_chain(model, tx, loss_fn, steps):
-    """The ONE training-semantics definition every ResNet variant
-    uses: returns ``(train_step, run)`` where ``run`` is a
+    """Returns ``(train_step, run)`` where ``run`` is a
     ``steps``-long ``lax.scan`` chain of ``train_step`` over a fixed
     batch (one dispatch + one scalar fetch per measurement)."""
     import jax
@@ -97,12 +96,6 @@ def main():
     init_nncontext(tpu_mesh={"data": 1}, devices=devices[:1],
                    log_level="WARNING")
     s2d = os.environ.get("ZOO_TPU_BENCH_S2D", "1") == "1"
-    # ZOO_TPU_BENCH_FUSED: "auto" (default) measures the unfused XLA
-    # graph, the phase-backward variant, the Pallas fused-bottleneck
-    # variant AND the chained deferred-apply variant, reporting the
-    # fastest sane one; "0"/"1"/"defer"/"phase" pin a single variant.
-    # Any variant that raises fails the run.
-    fused_mode = os.environ.get("ZOO_TPU_BENCH_FUSED", "auto")
     loss_fn = losses.softmax_cross_entropy
     tx = optimizers.SGD(lr=0.1, momentum=0.9).to_optax()
 
@@ -137,167 +130,92 @@ def main():
         float(np.asarray(tiny(jnp.zeros((), jnp.float32))))
         overhead = min(overhead, time.perf_counter() - t0)
 
-    # FLOPs accounting baseline: HloCostAnalysis cannot see inside
-    # Pallas custom calls, so the fused program under-reports its
-    # matmul FLOPs; every variant is accounted with the UNFUSED
-    # program's visible count (cost_analysis on the LOWERED program —
-    # no second backend compile).
-    ref_flops_holder = {}
-    # unfused 20-step loss: the numeric-sanity reference for the
-    # fused/defer variants (all variants init from the SAME
-    # PRNGKey(0) and see identical data, so a >2x divergence after
-    # `steps` steps is real numerical trouble, not init noise)
-    ref_loss_holder = {}
+    tag = "resnet50"
+    model = resnet50(input_shape=(image, image, 3), classes=1000,
+                     space_to_depth=s2d)
+    t0 = time.perf_counter()
+    # host-CPU param + opt init (``init_params(device="host")`` returns
+    # CPU-committed leaves, so the eager ``tx.init`` zeros follow them
+    # onto the CPU), then one device transfer
+    params = model.init_params(jax.random.PRNGKey(0), device="host")
+    params, opt_state = jax.device_put(
+        (params, tx.init(params)), jax.devices()[0])
+    jax.block_until_ready((params, opt_state))
+    print(f"# [{tag}] host init+transfer="
+          f"{time.perf_counter() - t0:.1f}s", file=sys.stderr,
+          flush=True)
+    # ONE compiled program: a lax.scan chain of `steps` train
+    # steps — one dispatch + one scalar fetch; the constant
+    # dispatch overhead is subtracted.
+    _, run = _resnet_train_chain(model, tx, loss_fn, steps)
 
-    VARIANT_TAGS = {False: "unfused", True: "fused",
-                    "defer": "defer", "phase": "phase"}
+    t0 = time.perf_counter()
+    lowered = jax.jit(run).lower(params, opt_state, x, y)
+    # executed-vs-model FLOPs ratio of the XLA graph measured
+    # (perf.flops: dilation zeros count as executed;
+    # HloCostAnalysis discounts them and cannot see the gap).
+    # flops_analytic counts MACs (torchvision's 4.09e9/img);
+    # executed_flops counts 2 FLOPs/MAC — hence the 2x.
+    _result["flops_ratio_executed_vs_model"] = round(
+        perf_flops.executed_flops(
+            perf_flops.hlo_text(lowered)) /
+        (2.0 * flops_analytic), 4)
+    lowered_flops = _cost_flops(lowered)
+    compiled = lowered.compile()
+    t_compile = time.perf_counter() - t0
+    print(f"# [{tag}] compile={t_compile:.1f}s", file=sys.stderr,
+          flush=True)
 
-    def _host_init(model):
-        """Host-CPU param + opt init, one device transfer later.
-        ``init_params(device="host")`` returns CPU-committed leaves,
-        so the eager ``tx.init`` zeros follow them onto the CPU
-        automatically. Fixed PRNGKey: every variant starts from
-        identical weights."""
-        params = model.init_params(jax.random.PRNGKey(0),
-                                   device="host")
-        return params, tx.init(params)
+    flops_per_step = max(_cost_flops(compiled), lowered_flops)
+    if not (0.2 * flops_analytic < flops_per_step <
+            5 * flops_analytic):
+        # nan/zero, or a cost-model change (per-trip counting)
+        flops_per_step = flops_analytic
 
-    def measure_variant(fused):
-        tag = VARIANT_TAGS[fused]
-        if fused == "phase":
-            # unfused XLA graph + phase-decomposed strided backward
-            # (ops.conv_grad): the flag is read at trace time, so it
-            # must wrap the lower() below; restored in the finally
-            os.environ["ZOO_TPU_PHASE_BWD"] = "1"
-        try:
-            return _measure_variant_inner(fused, tag)
-        finally:
-            if fused == "phase":
-                os.environ.pop("ZOO_TPU_PHASE_BWD", None)
-
-    def _measure_variant_inner(fused, tag):
-        model = resnet50(input_shape=(image, image, 3), classes=1000,
-                         space_to_depth=s2d,
-                         fused=False if fused == "phase" else fused)
+    def timed():
         t0 = time.perf_counter()
-        params, opt_state = jax.device_put(
-            _host_init(model), jax.devices()[0])
-        jax.block_until_ready((params, opt_state))
-        print(f"# [{tag}] host init+transfer="
-              f"{time.perf_counter() - t0:.1f}s", file=sys.stderr,
-              flush=True)
-        # ONE compiled program: a lax.scan chain of `steps` train
-        # steps — one dispatch + one scalar fetch; the constant
-        # dispatch overhead is subtracted.
-        _, run = _resnet_train_chain(model, tx, loss_fn, steps)
+        p, o, loss = compiled(params, opt_state, x, y)
+        loss_val = float(np.asarray(loss))  # host fetch = sync
+        return time.perf_counter() - t0, loss_val
 
-        t0 = time.perf_counter()
-        lowered = jax.jit(run).lower(params, opt_state, x, y)
-        if fused in (False, "phase") and \
-                "flops_ratio_executed_vs_model" not in _result:
-            # executed-vs-model FLOPs ratio of the XLA graph actually
-            # measured (perf.flops: dilation zeros count as executed;
-            # HloCostAnalysis discounts them and cannot see the gap).
-            # flops_analytic counts MACs (torchvision's 4.09e9/img);
-            # executed_flops counts 2 FLOPs/MAC — hence the 2x.
-            _result["flops_ratio_executed_vs_model"] = round(
-                perf_flops.executed_flops(
-                    perf_flops.hlo_text(lowered)) /
-                (2.0 * flops_analytic), 4)
-        if not fused:
-            ref_flops_holder["flops"] = _cost_flops(lowered)
-        elif "flops" not in ref_flops_holder:
-            # fused-only mode: lower (don't compile) the unfused
-            # program purely for the visible-FLOPs account
-            ref_model = resnet50(input_shape=(image, image, 3),
-                                 classes=1000, space_to_depth=s2d,
-                                 fused=False)
-            rp, ro = _host_init(ref_model)
-            ref_step, _ = _resnet_train_chain(
-                ref_model, tx, loss_fn, steps)
-            ref_flops_holder["flops"] = _cost_flops(
-                jax.jit(ref_step).lower(rp, ro, x, y))
-        compiled = lowered.compile()
-        t_compile = time.perf_counter() - t0
-        print(f"# [{tag}] compile={t_compile:.1f}s", file=sys.stderr,
-              flush=True)
+    def derive(best_dt):
+        dt = max(best_dt - overhead, 1e-9)
+        images_per_sec = batch * steps / dt
+        mfu = (flops_per_step * steps / dt) / peak_flops
+        # model-FLOPs MFU: the honest number (analytic 3x-forward
+        # FLOPs, not XLA's hardware-op count which includes remat
+        # and counts some fusions generously)
+        mfu_model = (flops_analytic * steps / dt) / peak_flops
+        return dt, images_per_sec, mfu, mfu_model
 
-        flops_per_step = max(_cost_flops(compiled),
-                             ref_flops_holder.get("flops", 0.0))
-        if not (0.2 * flops_analytic < flops_per_step <
-                5 * flops_analytic):
-            # nan/zero, or a cost-model change (per-trip counting)
-            flops_per_step = flops_analytic
-
-        def timed():
-            t0 = time.perf_counter()
-            p, o, loss = compiled(params, opt_state, x, y)
-            loss_val = float(np.asarray(loss))  # host fetch = sync
-            return time.perf_counter() - t0, loss_val
-
-        def derive(best_dt):
-            dt = max(best_dt - overhead, 1e-9)
-            images_per_sec = batch * steps / dt
-            mfu = (flops_per_step * steps / dt) / peak_flops
-            # model-FLOPs MFU: the honest number (analytic 3x-forward
-            # FLOPs, not XLA's hardware-op count which includes remat
-            # and counts some fusions generously)
-            mfu_model = (flops_analytic * steps / dt) / peak_flops
-            return dt, images_per_sec, mfu, mfu_model
-
-        timed()  # warmup (execution path, allocator)
-        profile_dir = os.environ.get("ZOO_TPU_BENCH_PROFILE_DIR")
-        if profile_dir:  # jax.profiler trace of one measured chain
-            jax.profiler.start_trace(os.path.join(profile_dir, tag))
-            timed()
-            jax.profiler.stop_trace()
-            print(f"# [{tag}] profile trace -> {profile_dir}/{tag}",
-                  file=sys.stderr, flush=True)
-        best_dt, loss = None, float("nan")
-        for _ in range(2):
-            dt_i, loss = timed()
-            # numeric sanity: a variant whose 20-step loss is not
-            # finite (or wildly off the unfused reference's — garbage
-            # computed fast) must not win the A/B on speed alone
-            if not np.isfinite(loss):
-                raise RuntimeError(
-                    f"non-finite loss {loss} after {steps} steps")
-            ref_loss = ref_loss_holder.get("loss")
-            if ref_loss is not None and not (
-                    0.5 * ref_loss < loss < 2.0 * ref_loss):
-                raise RuntimeError(
-                    f"loss {loss:.3f} diverges from the unfused "
-                    f"reference's {ref_loss:.3f}")
-            if not fused:
-                ref_loss_holder["loss"] = loss
-            best_dt = dt_i if best_dt is None else min(best_dt, dt_i)
-        dt, images_per_sec, mfu, mfu_model = derive(best_dt)
-        if images_per_sec > _result["value"]:
-            _result.update(
-                value=round(images_per_sec, 2),
-                vs_baseline=round(mfu / 0.45, 4),
-                mfu_xla_flops=round(mfu, 6),
-                mfu_model_flops=round(mfu_model, 6),
-                vs_baseline_model_flops=round(mfu_model / 0.45, 6),
-                variant=tag)
-        print(f"# [{tag}] batch={batch} image={image} steps={steps} "
-              f"step_time={dt / steps * 1000:.1f}ms mfu={mfu:.3f} "
-              f"mfu_model={mfu_model:.3f} "
-              f"loss={loss:.3f} flops/step={flops_per_step:.3e} "
-              f"overhead={overhead * 1000:.1f}ms "
-              f"compile={t_compile:.1f}s", file=sys.stderr, flush=True)
-        return images_per_sec
-
-    # auto order: unfused first (it is the loss and FLOPs reference
-    # for the others), then phase (plain XLA, cheap to compile), then
-    # the Pallas variants
-    variants = {"0": [False], "1": [True], "defer": ["defer"],
-                "phase": ["phase"]}.get(
-                    fused_mode, [False, "phase", True, "defer"])
-    for fused in variants:
-        measure_variant(fused)
-        if len(variants) > 1:
-            _emit()  # one line per variant; the last is complete
+    timed()  # warmup (execution path, allocator)
+    profile_dir = os.environ.get("ZOO_TPU_BENCH_PROFILE_DIR")
+    if profile_dir:  # jax.profiler trace of one measured chain
+        jax.profiler.start_trace(os.path.join(profile_dir, tag))
+        timed()
+        jax.profiler.stop_trace()
+        print(f"# [{tag}] profile trace -> {profile_dir}/{tag}",
+              file=sys.stderr, flush=True)
+    best_dt, loss = None, float("nan")
+    for _ in range(2):
+        dt_i, loss = timed()
+        if not np.isfinite(loss):
+            raise RuntimeError(
+                f"non-finite loss {loss} after {steps} steps")
+        best_dt = dt_i if best_dt is None else min(best_dt, dt_i)
+    dt, images_per_sec, mfu, mfu_model = derive(best_dt)
+    _result.update(
+        value=round(images_per_sec, 2),
+        vs_baseline=round(mfu / 0.45, 4),
+        mfu_xla_flops=round(mfu, 6),
+        mfu_model_flops=round(mfu_model, 6),
+        vs_baseline_model_flops=round(mfu_model / 0.45, 6))
+    print(f"# [{tag}] batch={batch} image={image} steps={steps} "
+          f"step_time={dt / steps * 1000:.1f}ms mfu={mfu:.3f} "
+          f"mfu_model={mfu_model:.3f} "
+          f"loss={loss:.3f} flops/step={flops_per_step:.3e} "
+          f"overhead={overhead * 1000:.1f}ms "
+          f"compile={t_compile:.1f}s", file=sys.stderr, flush=True)
     if os.environ.get("ZOO_TPU_BENCH_NCF", "1") == "1":
         # second BASELINE.json workload rides the same record
         from bench_ncf import measure as ncf_measure

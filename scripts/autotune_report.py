@@ -5,10 +5,10 @@ Three jobs, one process (one backend init):
 - default: render the current decision table — every cached/default
   entry for this device, its winning config vs the analytic heuristic,
   and the measured delta when the entry came from a sweep;
-- ``--sweep``: populate the cache for the bench shapes (the ResNet
-  1x1 matmuls, the attention crossover key lengths, the conv_bn
-  backward gate) by routing each through ``autotune.decide`` with
-  ``ZOO_TPU_AUTOTUNE=1`` semantics — the one-time search cost;
+- ``--sweep``: populate the cache for the bench shapes (the
+  attention crossover key lengths) by routing each through
+  ``autotune.decide`` with ``ZOO_TPU_AUTOTUNE=1`` semantics — the
+  one-time search cost;
 - ``--emit-defaults``: freeze the current entries into the committed
   per-device table ``perf/autotune_defaults/<device>.json``,
   stamping ``--round`` into the table header.
@@ -29,35 +29,16 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-# sweep work-list: (op, params, dtype) per bench shape. Shapes mirror
-# scripts/measure_fused.py's ResNet-50 1x1 list and PERF.md's
+# sweep work-list: (op, params, dtype) per bench shape: PERF.md's
 # attention crossover ladder.
-_RESNET_MKN = [
-    (128 * 56 * 56, 64, 64),
-    (128 * 56 * 56, 64, 256),
-    (128 * 56 * 56, 256, 64),
-    (128 * 28 * 28, 512, 128),
-    (128 * 28 * 28, 128, 512),
-    (128 * 14 * 14, 1024, 256),
-    (128 * 14 * 14, 256, 1024),
-    (128 * 7 * 7, 2048, 512),
-    (128 * 7 * 7, 512, 2048),
-]
-_TINY_MKN = [(512, 128, 256), (256, 256, 128)]
 _ATTN_T = [256, 512, 1024, 2048, 4096]
 _TINY_ATTN_T = [128, 256]
 
 
 def sweep_keys(tiny: bool):
     """The (op, params, dtype) work-list `--sweep` resolves."""
-    mkn = _TINY_MKN if tiny else _RESNET_MKN
     ts = _TINY_ATTN_T if tiny else _ATTN_T
     keys = []
-    for m, k, n in mkn:
-        keys.append(("conv_bn_blocks",
-                     {"m": m, "k": k, "n": n, "isz": 2}, "any"))
-        keys.append(("conv_bn_bwd",
-                     {"m": m, "k": k, "n": n}, "any"))
     for t in ts:
         keys.append(("attn_crossover", {"tk": t}, "any"))
         keys.append(("decode_crossover", {"tk": t}, "any"))
@@ -68,7 +49,7 @@ def _register_ops():
     """Import the ops modules that register specs (registration is an
     import-time side effect of each decision point's owner)."""
     from analytics_zoo_tpu.ops import (  # noqa: F401
-        attention, conv_bn, flash_attention)
+        attention, flash_attention)
 
 
 def run_sweep(tiny: bool) -> int:

@@ -3,7 +3,7 @@
 Orchestrates, against a throwaway cache path:
 
 1. phase ``sweep`` (subprocess, ``ZOO_TPU_AUTOTUNE=1``): resolve two
-   tiny conv_bn_blocks shapes through the real `_pick_blocks` call
+   tiny flash_blocks shapes through the real `_pick_blocks` call
    site — first sight of each key sweeps (interpret-guarded
    candidates) and persists the winners;
 2. phase ``reload`` (FRESH subprocess, ``ZOO_TPU_AUTOTUNE=1``): the
@@ -30,9 +30,19 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 
 # two CPU-sized shapes (interpret-mode Pallas budget)
 _SHAPES = [
-    {"m": 512, "k": 128, "n": 256, "isz": 2},
-    {"m": 256, "k": 256, "n": 128, "isz": 2},
+    {"tq": 256, "tk": 256, "isz": 2},
+    {"tq": 128, "tk": 256, "isz": 2},
 ]
+
+
+def _pick(p):
+    from analytics_zoo_tpu.ops import flash_attention
+    return list(flash_attention._pick_blocks(p["tq"], p["tk"],
+                                             p["isz"]))
+
+
+def _name(p):
+    return f"{p['tq']}x{p['tk']}"
 
 
 def _counter_value(name: str) -> float:
@@ -44,13 +54,9 @@ def _counter_value(name: str) -> float:
 
 
 def phase_sweep() -> int:
-    from analytics_zoo_tpu.ops import conv_bn
     from analytics_zoo_tpu.perf import autotune
     assert autotune.sweep_enabled() >= 1, "phase runs under AUTOTUNE=1"
-    picks = {}
-    for p in _SHAPES:
-        picks[f"{p['m']}x{p['k']}x{p['n']}"] = \
-            conv_bn._pick_blocks(p["m"], p["k"], p["n"], p["isz"])
+    picks = {_name(p): _pick(p) for p in _SHAPES}
     s = autotune.stats()
     assert s["sweeps"] == len(_SHAPES), \
         f"expected {len(_SHAPES)} sweeps, got {s['sweeps']}"
@@ -58,18 +64,14 @@ def phase_sweep() -> int:
         len(_SHAPES), "sweep counter disagrees"
     assert os.path.exists(os.environ["ZOO_TPU_AUTOTUNE_CACHE"]), \
         "cache file not persisted"
-    print(json.dumps({"picks": {k: list(v) for k, v in
-                                picks.items()}}))
+    print(json.dumps({"picks": picks}))
     return 0
 
 
 def phase_reload(expect: dict) -> int:
-    from analytics_zoo_tpu.ops import conv_bn
     from analytics_zoo_tpu.perf import autotune
     for p in _SHAPES:
-        got = list(conv_bn._pick_blocks(p["m"], p["k"], p["n"],
-                                        p["isz"]))
-        want = expect[f"{p['m']}x{p['k']}x{p['n']}"]
+        got, want = _pick(p), expect[_name(p)]
         assert got == want, f"reloaded pick {got} != swept {want}"
     s = autotune.stats()
     assert s["sweeps"] == 0, f"fresh process re-swept: {s}"

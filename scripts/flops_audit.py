@@ -3,12 +3,11 @@
 Two modes:
 
   1. Model mode (default, CPU-safe — `make flops-audit`): lowers the
-     ResNet-50 train step (bench.py's `_resnet_train_chain`, the one
-     training-semantics definition) with the phase-decomposed
-     backward off and on, and reports per-category executed FLOPs
-     (perf.flops counting: dilation zeros are EXECUTED, unlike
-     HloCostAnalysis which discounts them), the
-     executed-vs-model-FLOPs ratio, and the top-N costliest ops.
+     ResNet-50 train step (bench.py's `_resnet_train_chain`) and
+     reports per-category executed FLOPs (perf.flops counting:
+     dilation zeros are EXECUTED, unlike HloCostAnalysis which
+     discounts them), the executed-vs-model-FLOPs ratio, and the
+     top-N costliest ops.
 
   2. Dump mode (`--dump-dir DIR`): audits the *after_optimizations*
      HLO modules of an `--xla_dump_to` dump, so the numbers reflect
@@ -22,8 +21,7 @@ MACs; executed FLOPs count 2 FLOPs/MAC — the 2x below matches the
 conventions (PERF.md round 7).
 
 Usage:
-  python scripts/flops_audit.py [--image 224] [--batch 1]
-      [--phase both|0|1] [--top 10]
+  python scripts/flops_audit.py [--image 224] [--batch 1] [--top 10]
   python scripts/flops_audit.py --dump-dir /tmp/xla_dump [--top 10]
 """
 
@@ -108,7 +106,7 @@ def audit_dump(dump_dir: str, top: int) -> None:
         report(text, os.path.basename(path), top, None)
 
 
-def audit_model(image: int, batch: int, phase_modes, top: int):
+def audit_model(image: int, batch: int, top: int):
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     import jax
     import jax.numpy as jnp
@@ -130,37 +128,21 @@ def audit_model(image: int, batch: int, phase_modes, top: int):
     y = jnp.asarray(rs.randint(0, 1000, size=(batch, 1)), jnp.int32)
     model_flops = 2.0 * 3 * 4.09e9 * batch * (image / 224.0) ** 2
 
-    totals = {}
-    for phase in phase_modes:
-        os.environ["ZOO_TPU_PHASE_BWD"] = phase
-        try:
-            model = resnet50(input_shape=(image, image, 3),
-                             classes=1000, space_to_depth=False,
-                             fused=False)
-            params = model.init_params(jax.random.PRNGKey(0),
-                                       device="host")
-            step, _ = _resnet_train_chain(
-                model, tx, losses.softmax_cross_entropy, 1)
-            text = pf.hlo_text(
-                jax.jit(step).lower(params, tx.init(params), x, y))
-        finally:
-            os.environ.pop("ZOO_TPU_PHASE_BWD", None)
-        totals[phase] = report(
-            text, f"ResNet-50 train step image={image} batch={batch} "
-            f"ZOO_TPU_PHASE_BWD={phase}", top, model_flops)
-    if len(totals) == 2:
-        off, on = totals["0"], totals["1"]
-        print(f"\nphase-decomposed backward: executed FLOPs "
-              f"{off:.4e} -> {on:.4e} ({100 * (off - on) / off:.1f}% "
-              "drop)")
+    model = resnet50(input_shape=(image, image, 3), classes=1000,
+                     space_to_depth=False)
+    params = model.init_params(jax.random.PRNGKey(0), device="host")
+    step, _ = _resnet_train_chain(
+        model, tx, losses.softmax_cross_entropy, 1)
+    text = pf.hlo_text(
+        jax.jit(step).lower(params, tx.init(params), x, y))
+    report(text, f"ResNet-50 train step image={image} batch={batch}",
+           top, model_flops)
 
 
 def main():
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--image", type=int, default=224)
     p.add_argument("--batch", type=int, default=1)
-    p.add_argument("--phase", choices=("both", "0", "1"),
-                   default="both")
     p.add_argument("--top", type=int, default=10)
     p.add_argument("--dump-dir", default=None,
                    help="audit an --xla_dump_to directory instead "
@@ -169,8 +151,7 @@ def main():
     if args.dump_dir:
         audit_dump(args.dump_dir, args.top)
     else:
-        modes = ["0", "1"] if args.phase == "both" else [args.phase]
-        audit_model(args.image, args.batch, modes, args.top)
+        audit_model(args.image, args.batch, args.top)
 
 
 if __name__ == "__main__":

@@ -28,7 +28,7 @@ def _run(args, timeout, **env):
         timeout=timeout, cwd=_ROOT, env=dict(os.environ, **env))
 
 
-_TINY_BENCH = dict(ZOO_TPU_BENCH_FUSED="0", ZOO_TPU_BENCH_BATCH="2",
+_TINY_BENCH = dict(ZOO_TPU_BENCH_BATCH="2",
                    ZOO_TPU_BENCH_IMAGE="64", ZOO_TPU_BENCH_STEPS="2",
                    ZOO_TPU_BENCH_NCF_BATCH="64")
 
@@ -48,10 +48,10 @@ def test_bench_live_carries_both_workloads_and_model_mfu():
     assert rec["device"]["platform"] == "cpu"
     assert rec["device"]["kind"] == "cpu"
     assert "not a chip measurement" in rec["device"]["smoke"]
-    # executed-vs-model FLOPs ratio of the measured (unfused,
-    # transpose-rule-backward) XLA graph; >1 is the phase backward's
-    # before number (docs/perf_flags.md)
+    # executed-vs-model FLOPs ratio of the measured XLA graph: > 1,
+    # the strided convolutions' backward multiplies dilation zeros
     assert rec["flops_ratio_executed_vs_model"] > 1.0
+    assert "variant" not in rec
     extras = {m["metric"]: m for m in rec["extra_metrics"]}
     assert extras["ncf_train_samples_per_sec_per_chip"]["value"] > 0
 
@@ -225,52 +225,6 @@ def test_no_script_sets_a_cache_dir_in_code():
     assert offenders == []
 
 
-# -- a chip belongs to one process --------------------------------------
-
-_LOAD_MEASURE_FUSED = (
-    "import importlib.util, subprocess, sys\n"
-    "spec = importlib.util.spec_from_file_location(\n"
-    "    'measure_fused', 'scripts/measure_fused.py')\n"
-    "mod = importlib.util.module_from_spec(spec)\n"
-    "spec.loader.exec_module(mod)\n")
-
-
-def test_measure_fused_parent_has_not_imported_jax_when_it_spawns():
-    # the parent must stay off JAX so each child gets the chip; record
-    # what is imported at every spawn, with the children faked
-    code = _LOAD_MEASURE_FUSED + (
-        "spawned = []\n"
-        "class Done:\n"
-        "    returncode = 0\n"
-        "    stdout = '{\"value\": 1.0}\\n'\n"
-        "    stderr = ''\n"
-        "def fake_run(cmd, **kw):\n"
-        "    spawned.append(('jax' in sys.modules, cmd[1:3]))\n"
-        "    return Done()\n"
-        "subprocess.run = fake_run\n"
-        "rc = mod.main(['--tiny'])\n"
-        "print('RC', rc, 'SPAWNED', len(spawned),\n"
-        "      'JAX_AT_SPAWN', any(j for j, _ in spawned))\n")
-    out = _run(["-c", code], 120)
-    assert out.returncode == 0, (out.stdout + out.stderr)[-2000:]
-    # one micro child + two bench.py children, jax never imported
-    assert "RC 0 SPAWNED 3 JAX_AT_SPAWN False" in out.stdout
-
-
-def test_measure_fused_fails_when_a_child_fails():
-    code = _LOAD_MEASURE_FUSED + (
-        "class Dead:\n"
-        "    returncode = 1\n"
-        "    stdout = ''\n"
-        "    stderr = 'no chip'\n"
-        "subprocess.run = lambda cmd, **kw: Dead()\n"
-        "print('RC', mod.main(['--tiny', '--skip-micro']))\n")
-    out = _run(["-c", code], 120)
-    assert out.returncode == 0, (out.stdout + out.stderr)[-2000:]
-    assert "RC 1" in out.stdout
-    assert "FAIL: bench.py fused=0 exited 1" in out.stdout
-
-
 # -- the native library is built from its sources or not used -----------
 
 def test_stale_native_binary_is_not_loaded(tmp_path, monkeypatch):
@@ -378,9 +332,8 @@ def test_chip_smoke_rehearsal_lines_say_what_ran(rehearsal):
     assert gen["train_step"]["attention"] == "flash"
     kern = by["kernels"]
     assert kern["interpret"] is True      # the Pallas interpreter
-    assert len(kern["cases"]) >= 12
+    # the flash attention family is every Pallas kernel there is
+    assert len(kern["cases"]) == 6
     assert all(c["passed"] for c in kern["cases"])
-    assert all(r.startswith("pallas")
-               for r in kern["conv3x3_routes"].values())
-    assert kern["fused_resnet50_step"][
-        "conv_bn_kernel_calls_traced"] > 0
+    assert all(c["kernel"].startswith("flash_") for c in kern["cases"])
+    assert "fused_resnet50_step" not in kern

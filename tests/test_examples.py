@@ -181,7 +181,7 @@ def test_resnet_imagenet_recipe(tmp_path):
     hist = _run("resnet_imagenet",
                 ["--folder", str(tmp_path), "--devices", "2",
                  "--image-size", "32", "--batch-per-device", "2",
-                 "--epochs", "1", "--fused", "0",
+                 "--epochs", "1",
                  "--checkpoint", str(tmp_path / "ck")])
     assert np.isfinite(hist[-1]["loss"])
     assert (tmp_path / "ck" / "LATEST").exists()

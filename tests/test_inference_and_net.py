@@ -211,39 +211,6 @@ def test_export_compiled_requires_aot(tmp_path):
         im.export_compiled(str(tmp_path / "m.zooaot"))
 
 
-def test_inference_model_serves_fused_resnet_eval_path():
-    # the serving surface must route a fused ImageClassifier through
-    # the eval-fold kernels (matmul_bn_apply/conv3x3_bn_apply — no
-    # stats, BN+residual+ReLU in the epilogues) and agree with the
-    # unfused graph under identical weights
-    from analytics_zoo_tpu.models.image.imageclassification import \
-        ImageClassifier
-    from analytics_zoo_tpu.ops import conv_bn
-
-    rs = np.random.RandomState(0)
-    x = rs.randn(2, 32, 32, 3).astype(np.float32)
-    from analytics_zoo_tpu.models.image.imageclassification.resnet \
-        import convert_resnet_params
-    fused = ImageClassifier("resnet-50", input_shape=(32, 32, 3),
-                            classes=10, fused=True)
-    fused.compile()
-    fused.model.estimator._ensure_initialized()
-    unfused = ImageClassifier("resnet-50", input_shape=(32, 32, 3),
-                              classes=10, fused=False)
-    unfused.compile()
-    unfused.model.estimator._ensure_initialized()
-    unfused.model.estimator.params = convert_resnet_params(
-        fused.model.estimator.params, unfused.model.estimator.params)
-
-    im = InferenceModel()
-    im.load_keras_net(fused.model)
-    before = conv_bn.invocations
-    out = im.predict(x)
-    assert conv_bn.invocations > before     # served via the kernels
-    np.testing.assert_allclose(
-        out, unfused.predict(x, batch_size=2), rtol=1e-3, atol=1e-3)
-
-
 def test_inference_model_concurrent_predict():
     m, x = _trained_model()
     im = InferenceModel(supported_concurrent_num=4)

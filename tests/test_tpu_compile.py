@@ -1,8 +1,9 @@
 """Chip compiles kept among the tests: every Pallas kernel of
-chip_smoke.py's phase 4, at its real ResNet-50-b128 / T=4096 shape,
-compiled by the TPU compiler for a *described* v5e (no chip attached,
-nothing runs). Interpret mode cannot see what this sees: slices not
-aligned to the tiling, a kernel that wants more VMEM than it may use.
+chip_smoke.py's phase 4 at its real T=4096 shape, the ResNet-50
+b128 train step and the generation programs, compiled by the TPU
+compiler for a *described* v5e (no chip attached, nothing runs).
+Interpret mode cannot see what this sees: slices not aligned to the
+tiling, a kernel that wants more VMEM than it may use.
 
 The one file that does this (a second file could land on another
 xdist worker, whose fixture would then skip every test): the
@@ -11,14 +12,13 @@ import, in a ``skipif`` or in ``parametrize`` — because only one
 process at a time may hold the TPU library.
 """
 
-import functools
-
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
+                          SingleDeviceSharding)
 
-from analytics_zoo_tpu.ops import conv_bn as cb
 from analytics_zoo_tpu.ops import flash_attention as fa
 from analytics_zoo_tpu.ops import kv_cache as kvc
 from analytics_zoo_tpu.perf import autotune
@@ -27,10 +27,10 @@ BF, F32 = jnp.bfloat16, jnp.float32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """A described v5e device; the persistent compilation cache is off
-    around these compiles (an entry written for a described chip
-    cannot be read back without one, and warns)."""
+def topo():
+    """A described v5e host of four chips; the persistent compilation
+    cache is off around these compiles (an entry written for a
+    described chip cannot be read back without one, and warns)."""
     import os
 
     from jax.experimental import topologies
@@ -44,9 +44,14 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture(autouse=True)
@@ -93,55 +98,12 @@ def _decode(q, k, v, mask, ks=None, vs=None):
         v_scales=vs)
 
 
-def _matmul(x, w, s, t, sh, r=None):
-    return cb.matmul_bn(x, w, in_scale=s, in_shift=t, relu_in=True,
-                        stat_shift=sh, in_residual=r, interpret=False)
-
-
-def _matmul_grads(x, w, s, t, sh):
-    def loss(x, w):
-        y, su, sq = _matmul(x, w, s, t, sh)
-        return jnp.sum(y.astype(F32)) + jnp.sum(su) + jnp.sum(sq)
-    return jax.grad(loss, argnums=(0, 1))(x, w)
-
-
-def _matmul_apply(x, w, s, t, os_):
-    return cb.matmul_bn_apply(x, w, in_scale=s, in_shift=t,
-                              relu_in=True, out_scale=os_,
-                              out_shift=os_, relu_out=True,
-                              interpret=False)
-
-
-def _conv3(x, w, s, t, sh, stride):
-    return cb.conv3x3_bn(x, w, in_scale=s, in_shift=t, relu_in=True,
-                         stat_shift=sh, stride=stride, interpret=False)
-
-
-def _conv3_apply(x, w, s, t, os_, stride):
-    return cb.conv3x3_bn_apply(x, w, in_scale=s, in_shift=t,
-                               relu_in=True, out_scale=os_,
-                               out_shift=os_, relu_out=True,
-                               stride=stride, interpret=False)
-
-
-def _mm_args(m, k, n):
-    return [((m, k), BF), ((k, n), BF), ((k,), F32), ((k,), F32),
-            ((n,), F32)]
-
-
-def _conv_args(shape, cout):
-    cin = shape[-1]
-    return [(shape, BF), ((3, 3, cin, cout), BF), ((cin,), F32),
-            ((cin,), F32), ((cout,), F32)]
-
-
 _QKV = [(_ATT, BF)] * 3
 _HALF = [((4, 2048, 16, 64), BF)] * 3
 _DQKV = [((_DEC["s"], _DEC["h"], _DEC["d"]), BF)] + \
     [((_DEC["s"], _DEC["t"], _DEC["h"], _DEC["d"]), BF)] * 2
 _DMASK = [((_DEC["s"], _DEC["t"]), jnp.bool_)]
 _DSCALES = [((_DEC["s"], _DEC["t"], _DEC["h"]), F32)] * 2
-_M0 = (128 * 56 * 56, 64, 256)    # stage-0 c3, the longest M
 
 KERNELS = {
     "flash_fwd": (_flash, _QKV),
@@ -152,32 +114,6 @@ KERNELS = {
     "flash_decode_int8": (
         _decode,
         [_DQKV[0]] + [(_DQKV[1][0], jnp.int8)] * 2 + _DMASK + _DSCALES),
-    "matmul_bn_fwd": (_matmul, _mm_args(*_M0)),
-    "matmul_bn_bwd": (_matmul_grads, _mm_args(*_M0)),
-    "matmul_bn_bwd_stage3": (_matmul_grads,
-                             _mm_args(128 * 7 * 7, 2048, 512)),
-    "matmul_bn_in_residual": (
-        _matmul, _mm_args(128 * 56 * 56, 256, 64) +
-        [((128 * 56 * 56, 256), BF)]),
-    "matmul_bn_apply": (_matmul_apply, _mm_args(*_M0)),
-    # the two planes the v5e compiler refused (scoped VMEM 18.17M and
-    # 50.15M against 16M) before _conv3_batch_tile counted lane
-    # padding and double buffers and the kernels named their limit
-    "conv3x3_bn_56x56x64_s1": (
-        functools.partial(_conv3, stride=1),
-        _conv_args((128, 56, 56, 64), 64)),
-    "conv3x3_bn_56x56x128_s2": (
-        functools.partial(_conv3, stride=2),
-        _conv_args((128, 56, 56, 128), 128)),
-    "conv3x3_bn_14x14x256_s1": (
-        functools.partial(_conv3, stride=1),
-        _conv_args((128, 14, 14, 256), 256)),
-    "conv3x3_bn_apply_56x56x64_s1": (
-        functools.partial(_conv3_apply, stride=1),
-        _conv_args((128, 56, 56, 64), 64)),
-    "conv3x3_bn_apply_14x14x512_s2": (
-        functools.partial(_conv3_apply, stride=2),
-        _conv_args((128, 14, 14, 512), 512)),
 }
 
 
@@ -200,25 +136,69 @@ def test_int8_decode_operands_match_the_cache_codec():
     assert q.shape == k.shape and scale.shape == k.shape[:-1]
 
 
-@pytest.mark.parametrize("shape,cout,stride,tile", [
-    ((128, 56, 56, 64), 64, 1, 1),
-    ((128, 56, 56, 128), 128, 2, 1),
-    ((128, 28, 28, 128), 128, 1, 4),
-    ((128, 28, 28, 256), 256, 2, 2),
-    ((128, 14, 14, 256), 256, 1, 8),
-    ((128, 14, 14, 512), 512, 2, 2),
-    ((128, 7, 7, 512), 512, 1, 4),
-    # a plane that cannot fit even one image: the XLA reference route
-    ((128, 112, 112, 64), 64, 1, None),
-])
-def test_conv3_batch_tile_counts_padding_and_double_buffers(
-        shape, cout, stride, tile):
-    # every ResNet-50 b128 3x3 plane gets a Pallas tile under the
-    # 16 MiB budget, counted with lane/sublane padding (a 64-channel
-    # bf16 row occupies 128 lanes; 56 columns occupy 64 sublanes)
-    assert cb._conv3_batch_tile(shape, cout, 2, stride) == tile
-    assert cb._vmem_bytes((56, 56, 64), 2) == 56 * 64 * 128 * 2
-    assert cb._vmem_bytes((56, 56, 64), 4) == 56 * 56 * 128 * 4
+# ---------------------------------------------------------------------
+# the ResNet-50 train step as both train cells run it: Estimator's own
+# jitted step, batch 128 a chip, mixed_bfloat16, SGD with momentum
+# ---------------------------------------------------------------------
+
+_V5E_BYTES = 15.75e9          # what the v5e's compiler allows a program
+
+
+def _resnet_step(topo, chips):
+    from analytics_zoo_tpu import init_nncontext
+    from analytics_zoo_tpu.models.image.imageclassification import \
+        resnet50
+    from analytics_zoo_tpu.ops.optimizers import SGD
+    from analytics_zoo_tpu.pipeline.estimator import Estimator
+    model = resnet50(input_shape=(224, 224, 3), classes=1000)
+    est = Estimator(model, optimizer=SGD(lr=0.002, momentum=0.9),
+                    loss="softmax_cross_entropy",
+                    ctx=init_nncontext(log_level="WARNING"),
+                    dtype_policy="mixed_bfloat16")
+    est.params = jax.eval_shape(
+        lambda: model.init_params(jax.random.key(0)))
+    tx = est._tx()
+    if chips == 1:
+        whole = rows = SingleDeviceSharding(topo.devices[0])
+    else:
+        mesh = Mesh(np.array(topo.devices[:chips]), ("data",))
+        whole = NamedSharding(mesh, PartitionSpec())
+        rows = NamedSharding(mesh, PartitionSpec("data"))
+
+    def on(tree, sharding):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=sharding), tree)
+    state = on((est.params, jax.eval_shape(tx.init, est.params)),
+               whole)
+    batch = 128 * chips
+    return state, est._build_train_step(tx).lower(
+        *state, jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole),
+        jax.ShapeDtypeStruct((batch, 224, 224, 3), F32, sharding=rows),
+        jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=rows)
+    ).compile()
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_resnet50_train_step_is_all_xla_and_fits(topo, chips):
+    """One ResNet path: no Pallas kernel in the step; arguments,
+    results and temporaries inside 15.75 GB a chip; parameters and
+    optimiser state donated, so the new ones are written over the
+    old; over a data: 4 mesh the gradients meet in an all-reduce."""
+    import re
+    state, compiled = _resnet_step(topo, chips)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" not in hlo
+    assert len(re.findall(r" convolution\(", hlo)) == 161
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
+        mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < _V5E_BYTES, held
+    donated = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(state))
+    assert mem.alias_size_in_bytes >= donated, (
+        mem.alias_size_in_bytes, donated)
+    assert ("all-reduce" in hlo) == (chips == 4)
 
 
 # ---------------------------------------------------------------------
@@ -298,9 +278,6 @@ def test_generation_programs_leave_the_pools_in_place(one_chip,
 # largest prefill bucket fit the chip beside the weights, and the one
 # latent pool stays where it lies
 # ---------------------------------------------------------------------
-
-_V5E_BYTES = 15.75e9          # what the v5e's compiler allows a program
-
 
 def _ds_config():
     import json
