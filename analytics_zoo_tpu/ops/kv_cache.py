@@ -422,20 +422,32 @@ def append_latent_rows(cache: LatentPagedCache, rows, active=None):
         pages=_put_rows(cache.pages, phys, offset, rows))
 
 
+def prompt_seq_lens(seq_lens, slots, prompt_lens):
+    """``seq_lens`` (S,) after a prefill of rows ``slots`` (A,):
+    ``prompt_lens[a]`` at slot ``slots[a]`` of every row that holds a
+    prompt; a row with ``prompt_lens == 0`` leaves its slot's length,
+    and every slot not named keeps its own."""
+    return seq_lens.at[slots].set(
+        jnp.where(prompt_lens > 0, prompt_lens, seq_lens[slots]))
+
+
 @jax.named_scope("zoo:kv_cache/write_prompt")
-def write_latent_prompt(cache: LatentPagedCache, prompt_lens, rows):
+def write_latent_prompt(pages, page_table, prompt_lens, rows):
     """:func:`write_prompt_layer` for a latent pool: ``rows``
-    (L, S, T, width) hold every layer's (right-padded) prompt rows;
-    positions past ``prompt_lens[s]`` are dropped."""
-    s, t = rows.shape[1], rows.shape[2]
+    (L, A, T, width) hold every layer's (right-padded) prompt rows,
+    ``page_table`` (A, pages_per_slot) the table row of each;
+    positions past ``prompt_lens[a]`` are dropped. Returns the
+    pool."""
+    a, t = rows.shape[1], rows.shape[2]
+    page_size = pages.shape[-2]
     positions = jnp.broadcast_to(
-        jnp.arange(t, dtype=jnp.int32)[None, :], (s, t))
-    active = jnp.logical_and(positions < prompt_lens[:, None],
-                             positions < cache.max_context)
-    phys, offset = _scatter_coords(cache.page_table, prompt_lens,
-                                   positions, cache.page_size, active)
-    return cache._replace(pages=_put_rows(
-        cache.pages, phys, offset, _latent_rows(cache.pages, rows)))
+        jnp.arange(t, dtype=jnp.int32)[None, :], (a, t))
+    active = jnp.logical_and(
+        positions < prompt_lens[:, None],
+        positions < page_table.shape[1] * page_size)
+    phys, offset = _scatter_coords(page_table, prompt_lens,
+                                   positions, page_size, active)
+    return _put_rows(pages, phys, offset, _latent_rows(pages, rows))
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
@@ -448,6 +460,9 @@ def write_prompt_layer(k_pages, v_pages, page_table, prompt_lens,
     cannot leak into pages a later admit might reuse. Stacked pools
     (L, P, page, W) with k_seq/v_seq (L, S, T, H, D) write every
     layer at once (whole-prompt prefill, after its layer scan).
+    ``page_table`` has one row for each row of k_seq: the cache's
+    table when every slot is a row, ``cache.page_table[slots]`` when
+    the rows are the slots being admitted.
 
     ``start`` (S,) int32 shifts each slot's write window: row j of
     k_seq lands at position ``start[s] + j`` (still gated by

@@ -367,23 +367,29 @@ class PatternDecoder(KerasLayer):
             self.attention.row_width, page_size=int(page_size),
             dtype=dtype or jnp.float32)
 
-    def prefill(self, params, cache, token_ids, prompt_lens):
+    def prefill(self, params, cache, token_ids, prompt_lens,
+                slots=None):
         """`TransformerLayer.prefill`'s contract over the latent
-        pool: slots with ``prompt_lens == 0`` are untouched, and
-        neither they nor the padding reach an expert."""
+        pool: the rows are the prompts being admitted, ``slots``
+        says which cache slot each is, every other slot is
+        untouched, and no padding reaches an expert."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        s, t = token_ids.shape
+        a, t = token_ids.shape
         prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
+        slots = jnp.arange(a, dtype=jnp.int32) if slots is None \
+            else jnp.asarray(slots, jnp.int32)
         valid = jnp.arange(t, dtype=jnp.int32)[None, :] < \
             prompt_lens[:, None]
         final, rows = self._prompt(params, token_ids, valid)
-        cache = kvc.write_latent_prompt(cache, prompt_lens, rows)
         cache = cache._replace(
-            seq_lens=jnp.where(prompt_lens > 0, prompt_lens,
-                               cache.seq_lens))
+            pages=kvc.write_latent_prompt(
+                cache.pages, cache.page_table[slots], prompt_lens,
+                rows),
+            seq_lens=kvc.prompt_seq_lens(cache.seq_lens, slots,
+                                         prompt_lens))
         with jax.named_scope("zoo:prefill/lm_head"):
             logits = self._logits(params, final[
-                jnp.arange(s), jnp.maximum(prompt_lens - 1, 0)])
+                jnp.arange(a), jnp.maximum(prompt_lens - 1, 0)])
         return cache, logits
 
     def decode_step(self, params, cache, token_ids, active=None,

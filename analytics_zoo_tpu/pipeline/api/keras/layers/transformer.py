@@ -437,21 +437,31 @@ class TransformerLayer(KerasLayer):
             self.n_head, self.hidden_size // self.n_head,
             page_size=int(page_size), dtype=dtype or jnp.float32)
 
-    def prefill(self, params, cache, token_ids, prompt_lens):
+    def prefill(self, params, cache, token_ids, prompt_lens,
+                slots=None):
         """Run the (right-padded) prompts once, writing every block's
         K/V into the cache, and return ``(cache', logits)`` with
-        logits taken at each slot's last real prompt position.
+        logits taken at each row's last real prompt position.
 
-        token_ids: (S, T) int; prompt_lens: (S,) int32 — slots with
-        ``prompt_lens == 0`` are untouched (their pages, seq_lens and
-        neighbours' state are preserved), which is what lets the
-        continuous batcher admit into a live batch. Causality makes
+        token_ids: (A, T) int, the prompts being admitted, one a row;
+        prompt_lens: (A,) int32; slots: (A,) int32, distinct — the
+        cache slot each row is (``None``: row a is slot a, every slot
+        a row, which is how `generate` calls). The cache is touched
+        through ``slots`` alone: the rows' K/V go to the pages of
+        ``cache.page_table[slots]``, ``seq_lens`` is set at ``slots``,
+        and logits are (A, vocab). Every other slot — its pages, its
+        length, what it decodes next — is what it was, and so is the
+        slot of a row with ``prompt_lens == 0``; that is what lets
+        the continuous batcher admit into a live batch with a program
+        no larger than the prompts it admits. Causality makes
         right-padding safe: pad positions sit after every real token,
         so they influence nothing — their K/V rows are dropped at the
         scatter and masked at gather anyway."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        s, t = token_ids.shape
+        a, t = token_ids.shape
         prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
+        slots = jnp.arange(a, dtype=jnp.int32) if slots is None \
+            else jnp.asarray(slots, jnp.int32)
         h0 = self._embed(params, token_ids)
         causal = not self.bidirectional
 
@@ -461,34 +471,33 @@ class TransformerLayer(KerasLayer):
             with jax.named_scope("zoo:prefill/attention"):
                 attn = dot_product_attention(
                     q, k, v, causal=causal, impl=self.attention_impl)
-            attn = attn.reshape(s, t, self.hidden_size)
+            attn = attn.reshape(a, t, self.hidden_size)
             return self._block_tail(p, x, attn), (k, v)
 
         final, (k_all, v_all) = jax.lax.scan(block, h0,
                                              params["blocks"])
-        cache = self._write_prompt_all(cache, k_all, v_all,
-                                       prompt_lens)
-        cache = cache._replace(
-            seq_lens=jnp.where(prompt_lens > 0, prompt_lens,
-                               cache.seq_lens))
+        cache = self._write_prompt_all(
+            cache, cache.page_table[slots], k_all, v_all, prompt_lens)
+        cache = cache._replace(seq_lens=kvc.prompt_seq_lens(
+            cache.seq_lens, slots, prompt_lens))
         with jax.named_scope("zoo:prefill/lm_head"):
-            last = final[jnp.arange(s),
+            last = final[jnp.arange(a),
                          jnp.maximum(prompt_lens - 1, 0)]
             logits = last @ params["tok_embed"].astype(last.dtype).T
         return cache, logits
 
-    def _write_prompt_all(self, cache, k_all, v_all, total_lens,
-                          start=None):
+    def _write_prompt_all(self, cache, table, k_all, v_all,
+                          total_lens):
         """Scatter every block's prompt K/V (k_all/v_all:
-        (L, S, T, nh, hd)) into the stacked pools; quantized caches
-        thread their scale pools through the same coordinates.
+        (L, A, T, nh, hd)) into the stacked pools through ``table``
+        (A, pages_per_slot), the rows' own table rows; quantized
+        caches thread their scale pools through the same coordinates.
         Returns the cache with pages (and scales) replaced —
         ``seq_lens`` is the caller's to update."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
         pools = kvc.write_prompt_layer(
-            cache.k_pages, cache.v_pages, cache.page_table,
-            total_lens, k_all, v_all, start=start,
-            k_scales=cache.k_scales, v_scales=cache.v_scales)
+            cache.k_pages, cache.v_pages, table, total_lens, k_all,
+            v_all, k_scales=cache.k_scales, v_scales=cache.v_scales)
         return cache._replace(**dict(zip(
             ("k_pages", "v_pages", "k_scales", "v_scales"), pools)))
 

@@ -681,9 +681,9 @@ class ContinuousBatcher:
     (`pipeline/inference/generation.py::GenerationEngine`), and this
     batcher reschedules **between steps**: finished sequences retire
     (pages reclaimed, future resolved) and queued ones are admitted
-    into the freed slots via a bucket-padded prefill — the running
-    neighbours never stop, and (inactive-slot scatters being dropped)
-    never observe the churn.
+    into the freed slots, each by a prefill of its own prompt alone —
+    the running neighbours never stop, and (a prefill touching only
+    the slot it is addressed to) never observe the churn.
 
     Thread model: handler threads call :meth:`submit`; ONE loop
     thread drives admit → step → retire. Admission is gated on a free
@@ -1071,10 +1071,14 @@ class ContinuousBatcher:
 
     def _prefill_span(self, entries, bucket: int):
         """The ``decode/prefill`` span round one admission call;
-        ``bucket``: the padded length its program runs at."""
+        ``bucket``: the longest padded length its programs run at.
+        ``calls`` programs held ``rows`` prompt rows between them
+        (none yet for a chunked admission): ``rows / n`` is 1 when
+        only admitted prompts are computed."""
         return obs.span(
             "decode/prefill", n=len(entries), bucket=bucket,
-            prompt_tokens=sum(len(e.ids) for e in entries))
+            prompt_tokens=sum(len(e.ids) for e in entries),
+            calls=0, rows=0)
 
     def _iterate(self, fresh: "list[_GenEntry]", it):
         """One pass: admit ``fresh``, advance chunked prefills, step
@@ -1126,8 +1130,8 @@ class ContinuousBatcher:
             # chunked admission only pays off past one
             # chunk: a prompt that fits in a single chunk
             # would run the full-width chunk program padded,
-            # where the classic bucket-padded prefill runs
-            # one right-sized call — so short prompts keep
+            # where the whole-prompt prefill runs one row at
+            # the prompt's own bucket — so short prompts keep
             # the direct path even when chunking is on
             long_p = [e for e in fresh if chunked
                       and len(e.ids) > engine.prefill_chunk]
@@ -1161,8 +1165,10 @@ class ContinuousBatcher:
                         for e in short_p]
                 with self._prefill_span(
                         short_p, engine.prompt_bucket(
-                            max(len(e.ids) for e in short_p))):
+                            max(len(e.ids) for e in short_p))) as sp:
                     first = engine.admit(reqs)
+                    calls, rows = engine.prefill_counts
+                    sp.annotate(calls=calls, rows=rows)
                 now = time.monotonic()
                 for e, (slot, tok) in zip(short_p, first):
                     e.slot = slot

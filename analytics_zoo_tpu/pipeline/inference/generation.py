@@ -11,8 +11,10 @@ this module owns everything a *server* needs around it:
   reclaiming them at retirement — the vLLM bookkeeping half;
 - ONE compiled decode-step program (shape-static over the full slot
   array, inactive slots frozen by the ``active`` mask) plus one
-  compiled prefill program per prompt-length bucket (the PR 4 bucket
-  ladder, reused) — after :meth:`GenerationEngine.warm`, steady-state
+  compiled prefill program per prompt-length bucket (powers of two
+  from ``PROMPT_BUCKET_FLOOR`` up), each of ONE prompt row addressed
+  to its slot: an admission runs the prompts it admits and no other
+  slot — after :meth:`GenerationEngine.warm`, steady-state
   serving performs **zero** compilations regardless of the
   prompt/output-length mix;
 - per-slot sampling state: a traced ``(max_slots,)`` temperature
@@ -93,6 +95,26 @@ __all__ = ["GenerationEngine", "resolve_kv_dtype"]
 _STEP_FAULT = faults.point("generation/decode_step")
 
 _KV_DTYPES = ("f32", "bf16", "int8")
+
+# The shortest prompt bucket. A prefill program holds ONE prompt, so
+# below a few dozen tokens its time is the read of the weights
+# whatever the bucket (a v5e's ridge is 240 FLOPs a byte: about 240
+# tokens over bf16 weights), and shorter buckets would be programs to
+# compile, cache and warm that buy nothing: on a v5e the one-row
+# programs of 8 / 16 / 32 / 64 / 128 tokens take 7.5 / 7.8 / 8.0 /
+# 8.9 / 10.1 ms at GPT-2-XL's widths and 9.7 / 10.5 / 13.8 / 17.3 /
+# 22.3 ms at DeepSeek-V2's (PERF.md, PR 33). 32 and not more, because
+# an expert model's programs grow from the first token on (more
+# tokens reach more experts).
+PROMPT_BUCKET_FLOOR = 32
+
+
+def prompt_ladder(longest: int) -> "tuple[int, ...]":
+    """The padded prompt lengths an engine compiles for prompts of
+    up to ``longest`` tokens: powers of two from
+    ``PROMPT_BUCKET_FLOOR`` up, ``longest`` itself the last."""
+    return tuple(b for b in bucket_ladder(longest)
+                 if b >= min(PROMPT_BUCKET_FLOOR, longest))
 
 
 def resolve_kv_dtype(cache_dtype=None):
@@ -254,14 +276,17 @@ class GenerationEngine:
         self.spec_proposed = 0
         self.spec_accepted = 0
 
-        # prompt-length buckets: the PR 4 ladder, capped at what the
-        # position table and the cache can hold
-        self.prompt_buckets = bucket_ladder(
+        # prompt-length buckets: powers of two from the floor up,
+        # capped at what the position table and the cache can hold
+        self.prompt_buckets = prompt_ladder(
             min(self.max_context, int(net.seq_len)))
 
         # (dispatch_s, fetch_s) of the latest `step`: the compiled call
         # returning, then blocked on the tokens (`decode/step` fields)
         self.step_times = (0.0, 0.0)
+        # (calls, rows) of the latest `admit`: prefill programs run,
+        # and the prompt rows they held (`decode/prefill` fields)
+        self.prefill_counts = (0, 0)
         self._compiled_step = None
         self._compiled_prefill: dict = {}
         self._compiled_chunk = None
@@ -288,11 +313,12 @@ class GenerationEngine:
         return cache, jax.numpy.concatenate([nxt] + counts) \
             if counts else nxt
 
-    def _prefill_fn(self, cache, params, ids, plens, temps, rng,
-                    step):
+    def _prefill_fn(self, cache, params, ids, plens, slots, temps,
+                    rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
-        cache, logits = self.net.prefill(params, cache, ids, plens)
+        cache, logits = self.net.prefill(params, cache, ids, plens,
+                                         slots)
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
@@ -317,8 +343,9 @@ class GenerationEngine:
                             self.top_k)
         return cache, nxt
 
-    def _draft_prefill_fn(self, dcache, dparams, ids, plens):
-        dcache, _ = self.drafter.prefill(dparams, dcache, ids, plens)
+    def _draft_prefill_fn(self, dcache, dparams, ids, plens, slots):
+        dcache, _ = self.drafter.prefill(dparams, dcache, ids, plens,
+                                         slots)
         return dcache
 
     def _draft_chunk_fn(self, dcache, dparams, ids, starts, n_new):
@@ -439,15 +466,17 @@ class GenerationEngine:
         return self._compiled_step
 
     def _get_prefill(self, tp: int):
+        """The prefill program of bucket ``tp``: one prompt row,
+        addressed by slot."""
         fn = self._compiled_prefill.get(tp)
         if fn is None:
-            s = self.max_slots
             structs = (
                 self._abstract(self.cache),
                 self._abstract(self.params),
-                self._shape(s, tp),
-                self._shape(s),
-                self._shape(s, dtype=np.float32),
+                self._shape(1, tp),
+                self._shape(1),
+                self._shape(1),
+                self._shape(1, dtype=np.float32),
                 self._abstract(self._rng),
                 self._shape(),
             )
@@ -476,12 +505,12 @@ class GenerationEngine:
     def _get_draft_prefill(self, tp: int):
         fn = self._compiled_draft_prefill.get(tp)
         if fn is None:
-            s = self.max_slots
             structs = (
                 self._abstract(self._draft_cache),
                 self._abstract(self.drafter_params),
-                self._shape(s, tp),
-                self._shape(s),
+                self._shape(1, tp),
+                self._shape(1),
+                self._shape(1),
             )
             fn = self._compile(self._draft_prefill_fn, structs,
                                "draft_prefill", bucket=tp)
@@ -640,7 +669,7 @@ class GenerationEngine:
             if self.prefill_chunk > 0:
                 self._get_draft_chunk()
             # prompts that fit in one chunk admit through the
-            # bucket-padded path even when chunking is on (the
+            # whole-prompt path even when chunking is on (the
             # batcher routes them directly), so the drafter's
             # prefill buckets are steady-state programs regardless
             for tp in self.prompt_buckets:
@@ -672,14 +701,17 @@ class GenerationEngine:
     def admit(self, requests: "Sequence[tuple]") -> "list[tuple]":
         """Admit ``[(prompt_ids, max_new, temperature), ...]`` into
         free slots of the LIVE batch: assign pages, write the table
-        rows, run ONE bucket-padded prefill (slots not being admitted
-        pass ``prompt_lens == 0`` and are untouched — the property
-        `prefill` guarantees), and sample each new slot's first
-        token. Returns ``[(slot, first_token), ...]``. Raises
+        rows (one push), then run ONE one-row prefill a request, at
+        that request's own bucket and addressed to its slot — the
+        programs compute the admitted prompts and nothing else, and
+        touch no other slot (the property `prefill` guarantees) —
+        and sample each new slot's first token; the first tokens are
+        fetched together, after the last dispatch. Returns
+        ``[(slot, first_token), ...]`` and leaves ``(calls, rows)``
+        of this admission in :attr:`prefill_counts`. Raises
         MemoryError when slots/pages run out mid-list (callers gate
         with :meth:`can_admit` per request first)."""
         import jax
-        from analytics_zoo_tpu.ops.kv_cache import PageAllocator
         if not requests:
             return []
         for prompt_ids, _, _ in requests:
@@ -687,32 +719,32 @@ class GenerationEngine:
                 raise ValueError(
                     f"prompt length {len(prompt_ids)} outside [1, "
                     f"{self.max_context - 1}]")
-        tp = self.prompt_bucket(max(len(r[0]) for r in requests))
-        ids_arr = np.zeros((self.max_slots, tp), np.int32)
-        plens = np.zeros((self.max_slots,), np.int32)
-        admitted = []
-        for prompt_ids, max_new, temperature in requests:
-            slot = self._claim_slot(prompt_ids, max_new, temperature)
-            n = len(prompt_ids)
-            ids_arr[slot, :n] = np.asarray(prompt_ids, np.int32)
-            plens[slot] = n
-            admitted.append(slot)
+        admitted = [self._claim_slot(*r) for r in requests]
         self._push_table()
-        fn = self._get_prefill(tp)
-        self.cache, toks = fn(self.cache, self.params, ids_arr,
-                              plens, self._temps, self._rng,
-                              np.int32(self._step_id))
-        self._step_id += 1
-        if self._draft_cache is not None:
-            dfn = self._get_draft_prefill(tp)
-            self._draft_cache = dfn(self._draft_cache,
-                                    self.drafter_params, ids_arr,
-                                    plens)
-        toks = np.asarray(toks)
+        firsts, rows = [], 0
+        for slot, (prompt_ids, _, _) in zip(admitted, requests):
+            n = len(prompt_ids)
+            tp = self.prompt_bucket(n)
+            ids = np.zeros((1, tp), np.int32)
+            ids[0, :n] = np.asarray(prompt_ids, np.int32)
+            plens = np.full((1,), n, np.int32)
+            at = np.full((1,), slot, np.int32)
+            self.cache, tok = self._get_prefill(tp)(
+                self.cache, self.params, ids, plens, at,
+                self._temps[slot:slot + 1], self._rng,
+                np.int32(self._step_id))
+            self._step_id += 1
+            firsts.append(tok)
+            rows += ids.shape[0]
+            if self._draft_cache is not None:
+                self._draft_cache = self._get_draft_prefill(tp)(
+                    self._draft_cache, self.drafter_params, ids,
+                    plens, at)
+        self.prefill_counts = (len(firsts), rows)
         out = []
-        for slot in admitted:
-            self._last_tok[slot] = toks[slot]
-            out.append((slot, int(toks[slot])))
+        for slot, tok in zip(admitted, jax.device_get(firsts)):
+            self._last_tok[slot] = tok[0]
+            out.append((slot, int(tok[0])))
         return out
 
     def _claim_slot(self, prompt_ids, max_new, temperature) -> int:

@@ -209,13 +209,22 @@ def test_resnet50_train_step_is_all_xla_and_fits(topo, chips):
 _XL = dict(hidden_size=1600, n_head=25, seq_len=1024, vocab=50257,
            intermediate_size=6400)
 _XL_BLOCKS, _XL_SLOTS, _XL_PAGE = 4, 8, 16    # the scan body compiles once
+_XL_DEPTH = 48                # the prefill at the published depth: its
+#                               temporaries grow with the layers
 
 
 def _xl_program(one_chip, program):
+    """("step" | "prefill") -> (cache shapes, compiled): the decode
+    step over every slot, or the engine's longest prefill program,
+    ONE prompt row of 1024 tokens addressed by slot."""
     from analytics_zoo_tpu.pipeline.api.keras.layers.transformer \
         import TransformerLayer
-    net = TransformerLayer(n_block=_XL_BLOCKS, hidden_p_drop=0.0,
-                           attn_p_drop=0.0, embed_p_drop=0.0, **_XL)
+    # "auto" asks jax.devices(), the CPU here: name the kernel the
+    # chip's "auto" takes from 1024 keys up
+    net = TransformerLayer(
+        n_block=_XL_BLOCKS if program == "step" else _XL_DEPTH,
+        hidden_p_drop=0.0, attn_p_drop=0.0, embed_p_drop=0.0,
+        attention_impl=None if program == "step" else "flash", **_XL)
 
     def on_chip(tree, dtype=None):
         return jax.tree_util.tree_map(
@@ -234,14 +243,26 @@ def _xl_program(one_chip, program):
         args = [jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
                 jax.ShapeDtypeStruct((s,), jnp.bool_, sharding=one_chip)]
     else:
-        def fn(cache, params, ids, plens):
-            return net.prefill(params, cache, ids, plens)
-        args = [jax.ShapeDtypeStruct((s, 128), jnp.int32,
-                                     sharding=one_chip),
-                jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip)]
+        def fn(cache, params, ids, plens, slots):
+            return net.prefill(params, cache, ids, plens, slots)
+        args = [jax.ShapeDtypeStruct((1, _XL["seq_len"]), jnp.int32,
+                                     sharding=one_chip)] + \
+            [jax.ShapeDtypeStruct((1,), jnp.int32,
+                                  sharding=one_chip)] * 2
     compiled = jax.jit(fn, donate_argnums=(0,)).lower(
         cache, params, *args).compile()
-    return cache, compiled.as_text()
+    return cache, compiled
+
+
+def _no_shape_leads_with(hlo, *dims):
+    """No operand or result of the program has a shape whose leading
+    dimensions are ``dims``, or their product (the same rows
+    flattened)."""
+    import re
+    flat = int(np.prod(dims))
+    lead = r"\[(?:%s|%d)[,\]]" % (",".join(map(str, dims)), flat)
+    hits = sorted(set(re.findall(r"\w+" + lead + r"[^ ]*", hlo)))
+    assert not hits, hits[:8]
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
@@ -255,7 +276,8 @@ def test_generation_programs_leave_the_pools_in_place(one_chip,
     shape: the only operations that produce a pool are the two
     in-place scatters."""
     import re
-    cache, hlo = _xl_program(one_chip, program)
+    cache, compiled = _xl_program(one_chip, program)
+    hlo = compiled.as_text()
     pool = ",".join(map(str, cache.k_pages.shape))
     slab = ",".join(map(str, cache.k_pages.shape[1:]))
     assert cache.k_pages.shape[-1] % kvc.ROW_ALIGN == 0
@@ -269,6 +291,20 @@ def test_generation_programs_leave_the_pools_in_place(one_chip,
     assert not moved, moved
     # donated and written in place: output pools alias input pools
     assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in hlo
+
+
+def test_gpt2xl_prefill_computes_the_admitted_row_only(one_chip):
+    """The longest prefill program of the GPT-2-XL cell holds one
+    prompt: nothing in it has `max_slots x bucket` rows, its
+    temporaries are an eighth of the 5.14 GB that all 8 slots padded
+    to 1024 took, and prompts that long still reach the flash kernel.
+    (`bf16[393216,1664]` is in it and is no padding: 48 layers x 512
+    pages x 16 rows, the pool as its in-place scatter sees it.)"""
+    cache, compiled = _xl_program(one_chip, "prefill")
+    hlo = compiled.as_text()
+    _no_shape_leads_with(hlo, _XL_SLOTS, _XL["seq_len"])
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.75e9
+    assert "zoo_flash_fwd" in hlo
 
 
 # ---------------------------------------------------------------------
@@ -289,7 +325,11 @@ def _ds_config():
         return json.load(f)
 
 
-def _ds_program(one_chip, program):
+def _ds_program(one_chip, program, bucket=None, lower_only=False):
+    """("step" | "prefill") -> (cache shapes, weight bytes,
+    compiled): the decode step over every slot, or a prefill program
+    of the engine, ONE prompt row of ``bucket`` tokens (default: the
+    longest, ``max_context``) addressed by slot."""
     from analytics_zoo_tpu.pipeline.api.keras.layers import \
         deepseek_v2_decoder
     cfg = _ds_config()
@@ -300,7 +340,7 @@ def _ds_program(one_chip, program):
     net = deepseek_v2_decoder(
         dict(cfg, n_routed_experts=cfg["published"]["n_routed_experts"]),
         n_layer=cfg["n_layer"], experts_held=(first, end - first),
-        attention_impl="flash")
+        attention_impl="xla" if bucket and bucket < 1024 else "flash")
 
     def on_chip(tree, dtype=None):
         return jax.tree_util.tree_map(
@@ -320,16 +360,18 @@ def _ds_program(one_chip, program):
         args = [jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip),
                 jax.ShapeDtypeStruct((s,), jnp.bool_, sharding=one_chip)]
     else:
-        def fn(cache, params, ids, plens):
-            return net.prefill(params, cache, ids, plens)
-        args = [jax.ShapeDtypeStruct((s, ctx), jnp.int32,
-                                     sharding=one_chip),
-                jax.ShapeDtypeStruct((s,), jnp.int32, sharding=one_chip)]
-    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
-        cache, params, *args).compile()
+        def fn(cache, params, ids, plens, slots):
+            return net.prefill(params, cache, ids, plens, slots)
+        args = [jax.ShapeDtypeStruct((1, bucket or ctx), jnp.int32,
+                                     sharding=one_chip)] + \
+            [jax.ShapeDtypeStruct((1,), jnp.int32,
+                                  sharding=one_chip)] * 2
+    lowered = jax.jit(fn, donate_argnums=(0,)).lower(
+        cache, params, *args)
     weights = sum(a.size * a.dtype.itemsize
                   for a in jax.tree_util.tree_leaves(params))
-    return cache, weights, compiled
+    return cache, weights, lowered if lower_only else \
+        lowered.compile()
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
@@ -365,3 +407,41 @@ def test_deepseek_v2_programs_fit_the_chip_and_leave_the_pool(
     assert "ragged-dot" in hlo
     if program == "prefill":
         assert "zoo_flash_fwd" in hlo
+        # one prompt row: nothing has `max_slots x bucket` rows, and
+        # the temporaries are a quarter of the 2.54 GB that 16 slots
+        # padded to 2048 took
+        eng = _ds_config()["engine"]
+        _no_shape_leads_with(hlo, eng["max_slots"], eng["max_context"])
+        assert mem.temp_size_in_bytes < 0.8e9, mem.temp_size_in_bytes
+
+
+def test_deepseek_v2_prefill_ladder_never_looks_like_a_decode_step(
+        one_chip):
+    """`benchmark/reduce/moe.py` tells a decode step's grouped
+    products from a prefill's by their rows alone: `max_slots x
+    experts a token` = 96. A prefill program holds one prompt, so its
+    grouped products have `bucket x 6` rows: no bucket of the
+    engine's ladder may be as short as the engine has slots. Every
+    program of the ladder as lowered, and the shortest as the v5e
+    compiler names it."""
+    import re
+
+    from analytics_zoo_tpu.pipeline.inference.generation import \
+        prompt_ladder
+    cfg = _ds_config()
+    eng, per_token = cfg["engine"], cfg["num_experts_per_tok"]
+    decode_rows = eng["max_slots"] * per_token
+    assert decode_rows == 96
+    ladder = prompt_ladder(eng["max_context"])
+    assert ladder[0] >= 32 and ladder[-1] == eng["max_context"]
+    for bucket in ladder:
+        _, _, lowered = _ds_program(one_chip, "prefill", bucket=bucket,
+                                    lower_only=True)
+        rows = set(re.findall(r"ragged_dot.*?tensor<(\d+)x",
+                              lowered.as_text()))
+        assert rows == {str(bucket * per_token)}, (bucket, rows)
+        assert bucket * per_token != decode_rows
+    compiled = _ds_program(one_chip, "prefill", bucket=ladder[0])[2]
+    named = set(re.findall(r"%ragged-dot-none\.\d+ = \w+\[(\d+),",
+                           compiled.as_text()))
+    assert named == {str(ladder[0] * per_token)}, named

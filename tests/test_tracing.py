@@ -472,7 +472,8 @@ def decode_records():
 @pytest.mark.parametrize("name,fields", [
     ("decode/iteration", {"admitted", "active", "emitted",
                           "retired"}),
-    ("decode/prefill", {"n", "bucket", "prompt_tokens"}),
+    ("decode/prefill", {"n", "bucket", "prompt_tokens", "calls",
+                        "rows"}),
     ("decode/step", {"n", "dispatch_s", "fetch_s"}),
     ("decode/release", {"slot", "tokens"}),
     ("decode/queue_wait", set()),
@@ -496,8 +497,11 @@ def test_decode_span_in_store_with_fields(decode_records, name,
     if name == "decode/prefill":
         assert sum(r["fields"]["prompt_tokens"] for r in recs) == \
             3 + 7 + 2 + 5 + 4
-        assert all(r["fields"]["bucket"] in (1, 2, 4, 8, 16, 32)
-                   for r in recs)
+        # the engine's ladder starts at its floor; a program holds
+        # one prompt, so an admission runs a row a request
+        assert all(r["fields"]["bucket"] == 32 for r in recs)
+        assert all(r["fields"]["rows"] == r["fields"]["calls"]
+                   == r["fields"]["n"] >= 1 for r in recs)
     if name == "decode/iteration":
         assert sum(r["fields"]["admitted"] for r in recs) == 5
         assert sum(r["fields"]["retired"] for r in recs) == 5
@@ -726,10 +730,11 @@ def test_scopes_in_hlo_and_outputs_bit_identical(monkeypatch,
 def test_prefill_program_carries_its_scopes():
     import jax
     eng = _toy_engine()
-    ids = np.zeros((2, 8), np.int32)
+    ids = np.zeros((1, 32), np.int32)
     text = jax.jit(eng._prefill_fn).lower(
-        eng.cache, eng.params, ids, np.array([3, 0], np.int32),
-        eng._temps, eng._rng, np.int32(0)).as_text(debug_info=True)
+        eng.cache, eng.params, ids, np.array([3], np.int32),
+        np.array([1], np.int32), eng._temps[:1], eng._rng,
+        np.int32(0)).as_text(debug_info=True)
     for scope in ("zoo:kv_cache/write_prompt", "zoo:prefill/layer",
                   "zoo:prefill/attention", "zoo:prefill/lm_head",
                   "zoo:decode/sampling"):
