@@ -143,35 +143,132 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("sht,sthd->shd", probs, v)
 
 
-@jax.named_scope("zoo:decode/mla_attention")
-def mla_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
-                         ctx: jnp.ndarray, seq_lens: jnp.ndarray,
-                         scale: float) -> jnp.ndarray:
-    """Single-query latent (MLA) attention in the absorbed form: the
-    128 query heads of a slot all attend to ONE latent row a cached
-    token, and no per-head key or value is ever formed from the
-    cache.
-
-    ``q_lat``: (S, H, R) — each head's content query already carried
-    into the latent space (``q_nope @ W_kvb[key part]ᵀ``); ``q_pe``:
-    (S, H, P) its rotated part; ``ctx``: (S, T, W) gathered latent
-    rows, ``[c_kv (R) | k_pe (P) | padding]`` (the view from
-    `ops.kv_cache.latent_decode_view`); ``seq_lens`` (S,) masks
-    positions ``>= seq_lens[s]``. Scores are ``(q_lat·c_kv +
-    q_pe·k_pe) * scale``, softmax in f32. Returns the latent outputs
-    ``P c_kv`` (S, H, R); the caller carries them out through
-    ``W_kvb[value part]``."""
+def latent_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
+                            ctx: jnp.ndarray, valid: jnp.ndarray,
+                            scale: float) -> jnp.ndarray:
+    """Single-query latent attention in the absorbed form over the
+    rows ``ctx`` (S, T, W) = ``[c_kv (R) | k_pe (P) | padding]`` that
+    ``valid`` (S, T) marks: whichever rows the caller gathered (a
+    whole context, a window, the rows an indexer chose). ``q_lat``
+    (S, H, R) is each head's content query carried into the latent
+    space, ``q_pe`` (S, H, P) its rotated part; scores are
+    ``(q_lat.c_kv + q_pe.k_pe) * scale``, softmax in f32. Returns the
+    latent outputs ``P c_kv`` (S, H, R)."""
     r, p = q_lat.shape[-1], q_pe.shape[-1]
-    t = ctx.shape[1]
     ctx = ctx.astype(q_lat.dtype)
     c_kv, k_pe = ctx[..., :r], ctx[..., r:r + p]
     f32 = dict(preferred_element_type=jnp.float32)
     logits = (jnp.einsum("shr,str->sht", q_lat, c_kv, **f32) +
               jnp.einsum("shp,stp->sht", q_pe, k_pe, **f32)) * scale
-    valid = (jnp.arange(t, dtype=jnp.int32)[None, None, :] <
-             seq_lens[:, None, None])
-    probs = jax.nn.softmax(jnp.where(valid, logits, -1e30), axis=-1)
+    probs = jax.nn.softmax(jnp.where(valid[:, None, :], logits, -1e30),
+                           axis=-1)
     return jnp.einsum("sht,str->shr", probs.astype(q_lat.dtype), c_kv)
+
+
+@jax.named_scope("zoo:decode/mla_attention")
+def mla_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
+                         ctx: jnp.ndarray, seq_lens: jnp.ndarray,
+                         scale: float) -> jnp.ndarray:
+    """:func:`latent_decode_attention` over a whole gathered context
+    (the view from `ops.kv_cache.latent_decode_view`): the 128 query
+    heads of a slot all attend to ONE latent row a cached token, no
+    per-head key or value is ever formed from the cache, and
+    ``seq_lens`` (S,) masks positions ``>= seq_lens[s]``."""
+    valid = (jnp.arange(ctx.shape[1], dtype=jnp.int32)[None, :] <
+             seq_lens[:, None])
+    return latent_decode_attention(q_lat, q_pe, ctx, valid, scale)
+
+
+def index_scores(q: jnp.ndarray, w: jnp.ndarray, k: jnp.ndarray,
+                 head_block: int = 16) -> jnp.ndarray:
+    """A sparse-attention indexer's scores (DeepSeek-V3.2-Exp):
+    ``I[a, c, t] = sum_h w[a, c, h] * relu(q[a, c, h] . k[a, t])``
+    for queries ``q`` (A, C, H, D) with head weights ``w`` (A, C, H)
+    f32 against ONE index key a token ``k`` (A, T, D). Float32
+    (A, C, T); ``head_block`` heads at a time, so the per-head
+    products never exist for every head at once."""
+    a, c, h, d = q.shape
+    hb = head_block if h % head_block == 0 else h
+    qs = jnp.moveaxis(q.reshape(a, c, h // hb, hb, d), 2, 0)
+    ws = jnp.moveaxis(w.reshape(a, c, h // hb, hb), 2, 0)
+
+    def block(acc, qw):
+        qb, wb = qw
+        dots = jnp.einsum("achd,atd->acht", qb, k,
+                          preferred_element_type=jnp.float32)
+        return acc + jnp.einsum("acht,ach->act", jax.nn.relu(dots),
+                                wb.astype(jnp.float32)), None
+
+    out, _ = jax.lax.scan(
+        block, jnp.zeros((a, c, k.shape[1]), jnp.float32), (qs, ws))
+    return out
+
+
+def _ordered_bits(x: jnp.ndarray) -> jnp.ndarray:
+    """float32 -> uint32 whose unsigned order is the floats' order."""
+    b = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    return jnp.where(b >> 31 == 1, ~b, b | jnp.uint32(1 << 31))
+
+
+def topk_mask(scores: jnp.ndarray, visible: jnp.ndarray, k: int
+              ) -> jnp.ndarray:
+    """The EXACT top-``k`` of ``scores`` (..., T) among ``visible``
+    (..., T) as a mask: every visible key where fewer than ``k``
+    are, else the ``k`` of largest score, equal scores by lowest
+    index (`jax.lax.top_k`'s order). Finds each row's k-th largest
+    score bit by bit (32 counting passes over the scores, no sort),
+    and orders equal scores only in a row that has them at the
+    cut."""
+    if scores.shape[-1] <= k:
+        return visible
+    u = jnp.where(visible, _ordered_bits(scores), jnp.uint32(0))
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(u >= cand[..., None], axis=-1,
+                         dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit,
+                            jnp.zeros(u.shape[:-1], jnp.uint32))
+    above = u > kth[..., None]
+    at = u == kth[..., None]
+    room = k - jnp.sum(above, axis=-1, dtype=jnp.int32)
+    tied = jnp.sum(at, axis=-1, dtype=jnp.int32) > room
+    take = jax.lax.cond(
+        jnp.any(tied),
+        lambda: jnp.logical_and(at, jnp.cumsum(
+            at, axis=-1, dtype=jnp.int32) <= room[..., None]),
+        lambda: at)
+    return jnp.logical_and(jnp.logical_or(above, take), visible)
+
+
+def masked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     mask: jnp.ndarray, scale: float,
+                     q_block: int = 256) -> jnp.ndarray:
+    """Dense attention of a chunk's queries ``q`` (A, C, H, D) over
+    keys ``k`` (A, T, H, D) and values ``v`` (A, T, H, Dv) under an
+    arbitrary ``mask`` (A, C, T) (1 = attend: causality, a window, an
+    indexer's choice), ``q_block`` queries at a time so that the f32
+    scores are (A, H, q_block, T). A query with no key gets a uniform
+    softmax, never NaN: the caller drops it. Returns (A, C, H, Dv)."""
+    a, c, h, d = q.shape
+    qb = q_block if c % q_block == 0 else c
+
+    def block(qm):
+        qs, ms = qm                        # (A, qb, H, D), (A, qb, T)
+        logits = jnp.einsum("aqhd,athd->ahqt", qs, k,
+                            preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(
+            jnp.where(ms[:, None], logits * scale, -1e30), axis=-1)
+        return jnp.einsum("ahqt,athd->aqhd", probs.astype(q.dtype), v)
+
+    if qb == c:
+        return block((q, mask))
+    out = jax.lax.map(block, (
+        jnp.moveaxis(q.reshape(a, c // qb, qb, h, d), 1, 0),
+        jnp.moveaxis(mask.reshape(a, c // qb, qb, -1), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(a, c, h, v.shape[-1])
 
 
 @jax.named_scope("zoo:decode/chunk_attention")

@@ -73,6 +73,22 @@ coordinates, gather and in-place scatters. Int8 latent pages are
 refused by name (the scales are per (token, head) and a latent row
 has no heads), and so is the handoff codec, which carries K and V.
 
+A latent cache may hold layers of two kinds side by side, each with
+its own pool, row width and rule for which positions of a slot are
+live. *Context* layers keep every position: ``pages`` (and, for
+layers whose attention chooses its keys, ``index``: the index key of
+each token in a pool of the same page geometry, so that scoring a
+context reads 128 values a token and not the row), addressed through
+the page table the allocator fills. *Window* layers keep the last
+``window`` positions and the chunk being written: ``window`` is a
+ring of pages a slot (logical page j of slot s lives in ring page
+``s * ring + j % ring``), so a page that has slid out of the window
+is the page the next tokens are written to, the pool's size does not
+depend on ``max_context``, and a slot's ring comes and goes with the
+slot (nothing to allocate or release). :func:`window_view`,
+:func:`write_window_rows` and :func:`gather_rows` are their
+primitives.
+
 The host-side :class:`PageAllocator` is the bookkeeping half: a free
 list of physical page ids for the continuous batcher, which assigns
 pages at admission / token-boundary growth and reclaims them at
@@ -147,12 +163,20 @@ class LatentPagedCache(NamedTuple):
     pages: jnp.ndarray
     page_table: jnp.ndarray
     seq_lens: jnp.ndarray
+    # (context layers that index, max_pages, page_size, index width):
+    # the index keys, at their rows' coordinates; None without
+    index: "jnp.ndarray | None" = None
+    # (window layers, max_slots * ring, page_size, window row): the
+    # window layers' ring of pages a slot; None without
+    window: "jnp.ndarray | None" = None
 
     page_size = property(lambda self: self.pages.shape[2])
     max_context = PagedKVCache.max_context
     max_slots = PagedKVCache.max_slots
     num_pages = property(lambda self: self.pages.shape[1])
     pool_dtype = property(lambda self: self.pages.dtype)
+    window_ring = property(
+        lambda self: self.window.shape[1] // self.page_table.shape[0])
 
 
 # K/V rows are padded to whole lane tiles: what keeps the device's
@@ -208,10 +232,17 @@ def _pool_geometry(num_layers, max_slots, max_context, width,
 def init_latent_cache(num_layers: int, max_slots: int,
                       max_context: int, width: int,
                       page_size: int = 16, max_pages: int = 0,
-                      dtype=jnp.float32) -> LatentPagedCache:
+                      dtype=jnp.float32, index_layers: int = 0,
+                      index_width: int = 0, window_layers: int = 0,
+                      window_width: int = 0, window_tokens: int = 0
+                      ) -> LatentPagedCache:
     """Allocate a latent pool of ``width``-value rows (the KV latent
-    and the rotated key part side by side); geometry and table as
-    :func:`init_cache`."""
+    and the rotated key part side by side) for ``num_layers`` context
+    layers; geometry and table as :func:`init_cache`. With
+    ``index_layers`` an index pool of ``index_width`` beside it, and
+    with ``window_layers`` the window pool: rows of ``window_width``
+    in a ring a slot that holds ``window_tokens`` consecutive
+    positions wherever they start (one page more than they fill)."""
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
         raise ValueError(
             "int8 pages for a latent cache (LatentPagedCache): the "
@@ -219,9 +250,19 @@ def init_latent_cache(num_layers: int, max_slots: int,
             "no heads; use f32 or bf16")
     shape, table = _pool_geometry(num_layers, max_slots, max_context,
                                   width, page_size, max_pages)
+    index = window = None
+    if index_layers:
+        index = jnp.zeros((int(index_layers),) + shape[1:3] + (
+            -(-int(index_width) // ROW_ALIGN) * ROW_ALIGN,), dtype)
+    if window_layers:
+        ring = -(-int(window_tokens) // shape[2]) + 1
+        window = jnp.zeros(
+            (int(window_layers), int(max_slots) * ring, shape[2],
+             -(-int(window_width) // ROW_ALIGN) * ROW_ALIGN), dtype)
     return LatentPagedCache(
         pages=jnp.zeros(shape, dtype), page_table=table,
-        seq_lens=jnp.zeros((int(max_slots),), jnp.int32))
+        seq_lens=jnp.zeros((int(max_slots),), jnp.int32),
+        index=index, window=window)
 
 
 # int8 pages: symmetric per-(token, head) quantization over head_dim.
@@ -412,14 +453,81 @@ def latent_decode_view(cache: LatentPagedCache, layer, new,
 
 
 @jax.named_scope("zoo:kv_cache/append")
-def append_latent_rows(cache: LatentPagedCache, rows, active=None):
+def append_latent_rows(cache: LatentPagedCache, rows, active=None,
+                       index_rows=None, window_rows=None):
     """:func:`append_rows` for a latent pool: ``rows`` (L, S, W),
-    every layer's new row, scattered in place at ``seq_lens[s]``."""
+    every context layer's new row, scattered in place at
+    ``seq_lens[s]``; ``index_rows`` and ``window_rows`` likewise into
+    the index and the window pool."""
+    writes = _decode_writes(cache, active)
     phys, offset = _scatter_coords(
         cache.page_table, cache.seq_lens, cache.seq_lens,
-        cache.page_size, _decode_writes(cache, active))
-    return cache._replace(
-        pages=_put_rows(cache.pages, phys, offset, rows))
+        cache.page_size, writes)
+    if rows is not None:
+        cache = cache._replace(
+            pages=_put_rows(cache.pages, phys, offset, rows))
+    if index_rows is not None:
+        cache = cache._replace(
+            index=_put_rows(cache.index, phys, offset, index_rows))
+    if window_rows is not None:
+        cache = cache._replace(window=write_window_rows(
+            cache, jnp.arange(cache.max_slots, dtype=jnp.int32),
+            cache.seq_lens[:, None], writes[:, None],
+            window_rows[:, :, None]))
+    return cache
+
+
+def _window_coords(cache: LatentPagedCache, slots, positions, active):
+    """(ring page, in-page offset) of ``positions`` (A, C) of slots
+    ``slots`` (A,) in the window pool; inactive ones out of range."""
+    ring, page = cache.window_ring, cache.page_size
+    phys = slots[:, None] * ring + (positions // page) % ring
+    return jnp.where(active, phys, cache.window.shape[1] + 2 ** 20), \
+        positions % page
+
+
+@jax.named_scope("zoo:kv_cache/write_prompt")
+def write_window_rows(cache: LatentPagedCache, slots, positions,
+                      active, rows):
+    """The window pool with ``rows`` (L, A, C, width) written at
+    ``positions`` (A, C) of slots ``slots`` (A,) where ``active``.
+    The positions written in one call must lie within one turn of the
+    ring (``(ring - 1) * page_size`` consecutive positions a slot),
+    or two of them would fall on one row."""
+    phys, offset = _window_coords(cache, slots, positions, active)
+    return _put_rows(cache.window, phys, offset,
+                     _latent_rows(cache.window, rows))
+
+
+@jax.named_scope("zoo:kv_cache/gather")
+def window_view(cache: LatentPagedCache, layer, slots, first_page,
+                n_pages: int):
+    """``n_pages`` consecutive logical pages of window layer
+    ``layer`` from ``first_page`` (A,) on, for slots ``slots`` (A,):
+    ``(rows (A, n_pages * page_size, W), positions (A, n_pages *
+    page_size))``. A row holds its position only if that position was
+    written within the last turn of the ring: the caller's mask owns
+    validity."""
+    ring, page = cache.window_ring, cache.page_size
+    logical = first_page[:, None] + jnp.arange(n_pages,
+                                               dtype=jnp.int32)[None]
+    picked = cache.window.at[
+        layer, slots[:, None] * ring + logical % ring].get(mode="clip")
+    positions = (logical[:, :, None] * page + jnp.arange(
+        page, dtype=jnp.int32)).reshape(len(slots), n_pages * page)
+    return picked.reshape(len(slots), n_pages * page, -1), positions
+
+
+@jax.named_scope("zoo:kv_cache/gather")
+def gather_rows(pages, page_table, positions, layer):
+    """Single rows of one layer of a stacked pool: ``positions``
+    (A, K) of the slots whose table rows are ``page_table`` (A,
+    pages_per_slot) -> (A, K, W)."""
+    page = pages.shape[-2]
+    phys = jnp.take_along_axis(
+        page_table, jnp.minimum(positions // page,
+                                page_table.shape[1] - 1), axis=1)
+    return pages.at[layer, phys, positions % page].get(mode="clip")
 
 
 def prompt_seq_lens(seq_lens, slots, prompt_lens):
@@ -432,16 +540,20 @@ def prompt_seq_lens(seq_lens, slots, prompt_lens):
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
-def write_latent_prompt(pages, page_table, prompt_lens, rows):
+def write_latent_prompt(pages, page_table, prompt_lens, rows,
+                        start=None):
     """:func:`write_prompt_layer` for a latent pool: ``rows``
     (L, A, T, width) hold every layer's (right-padded) prompt rows,
     ``page_table`` (A, pages_per_slot) the table row of each;
-    positions past ``prompt_lens[a]`` are dropped. Returns the
-    pool."""
+    positions past ``prompt_lens[a]`` are dropped. With ``start``
+    (A,) row j lands at ``start[a] + j`` and ``prompt_lens`` is the
+    length after the chunk. Returns the pool."""
     a, t = rows.shape[1], rows.shape[2]
     page_size = pages.shape[-2]
     positions = jnp.broadcast_to(
         jnp.arange(t, dtype=jnp.int32)[None, :], (a, t))
+    if start is not None:
+        positions = positions + jnp.asarray(start, jnp.int32)[:, None]
     active = jnp.logical_and(
         positions < prompt_lens[:, None],
         positions < page_table.shape[1] * page_size)

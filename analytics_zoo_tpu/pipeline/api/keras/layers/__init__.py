@@ -32,7 +32,8 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.noise import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
     MoE, GatedMLP, GroupLimitedMoE)
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder import (
-    YarnRope, LatentAttention, PatternDecoder, deepseek_v2_decoder)
+    YarnRope, LatentAttention, SparseIndexer, PatternDecoder,
+    deepseek_v2_decoder, dots3_note_decoder)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
     MultiHeadAttention, TransformerLayer, BERT)
 from analytics_zoo_tpu.pipeline.api.keras.layers.elementwise import (
@@ -83,7 +84,8 @@ __all__ = [
     # transformer
     "MultiHeadAttention", "TransformerLayer", "MoE", "BERT",
     "GatedMLP", "GroupLimitedMoE", "YarnRope", "LatentAttention",
-    "PatternDecoder", "deepseek_v2_decoder",
+    "SparseIndexer", "PatternDecoder", "deepseek_v2_decoder",
+    "dots3_note_decoder",
     # elementwise / tensor utilities
     "AddConstant", "MulConstant", "CAdd", "CMul", "Mul", "Scale", "Power",
     "Negative", "Exp", "Log", "Sqrt", "Square", "Identity",

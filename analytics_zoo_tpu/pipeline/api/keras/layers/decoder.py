@@ -4,41 +4,46 @@ architectures whose layers are not all alike.
 `TransformerLayer` is one hard-wired block (learned positions,
 LayerNorm, fused-QKV heads, GELU MLP, tied head). Here a layer is
 ``h = x + Attn(norm1(x)); y = h + FFN(norm2(h))`` over RMSNorm, with
-the attention part shared by every layer and the feed-forward part
-given PER LAYER (the *pattern*: DeepSeek-V2 is one dense SwiGLU layer,
-then expert layers), a final norm and an untied head. `prefill`,
-`decode_step` and `generate` are written once over the pattern, and
+the attention part and the feed-forward part given PER LAYER (the
+*pattern*: DeepSeek-V2 is one attention for all layers, one dense
+SwiGLU layer, then expert layers; dots3-note is full layers whose
+attention chooses its keys among sliding layers of other widths), a
+final norm and an untied head. `prefill`, `decode_step`,
+`forward_chunk` and `generate` are written once over the pattern, and
 the surface is the one `GenerationEngine` drives
-(``init_kv_cache / prefill / decode_step / generate``, ``seq_len``,
-``vocab``); ``forward_chunk`` is not there, so chunked prefill and
-speculative verify are refused for this decoder by the engine.
+(``init_kv_cache / prefill / decode_step / forward_chunk /
+generate``, ``seq_len``, ``vocab``).
 
 Parts here: :class:`YarnRope` (rotary positions with YaRN scaling,
 rotate-half convention), :class:`LatentAttention` (multi-head latent
 attention: low-rank queries, one KV latent a token shared by all
-heads; expanded per-head K/V for the prompt, the absorbed form
-against the latent page pool for a decode step). Feed-forward parts
-are `layers.moe.GatedMLP` and `layers.moe.GroupLimitedMoE`.
+heads; expanded per-head K/V for prompts and chunks, the absorbed
+form against the latent page pool for a decode step; optionally
+windowed, gated, or sparse through a :class:`SparseIndexer`).
+Feed-forward parts are `layers.moe.GatedMLP` and
+`layers.moe.GroupLimitedMoE`.
 
 The layers are a Python loop, each with its own weight arrays: an
 expert layer's weights are gigabytes, and a slab sliced out of a
-stacked array for a scan's body would be copied every step. The page
-pool is closed over and written once after the last layer, as in
-`TransformerLayer.decode_step`.
+stacked array for a scan's body would be copied every step. The
+cache's pools are closed over, only read by the layers, and written
+once after the last layer, as in `TransformerLayer.decode_step`.
 """
 
 from __future__ import annotations
 
+import functools
 import math
-from typing import Optional, Sequence
+from typing import NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from analytics_zoo_tpu.ops.attention import (dot_product_attention,
-                                             mla_decode_attention,
-                                             resolve_attention_impl)
+from analytics_zoo_tpu.ops.attention import (
+    dot_product_attention, index_scores, latent_decode_attention,
+    masked_attention, mla_decode_attention, resolve_attention_impl,
+    topk_mask)
 from analytics_zoo_tpu.pipeline.api.keras.engine import (KerasLayer,
                                                          ShapeLike)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
@@ -128,6 +133,30 @@ class YarnRope:
             axis=-1).astype(x.dtype)
 
 
+class SparseIndexer(NamedTuple):
+    """The key chooser of a sparse latent attention
+    (DeepSeek-V3.2-Exp's lightning indexer): ``n_head`` index queries
+    of ``head_dim`` a token, ONE index key a token cached beside the
+    latent, and the ``top_k`` keys of largest score kept for each
+    query."""
+    n_head: int
+    head_dim: int
+    top_k: int
+
+
+# the indexer's LayerNorm on its key (DeepSeek-V3.2-Exp's)
+INDEX_NORM_EPS = 1e-6
+
+
+def _layer_norm(x, gain, bias, eps: float):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * gain.astype(jnp.float32) +
+            bias.astype(jnp.float32)).astype(x.dtype)
+
+
 class LatentAttention:
     """Multi-head latent attention (DeepSeek-V2): queries through a
     ``q_lora_rank`` bottleneck, keys and values through ONE
@@ -140,15 +169,37 @@ class LatentAttention:
     from the latent (the expanded form) ``head_block`` heads at a
     time; :meth:`decode` carries the query into the latent space and
     the result out of it (the absorbed form) and never expands the
-    cache."""
+    cache; :meth:`chunk` is the expanded form for a chunk of new
+    tokens against what the cache holds before them.
+
+    Which keys a query sees is the part's *kind*:
+
+    - ``window = W``: the last ``W`` positions, its own among them
+      (a sliding layer; its rows live in the cache's window pool);
+    - ``indexer``: the ``top_k`` keys of largest index score among
+      those before it (all of them while there are no more), scored
+      from ONE index key a token that is cached beside the latent
+      (:attr:`index_width` values);
+    - neither: every key before it.
+
+    ``gate``: a head-wise output gate, ``sigmoid(x W_g)`` one scalar
+    a head, on the attention output before ``o`` (Gated Attention,
+    arXiv:2505.06708). ``lora_rescale``: the normed latents times
+    ``sqrt(hidden / rank)`` (LongCat-Flash's scale correction)."""
 
     def __init__(self, hidden_size: int, n_head: int,
                  q_lora_rank: int, kv_lora_rank: int,
                  qk_nope_head_dim: int, qk_rope_head_dim: int,
                  v_head_dim: int, rope: YarnRope,
-                 rms_eps: float = 1e-6, head_block: int = 16):
+                 rms_eps: float = 1e-6, head_block: int = 16,
+                 window: int = 0, gate: bool = False,
+                 indexer: Optional[SparseIndexer] = None,
+                 lora_rescale: bool = False):
         if rope.dim != qk_rope_head_dim:
             raise ValueError("rope.dim must equal qk_rope_head_dim")
+        if window and indexer:
+            raise ValueError("a windowed part sees few keys: it takes "
+                             "no indexer")
         self.hidden_size, self.n_head = int(hidden_size), int(n_head)
         self.q_rank, self.kv_rank = int(q_lora_rank), \
             int(kv_lora_rank)
@@ -161,11 +212,23 @@ class LatentAttention:
         self.row_width = self.kv_rank + self.rope_dim
         self.scale = (self.nope + self.rope_dim) ** -0.5 * \
             rope.attention_mscale ** 2
+        self.window, self.gate = int(window), bool(gate)
+        self.indexer = SparseIndexer(*indexer) if indexer else None
+        self.index_width = self.indexer.head_dim if indexer else 0
+        self.q_rescale = math.sqrt(hidden_size / q_lora_rank) \
+            if lora_rescale else 1.0
+        self.kv_rescale = math.sqrt(hidden_size / kv_lora_rank) \
+            if lora_rescale else 1.0
+        # the pool its rows live in, and the scope its work is under
+        self.kind = "window" if self.window else "context"
+        self.scope = "swa_attention" if self.window else \
+            "dsa_attention" if self.indexer else "mla_attention"
+        self.plain = not (self.window or self.gate or self.indexer)
 
     def build(self, rng, stddev: float) -> dict:
         h, nh = self.hidden_size, self.n_head
-        k = jax.random.split(rng, 5)
-        return {
+        k = jax.random.split(rng, 9)
+        out = {
             "q_a": _normal(k[0], (h, self.q_rank), stddev),
             "q_norm": jnp.ones((self.q_rank,), jnp.float32),
             "q_b": _normal(k[1], (self.q_rank,
@@ -178,6 +241,17 @@ class LatentAttention:
                             stddev),
             "o": _normal(k[4], (nh * self.v_dim, h), stddev),
         }
+        if self.gate:
+            out["gate"] = _normal(k[5], (h, nh), stddev)
+        if self.indexer:
+            hi, di, _ = self.indexer
+            out["index"] = {
+                "q": _normal(k[6], (self.q_rank, hi * di), stddev),
+                "k": _normal(k[7], (h, di), stddev),
+                "k_gain": jnp.ones((di,), jnp.float32),
+                "k_bias": jnp.zeros((di,), jnp.float32),
+                "w": _normal(k[8], (h, hi), stddev)}
+        return out
 
     def _latents(self, p, x, positions):
         """``x`` (..., hidden) at ``positions`` (...): the normed
@@ -188,13 +262,47 @@ class LatentAttention:
         kv = x @ p["kv_a"].astype(dt)
         c_kv = rms_norm(kv[..., :self.kv_rank], p["kv_norm"],
                         self.rms_eps)
+        if self.q_rescale != 1.0 or self.kv_rescale != 1.0:
+            c_q = (c_q * self.q_rescale).astype(dt)
+            c_kv = (c_kv * self.kv_rescale).astype(dt)
         k_pe = self.rope(kv[..., self.kv_rank:], positions)
         return c_q, jnp.concatenate([c_kv, k_pe], axis=-1)
 
+    def _partial_rope(self, x, positions):
+        """The rotary part on the first ``rope_dim`` values of
+        ``x``, the rest as they are (the indexer's convention)."""
+        return jnp.concatenate(
+            [self.rope(x[..., :self.rope_dim], positions),
+             x[..., self.rope_dim:]], axis=-1)
+
+    def _index_parts(self, p, x, c_q, positions):
+        """The indexer's queries (..., H_I, D_I), head weights
+        (..., H_I) f32 and the token's index key (..., D_I)."""
+        hi, di, _ = self.indexer
+        dt, pi = x.dtype, p["index"]
+        q = (c_q @ pi["q"].astype(dt)).reshape(
+            c_q.shape[:-1] + (hi, di))
+        q = self._partial_rope(q, positions[..., None])
+        k = _layer_norm(x @ pi["k"].astype(dt), pi["k_gain"],
+                        pi["k_bias"], INDEX_NORM_EPS)
+        k = self._partial_rope(k, positions)
+        w = (x @ pi["w"].astype(dt)).astype(jnp.float32) * \
+            (hi ** -0.5 * di ** -0.5)
+        return q, w, k
+
+    def _gates(self, p, x, phase: str):
+        """(..., n_head) f32 head gates of ``x``, or None."""
+        if not self.gate:
+            return None
+        with jax.named_scope(f"zoo:{phase}/attn_gate"):
+            return jax.nn.sigmoid(
+                (x @ p["gate"].astype(x.dtype)).astype(jnp.float32))
+
     def prefill(self, p, x, impl=None):
         """Causal self-attention of (S, T, hidden) prompts at
-        positions 0..T-1. Returns ``(out (S, T, hidden), rows
-        (S, T, row_width))``."""
+        positions 0..T-1, for a part that sees every key (no window,
+        indexer or gate: those go through :meth:`chunk`). Returns
+        ``(out (S, T, hidden), rows (S, T, row_width))``."""
         s, t, _ = x.shape
         dt = x.dtype
         nb, nh = self.head_block, self.n_head
@@ -236,48 +344,248 @@ class LatentAttention:
             (jnp.moveaxis(q_b, 1, 0), jnp.moveaxis(kv_b, 1, 0), o_w))
         return out, rows
 
-    def decode(self, p, x, positions, view, lens_after):
-        """One new token a slot: ``x`` (S, hidden) at ``positions``
-        (S,). ``view(row)`` returns the layer's gathered latent
-        context with the new row laid in, and the pool's row.
-        Returns ``(out (S, hidden), pool row)``."""
-        s = x.shape[0]
-        dt = x.dtype
+    def _queries(self, p, c_q, positions):
+        """Absorbed-form queries of (S, q_rank) latents: ``q_lat``
+        (S, H, kv_rank), ``q_pe`` (S, H, rope) and ``kv_b`` split by
+        head."""
+        s, dt = c_q.shape[0], c_q.dtype
         nh, qk = self.n_head, self.nope + self.rope_dim
-        c_q, row = self._latents(p, x, positions)
         q = (c_q @ p["q_b"].astype(dt)).reshape(s, nh, qk)
         q_pe = self.rope(q[..., self.nope:], positions[:, None])
         kv_b = p["kv_b"].astype(dt).reshape(self.kv_rank, nh,
                                             self.nope + self.v_dim)
         q_lat = jnp.einsum("shd,rhd->shr", q[..., :self.nope],
                            kv_b[..., :self.nope])
-        ctx, pool_row = view(row)
-        o_lat = mla_decode_attention(q_lat, q_pe, ctx, lens_after,
-                                     self.scale)
+        return q_lat, q_pe, kv_b
+
+    def decode(self, p, x, positions, view, lens_after):
+        """One new token a slot: ``x`` (S, hidden) at ``positions``
+        (S,). ``view(row)`` returns the layer's gathered latent
+        context with the new row laid in, and the pool's row; a part
+        that sees a window or chooses its keys reads the cache
+        itself, through ``view.cache`` (only read), ``view.at`` =
+        (the layer's index in its pool, in the index pool) and
+        ``view.active``. Returns ``(out (S, hidden), pool row)``,
+        and with an indexer the index pool's row third."""
+        from analytics_zoo_tpu.ops import kv_cache as kvc
+        s, dt = x.shape[0], x.dtype
+        c_q, row = self._latents(p, x, positions)
+        q_lat, q_pe, kv_b = self._queries(p, c_q, positions)
+        rows = {}
+        if self.window or self.indexer:
+            cache, at, active = view.cache, view.at, view.active
+        if self.window:
+            rows["row"] = new = kvc._latent_rows(cache.window, row)
+            with jax.named_scope("zoo:decode/swa_attention"):
+                first = jnp.maximum(
+                    cache.seq_lens - self.window + 1, 0) // \
+                    cache.page_size
+                ctx, at_pos = kvc.window_view(
+                    cache, at[0], jnp.arange(s, dtype=jnp.int32),
+                    first, max(self.window - 2, 0) //
+                    cache.page_size + 2)
+                valid = jnp.logical_and(
+                    at_pos < cache.seq_lens[:, None],
+                    at_pos > cache.seq_lens[:, None] - self.window)
+                o_lat = latent_decode_attention(
+                    q_lat, q_pe,
+                    jnp.concatenate([ctx, new[:, None]], axis=1),
+                    jnp.concatenate(
+                        [valid, (lens_after > cache.seq_lens)[:, None]],
+                        axis=1), self.scale)
+        elif self.indexer:
+            q_i, w_i, k_i = self._index_parts(p, x, c_q, positions)
+            rows["row"] = new = kvc._latent_rows(cache.pages, row)
+            rows["index"] = k_new = kvc._latent_rows(cache.index, k_i)
+            writes = kvc._decode_writes(cache, active)
+            t = cache.max_context
+            with jax.named_scope("zoo:decode/dsa_index"):
+                keys = kvc._lay_rows(
+                    kvc.gather_layer(cache.index, cache.page_table, t,
+                                     at[1]),
+                    cache.seq_lens, k_new, writes)
+                scores = index_scores(
+                    q_i[:, None], w_i[:, None],
+                    keys[..., :self.index_width].astype(dt))[:, 0]
+                scores = jnp.where(kvc.length_mask(lens_after, t),
+                                   scores, -jnp.inf)
+            with jax.named_scope("zoo:decode/dsa_select"):
+                _, chosen = jax.lax.top_k(
+                    scores, min(self.indexer.top_k, t))
+            with jax.named_scope("zoo:decode/dsa_attention"):
+                ctx = kvc.gather_rows(cache.pages, cache.page_table,
+                                      chosen, at[0])
+                mine = jnp.logical_and(
+                    chosen == cache.seq_lens[:, None],
+                    writes[:, None])
+                o_lat = latent_decode_attention(
+                    q_lat, q_pe,
+                    jnp.where(mine[:, :, None], new[:, None], ctx),
+                    chosen < lens_after[:, None], self.scale)
+        else:
+            ctx, rows["row"] = view(row)
+            o_lat = mla_decode_attention(q_lat, q_pe, ctx, lens_after,
+                                         self.scale)
         o = jnp.einsum("shr,rhd->shd", o_lat, kv_b[..., self.nope:])
-        return o.reshape(s, nh * self.v_dim) @ p["o"].astype(dt), \
-            pool_row
+        g = self._gates(p, x, "decode")
+        if g is not None:
+            o = (o * g[..., None]).astype(dt)
+        return (o.reshape(s, self.n_head * self.v_dim) @
+                p["o"].astype(dt), *rows.values())
+
+    def chunk(self, p, x, q_pos, valid, cached=None, phase="prefill"):
+        """A chunk of new tokens a row: ``x`` (A, C, hidden) at
+        positions ``q_pos`` (A, C), ``valid`` (A, C) the real ones,
+        in the expanded form against ``cached`` = ``(rows (A, T, W),
+        positions (A, T), valid (A, T), index keys (A, T, D_I) or
+        None)``, what the cache holds of the positions before the
+        chunk (None: nothing), and against the chunk's own rows in
+        flight. The mask is the part's kind: causal, and the window
+        or the indexer's exact top-k over causal keys. Returns
+        ``(out (A, C, hidden), rows)`` as :meth:`decode`, rows
+        (A, C, width) unpadded."""
+        a, c, _ = x.shape
+        dt = x.dtype
+        nb, nh = self.head_block, self.n_head
+        qk = self.nope + self.rope_dim
+        c_q, new = self._latents(p, x, q_pos)
+        rows = {"row": new}
+        lat, k_pos, k_valid = new, q_pos, valid
+        if cached is not None:
+            lat = jnp.concatenate(
+                [cached[0][..., :self.row_width].astype(dt), new],
+                axis=1)
+            k_pos = jnp.concatenate([cached[1], q_pos], axis=1)
+            k_valid = jnp.concatenate([cached[2], valid], axis=1)
+        mask = jnp.logical_and(
+            k_valid[:, None, :],
+            k_pos[:, None, :] <= q_pos[:, :, None])
+        if self.window:
+            mask = jnp.logical_and(
+                mask, q_pos[:, :, None] - k_pos[:, None, :]
+                < self.window)
+        if self.indexer:
+            q_i, w_i, k_i = self._index_parts(p, x, c_q, q_pos)
+            rows["index"] = k_i
+            if cached is not None:
+                k_i = jnp.concatenate(
+                    [cached[3][..., :self.index_width].astype(dt),
+                     k_i], axis=1)
+            with jax.named_scope(f"zoo:{phase}/dsa_index"):
+                scores = index_scores(q_i, w_i, k_i)
+            with jax.named_scope(f"zoo:{phase}/dsa_select"):
+                mask = topk_mask(scores, mask, self.indexer.top_k)
+        c_kv, k_pe = lat[..., :self.kv_rank], lat[..., self.kv_rank:]
+        t = lat.shape[1]
+        q_b = p["q_b"].astype(dt).reshape(self.q_rank, nh // nb,
+                                          nb * qk)
+        kv_b = p["kv_b"].astype(dt).reshape(
+            self.kv_rank, nh // nb, nb * (self.nope + self.v_dim))
+        o_w = p["o"].astype(dt).reshape(nh // nb, nb * self.v_dim, -1)
+        g = self._gates(p, x, phase)
+        gates = jnp.ones((nh // nb, a, c, nb), jnp.float32) \
+            if g is None else jnp.moveaxis(
+                g.reshape(a, c, nh // nb, nb), 2, 0)
+
+        def heads(acc, w):
+            q_w, kv_w, o_blk, g_blk = w
+            q = (c_q @ q_w).reshape(a, c, nb, qk)
+            q = jnp.concatenate(
+                [q[..., :self.nope],
+                 self.rope(q[..., self.nope:], q_pos[:, :, None])],
+                axis=-1)
+            kv = (c_kv @ kv_w).reshape(a, t, nb,
+                                       self.nope + self.v_dim)
+            k = jnp.concatenate(
+                [kv[..., :self.nope], jnp.broadcast_to(
+                    k_pe[:, :, None, :], (a, t, nb, self.rope_dim))],
+                axis=-1)
+            with jax.named_scope(f"zoo:{phase}/{self.scope}"):
+                o = masked_attention(q, k, kv[..., self.nope:], mask,
+                                     self.scale)
+            if self.gate:
+                o = (o * g_blk[..., None]).astype(dt)
+            return acc + o.reshape(a, c, nb * self.v_dim) @ o_blk, None
+
+        out, _ = jax.lax.scan(
+            heads, jnp.zeros_like(x),
+            (jnp.moveaxis(q_b, 1, 0), jnp.moveaxis(kv_b, 1, 0), o_w,
+             gates))
+        return out, rows
+
+
+class _DecodeView:
+    """What one layer's attention reads of the cache in a decode
+    step: called with the new token's row, the gathered context of a
+    layer that sees every key (`ops.kv_cache.latent_decode_view`);
+    ``cache``, ``at`` and ``active`` for the kinds that gather for
+    themselves."""
+
+    def __init__(self, cache, at, active):
+        self.cache, self.at, self.active = cache, at, active
+
+    def __call__(self, row):
+        from analytics_zoo_tpu.ops import kv_cache as kvc
+        return kvc.latent_decode_view(self.cache, self.at[0], row,
+                                      active=self.active)
+
+
+# per call, as int32: over the layers whose attention chooses its
+# keys, the keys its queries could see and those they kept; over the
+# window layers, the pages of the window pool written over
+ATTENTION_COUNTERS = ("zoo_tpu_dsa_keys_visible_total",
+                      "zoo_tpu_dsa_keys_selected_total",
+                      "zoo_tpu_window_pages_recycled_total")
+
+
+def _record_attention(counts):
+    """Add one call's three counts to :data:`ATTENTION_COUNTERS`."""
+    from analytics_zoo_tpu.common import observability as obs
+    visible, kept, recycled = (int(c) for c in counts)
+    obs.counter(
+        "zoo_tpu_dsa_keys_visible_total",
+        help="keys visible to the queries of the sparse-attention "
+        "layers, steps and chunks").inc(visible)
+    obs.counter(
+        "zoo_tpu_dsa_keys_selected_total",
+        help="keys the indexer kept for them (its top-k of the "
+        "visible)").inc(kept)
+    obs.counter(
+        "zoo_tpu_window_pages_recycled_total",
+        help="window-pool pages written over by positions one turn "
+        "of the ring later").inc(recycled)
 
 
 class PatternDecoder(KerasLayer):
     """Pre-norm decoder over a layer pattern: ``attention`` (a
-    :class:`LatentAttention`) in every layer, ``feed_forward[i]`` (a
+    :class:`LatentAttention` for every layer, or one a layer: full
+    and sliding layers side by side) and ``feed_forward[i]`` (a
     `GatedMLP` or a `GroupLimitedMoE`) in layer i, RMSNorm, a final
     norm and an untied head over ``vocab`` rows. ``seq_len`` is the
     most positions the model declares (there is no position table).
 
     Input (seq_len,) int token ids; ``call`` returns logits
-    (B, T, vocab). The decode surface is `TransformerLayer`'s, less
-    ``forward_chunk``. A feed-forward part that counts (an expert
-    layer's assignments) names its counts in ``step_counters``;
-    ``decode_step(..., stats=True)`` then also returns their sums
-    over the layers as one int32 vector, which
+    (B, T, vocab). The decode surface is `TransformerLayer`'s,
+    ``forward_chunk`` included. The cache is one
+    `ops.kv_cache.LatentPagedCache`: the layers that keep their
+    whole context share its page pool (and an index pool, if their
+    attention chooses its keys), the window layers its ring of
+    ``window - 1 + max_chunk`` positions a slot, ``max_chunk`` being
+    the most tokens one chunk may write (:meth:`init_kv_cache`'s
+    argument: the engine passes its own). A part that counts (an
+    expert layer's assignments, a sparse attention's keys, a window's
+    recycled pages) names its counts in ``step_counters``;
+    ``decode_step`` and ``forward_chunk`` with ``stats=True`` then
+    also return their sums over the layers as one int32 vector, which
     :meth:`record_step_counts` adds to the counters of those
     names."""
 
+    # cached-context lengths a chunk program branches between start
+    # from this many tokens (and from the chunk's own length)
+    ctx_bucket_floor = 512
+
     def __init__(self, vocab: int, hidden_size: int,
-                 attention: LatentAttention,
-                 feed_forward: Sequence, seq_len: int,
+                 attention, feed_forward: Sequence, seq_len: int,
                  rms_eps: float = 1e-6,
                  initializer_range: float = 0.02,
                  attention_impl: Optional[str] = None,
@@ -288,27 +596,49 @@ class PatternDecoder(KerasLayer):
             resolve_attention_impl(attention_impl)
         self.attention_impl = attention_impl
         self.vocab, self.hidden_size = int(vocab), int(hidden_size)
-        self.attention = attention
         self.feed_forward = list(feed_forward)
         self.n_block = len(self.feed_forward)
+        self.attentions = list(attention) if isinstance(
+            attention, (list, tuple)) else [attention] * self.n_block
+        if len(self.attentions) != self.n_block:
+            raise ValueError("one attention part a layer, or one for "
+                             "all of them")
+        self.attention = self.attentions[0]
         self.seq_len = int(seq_len)
         self.rms_eps = float(rms_eps)
         self.initializer_range = float(initializer_range)
+        # where each layer's rows live: (index in its pool, index in
+        # the index pool)
+        self._at, n = [], {"context": 0, "window": 0, "index": 0}
+        for att in self.attentions:
+            self._at.append((n[att.kind], n["index"]))
+            n[att.kind] += 1
+            n["index"] += bool(att.indexer)
+        self._pool_layers = n
+        for kind in ("context", "window"):
+            widths = {a.row_width for a in self.attentions
+                      if a.kind == kind}
+            if len(widths) > 1:
+                raise ValueError(f"the {kind} layers' rows differ in "
+                                 f"width: {sorted(widths)}")
+        self._plain = all(a.plain for a in self.attentions)
         self._counting = next(
             (f for f in self.feed_forward if f.step_counters), None)
-        self.step_counters = self._counting.step_counters \
-            if self._counting else ()
+        self.step_counters = (
+            self._counting.step_counters if self._counting else ()) + (
+            () if self._plain else ATTENTION_COUNTERS)
 
     def build(self, rng, input_shape: ShapeLike) -> dict:
         r, h = self.initializer_range, self.hidden_size
         k_tok, k_head, *k_layers = jax.random.split(
             rng, 2 + self.n_block)
         layers = []
-        for key, ffn in zip(k_layers, self.feed_forward):
+        for key, att, ffn in zip(k_layers, self.attentions,
+                                 self.feed_forward):
             k_a, k_f = jax.random.split(key)
             layers.append({
                 "norm1": jnp.ones((h,), jnp.float32),
-                "attn": self.attention.build(k_a, r),
+                "attn": att.build(k_a, r),
                 "norm2": jnp.ones((h,), jnp.float32),
                 "ffn": ffn.build(k_f, r)})
         return {"tok_embed": _normal(k_tok, (self.vocab, h), r),
@@ -320,9 +650,13 @@ class PatternDecoder(KerasLayer):
         return (input_shape[0], self.vocab)
 
     def record_step_counts(self, counts):
-        """Add what ``decode_step(..., stats=True)`` counted to the
+        """Add what a program counted with ``stats=True`` to the
         counters ``step_counters`` names."""
-        self._counting.record(counts)
+        n = len(self._counting.step_counters) if self._counting else 0
+        if n:
+            self._counting.record(counts[:n])
+        if not self._plain:
+            _record_attention(counts[n:])
 
     # -- the pattern, once for prompts and once for a step -------------
     def _ffn(self, ffn, p, x, valid, scope):
@@ -337,20 +671,59 @@ class PatternDecoder(KerasLayer):
         h = rms_norm(h, params["norm_f"], self.rms_eps)
         return h @ params["lm_head"].astype(h.dtype)
 
+    def _attention_counts(self, first, n_new, ring_pages: int,
+                          page: int):
+        """The three :data:`ATTENTION_COUNTERS` of queries at
+        positions ``first[a] .. first[a] + n_new[a] - 1``: a query at
+        position t sees t + 1 keys and keeps ``min(t + 1, top_k)``;
+        a logical page past the ring's first turn lands on a page
+        that held an older one."""
+        first, n = first.astype(jnp.int32), n_new.astype(jnp.int32)
+        last = first + n
+        # sum of (t + 1) over the n positions from `first` on
+        seen = n * first + n * (n + 1) // 2
+        visible = kept = jnp.zeros((), jnp.int32)
+        for att in self.attentions:
+            if att.indexer is None:
+                continue
+            # what the positions past the top_k-th see beyond it
+            lo = jnp.clip(first, att.indexer.top_k, last)
+            m = last - lo
+            over = m * (lo + 1 - att.indexer.top_k) + m * (m - 1) // 2
+            visible = visible + jnp.sum(seen)
+            kept = kept + jnp.sum(seen - over)
+        # logical pages begun by this call (their first position in
+        # [first, last)) past the ring's first turn
+        up = lambda n: -(-n // page)
+        recycled = jnp.sum(jnp.maximum(
+            up(last) - jnp.maximum(up(first), ring_pages), 0)) * \
+            self._pool_layers["window"] if ring_pages \
+            else jnp.zeros((), jnp.int32)
+        return jnp.stack([visible, kept,
+                          recycled.astype(jnp.int32)])
+
     def _prompt(self, params, token_ids, valid):
-        """(hidden (S, T, hidden), rows (L, S, T, row_width)) of
-        right-padded prompts; ``valid`` (S, T) marks real tokens."""
+        """(hidden (S, T, hidden), rows: one dict a layer of
+        (S, T, width) arrays) of right-padded prompts; ``valid``
+        (S, T) marks real tokens."""
         x = jnp.take(params["tok_embed"],
                      token_ids.astype(jnp.int32), axis=0)
+        pos = jnp.broadcast_to(jnp.arange(
+            x.shape[1], dtype=jnp.int32)[None], x.shape[:2])
         rows = []
-        for p, ffn in zip(params["layers"], self.feed_forward):
+        for p, att, ffn in zip(params["layers"], self.attentions,
+                               self.feed_forward):
             with jax.named_scope("zoo:prefill/layer"):
-                a, r = self.attention.prefill(
-                    p["attn"], rms_norm(x, p["norm1"], self.rms_eps),
-                    impl=self.attention_impl)
+                y = rms_norm(x, p["norm1"], self.rms_eps)
+                if att.plain:
+                    a, r = att.prefill(p["attn"], y,
+                                       impl=self.attention_impl)
+                    r = {"row": r}
+                else:
+                    a, r = att.chunk(p["attn"], y, pos, valid)
                 x, _ = self._ffn(ffn, p, x + a, valid, "prefill")
                 rows.append(r)
-        return x, jnp.stack(rows)
+        return x, rows
 
     def call(self, params, x, *, training=False, rng=None):
         del training, rng
@@ -359,13 +732,59 @@ class PatternDecoder(KerasLayer):
 
     # -- decode surface ------------------------------------------------
     def init_kv_cache(self, max_slots: int, max_context: int,
-                      page_size: int = 16, dtype=None):
-        """A fresh latent page pool sized for this stack."""
+                      page_size: int = 16, dtype=None,
+                      max_chunk: int = 1):
+        """A fresh latent cache sized for this stack: the page pool
+        of the layers that keep their context, the index pool, and
+        the window layers' ring, which holds a window and the
+        ``max_chunk`` tokens one :meth:`forward_chunk` call may write
+        behind it (1: decode steps and whole-prompt prefill only)."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
+        by_kind = {a.kind: a for a in self.attentions}
+        n, win = self._pool_layers, by_kind.get("window")
         return kvc.init_latent_cache(
-            self.n_block, int(max_slots), int(max_context),
-            self.attention.row_width, page_size=int(page_size),
-            dtype=dtype or jnp.float32)
+            n["context"], int(max_slots), int(max_context),
+            by_kind["context"].row_width if "context" in by_kind
+            else 1, page_size=int(page_size),
+            dtype=dtype or jnp.float32, index_layers=n["index"],
+            index_width=max(a.index_width for a in self.attentions),
+            window_layers=n["window"],
+            window_width=win.row_width if win else 0,
+            window_tokens=(win.window - 1 + max(int(max_chunk), 1))
+            if win else 0)
+
+    def _stacked(self, rows, key, kind=None):
+        """Every layer's ``rows[key]`` of one kind, stacked on a
+        leading layer axis in the order of its pool; None if none."""
+        picked = [r[key] for r, a in zip(rows, self.attentions)
+                  if key in r and (kind is None or a.kind == kind)]
+        return jnp.stack(picked) if picked else None
+
+    def _write_chunk(self, cache, rows, slots, starts, total, q_pos,
+                     valid):
+        """The cache with one chunk's rows of every layer written:
+        ``rows`` as :meth:`LatentAttention.chunk` returns them, for
+        positions ``q_pos`` (A, C) of slots ``slots``; ``total``
+        (A,) the slots' lengths after it."""
+        from analytics_zoo_tpu.ops import kv_cache as kvc
+        table = cache.page_table[slots]
+        ctx = self._stacked(rows, "row", "context")
+        if ctx is not None:
+            cache = cache._replace(pages=kvc.write_latent_prompt(
+                cache.pages, table, total, ctx, start=starts))
+        idx = self._stacked(rows, "index")
+        if idx is not None:
+            cache = cache._replace(index=kvc.write_latent_prompt(
+                cache.index, table, total, idx, start=starts))
+        win = self._stacked(rows, "row", "window")
+        if win is not None:
+            # one turn of the ring at most: of a prompt longer than
+            # that, the positions the next tokens can still see
+            turn = (cache.window_ring - 1) * cache.page_size
+            cache = cache._replace(window=kvc.write_window_rows(
+                cache, slots, q_pos, jnp.logical_and(
+                    valid, q_pos >= total[:, None] - turn), win))
+        return cache
 
     def prefill(self, params, cache, token_ids, prompt_lens,
                 slots=None):
@@ -378,19 +797,125 @@ class PatternDecoder(KerasLayer):
         prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
         slots = jnp.arange(a, dtype=jnp.int32) if slots is None \
             else jnp.asarray(slots, jnp.int32)
-        valid = jnp.arange(t, dtype=jnp.int32)[None, :] < \
-            prompt_lens[:, None]
+        q_pos = jnp.broadcast_to(
+            jnp.arange(t, dtype=jnp.int32)[None, :], (a, t))
+        valid = q_pos < prompt_lens[:, None]
         final, rows = self._prompt(params, token_ids, valid)
-        cache = cache._replace(
-            pages=kvc.write_latent_prompt(
-                cache.pages, cache.page_table[slots], prompt_lens,
-                rows),
-            seq_lens=kvc.prompt_seq_lens(cache.seq_lens, slots,
-                                         prompt_lens))
+        cache = self._write_chunk(
+            cache, rows, slots, None, prompt_lens, q_pos,
+            valid)._replace(seq_lens=kvc.prompt_seq_lens(
+                cache.seq_lens, slots, prompt_lens))
         with jax.named_scope("zoo:prefill/lm_head"):
             logits = self._logits(params, final[
                 jnp.arange(a), jnp.maximum(prompt_lens - 1, 0)])
         return cache, logits
+
+    def _ctx_ladder(self, cache, chunk: int) -> "tuple[int, ...]":
+        """The cached-context lengths a chunk program of ``chunk``
+        tokens branches between: nothing, then powers of two from
+        the chunk's own length (at least ``ctx_bucket_floor``) up to
+        the cache's ``max_context``."""
+        page, top = cache.page_size, cache.max_context
+        b = max(1 << max(0, (chunk - 1).bit_length()),
+                self.ctx_bucket_floor, page)
+        out = [0]
+        while b < top:
+            out.append(b)
+            b *= 2
+        return tuple(out) + (top,)
+
+    def forward_chunk(self, params, cache, token_ids, starts, n_new,
+                      all_logits: bool = False, slots=None,
+                      stats: bool = False):
+        """`TransformerLayer.forward_chunk`'s contract over the
+        latent cache: row a holds the next ``n_new[a]`` tokens of
+        slot ``slots[a]`` (every slot in order when None) from
+        position ``starts[a]`` on. Each layer attends from the chunk's
+        queries to the rows the cache holds before ``starts`` and to
+        the chunk's own, in flight; the pools are only read, and
+        every layer's rows are written once, after the last layer.
+        The work over the cached context is sized by the longest
+        ``starts`` of the rows that hold tokens, among
+        :meth:`_ctx_ladder`'s lengths. With ``stats`` also the sums
+        of ``step_counters``."""
+        from analytics_zoo_tpu.ops import kv_cache as kvc
+        a, c = token_ids.shape
+        starts = jnp.asarray(starts, jnp.int32)
+        n_new = jnp.asarray(n_new, jnp.int32)
+        slots = jnp.arange(a, dtype=jnp.int32) if slots is None \
+            else jnp.asarray(slots, jnp.int32)
+        total = starts + n_new
+        q_pos = starts[:, None] + jnp.arange(c, dtype=jnp.int32)[None]
+        valid = jnp.arange(c, dtype=jnp.int32)[None] < n_new[:, None]
+        table = cache.page_table[slots]
+        ladder = self._ctx_ladder(cache, c)
+        bucket = jnp.searchsorted(
+            jnp.asarray(ladder, jnp.int32),
+            jnp.max(jnp.where(n_new > 0, starts, 0)), side="left")
+        win = cache.window is not None
+        if win and c > (cache.window_ring - 1) * cache.page_size:
+            raise ValueError(
+                f"a chunk of {c} tokens does not fit the window "
+                f"pool's ring ({cache.window_ring} pages a slot): "
+                f"make the cache with max_chunk >= {c}")
+
+        def cached(att, at, t_ctx):
+            """What the cache holds before the chunk, for ``att``."""
+            if att.kind == "window":
+                first = jnp.maximum(starts - att.window + 1, 0) // \
+                    cache.page_size
+                rows, pos = kvc.window_view(cache, at[0], slots,
+                                            first, cache.window_ring)
+                return rows, pos, pos < starts[:, None], None
+            if not t_ctx:
+                return None
+            pos = jnp.broadcast_to(jnp.arange(
+                t_ctx, dtype=jnp.int32)[None], (a, t_ctx))
+            return (kvc.gather_layer(cache.pages, table, t_ctx, at[0]),
+                    pos, pos < starts[:, None],
+                    kvc.gather_layer(cache.index, table, t_ctx, at[1])
+                    if att.indexer else None)
+
+        x = jnp.take(params["tok_embed"],
+                     token_ids.astype(jnp.int32), axis=0)
+        rows, counts = [], []
+        for p, att, at, ffn in zip(params["layers"], self.attentions,
+                                   self._at, self.feed_forward):
+            with jax.named_scope("zoo:prefill/chunk_layer"):
+                y = rms_norm(x, p["norm1"], self.rms_eps)
+                run = lambda t, p=p, att=att, at=at, y=y: att.chunk(
+                    p["attn"], y, q_pos, valid, cached(att, at, t))
+                if att.kind == "window":
+                    o, r = run(0)
+                else:
+                    o, r = jax.lax.switch(
+                        bucket, [functools.partial(run, t)
+                                 for t in ladder])
+                x, cnt = self._ffn(ffn, p, x + o, valid, "prefill")
+                rows.append(r)
+                if cnt is not None:
+                    counts.append(cnt)
+        cache = self._write_chunk(
+            cache, rows, slots, starts, total, q_pos, valid)._replace(
+                seq_lens=cache.seq_lens.at[slots].set(jnp.where(
+                    n_new > 0, total, cache.seq_lens[slots])))
+        with jax.named_scope("zoo:prefill/lm_head"):
+            logits = self._logits(params, x if all_logits else x[
+                jnp.arange(a), jnp.clip(n_new - 1, 0, c - 1)])
+        if not stats:
+            return cache, logits
+        return cache, logits, self._counts(
+            counts, starts, n_new, cache)
+
+    def _counts(self, ffn_counts, first, n_new, cache):
+        out = [sum(ffn_counts)] if ffn_counts else []
+        if not self._plain:
+            out.append(self._attention_counts(
+                first, n_new,
+                cache.window_ring if cache.window is not None else 0,
+                cache.page_size))
+        return jnp.concatenate(out) if out else \
+            jnp.zeros((0,), jnp.int32)
 
     def decode_step(self, params, cache, token_ids, active=None,
                     stats: bool = False):
@@ -405,26 +930,29 @@ class PatternDecoder(KerasLayer):
         x = jnp.take(params["tok_embed"],
                      token_ids.astype(jnp.int32), axis=0)
         rows, counts = [], []
-        for i, (p, ffn) in enumerate(zip(params["layers"],
-                                         self.feed_forward)):
+        for p, att, at, ffn in zip(params["layers"], self.attentions,
+                                   self._at, self.feed_forward):
             with jax.named_scope("zoo:decode/layer"):
-                a, row = self.attention.decode(
+                a, *row = att.decode(
                     p["attn"], rms_norm(x, p["norm1"], self.rms_eps),
-                    pos, lambda r, i=i: kvc.latent_decode_view(
-                        cache, i, r, active=active), lens_after)
+                    pos, _DecodeView(cache, at, active), lens_after)
                 x, c = self._ffn(ffn, p, x + a, active, "decode")
-                rows.append(row)
+                rows.append(dict(zip(("row", "index"), row)))
                 if c is not None:
                     counts.append(c)
+        tally = self._counts(counts, cache.seq_lens,
+                             active.astype(jnp.int32), cache) \
+            if stats else None
         cache = kvc.append_latent_rows(
-            cache, jnp.stack(rows), active=active)._replace(
-                seq_lens=lens_after)
+            cache, self._stacked(rows, "row", "context"),
+            active=active, index_rows=self._stacked(rows, "index"),
+            window_rows=self._stacked(rows, "row", "window")
+        )._replace(seq_lens=lens_after)
         with jax.named_scope("zoo:decode/lm_head"):
             logits = self._logits(params, x)
         if not stats:
             return cache, logits
-        return cache, logits, sum(counts) if counts else \
-            jnp.zeros((0,), jnp.int32)
+        return cache, logits, tally
 
     # written against init_kv_cache / prefill / decode_step alone
     generate = TransformerLayer.generate
@@ -479,6 +1007,76 @@ def deepseek_v2_decoder(config: dict, *, n_layer: Optional[int] = None,
 
     return PatternDecoder(
         c["vocab_size"] if vocab is None else vocab, h, attention,
+        [ffn(i) for i in range(n_layer)],
+        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        initializer_range=c.get("initializer_range", 0.02), **kwargs)
+
+
+def dots3_note_decoder(config: dict, *, n_layer: Optional[int] = None,
+                       experts_held: "Optional[tuple]" = None,
+                       vocab: Optional[int] = None,
+                       **kwargs) -> PatternDecoder:
+    """A `PatternDecoder` from the keys of a ``config.json`` of
+    ``model_type`` ``dots3_note`` (the language model only): layer i
+    is ``layer_types[i]``. A ``full_attention`` layer is latent
+    attention whose queries keep the ``index_topk`` keys an indexer
+    of ``index_n_heads`` x ``index_head_dim`` scores highest; a
+    ``sliding_attention`` layer is latent attention at the ``swa_*``
+    widths over the last ``sliding_window_size`` positions with its
+    own rotary base; both with a head-wise output gate and rescaled
+    latents where the config says so. A dense SwiGLU in the first
+    ``first_k_dense_replace`` layers, then ungrouped sigmoid-scored
+    expert layers with a selection bias (``noaux_tc``) and
+    renormalised weights.
+
+    ``n_layer``, ``experts_held`` and ``vocab`` state one chip's
+    share, as for :func:`deepseek_v2_decoder`; ``kwargs`` go to
+    `PatternDecoder`."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
+        GatedMLP, GroupLimitedMoE)
+    c = config
+    if c.get("rope_scaling"):
+        raise ValueError("dots3_note: rope_scaling is null in the "
+                         "published config; none is implemented")
+    h, eps = c["hidden_size"], c.get("rms_norm_eps", 1e-6)
+    rescale = bool(c.get("apply_mla_qkv_lora_rescale", False))
+    gated = lambda key: c.get(key) == "headwise"
+    full = LatentAttention(
+        h, c["num_attention_heads"], c["q_lora_rank"],
+        c["kv_lora_rank"], c["qk_nope_head_dim"],
+        c["qk_rope_head_dim"], c["v_head_dim"],
+        YarnRope(c["qk_rope_head_dim"], theta=c["rope_theta"]),
+        rms_eps=eps, gate=gated("attention_gate_type"),
+        indexer=SparseIndexer(c["index_n_heads"], c["index_head_dim"],
+                              c["index_topk"]),
+        lora_rescale=rescale)
+    sliding = LatentAttention(
+        h, c["swa_num_attention_heads"], c["swa_q_lora_rank"],
+        c["swa_kv_lora_rank"], c["swa_qk_nope_head_dim"],
+        c["swa_qk_rope_head_dim"], c["swa_v_head_dim"],
+        YarnRope(c["swa_qk_rope_head_dim"], theta=c["swa_rope_theta"]),
+        rms_eps=eps, window=c["sliding_window_size"],
+        gate=gated("swa_attention_gate_type"), lora_rescale=rescale)
+    n_layer = c["num_hidden_layers"] if n_layer is None else n_layer
+    kinds = {"full_attention": full, "sliding_attention": sliding}
+
+    def ffn(i):
+        if i < c.get("first_k_dense_replace", 0) or \
+                i % c.get("moe_layer_freq", 1):
+            return GatedMLP(h, c["intermediate_size"])
+        return GroupLimitedMoE(
+            h, c["moe_intermediate_size"], c["n_routed_experts"],
+            c["num_experts_per_tok"], n_group=c.get("n_group", 1),
+            topk_group=c.get("topk_group", 1),
+            n_shared=c.get("n_shared_experts", 0),
+            routed_scaling=c.get("routed_scaling_factor", 1.0),
+            experts_held=experts_held,
+            scoring=c.get("scoring_func", "sigmoid"),
+            norm_topk=c.get("norm_topk_prob", True))
+
+    return PatternDecoder(
+        c["vocab_size"] if vocab is None else vocab, h,
+        [kinds[c["layer_types"][i]] for i in range(n_layer)],
         [ffn(i) for i in range(n_layer)],
         seq_len=c["max_position_embeddings"], rms_eps=eps,
         initializer_range=c.get("initializer_range", 0.02), **kwargs)

@@ -74,9 +74,18 @@ class GroupLimitedMoE:
     """Expert feed-forward part: ``n_experts`` routed SwiGLU experts
     of width ``width`` in ``n_group`` groups, ``top_k`` a token
     chosen among the ``topk_group`` best groups (a group's score is
-    its best expert's), weights the softmax scores themselves (not
-    renormalised) times ``routed_scaling``; ``n_shared`` shared
-    experts (one SwiGLU of ``n_shared * width``) always on.
+    its best expert's), weights the scores themselves times
+    ``routed_scaling``; ``n_shared`` shared experts (one SwiGLU of
+    ``n_shared * width``) always on.
+
+    ``scoring``: ``"softmax"`` over the router's outputs
+    (DeepSeek-V2), or ``"sigmoid"`` of each (DeepSeek-V3's
+    ``noaux_tc``): a ``router_bias`` a expert is then added to the
+    scores for CHOOSING and takes no part in the weights.
+    ``norm_topk``: the chosen experts' weights divided by their sum.
+    ``n_group=1, scoring="sigmoid", norm_topk=True`` is the ungrouped
+    router (every expert is a candidate for every token): the class
+    keeps its name, the group limit is then no limit.
 
     ``experts_held = (first, count)``: the experts whose weights
     this layer holds. It routes over all ``n_experts`` and adds, for
@@ -118,7 +127,12 @@ class GroupLimitedMoE:
                  top_k: int, n_group: int = 1, topk_group: int = 1,
                  n_shared: int = 0, routed_scaling: float = 1.0,
                  experts_held: "Optional[tuple]" = None,
-                 token_block: int = 2048):
+                 token_block: int = 2048, scoring: str = "softmax",
+                 norm_topk: bool = False):
+        if scoring not in ("softmax", "sigmoid"):
+            raise ValueError(f"scoring {scoring!r} not 'softmax' or "
+                             "'sigmoid'")
+        self.scoring, self.norm_topk = scoring, bool(norm_topk)
         self.hidden_size, self.width = int(hidden_size), int(width)
         self.n_experts, self.top_k = int(n_experts), int(top_k)
         self.n_group, self.topk_group = int(n_group), int(topk_group)
@@ -146,6 +160,9 @@ class GroupLimitedMoE:
                "experts_gate": n(k[1], (e, h, m)),
                "experts_up": n(k[2], (e, h, m)),
                "experts_down": n(k[3], (e, m, h))}
+        if self.scoring == "sigmoid":
+            out["router_bias"] = jnp.zeros((self.n_experts,),
+                                           jnp.float32)
         if self.n_shared:
             ms = self.n_shared * m
             out.update(shared_gate=n(k[4], (h, ms)),
@@ -158,18 +175,29 @@ class GroupLimitedMoE:
         tokens ``x`` (N, hidden), over all ``n_experts``. The router
         runs in float32 at the highest matmul precision: it is tiny,
         and a rounded score moves a token to another expert."""
-        scores = jax.nn.softmax(jnp.dot(
+        logits = jnp.dot(
             x.astype(jnp.float32), p["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST), axis=-1)
+            precision=jax.lax.Precision.HIGHEST)
+        if self.scoring == "sigmoid":
+            scores = jax.nn.sigmoid(logits)
+            choice = scores + p["router_bias"].astype(jnp.float32)
+        else:
+            scores = choice = jax.nn.softmax(logits, axis=-1)
         n, g = scores.shape[0], self.n_group
         if g > 1:
-            best = scores.reshape(n, g, -1).max(axis=-1)
+            best = choice.reshape(n, g, -1).max(axis=-1)
             _, keep = jax.lax.top_k(best, self.topk_group)
             kept = jnp.zeros((n, g), jnp.bool_).at[
                 jnp.arange(n)[:, None], keep].set(True)
-            scores = jnp.where(jnp.repeat(
-                kept, self.n_experts // g, axis=1), scores, 0.0)
-        weights, experts = jax.lax.top_k(scores, self.top_k)
+            choice = jnp.where(jnp.repeat(
+                kept, self.n_experts // g, axis=1), choice,
+                0.0 if self.scoring == "softmax" else -jnp.inf)
+        weights, experts = jax.lax.top_k(choice, self.top_k)
+        if self.scoring == "sigmoid":
+            weights = jnp.take_along_axis(scores, experts, axis=1)
+        if self.norm_topk:
+            weights = weights / (jnp.sum(weights, axis=-1,
+                                         keepdims=True) + 1e-20)
         return experts.astype(jnp.int32), \
             weights * self.routed_scaling
 

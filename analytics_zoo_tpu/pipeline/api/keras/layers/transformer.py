@@ -428,10 +428,15 @@ class TransformerLayer(KerasLayer):
     # Inference-only: no dropout, no sequence/pipeline parallelism.
 
     def init_kv_cache(self, max_slots: int, max_context: int,
-                      page_size: int = 16, dtype=None):
+                      page_size: int = 16, dtype=None,
+                      max_chunk: int = 1):
         """A fresh paged cache sized for this stack: one page pool
-        per block, identity page table (see `ops.kv_cache`)."""
+        per block, identity page table (see `ops.kv_cache`).
+        ``max_chunk`` (the most tokens one :meth:`forward_chunk` call
+        will write a slot) sizes nothing here: every position of the
+        context keeps its page."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
+        del max_chunk
         return kvc.init_cache(
             self.n_block, int(max_slots), int(max_context),
             self.n_head, self.hidden_size // self.n_head,
@@ -549,7 +554,7 @@ class TransformerLayer(KerasLayer):
         return cache, logits
 
     def forward_chunk(self, params, cache, token_ids, starts, n_new,
-                      all_logits: bool = False):
+                      all_logits: bool = False, slots=None):
         """Consume a bounded CHUNK of new tokens per slot against the
         cache — `decode_step` generalized from 1 to C tokens, with a
         per-slot write offset.
@@ -573,6 +578,10 @@ class TransformerLayer(KerasLayer):
         score every draft). ``seq_lens`` advances to
         ``starts + n_new`` for touched slots. Shape-static in (S, C);
         safe to AOT-compile once per chunk width.
+
+        ``slots`` (A,) int32: the rows are chunks of THESE slots (as
+        :meth:`prefill`'s rows are the prompts being admitted), not
+        of every slot in order; no other slot is read or written.
         """
         from analytics_zoo_tpu.ops import kv_cache as kvc
         from analytics_zoo_tpu.ops.attention import chunk_attention
@@ -586,7 +595,9 @@ class TransformerLayer(KerasLayer):
                      token_ids.astype(jnp.int32), axis=0) + \
             jnp.take(params["pos_embed"], pos_ids, axis=0)
         t_max = cache.max_context
-        table = cache.page_table
+        slots = jnp.arange(s, dtype=jnp.int32) if slots is None \
+            else jnp.asarray(slots, jnp.int32)
+        table = cache.page_table[slots]
 
         @jax.named_scope("zoo:prefill/chunk_layer")
         def block(x, xs):
@@ -621,7 +632,8 @@ class TransformerLayer(KerasLayer):
         cache = cache._replace(
             k_pages=k_pages, v_pages=v_pages,
             k_scales=k_scales, v_scales=v_scales,
-            seq_lens=jnp.where(n_new > 0, total, cache.seq_lens))
+            seq_lens=cache.seq_lens.at[slots].set(jnp.where(
+                n_new > 0, total, cache.seq_lens[slots])))
         embed_t = params["tok_embed"].astype(final.dtype).T
         if all_logits:
             return cache, final @ embed_t

@@ -1093,13 +1093,19 @@ class ContinuousBatcher:
                                 e.t_enq_wall, now - e.t_enq)
 
         def chunk_step():
-            # advance every mid-prefill slot by one chunk
-            # and emit first tokens for prompts whose final
-            # chunk just landed
+            # advance the mid-prefill slot whose turn it is by
+            # one chunk and emit the first token if that was
+            # its prompt's last
             with obs.span(
                     "decode/prefill_chunk",
-                    n=len(engine.prefilling_slots)):
+                    n=len(engine.prefilling_slots),
+                    tokens=0, context=0) as sp:
                 firsts = engine.prefill_step()
+                # the tokens the chunk wrote, and the cached
+                # context it wrote them behind
+                work = getattr(engine, "chunk_work", None)
+                if work:
+                    sp.annotate(tokens=work[2], context=work[1])
             t = time.monotonic()
             obs.counter(
                 "zoo_tpu_serving_gen_prefill_chunks_total",
@@ -1154,12 +1160,6 @@ class ContinuousBatcher:
                         e.t_enq_wall, now - e.t_enq,
                         slot=slot, prompt_len=len(e.ids))
                     self._active.append(e)
-                # kickoff: land the fresh prompts' first
-                # chunk in the iteration that admitted them
-                # rather than waiting a full loop pass —
-                # one bounded extra chunk call, mirroring
-                # how short prompts prefill inline at admit
-                chunk_step()
             if short_p:
                 reqs = [(e.ids, e.max_new, e.temperature)
                         for e in short_p]

@@ -30,12 +30,14 @@ compounding — docs/serving.md has the tuning guide):
 
 - **Chunked prefill** (``ZOO_TPU_PREFILL_CHUNK`` = chunk width C,
   0 = off): :meth:`admit_partial` assigns slots/pages WITHOUT running
-  the prompt; :meth:`prefill_step` then advances every prefilling
-  slot by at most C prompt tokens through ONE compiled chunk program
-  (`TransformerLayer.forward_chunk`), so the batcher can interleave
-  a bounded chunk with every decode iteration — a long prompt never
-  stalls resident sequences for more than one chunk's latency, and
-  TTFT p99 stops depending on the longest co-resident prompt.
+  the prompt; :meth:`prefill_step` then advances ONE prefilling
+  slot, the one whose turn it is, by at most C prompt tokens through
+  ONE compiled chunk program (the net's ``forward_chunk``) of ONE
+  row addressed to that slot, so the batcher interleaves exactly one
+  bounded chunk with every decode iteration however many prompts are
+  in flight — resident sequences never wait for more than one
+  chunk's latency between tokens, and a short prompt admitted behind
+  a long one takes turns with it instead of waiting it out.
 - **Int8 paged KV** (``ZOO_TPU_KV_DTYPE=int8|bf16|f32``): the cache
   pools quantize per row with per-page scale arrays
   (`ops/kv_cache.quantize_rows`) — ~2x resident sequences per chip
@@ -146,12 +148,13 @@ class GenerationEngine:
     / generate`` and ``seq_len`` (the most positions it takes) /
     ``vocab`` attributes (duck-typed — any net with those methods
     serves: `TransformerLayer` with its K/V pools, `PatternDecoder`
-    with its one latent pool). A net without ``forward_chunk`` serves
+    with its latent pools). A net without ``forward_chunk`` serves
     whole-prompt prefill only: ``prefill_chunk > 0`` and
     ``spec_k > 0`` are refused for it here. A net that names
-    ``step_counters`` has ``decode_step(..., stats=True)`` return
-    their per-step counts, which come back in the tokens' fetch and
-    go to its ``record_step_counts``. A ``drafter`` (same
+    ``step_counters`` has ``decode_step(..., stats=True)`` and
+    ``forward_chunk(..., stats=True)`` return their counts, which
+    come back in the tokens' fetch and go to its
+    ``record_step_counts``. A ``drafter`` (same
     surface, same vocab, typically far fewer blocks) plus
     ``spec_k > 0`` turns on speculative decoding.
     """
@@ -222,9 +225,14 @@ class GenerationEngine:
             raise ValueError(f"spec_k {self.spec_k} is absurd")
 
         from analytics_zoo_tpu.ops import kv_cache as kvc
+        # the most tokens one forward_chunk call writes a slot (a
+        # prompt chunk, or a speculative round's k + 1)
+        max_chunk = max(1, self.prefill_chunk,
+                        self.spec_k + 1 if self.spec_k else 0)
         cache = net.init_kv_cache(self.max_slots, int(max_context),
                                   page_size=self.page_size,
-                                  dtype=self.cache_dtype)
+                                  dtype=self.cache_dtype,
+                                  max_chunk=max_chunk)
         self.max_context = cache.max_context  # whole-page rounded
         self.pages_per_slot = cache.page_table.shape[1]
         # the engine owns page placement: blank the identity table and
@@ -255,7 +263,8 @@ class GenerationEngine:
                     f"drafter's position table ({drafter.seq_len})")
             dcache = drafter.init_kv_cache(
                 self.max_slots, int(max_context),
-                page_size=self.page_size, dtype=self.cache_dtype)
+                page_size=self.page_size, dtype=self.cache_dtype,
+                max_chunk=max_chunk)
             # own device copy of the table — the compiled programs
             # donate whole cache pytrees, and a buffer shared with
             # the target cache would be deleted out from under it
@@ -271,6 +280,9 @@ class GenerationEngine:
         # chunked-prefill scheduler state: slot -> [ids, next_offset]
         # (prompts admitted but not yet fully written to the cache)
         self._pending_prompts: "dict[int, list]" = {}
+        # (slot, start, tokens) of the latest `prefill_step`'s chunk
+        # (`decode/prefill_chunk` fields)
+        self.chunk_work: "tuple | None" = None
 
         # speculative acceptance accounting (bench + /health)
         self.spec_proposed = 0
@@ -332,25 +344,29 @@ class GenerationEngine:
             if not hasattr(a, "aval") else
             jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
-    def _chunk_fn(self, cache, params, ids, starts, n_new, temps,
-                  rng, step):
+    def _chunk_fn(self, cache, params, ids, starts, n_new, slots,
+                  temps, rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
-        cache, logits = self.net.forward_chunk(params, cache, ids,
-                                               starts, n_new)
+        counted = bool(getattr(self.net, "step_counters", ()))
+        cache, logits, *counts = self.net.forward_chunk(
+            params, cache, ids, starts, n_new, slots=slots,
+            **({"stats": True} if counted else {}))
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        return cache, nxt
+        return cache, jax.numpy.concatenate([nxt] + counts) \
+            if counts else nxt
 
     def _draft_prefill_fn(self, dcache, dparams, ids, plens, slots):
         dcache, _ = self.drafter.prefill(dparams, dcache, ids, plens,
                                          slots)
         return dcache
 
-    def _draft_chunk_fn(self, dcache, dparams, ids, starts, n_new):
-        dcache, _ = self.drafter.forward_chunk(dparams, dcache, ids,
-                                               starts, n_new)
+    def _draft_chunk_fn(self, dcache, dparams, ids, starts, n_new,
+                        slots):
+        dcache, _ = self.drafter.forward_chunk(
+            dparams, dcache, ids, starts, n_new, slots=slots)
         return dcache
 
     def _draft_fn(self, dcache, dparams, t0, active, temps, rng,
@@ -486,15 +502,17 @@ class GenerationEngine:
         return fn
 
     def _get_chunk(self):
+        """The chunk program: one row of ``prefill_chunk`` tokens,
+        addressed by slot."""
         if self._compiled_chunk is None:
-            s, c = self.max_slots, self.prefill_chunk
             structs = (
                 self._abstract(self.cache),
                 self._abstract(self.params),
-                self._shape(s, c),
-                self._shape(s),
-                self._shape(s),
-                self._shape(s, dtype=np.float32),
+                self._shape(1, self.prefill_chunk),
+                self._shape(1),
+                self._shape(1),
+                self._shape(1),
+                self._shape(1, dtype=np.float32),
                 self._abstract(self._rng),
                 self._shape(),
             )
@@ -519,13 +537,13 @@ class GenerationEngine:
 
     def _get_draft_chunk(self):
         if self._compiled_draft_chunk is None:
-            s, c = self.max_slots, self.prefill_chunk
             structs = (
                 self._abstract(self._draft_cache),
                 self._abstract(self.drafter_params),
-                self._shape(s, c),
-                self._shape(s),
-                self._shape(s),
+                self._shape(1, self.prefill_chunk),
+                self._shape(1),
+                self._shape(1),
+                self._shape(1),
             )
             self._compiled_draft_chunk = self._compile(
                 self._draft_chunk_fn, structs, "draft_chunk")
@@ -655,7 +673,7 @@ class GenerationEngine:
         if self.role != "prefill":
             self._get_step()
         if self.role != "decode":
-            for tp in self.prompt_buckets:
+            for tp in self._warm_buckets():
                 self._get_prefill(tp)
             if self.prefill_chunk > 0:
                 self._get_chunk()
@@ -672,9 +690,21 @@ class GenerationEngine:
             # whole-prompt path even when chunking is on (the
             # batcher routes them directly), so the drafter's
             # prefill buckets are steady-state programs regardless
-            for tp in self.prompt_buckets:
+            for tp in self._warm_buckets():
                 self._get_draft_prefill(tp)
         return self._warmed() - n0
+
+    def _warm_buckets(self) -> "tuple[int, ...]":
+        """The prompt buckets steady-state serving reaches: all of
+        them, or under chunked prefill those of prompts that fit one
+        chunk (the batcher sends every longer prompt through
+        :meth:`admit_partial`; a caller of :meth:`admit` that does
+        not compiles a longer bucket at its first use)."""
+        if self.prefill_chunk <= 0:
+            return self.prompt_buckets
+        top = self.prompt_bucket(min(self.prefill_chunk,
+                                     self.prompt_buckets[-1]))
+        return tuple(b for b in self.prompt_buckets if b <= top)
 
     # -- admission / stepping / retirement ----------------------------------
     def pages_for(self, prompt_len: int, max_new: int) -> int:
@@ -820,47 +850,48 @@ class GenerationEngine:
         self._pending_prompts.pop(slot, None)
 
     def prefill_step(self) -> "list[tuple]":
-        """Advance every prefilling slot by ONE chunk (at most
-        ``prefill_chunk`` prompt tokens) through the compiled chunk
-        program. Slots whose final chunk just landed sample their
-        first token: returns ``[(slot, first_token), ...]`` for
-        exactly those. No-op ([]) when nothing is prefilling."""
+        """Advance ONE prefilling slot by one chunk (at most
+        ``prefill_chunk`` prompt tokens): the compiled one-row chunk
+        program, addressed to the slot, so a chunk computes the
+        tokens it writes and no idle slot's padding. The slots take
+        turns in the order they were admitted, a slot with prompt
+        left going to the back, so a call costs one chunk program
+        however many prompts are mid-prefill. Returns ``[(slot,
+        first_token)]`` if the chunk was its prompt's last (the slot
+        then decodes), else []. Leaves ``(slot, start, tokens)`` of
+        the chunk in :attr:`chunk_work` (None when nothing is
+        prefilling: a no-op)."""
+        self.chunk_work = None
         if not self._pending_prompts:
             return []
         c = self.prefill_chunk
-        ids_arr = np.zeros((self.max_slots, c), np.int32)
-        starts = np.zeros((self.max_slots,), np.int32)
-        n_new = np.zeros((self.max_slots,), np.int32)
-        finishing = []
-        for slot, st in self._pending_prompts.items():
-            ids, off = st
-            n = min(c, len(ids) - off)
-            ids_arr[slot, :n] = ids[off:off + n]
-            starts[slot] = off
-            n_new[slot] = n
-            if off + n >= len(ids):
-                finishing.append(slot)
-        fn = self._get_chunk()
-        self.cache, toks = fn(self.cache, self.params, ids_arr,
-                              starts, n_new, self._temps, self._rng,
-                              np.int32(self._step_id))
+        slot = next(iter(self._pending_prompts))
+        ids, off = st = self._pending_prompts.pop(slot)
+        n = min(c, len(ids) - off)
+        row = np.zeros((1, c), np.int32)
+        row[0, :n] = ids[off:off + n]
+        starts = np.full((1,), off, np.int32)
+        n_new = np.full((1,), n, np.int32)
+        at = np.full((1,), slot, np.int32)
+        self.cache, tok = self._get_chunk()(
+            self.cache, self.params, row, starts, n_new, at,
+            self._temps[slot:slot + 1], self._rng,
+            np.int32(self._step_id))
         self._step_id += 1
         if self._draft_cache is not None:
-            dfn = self._get_draft_chunk()
-            self._draft_cache = dfn(self._draft_cache,
-                                    self.drafter_params, ids_arr,
-                                    starts, n_new)
-        toks = np.asarray(toks)
-        out = []
-        for slot in list(self._pending_prompts):
-            if slot in finishing:
-                del self._pending_prompts[slot]
-            else:
-                self._pending_prompts[slot][1] += int(n_new[slot])
-        for slot in finishing:
-            self._last_tok[slot] = toks[slot]
-            out.append((slot, int(toks[slot])))
-        return out
+            self._draft_cache = self._get_draft_chunk()(
+                self._draft_cache, self.drafter_params, row, starts,
+                n_new, at)
+        self.chunk_work = (slot, off, n)
+        tok = np.asarray(tok)
+        if len(tok) > 1:
+            self.net.record_step_counts(tok[1:])
+        if off + n < len(ids):
+            st[1] = off + n
+            self._pending_prompts[slot] = st    # to the back
+            return []
+        self._last_tok[slot] = tok[0]
+        return [(slot, int(tok[0]))]
 
     def step(self, active: np.ndarray) -> np.ndarray:
         """One decode iteration over the WHOLE slot array: append each

@@ -123,9 +123,15 @@ def test_time_chain_counts_execution_not_just_dispatch():
                     jnp.float32)
     compiled = jax.jit(run).lower(p).compile()
     jax.block_until_ready(compiled(p))  # warm
-    t0 = time.perf_counter()
-    jax.block_until_ready(compiled(p))
-    wall = time.perf_counter() - t0
+    walls = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(compiled(p))
+        walls.append(time.perf_counter() - t0)
+    # the least of three: on a host shared with five other test
+    # workers one slow blocked run made `wall` three times the
+    # program's time and failed a sound `time_chain` (PR 34's run)
+    wall = min(walls)
     dt, loss = time_chain(compiled, (p,), reps=2)
     assert np.isfinite(loss)
     assert dt > 0.3 * wall, \

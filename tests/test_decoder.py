@@ -315,12 +315,19 @@ def _engine(cfg, **kw):
                             **kw)
 
 
+class _NoChunk:
+    """A net that truly lacks ``forward_chunk`` (every decoder of
+    the package has it now)."""
+    seq_len, vocab = 32, 100
+
+
 def test_engine_refuses_what_the_decoder_lacks():
     cfg = _share(0, 8)
     with pytest.raises(ValueError, match="forward_chunk"):
-        _engine(cfg, prefill_chunk=8)
+        GenerationEngine(_NoChunk(), {}, prefill_chunk=8)
     with pytest.raises(ValueError, match="forward_chunk"):
-        _engine(cfg, spec_k=2, drafter=_net(cfg), drafter_params={})
+        GenerationEngine(_NoChunk(), {}, spec_k=2,
+                         drafter=_net(cfg), drafter_params={})
     with pytest.raises(TypeError, match="latent cache"):
         _engine(cfg, role="prefill")
     eng = _engine(cfg)

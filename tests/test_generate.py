@@ -439,6 +439,31 @@ def test_resolve_kv_dtype(monkeypatch):
         resolve_kv_dtype()
 
 
+def test_prefill_step_runs_one_chunk_program_in_turn():
+    """However many prompts are mid-prefill, a `prefill_step` is ONE
+    chunk program: the slots take turns in the order they were
+    admitted, so a decode iteration waits for one chunk and a short
+    prompt behind a long one is not kept waiting for all of it."""
+    eng = _engine(prefill_chunk=4)
+    rs = np.random.RandomState(9)
+    long_p = rs.randint(1, VOCAB, size=11).tolist()   # 3 chunks
+    short_p = rs.randint(1, VOCAB, size=6).tolist()   # 2 chunks
+    want = {tuple(p): int(eng.generate(p, max_new_tokens=1)[0][0])
+            for p in (long_p, short_p)}
+    s0, s1 = eng.admit_partial([(long_p, 2, 0.0), (short_p, 2, 0.0)])
+    work, firsts = [], []
+    while eng.prefilling_slots:
+        firsts += eng.prefill_step()
+        work.append(eng.chunk_work)
+    assert work == [(s0, 0, 4), (s1, 0, 4), (s0, 4, 4), (s1, 4, 2),
+                    (s0, 8, 3)]
+    assert firsts == [(s1, want[tuple(short_p)]),
+                      (s0, want[tuple(long_p)])]
+    assert eng.prefill_step() == [] and eng.chunk_work is None
+    eng.release(s0)
+    eng.release(s1)
+
+
 def test_chunked_prefill_engine_exact_and_cancel_reclaims():
     """Chunk-at-a-time prompt writes produce the identical token
     stream, and cancelling one slot mid-prefill neither perturbs its

@@ -445,3 +445,102 @@ def test_deepseek_v2_prefill_ladder_never_looks_like_a_decode_step(
     named = set(re.findall(r"%ragged-dot-none\.\d+ = \w+\[(\d+),",
                            compiled.as_text()))
     assert named == {str(ladder[0] * per_token)}, named
+
+
+def _config(name):
+    import json
+    import os
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "configs",
+        name + ".json")
+    with open(path, encoding="utf-8") as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("program", ["step", "chunk"])
+def test_dots3_note_programs_fit_the_chip_and_leave_the_pools(
+        one_chip, program):
+    """At the published widths and the configuration's depth, slots,
+    context and chunk: 10.02 GB of weights, the program's arguments,
+    results and temporaries inside 15.75 GB; the three pools (context
+    rows of 576 padded to 640, index keys of 128, window rows of 1088
+    padded to 1152 in a ring of 161 pages a slot) row-major, aliased
+    to their inputs, and nothing of a pool's shape copied, transposed
+    or sliced. The step's view of every slot's whole index context is
+    the size of a layer's index slab by construction (8 slots x 2048
+    pages), so only the whole pool is looked for there."""
+    import re
+
+    from analytics_zoo_tpu.pipeline.api.keras.layers import \
+        dots3_note_decoder
+    cfg = _config("dots3-note-prev-ep8")
+    eng = cfg["engine"]
+    first, end = cfg["held"]["experts"]
+    net = dots3_note_decoder(
+        dict(cfg, n_routed_experts=cfg["published"]["n_routed_experts"]),
+        n_layer=cfg["n_layer"], experts_held=(first, end - first))
+
+    def on_chip(tree, dtype=None):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, dtype if dtype and a.dtype == F32 else a.dtype,
+                sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: net.build(jax.random.key(0), (16,))), BF)
+    s = eng["max_slots"]
+    cache = on_chip(jax.eval_shape(lambda: net.init_kv_cache(
+        s, eng["max_context"], page_size=eng["page_size"], dtype=BF,
+        max_chunk=eng["prefill_chunk"])))
+    i32 = lambda *d: jax.ShapeDtypeStruct(d, jnp.int32,
+                                          sharding=one_chip)
+    if program == "step":
+        def fn(cache, params, tok, active):
+            return net.decode_step(params, cache, tok, active=active,
+                                   stats=True)
+        args = [i32(s), jax.ShapeDtypeStruct((s,), jnp.bool_,
+                                             sharding=one_chip)]
+    else:
+        def fn(cache, params, ids, starts, n_new, slots):
+            return net.forward_chunk(params, cache, ids, starts, n_new,
+                                     slots=slots, stats=True)
+        args = [i32(1, eng["prefill_chunk"]), i32(1), i32(1), i32(1)]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        cache, params, *args).compile()
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 10.02e9) < 0.005e9, weights
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
+        mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < _V5E_BYTES, held
+    assert cache.pages.shape == (3, 16384, 16, 640)
+    assert cache.index.shape == (3, 16384, 16, 128)
+    assert cache.window.shape == (3, 8 * 161, 16, 1152)
+    hlo = compiled.as_text()
+    entry = hlo.split("entry_computation_layout={(", 1)[1]
+    assert entry.startswith(
+        "bf16[3,16384,16,640]{3,2,1,0:"), entry[:80]
+    shapes = []
+    for pool, slab in ((cache.pages, True), (cache.index, False),
+                       (cache.window, True)):
+        shapes.append(",".join(map(str, pool.shape)))
+        if slab:
+            shapes.append(",".join(map(str, pool.shape[1:])))
+        assert "bf16[%s]{3,2,1,0:" % shapes[-1 - slab] in entry
+    made = re.findall(
+        r"= bf16\[(?:1,)?(?:%s)\]\S* ([\w\-]+)\(" % "|".join(shapes),
+        hlo)
+    moved = [op for op in made if op in (
+        "copy", "transpose", "dynamic-slice", "dynamic-update-slice")]
+    assert not moved, moved
+    # donated and written in place: all five leaves of the cache
+    for leaf in range(5):
+        assert "{%d}: (%d, {}, may-alias)" % (leaf, leaf) in hlo
+    # the routed experts are the compiler's grouped product, and its
+    # rows tell the phases apart (`benchmark/reduce/moe.py`): 8 slots
+    # x 8 experts a token in a step, 2048 x 8 in a chunk
+    rows = set(re.findall(r"%ragged-dot[\w\-.]* = \w+\[(\d+),", hlo))
+    want = s * cfg["num_experts_per_tok"] if program == "step" \
+        else eng["prefill_chunk"] * cfg["num_experts_per_tok"]
+    assert rows == {str(want)}, rows
