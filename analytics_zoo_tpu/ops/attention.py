@@ -143,6 +143,69 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return jnp.einsum("sht,sthd->shd", probs, v)
 
 
+def paged_decode_ok(cache, dtype, impl: Optional[str] = None) -> bool:
+    """Whether a decode step over ``cache`` with activations of
+    ``dtype`` takes its context from the pages themselves
+    (:func:`paged_decode_attention`) rather than from a dense
+    gathered view (:func:`decode_attention`). Decided by what the
+    step can observe, nothing to set: the backend runs the Pallas
+    kernels (`flash_backend_ok`), the selector does not force XLA,
+    and the pools are floating point, hold the activations' own dtype
+    (the products then round nothing the dense path keeps) and have a
+    page that is a whole number of that dtype's sublane tiles
+    (`ops.flash_attention.paged_decode_supported`). Int8 pools, with
+    their scale pools, take the dense view."""
+    if resolve_attention_impl(impl) == "xla" or cache.quantized or \
+            jnp.dtype(dtype) != cache.pool_dtype or \
+            not flash_backend_ok():
+        return False
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    return fa.paged_decode_supported(cache.page_size, cache.pool_dtype)
+
+
+@jax.named_scope("zoo:decode/attention")
+def paged_decode_attention(q: jnp.ndarray, k_row: jnp.ndarray,
+                           v_row: jnp.ndarray, cache, layer,
+                           writes: jnp.ndarray,
+                           scale: Optional[float] = None
+                           ) -> jnp.ndarray:
+    """:func:`decode_attention` with the cached context read page by
+    page where it lies (`ops.flash_attention.paged_decode_partial`):
+    one algorithm, operands pages and not a view.
+
+    ``q`` (S, H, D); ``k_row``/``v_row`` (S, W): the step's own token
+    as the pool will store it (`ops.kv_cache.decode_rows`), not in
+    the pool yet; ``cache`` a float `PagedKVCache` whose stacked pools
+    are only read; ``layer`` the scalar layer index; ``writes`` (S,)
+    bool, the slots whose row lands this step
+    (`ops.kv_cache._decode_writes`). Slot s attends to its
+    ``cache.seq_lens[s]`` cached positions and, where it writes, to
+    its own row: the kernel's partials over the pages are merged with
+    the row's score and value by the arithmetic ring attention merges
+    block partials with. A slot with nothing cached that does not
+    write returns zeros. Returns (S, H, D) in ``q``'s dtype."""
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    from analytics_zoo_tpu.ops import kv_cache as kvc
+    _, h, d = q.shape
+    scale = float(scale if scale is not None else 1.0 / (d ** 0.5))
+    q_row, _ = kvc._pool_rows(cache.k_pages, q)
+    o, m, l = fa.paged_decode_partial(
+        q_row, cache.k_pages, cache.v_pages, cache.page_table,
+        cache.seq_lens, layer, heads=h, head_dim=d, scale=scale)
+    k_new = kvc.split_heads(k_row, h, d).astype(jnp.float32)
+    v_new = kvc.split_heads(v_row, h, d).astype(jnp.float32)
+    s_new = jnp.sum(q.astype(jnp.float32) * k_new, axis=-1) * scale
+    # a slot that does not write has no such key: weight exp(-inf) = 0
+    # (m is finite, -1e30 at least, so no inf - inf)
+    s_new = jnp.where(writes[:, None], s_new, -jnp.inf)
+    m_new = jnp.maximum(m, s_new)
+    a_old = jnp.exp(m - m_new)
+    a_new = jnp.exp(s_new - m_new)
+    l = l * a_old + a_new
+    o = o * a_old[..., None] + v_new * a_new[..., None]
+    return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
 def latent_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                             ctx: jnp.ndarray, valid: jnp.ndarray,
                             scale: float) -> jnp.ndarray:

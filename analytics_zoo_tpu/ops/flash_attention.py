@@ -20,6 +20,11 @@ owns the sharded longer-T regime).
 On non-TPU backends the same kernel runs under `interpret=True`
 (numerics identical, speed irrelevant) so the CPU test mesh exercises
 the exact kernel code path.
+
+Beside the family, `paged_decode_partial` (`zoo_paged_decode`):
+single-query decode attention straight from the live pages of a paged
+K/V pool, sharing the family's softmax recursion
+(`_softmax_accumulate`).
 """
 
 from __future__ import annotations
@@ -55,6 +60,27 @@ def _apply_causal_mask(s, qi, ki, off, block_q, block_k,
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1)
     return jnp.where(q_pos + off >= k_pos, s, fill)
+
+
+def _softmax_accumulate(s, v, acc_ref, m_ref, l_ref):
+    """One step of the flash recursion: fold the masked, scaled f32
+    scores ``s`` (rows, keys) of a key block and its values ``v``
+    (keys, D) into the running statistics. The single copy, shared by
+    `_attn_body` and the paged decode kernel (rows = heads, D = the
+    pool's row there)."""
+    m_prev = m_ref[:, :1]                    # (rows, 1)
+    l_prev = l_ref[:, :1]
+    m_cur = jnp.max(s, axis=-1, keepdims=True)
+    m_new = jnp.maximum(m_prev, m_cur)
+    alpha = jnp.exp(m_prev - m_new)          # rescale old accumulator
+    p = jnp.exp(s - m_new)                   # (rows, keys) f32
+    l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    acc_ref[:] = acc_ref[:] * alpha + pv
+    m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
 def _attn_body(off, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
@@ -105,19 +131,7 @@ def _attn_body(off, q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, *,
         if kmask_ref is not None:
             s = jnp.where(kmask_ref[0][:1, :] > 0, s, _NEG_INF)
 
-        m_prev = m_ref[:, :1]                # (block_q, 1)
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)      # rescale old accumulator
-        p = jnp.exp(s - m_new)               # (block_q, block_k) f32
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_ref[:] = acc_ref[:] * alpha + pv
-        m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
+        _softmax_accumulate(s, v, acc_ref, m_ref, l_ref)
 
 
 def _fwd_finalize(o_ref, acc_ref, l_ref):
@@ -676,6 +690,255 @@ def flash_decode_attention(q: jnp.ndarray, k: jnp.ndarray,
         interpret=interpret,
     )(qt, kt, vt, _kmask8(key_mask, t))
     return out[:, :, 0]
+
+
+# -- paged single-query attention -------------------------------------------
+# Decode attention straight from the page pools: no dense (S, T, W)
+# view of a layer's cache is ever formed. One grid step a slot; inside,
+# a loop over blocks of `_PAGED_BLOCK` tokens whose trip count is the
+# slot's own length, each block's live pages brought from HBM by one
+# async copy a page into double-buffered VMEM (the DMA pattern of jax's
+# `pallas/ops/tpu/paged_attention`; the pool keeps this repo's
+# `(L, P, page, W)` row-major layout, so a page is one contiguous
+# copy). The last block of a slot prefetches the first block of the
+# next slot that holds tokens, so only a call's first block waits for
+# its pages with nothing to overlap.
+
+# tokens a compute block: one lane tile of scores
+_PAGED_BLOCK = 128
+
+
+def paged_decode_supported(page_size: int, dtype) -> bool:
+    """Whether `paged_decode_partial` takes pools of this geometry: a
+    floating-point pool whose page is a whole number of the dtype's
+    sublane tiles (16 rows for bfloat16, 8 for float32), so that a
+    page lands tile-aligned in VMEM and a block of pages is one
+    (block, W) matrix with no relayout, and whose pages fill a
+    128-token block exactly."""
+    dtype = jnp.dtype(dtype)
+    if not jnp.issubdtype(dtype, jnp.floating) or dtype.itemsize > 4:
+        return False
+    return page_size % (32 // dtype.itemsize) == 0 and \
+        _PAGED_BLOCK % page_size == 0
+
+
+def _paged_decode_kernel(table_ref, lens_ref, layer_ref,
+                         q_ref, k_hbm, v_hbm,
+                         o_ref, m_out_ref, l_out_ref,
+                         k_buf, v_buf, sems, q_bd, acc_ref, m_ref,
+                         l_ref, state, *, scale: float, head_dim: int,
+                         pages_per_slot: int):
+    """One slot's attention over its cached pages, heads side by side.
+
+    Scalar prefetch (SMEM): the page table flattened
+    (S * pages_per_slot,), ``seq_lens`` (S,), the layer (1,). ``q_ref``
+    (1, 1, W): the slot's query row as the pool lays a row out;
+    ``k_hbm``/``v_hbm`` (L, P, page, W) stay in HBM. The query becomes
+    a block-diagonal (HP, W) matrix (row h holds head h's ``head_dim``
+    values in its own columns, zeros elsewhere), so ``Q_bd @ K_blk^T``
+    is every head's scores against a block of whole rows and
+    ``P @ V_blk`` an (HP, W) partial whose diagonal blocks are the
+    heads' outputs: `_softmax_accumulate` with D = W, for any
+    (heads, head_dim). Writes the unnormalised output row (1, 1, W)
+    f32 and the lanes-replicated statistics (1, HP, 128); a slot with
+    no cached token writes o = 0, m = -1e30, l = 0.
+
+    Scratch: ``k_buf``/``v_buf`` (2, pages a block, page, W), ``sems``
+    DMA (2, 2) = (pool, buffer), ``q_bd`` (HP, W), the accumulators,
+    and ``state`` SMEM (2,) = (buffer the next block is in, whether
+    the previous slot already started its copies), carried from one
+    grid step to the next."""
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    n_pool = k_hbm.shape[1]
+    _, ppb, page, w = k_buf.shape
+    bk = ppb * page
+    hp = q_bd.shape[0]
+    layer = layer_ref[0]
+    n_tok = lens_ref[s]
+    n_blk = jax.lax.div(n_tok + (bk - 1), bk)
+
+    def copies(slot, blk, buf):
+        # block `blk` of `slot`: (is the page live, its K copy, its V
+        # copy) a page; pages past the slot's length are never fetched
+        tok = lens_ref[slot]
+        out = []
+        for i in range(ppb):
+            j = blk * ppb + i
+            pid = table_ref[slot * pages_per_slot +
+                            jnp.minimum(j, pages_per_slot - 1)]
+            pid = jnp.clip(pid, 0, n_pool - 1)
+            out.append((
+                j * page < tok,
+                pltpu.make_async_copy(k_hbm.at[layer, pid],
+                                      k_buf.at[buf, i], sems.at[0, buf]),
+                pltpu.make_async_copy(v_hbm.at[layer, pid],
+                                      v_buf.at[buf, i], sems.at[1, buf])))
+        return out
+
+    def start(slot, blk, buf):
+        for live, kc, vc in copies(slot, blk, buf):
+            @pl.when(live)
+            def _go():
+                kc.start()
+                vc.start()
+
+    def wait(slot, blk, buf):
+        for live, kc, vc in copies(slot, blk, buf):
+            @pl.when(live)
+            def _arrived():
+                kc.wait()
+                vc.wait()
+
+    @pl.when(s == 0)
+    def _first():
+        state[0] = 0
+        state[1] = 0
+        # a value page that is never fetched multiplies probabilities
+        # that are exactly 0: it must hold numbers, whatever they are
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    rows = jax.lax.broadcasted_iota(jnp.int32, (hp, w), 0) * head_dim
+    cols = jax.lax.broadcasted_iota(jnp.int32, (hp, w), 1)
+    diag = jnp.logical_and(cols >= rows, cols < rows + head_dim)
+
+    @pl.when(n_blk > 0)
+    def _attend():
+        @pl.when(state[1] == 0)
+        def _cold():
+            start(s, 0, state[0])
+
+        q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (hp, w))
+        q_bd[:] = jnp.where(diag, q, 0.0).astype(q_bd.dtype)
+
+        def block(b, cur):
+            nxt = 1 - cur
+
+            @pl.when(b + 1 < n_blk)
+            def _next_block():
+                start(s, b + 1, nxt)
+
+            @pl.when(b + 1 == n_blk)
+            def _next_slot():
+                # the first later slot that holds tokens, if any
+                s2 = jax.lax.fori_loop(
+                    s + 1, n_slots,
+                    lambda i, c: jnp.where(
+                        jnp.logical_and(c == n_slots, lens_ref[i] > 0),
+                        i, c),
+                    n_slots)
+
+                @pl.when(s2 < n_slots)
+                def _prefetch():
+                    start(s2, 0, nxt)
+                state[1] = (s2 < n_slots).astype(jnp.int32)
+
+            wait(s, b, cur)
+            k = k_buf[cur].reshape(bk, w)
+            v = v_buf[cur].reshape(bk, w)
+            sc = jax.lax.dot_general(
+                q_bd[:], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            pos = b * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, bk), 1)
+            sc = jnp.where(pos < n_tok, sc, _NEG_INF)
+            _softmax_accumulate(sc, v, acc_ref, m_ref, l_ref)
+            return nxt
+
+        state[0] = jax.lax.fori_loop(0, n_blk, block, state[0])
+
+    o_ref[0] = jnp.sum(jnp.where(diag, acc_ref[:], 0.0), axis=0,
+                       keepdims=True)
+    m_out_ref[0] = m_ref[:]
+    l_out_ref[0] = l_ref[:]
+
+
+def paged_decode_partial(q_rows: jnp.ndarray, k_pages: jnp.ndarray,
+                         v_pages: jnp.ndarray, page_table: jnp.ndarray,
+                         seq_lens: jnp.ndarray, layer, *, heads: int,
+                         head_dim: int, scale: float,
+                         interpret: Optional[bool] = None):
+    """Single-query attention of every slot over the pages it holds,
+    read where they lie.
+
+    ``q_rows`` (S, W): each slot's query, heads side by side and
+    zero-padded as a pool row is (`ops.kv_cache`); ``k_pages`` /
+    ``v_pages`` (L, P, page_size, W), the stacked pools, never copied
+    (`memory_space=pl.ANY`: inside a layer scan they stay
+    loop-invariant); ``page_table`` (S, pages_per_slot), ``seq_lens``
+    (S,) and the scalar ``layer`` (traced or not) go to SMEM. Slot s
+    attends to positions ``[0, seq_lens[s])``: pages past them are
+    never fetched, stale rows inside the last live page are masked.
+
+    Returns the partials `flash_block_partial` returns, for a caller
+    that has more keys to merge (the step's own token, which is not
+    in the pool yet): ``o`` (S, heads, head_dim) f32 unnormalised,
+    ``m`` and ``l`` (S, heads) f32 with softmax base ``m``. A slot
+    with ``seq_lens == 0`` gives o = 0, m = -1e30, l = 0. Scores and
+    softmax in f32, probabilities cast to the pool's dtype before the
+    value product, as `ops.attention.decode_attention` does. Inference
+    only: no VJP."""
+    global invocations
+    invocations += 1
+    if interpret is None:
+        interpret = not on_tpu()
+    s, w = q_rows.shape
+    n_layers, _, page, wp = k_pages.shape
+    if wp != w or v_pages.shape != k_pages.shape or \
+            not paged_decode_supported(page, k_pages.dtype):
+        raise ValueError(
+            f"paged_decode_partial: pools {k_pages.shape} / "
+            f"{v_pages.shape} {k_pages.dtype} against query rows "
+            f"{q_rows.shape} (see paged_decode_supported)")
+    dtype = k_pages.dtype
+    ppb = _PAGED_BLOCK // page
+    # heads on sublanes: a whole number of the dtype's tiles
+    tile = 32 // jnp.dtype(dtype).itemsize
+    hp = -(-heads // tile) * tile
+    row = pl.BlockSpec((1, 1, w), lambda i, *_: (i, 0, 0))
+    stat = pl.BlockSpec((1, hp, 128), lambda i, *_: (i, 0, 0))
+    o, m, l = pl.pallas_call(
+        functools.partial(
+            _paged_decode_kernel, scale=float(scale),
+            head_dim=int(head_dim),
+            pages_per_slot=page_table.shape[1]),
+        name="zoo_paged_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s,),
+            in_specs=[row, pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[row, stat, stat],
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, page, w), dtype),
+                pltpu.VMEM((2, ppb, page, w), dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.VMEM((hp, w), dtype),
+                pltpu.VMEM((hp, w), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((s, 1, w), jnp.float32),
+            jax.ShapeDtypeStruct((s, hp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((s, hp, 128), jnp.float32),
+        ],
+        # the buffer state and the prefetched block pass from one slot
+        # to the next: the grid runs in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table.reshape(-1).astype(jnp.int32),
+      seq_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q_rows.astype(dtype)[:, None, :], k_pages, v_pages)
+    o = o[:, 0, :heads * head_dim].reshape(s, heads, head_dim)
+    return o, m[:, :heads, 0], l[:, :heads, 0]
 
 
 def as_key_mask(mask, b: int, tk: int):

@@ -27,13 +27,18 @@ handed to attention are split back to `(heads, head_dim)`.
 Everything device-side here is shape-static and jit-safe:
 
 - :func:`init_cache` — allocate the pool (zeros) + identity tables;
-- :func:`decode_view` — one layer's dense (S, T, H, D) context for a
-  decode step: ONE gather straight out of the stacked pool through
-  (layer, page table), with the new token's row laid over position
-  ``seq_lens[s]`` of every writing slot. The pools are only read, so
-  they stay loop-invariant in the layer scan (scanning them as
-  ``xs``/``ys`` made XLA copy every layer's slab, and the whole
-  stack, in every step);
+- :func:`decode_rows` — a decode step's new rows as the pool stores
+  them, which is all a step needs from here when its attention reads
+  the pages where they lie (`ops.attention.paged_decode_attention`:
+  float pools wherever the Pallas kernels run);
+- :func:`decode_view` — otherwise (int8 pools, the CPU), one layer's
+  dense (S, T, H, D) context for a decode step: ONE gather straight
+  out of the stacked pool through (layer, page table), with the new
+  token's row laid over position ``seq_lens[s]`` of every writing
+  slot. Either way the pools are only read, so they stay
+  loop-invariant in the layer scan (scanning them as ``xs``/``ys``
+  made XLA copy every layer's slab, and the whole stack, in every
+  step);
 - :func:`append_rows` — the step's one write: scatter every layer's
   new rows `(L, S, row)` into the stacked pools after the scan, in
   place when the cache is donated (inactive slots and full contexts
@@ -337,45 +342,55 @@ def _decode_writes(cache, active):
     return room if active is None else jnp.logical_and(active, room)
 
 
+def decode_rows(cache: PagedKVCache, k_new, v_new):
+    """A decode step's new K/V rows as :func:`append_rows` writes
+    them once every layer's are stacked. ``k_new``/``v_new``:
+    (S, H, D), the new token of every slot. Returns ``(k_row, v_row,
+    k_srow, v_srow)``: (S, W) rows in the pool's dtype (quantized for
+    int8 pools) and their (S, H) scales (None for float pools). No
+    read of the pools: attention that takes its context from the
+    pages themselves (`ops.attention.paged_decode_attention`) needs
+    nothing else from here."""
+    k_row, k_srow = _pool_rows(cache.k_pages, k_new)
+    v_row, v_srow = _pool_rows(cache.v_pages, v_new)
+    return k_row, v_row, k_srow, v_srow
+
+
 def decode_view(cache: PagedKVCache, layer, k_new, v_new,
                 active=None):
-    """One layer's attention operands for a decode step, the pools
-    only read.
+    """One layer's dense attention operands for a decode step, the
+    pools only read.
 
     ``layer``: scalar index into the stacked pools (traced inside the
     layer scan); ``k_new``/``v_new``: (S, H, D) — the new token of
     every slot. Gathers the layer's dense context through the page
-    table and lays each writing slot's row (quantized for int8 pools,
-    cast to the pool's dtype otherwise) over position
-    ``seq_lens[s]``, so attention sees exactly what a gather after
-    the append would hold. Returns ``(ctx, rows)``: ``ctx =
+    table and lays each writing slot's row (:func:`decode_rows`) over
+    position ``seq_lens[s]``, so attention sees exactly what a gather
+    after the append would hold. Returns ``(ctx, rows)``: ``ctx =
     (k_ctx, v_ctx, k_sctx, v_sctx)``, (S, T, H, D) views in the
     pool's dtype and their (S, T, H) scale views (None for float
-    pools); ``rows = (k_row, v_row, k_srow, v_srow)``, (S, W) and
-    (S, H): what :func:`append_rows` writes once every layer's are
-    stacked."""
+    pools); ``rows``: :func:`decode_rows`' result."""
     t_max = cache.max_context
     writes = _decode_writes(cache, active)
+    rows = decode_rows(cache, k_new, v_new)
 
-    def view(pages, scales, new):
-        row, srow = _pool_rows(pages, new)
+    def view(pages, scales, row, srow, heads_dim):
         ctx = _lay_rows(
             gather_layer(pages, cache.page_table, t_max, layer),
             cache.seq_lens, row, writes)
-        ctx = split_heads(ctx, *new.shape[1:])
+        ctx = split_heads(ctx, *heads_dim)
         if scales is None:
-            return ctx, None, row, None
-        sctx = _lay_rows(
+            return ctx, None
+        return ctx, _lay_rows(
             gather_layer(scales, cache.page_table, t_max, layer),
             cache.seq_lens, srow, writes)
-        return ctx, sctx, row, srow
 
-    k_ctx, k_sctx, k_row, k_srow = view(cache.k_pages, cache.k_scales,
-                                        k_new)
-    v_ctx, v_sctx, v_row, v_srow = view(cache.v_pages, cache.v_scales,
-                                        v_new)
-    return (k_ctx, v_ctx, k_sctx, v_sctx), (k_row, v_row, k_srow,
-                                            v_srow)
+    k_row, v_row, k_srow, v_srow = rows
+    k_ctx, k_sctx = view(cache.k_pages, cache.k_scales, k_row, k_srow,
+                         k_new.shape[1:])
+    v_ctx, v_sctx = view(cache.v_pages, cache.v_scales, v_row, v_srow,
+                         v_new.shape[1:])
+    return (k_ctx, v_ctx, k_sctx, v_sctx), rows
 
 
 @jax.named_scope("zoo:kv_cache/gather")

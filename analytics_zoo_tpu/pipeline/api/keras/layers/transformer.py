@@ -517,7 +517,8 @@ class TransformerLayer(KerasLayer):
         Shape-static — safe inside while_loop and as ONE compiled
         program under continuous batching."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        from analytics_zoo_tpu.ops.attention import decode_attention
+        from analytics_zoo_tpu.ops.attention import (
+            decode_attention, paged_decode_attention, paged_decode_ok)
         s = token_ids.shape[0]
         if active is None:
             active = cache.seq_lens > 0
@@ -526,6 +527,11 @@ class TransformerLayer(KerasLayer):
                      token_ids.astype(jnp.int32), axis=0) + \
             jnp.take(params["pos_embed"], pos, axis=0)
         lens_after = cache.seq_lens + active.astype(jnp.int32)
+        # one algorithm, single-query attention over a paged cache,
+        # whose operands are the pages where the kernel runs and a
+        # dense gathered view elsewhere (`paged_decode_ok`'s rule)
+        paged = paged_decode_ok(cache, x.dtype, self.attention_impl)
+        writes = kvc._decode_writes(cache, active) if paged else None
 
         # the pools are closed over, not scanned: the body only reads
         # them, and the step's rows are written once after the scan
@@ -533,14 +539,19 @@ class TransformerLayer(KerasLayer):
         def block(x, xs):
             p, layer = xs
             q, k_new, v_new = self._split_qkv(p, x)
-            (k_ctx, v_ctx, sk, sv), rows = kvc.decode_view(
-                cache, layer, k_new, v_new, active=active)
-            if sk is None:
-                k_ctx = k_ctx.astype(x.dtype)
-                v_ctx = v_ctx.astype(x.dtype)
-            attn = decode_attention(q, k_ctx, v_ctx, lens_after,
-                                    impl=self.attention_impl,
-                                    k_scales=sk, v_scales=sv)
+            if paged:
+                rows = kvc.decode_rows(cache, k_new, v_new)
+                attn = paged_decode_attention(
+                    q, rows[0], rows[1], cache, layer, writes)
+            else:
+                (k_ctx, v_ctx, sk, sv), rows = kvc.decode_view(
+                    cache, layer, k_new, v_new, active=active)
+                if sk is None:
+                    k_ctx = k_ctx.astype(x.dtype)
+                    v_ctx = v_ctx.astype(x.dtype)
+                attn = decode_attention(q, k_ctx, v_ctx, lens_after,
+                                        impl=self.attention_impl,
+                                        k_scales=sk, v_scales=sv)
             attn = attn.reshape(s, self.hidden_size)
             return self._block_tail(p, x, attn), rows
 

@@ -1232,8 +1232,18 @@ class ContinuousBatcher:
                               np.bool_)
             for e in regular:
                 active[e.slot] = True
-            with obs.span("decode/step",
-                          n=len(regular)) as sp:
+            # what of the page table the step's slots hold, from the
+            # lengths kept here (a slot has cached its prompt and all
+            # but the last of its tokens): the share a step that
+            # reads live pages only has to read
+            page = engine.page_size
+            pages_live = sum(
+                -(-(e.prompt_len + len(e.tokens) - 1) // page)
+                for e in regular)
+            pages_table = engine.max_slots * engine.pages_per_slot
+            with obs.span("decode/step", n=len(regular),
+                          pages_live=pages_live,
+                          pages_table=pages_table) as sp:
                 toks = engine.step(active)
                 # the step's two waits, timed inside the engine:
                 # the compiled call returning, then the tokens
@@ -1254,6 +1264,16 @@ class ContinuousBatcher:
             obs.counter(
                 "zoo_tpu_serving_gen_steps_total",
                 help="decode iterations executed").inc()
+        if regular:
+            obs.counter(
+                "zoo_tpu_decode_pages_live_total",
+                help="pages the active slots of decode steps held "
+                     "(up to each slot's cached length)"
+            ).inc(pages_live)
+            obs.counter(
+                "zoo_tpu_decode_pages_table_total",
+                help="pages the page table of decode steps spans "
+                     "(slots x pages a slot)").inc(pages_table)
         for e in done:
             self._finish(e, now)
         it.annotate(admitted=admitted, active=len(self._active),

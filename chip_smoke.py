@@ -13,7 +13,8 @@ against a plain reference:
   3. generate  load_generator -> /generate through ContinuousBatcher,
                then one Estimator step at T=1024 through the flash
                kernel, forward and backward
-  4. kernels   every Pallas entry point (the flash attention family)
+  4. kernels   every Pallas entry point (the flash attention family,
+               the paged decode kernel)
                compiled (not interpreted), run at T=4096 shapes and
                compared with its XLA reference
   5. four chips (only with ``--chips 4``, which runs nothing else):
@@ -698,6 +699,30 @@ def phase_kernels(sz: Sizes, seed: int, clock: _CompileClock,
         lambda q, k, v, ks, vs: att.decode_attention(
             q, k, v, lens, impl="xla", k_scales=ks, v_scales=vs),
         dq, k8, v8, ks, vs)
+
+    # -- paged decode: the same context as pages of 16 rows, read
+    # where they lie (no dense view), up to each slot's length ----------
+    page = 16
+    table = jnp.arange(d["s"] * d["t"] // page, dtype=jnp.int32
+                       ).reshape(d["s"], -1)
+
+    width = -(-d["h"] * d["d"] // kvc.ROW_ALIGN) * kvc.ROW_ALIGN
+
+    def as_rows(x):
+        # (..., H, D) -> (..., W): heads side by side, as a pool's row
+        return kvc._pool_rows(jax.ShapeDtypeStruct((width,), bf), x)[0]
+
+    def paged(q, k, v):
+        o, _, l = fa.paged_decode_partial(
+            as_rows(q), as_rows(k).reshape(1, -1, page, width),
+            as_rows(v).reshape(1, -1, page, width), table, lens, 0,
+            heads=d["h"], head_dim=d["d"], scale=dscale,
+            interpret=interp)
+        return (o / l[..., None]).astype(q.dtype)
+    run("paged_decode_partial bf16", paged,
+        lambda q, k, v: att.decode_attention(q, k, v, lens,
+                                             impl="xla"),
+        dq, dk, dv)
 
     bad = [c["kernel"] for c in cases if not c["passed"]]
     _check(not bad, f"kernels failed: {bad}")

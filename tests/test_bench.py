@@ -338,8 +338,10 @@ def test_chip_smoke_rehearsal_lines_say_what_ran(rehearsal):
     assert gen["train_step"]["attention"] == "flash"
     kern = by["kernels"]
     assert kern["interpret"] is True      # the Pallas interpreter
-    # the flash attention family is every Pallas kernel there is
-    assert len(kern["cases"]) == 6
+    # the flash attention family and the paged decode kernel are
+    # every Pallas kernel there is
+    assert len(kern["cases"]) == 7
     assert all(c["passed"] for c in kern["cases"])
-    assert all(c["kernel"].startswith("flash_") for c in kern["cases"])
+    assert sorted({c["kernel"].split("_")[0] for c in kern["cases"]}
+                  ) == ["flash", "paged"]
     assert "fused_resnet50_step" not in kern

@@ -98,6 +98,16 @@ def _decode(q, k, v, mask, ks=None, vs=None):
         v_scales=vs)
 
 
+def _paged_decode(q, k_pages, v_pages, table, lens, layer):
+    return fa.paged_decode_partial(
+        q, k_pages, v_pages, table, lens, layer[0], heads=25,
+        head_dim=64, scale=0.125, interpret=False)
+
+
+# GPT-2-XL's cell: 8 slots of 64 pages of 16 rows of 25 x 64 -> 1664
+_PAGED = [((8, 1664), BF)] + [((4, 512, 16, 1664), BF)] * 2 + \
+    [((8, 64), jnp.int32), ((8,), jnp.int32), ((1,), jnp.int32)]
+
 _QKV = [(_ATT, BF)] * 3
 _HALF = [((4, 2048, 16, 64), BF)] * 3
 _DQKV = [((_DEC["s"], _DEC["h"], _DEC["d"]), BF)] + \
@@ -114,6 +124,7 @@ KERNELS = {
     "flash_decode_int8": (
         _decode,
         [_DQKV[0]] + [(_DQKV[1][0], jnp.int8)] * 2 + _DMASK + _DSCALES),
+    "paged_decode": (_paged_decode, _PAGED),
 }
 
 
@@ -213,7 +224,16 @@ _XL_DEPTH = 48                # the prefill at the published depth: its
 #                               temporaries grow with the layers
 
 
-def _xl_program(one_chip, program):
+@pytest.fixture
+def as_on_the_chip(monkeypatch):
+    """The rule that chooses the paged decode kernel asks
+    `jax.devices()`, the CPU here: answer as the chip would, and
+    compile the kernel rather than interpret it."""
+    monkeypatch.setenv("ZOO_TPU_FLASH_FORCE_INTERPRET", "1")
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
+
+
+def _xl_program(one_chip, program, cache_dtype=BF):
     """("step" | "prefill") -> (cache shapes, compiled): the decode
     step over every slot, or the engine's longest prefill program,
     ONE prompt row of 1024 tokens addressed by slot."""
@@ -235,7 +255,8 @@ def _xl_program(one_chip, program):
     params = on_chip(jax.eval_shape(
         lambda: net.build(jax.random.key(0), (_XL["seq_len"],))), BF)
     cache = on_chip(jax.eval_shape(lambda: net.init_kv_cache(
-        _XL_SLOTS, _XL["seq_len"], page_size=_XL_PAGE, dtype=BF)))
+        _XL_SLOTS, _XL["seq_len"], page_size=_XL_PAGE,
+        dtype=cache_dtype)))
     s = _XL_SLOTS
     if program == "step":
         def fn(cache, params, tok, active):
@@ -266,15 +287,16 @@ def _no_shape_leads_with(hlo, *dims):
 
 
 @pytest.mark.parametrize("program", ["step", "prefill"])
-def test_generation_programs_leave_the_pools_in_place(one_chip,
-                                                      program):
+def test_generation_programs_leave_the_pools_in_place(
+        one_chip, as_on_the_chip, program):
     """The device lays a K/V pool out row-major (a page is one
     contiguous block: rows are `heads * head_dim` padded to whole
     lane tiles, or the runtime picks a layout with the page axis
     minor-most), and neither the decode step nor a prefill copies,
     transposes or slices anything of a pool's or a layer slab's
     shape: the only operations that produce a pool are the two
-    in-place scatters."""
+    in-place scatters (the step's paged attention kernel reads the
+    pools where they lie)."""
     import re
     cache, compiled = _xl_program(one_chip, program)
     hlo = compiled.as_text()
@@ -291,6 +313,31 @@ def test_generation_programs_leave_the_pools_in_place(one_chip,
     assert not moved, moved
     # donated and written in place: output pools alias input pools
     assert "{0}: (0, {}, may-alias), {1}: (1, {}, may-alias)" in hlo
+
+
+def test_gpt2xl_step_attends_from_the_pages(one_chip, as_on_the_chip):
+    """The GPT-2-XL cell's decode step holds the paged attention
+    kernel and no dense view of a layer's context: nothing of the
+    gathered pages' shape (8 slots x 64 pages = 512 pages of 16 rows),
+    of the view's (8 x 1024 rows of 1664) or of its head-split
+    relayout (8 x 1024 x 1600)."""
+    _, compiled = _xl_program(one_chip, "step")
+    hlo = compiled.as_text()
+    assert any("tpu_custom_call" in line and "zoo_paged_decode" in line
+               for line in hlo.splitlines())
+    for shape in ("8,1024,1600", "8,1024,1664", "512,16,1664",
+                  "8,1024,25,64", "8,64,16,1664"):
+        assert f"bf16[{shape}]" not in hlo, shape
+
+
+def test_gpt2xl_int8_step_keeps_the_dense_view(one_chip,
+                                               as_on_the_chip):
+    """Int8 pools, with their scale pools, are not the kernel's: that
+    step gathers its dense view as before."""
+    _, compiled = _xl_program(one_chip, "step", cache_dtype=jnp.int8)
+    hlo = compiled.as_text()
+    assert "zoo_paged_decode" not in hlo
+    assert "s8[512,16,1664]" in hlo or "s8[8,1024,1664]" in hlo
 
 
 def test_gpt2xl_prefill_computes_the_admitted_row_only(one_chip):

@@ -474,7 +474,8 @@ def decode_records():
                           "retired"}),
     ("decode/prefill", {"n", "bucket", "prompt_tokens", "calls",
                         "rows"}),
-    ("decode/step", {"n", "dispatch_s", "fetch_s"}),
+    ("decode/step", {"n", "dispatch_s", "fetch_s", "pages_live",
+                     "pages_table"}),
     ("decode/release", {"slot", "tokens"}),
     ("decode/queue_wait", set()),
     ("decode/admit", {"slot", "prompt_len"}),
@@ -508,6 +509,39 @@ def test_decode_span_in_store_with_fields(decode_records, name,
         # every token but each request's first comes from a step
         assert sum(r["fields"]["emitted"] for r in recs) == \
             6 + 4 + 8 + 5 + 7 - 5
+
+
+def test_decode_step_counts_the_pages_its_slots_hold(decode_records):
+    """`pages_live` is the pages up to each active slot's cached
+    length (its prompt and all but the last of its tokens), from the
+    host's own lengths; `pages_table` slots x pages a slot."""
+    steps = [r["fields"] for r in decode_records
+             if r["name"] == "decode/step"]
+    assert all(f["pages_table"] == 2 * 4 for f in steps)
+    assert all(f["n"] <= f["pages_live"] <= f["pages_table"]
+               for f in steps)
+    # a request of prompt n and m tokens decodes m - 1 steps, at
+    # cached lengths n, n + 1, ...: pages of 8
+    want = sum(-(-(n + j) // 8)
+               for n, m in [(3, 6), (7, 4), (2, 8), (5, 5), (4, 7)]
+               for j in range(m - 1))
+    assert sum(f["pages_live"] for f in steps) == want
+
+
+def test_decode_page_counters_follow_the_span():
+    from analytics_zoo_tpu.common import observability as obs
+    from analytics_zoo_tpu.pipeline.inference.batching import \
+        ContinuousBatcher
+    cb = ContinuousBatcher(_toy_engine(), queue_depth=4).start()
+    try:
+        cb.submit([1, 2, 3, 4, 5, 6, 7], max_new_tokens=4).result(
+            timeout=60)
+    finally:
+        cb.stop()
+    live = obs.counter("zoo_tpu_decode_pages_live_total").value
+    table = obs.counter("zoo_tpu_decode_pages_table_total").value
+    # three steps at cached lengths 7, 8, 9 over a table of 2 x 4
+    assert (live, table) == (1 + 1 + 2, 3 * 8)
 
 
 def test_decode_children_of_one_iteration(decode_records):
