@@ -195,15 +195,8 @@ def paged_decode_attention(q: jnp.ndarray, k_row: jnp.ndarray,
     k_new = kvc.split_heads(k_row, h, d).astype(jnp.float32)
     v_new = kvc.split_heads(v_row, h, d).astype(jnp.float32)
     s_new = jnp.sum(q.astype(jnp.float32) * k_new, axis=-1) * scale
-    # a slot that does not write has no such key: weight exp(-inf) = 0
-    # (m is finite, -1e30 at least, so no inf - inf)
-    s_new = jnp.where(writes[:, None], s_new, -jnp.inf)
-    m_new = jnp.maximum(m, s_new)
-    a_old = jnp.exp(m - m_new)
-    a_new = jnp.exp(s_new - m_new)
-    l = l * a_old + a_new
-    o = o * a_old[..., None] + v_new * a_new[..., None]
-    return (o / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+    return merge_decode_partials(o, m, l, s_new, v_new, writes,
+                                 dtype=q.dtype)
 
 
 def latent_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
@@ -233,7 +226,7 @@ def mla_decode_attention(q_lat: jnp.ndarray, q_pe: jnp.ndarray,
                          ctx: jnp.ndarray, seq_lens: jnp.ndarray,
                          scale: float) -> jnp.ndarray:
     """:func:`latent_decode_attention` over a whole gathered context
-    (the view from `ops.kv_cache.latent_decode_view`): the 128 query
+    (the view from `ops.kv_cache.row_decode_view`): the 128 query
     heads of a slot all attend to ONE latent row a cached token, no
     per-head key or value is ever formed from the cache, and
     ``seq_lens`` (S,) masks positions ``>= seq_lens[s]``."""
@@ -332,6 +325,195 @@ def masked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         jnp.moveaxis(q.reshape(a, c // qb, qb, h, d), 1, 0),
         jnp.moveaxis(mask.reshape(a, c // qb, qb, -1), 1, 0)))
     return jnp.moveaxis(out, 0, 1).reshape(a, c, h, v.shape[-1])
+
+
+def _sink_softmax(logits: jnp.ndarray, sink=None) -> jnp.ndarray:
+    """Softmax of masked f32 ``logits`` over their last axis. With
+    ``sink`` (broadcast against ``logits[..., :1]``) the denominator
+    holds one more term, ``exp(sink)``: a learned logit that takes
+    weight and carries no value (gpt-oss's attention sink), so the
+    probabilities sum to less than 1."""
+    if sink is None:
+        return jax.nn.softmax(logits, axis=-1)
+    m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), sink)
+    p = jnp.exp(logits - m)
+    return p / (jnp.sum(p, axis=-1, keepdims=True) + jnp.exp(sink - m))
+
+
+def grouped_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                      mask: jnp.ndarray, scale: float, sink=None,
+                      q_block: int = 256) -> jnp.ndarray:
+    """:func:`masked_attention` for grouped-query heads: queries ``q``
+    (A, C, G, R, D), R query heads to each of the G K/V heads, over
+    keys ``k`` (A, T, G, D) and values ``v`` (A, T, G, Dv) that are
+    never repeated, under ``mask`` (A, C, T). ``sink`` (G, R) f32: a
+    logit a head in the softmax's denominator (:func:`_sink_softmax`).
+    ``q_block`` queries at a time: the f32 scores are
+    (A, G, R, q_block, T). Returns (A, C, G, R, Dv)."""
+    a, c, g, r, d = q.shape
+    qb = q_block if c % q_block == 0 else c
+    if sink is not None:
+        sink = sink.astype(jnp.float32)[None, :, :, None, None]
+
+    def block(qm):
+        qs, ms = qm                   # (A, qb, G, R, D), (A, qb, T)
+        logits = jnp.einsum("aqgrd,atgd->agrqt", qs, k,
+                            preferred_element_type=jnp.float32)
+        probs = _sink_softmax(
+            jnp.where(ms[:, None, None], logits * scale, -1e30), sink)
+        return jnp.einsum("agrqt,atgd->aqgrd", probs.astype(q.dtype),
+                          v)
+
+    if qb == c:
+        return block((q, mask))
+    out = jax.lax.map(block, (
+        jnp.moveaxis(q.reshape(a, c // qb, qb, g, r, d), 1, 0),
+        jnp.moveaxis(mask.reshape(a, c // qb, qb, -1), 1, 0)))
+    return jnp.moveaxis(out, 0, 1).reshape(a, c, g, r, v.shape[-1])
+
+
+def banded_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                     q_pos: jnp.ndarray, k_pos: jnp.ndarray,
+                     k_valid: jnp.ndarray, window: int, scale: float,
+                     sink=None) -> jnp.ndarray:
+    """Sliding-window attention of a chunk whose work is bounded by
+    the window, not by the keys held: queries ``q`` (A, C, G, R, D) at
+    positions ``q_pos`` (A, C); keys ``k`` (A, B + C, G, D) and values
+    ``v`` (A, B + C, G, Dv) at positions ``k_pos`` (A, B + C), real
+    where ``k_valid``: the B positions before the chunk, then the
+    chunk's own, consecutive. B, the band's block, is ``k.shape[1] -
+    C``: at least ``window - 1`` and a divisor of C. Query block i
+    (B queries) is multiplied with key blocks i and i + 1 only, 2B
+    keys whatever C is; a query sees the keys at ``0 <= q_pos - k_pos
+    < window``. ``sink`` as in :func:`grouped_attention`. Returns
+    (A, C, G, R, Dv)."""
+    a, c, g, r, d = q.shape
+    b = k.shape[1] - c
+    if b < window - 1 or c % b:
+        raise ValueError(f"banded_attention: a band of {b} keys "
+                         f"before {c} queries, window {window}")
+    n = c // b
+    blocks = lambda x: x.reshape((a, n + 1, b) + x.shape[2:])
+    two = lambda x: jnp.concatenate(
+        [blocks(x)[:, :-1], blocks(x)[:, 1:]], axis=2)
+    k2, v2, p2, ok2 = two(k), two(v), two(k_pos), two(k_valid)
+    qs, qp = q.reshape(a, n, b, g, r, d), q_pos.reshape(a, n, b)
+    back = qp[:, :, :, None] - p2[:, :, None, :]     # (A, n, B, 2B)
+    mask = jnp.logical_and(
+        ok2[:, :, None, :],
+        jnp.logical_and(back >= 0, back < window))
+    logits = jnp.einsum("anqgrd,antgd->angrqt", qs, k2,
+                        preferred_element_type=jnp.float32)
+    if sink is not None:
+        sink = sink.astype(jnp.float32)[None, None, :, :, None, None]
+    probs = _sink_softmax(
+        jnp.where(mask[:, :, None, None], logits * scale, -1e30), sink)
+    out = jnp.einsum("angrqt,antgd->anqgrd", probs.astype(q.dtype), v2)
+    return out.reshape(a, c, g, r, v.shape[-1])
+
+
+def merge_decode_partials(o, m, l, s_new, v_new, writes, sink=None,
+                          dtype=jnp.float32) -> jnp.ndarray:
+    """A decode step's attention output from the flash partials over
+    the cached keys (``o`` (S, H, Dv) f32 unnormalised, ``m`` and
+    ``l`` (S, H), softmax base ``m``), the step's own key (score
+    ``s_new`` (S, H), value ``v_new`` (S, H, Dv), present where
+    ``writes`` (S,)) and, with ``sink`` (H,), one more term in the
+    denominator that has no value: the arithmetic ring attention
+    merges block partials with. A slot with no key at all gives
+    zeros."""
+    # a slot that does not write has no such key: weight exp(-inf) = 0
+    # (m is finite, -1e30 at least, so no inf - inf)
+    s_new = jnp.where(writes[:, None], s_new, -jnp.inf)
+    m_new = jnp.maximum(m, s_new)
+    if sink is not None:
+        sink = jnp.broadcast_to(sink.astype(jnp.float32)[None],
+                                m.shape)
+        m_new = jnp.maximum(m_new, sink)
+    a_old = jnp.exp(m - m_new)
+    a_new = jnp.exp(s_new - m_new)
+    l = l * a_old + a_new
+    o = o * a_old[..., None] + v_new * a_new[..., None]
+    if sink is not None:
+        # no key at all: the sink alone would make 0 / exp(0), still 0
+        l = l + jnp.exp(sink - m_new)
+    return (o / jnp.maximum(l, 1e-30)[..., None]).astype(dtype)
+
+
+def paged_rows_ok(pool, dtype, k_width: int, v_width: int,
+                  impl: Optional[str] = None) -> bool:
+    """:func:`paged_decode_ok` for a grouped-query layer's pool of
+    ``[K | V]`` rows (`ops.kv_cache.RowPagedCache.pages` or
+    ``.window``): whether a decode step reads it page by page where
+    it lies (`ops.flash_attention.paged_gqa_decode_partial`) rather
+    than through a gathered view. Decided by what the step can
+    observe: backend, selector, the pool's dtype against the
+    activations' and the geometry `paged_gqa_supported` names."""
+    if resolve_attention_impl(impl) == "xla" or \
+            jnp.dtype(dtype) != pool.dtype or not flash_backend_ok():
+        return False
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    return fa.paged_gqa_supported(pool.shape[2], pool.dtype,
+                                  pool.shape[3], k_width, v_width)
+
+
+def gqa_decode_attention(q: jnp.ndarray, new_row: jnp.ndarray,
+                         pool: jnp.ndarray, layer,
+                         page_table: jnp.ndarray, lens: jnp.ndarray,
+                         first: jnp.ndarray, writes: jnp.ndarray, *,
+                         v_dim: int, scale: float, sink=None,
+                         impl: Optional[str] = None) -> jnp.ndarray:
+    """Single-query attention of grouped-query heads over a pool of
+    ``[k of the G heads | v of the G heads]`` rows, for full and
+    sliding layers alike.
+
+    ``q`` (S, G, R, D): R query heads to each K/V head; ``new_row``
+    (S, W): the step's own token as the pool will store it, not in
+    the pool yet; ``pool`` (L, P, page, W), only read; ``page_table``
+    (S, n), ``lens`` and ``first`` (S,): slot s attends to positions
+    ``[first[s], lens[s])`` of its table row (a context pool's table
+    from 0, or `ops.kv_cache.window_table`) and, where ``writes``,
+    to its own row; ``sink`` (G, R): one more term in the softmax's
+    denominator, merged exactly as the own token is. Where
+    :func:`paged_rows_ok` the cached part is the Pallas kernel's
+    partials over the live pages; otherwise (the CPU) the same
+    partials from a gathered view. Returns (S, G, R, v_dim)."""
+    s, g, r, d = q.shape
+    wk, wv = g * d, g * v_dim
+    f32 = jnp.float32
+    if paged_rows_ok(pool, q.dtype, wk, wv, impl):
+        from analytics_zoo_tpu.ops import flash_attention as fa
+        o, m, l = fa.paged_gqa_decode_partial(
+            q, pool, page_table, lens, first, layer, k_dim=d,
+            v_dim=v_dim, scale=scale)
+    else:
+        page = pool.shape[2]
+        ctx = pool.at[layer, page_table].get(mode="clip").reshape(
+            s, page_table.shape[1] * page, -1).astype(q.dtype)
+        at = jnp.arange(ctx.shape[1], dtype=jnp.int32)[None]
+        seen = jnp.logical_and(at >= first[:, None], at < lens[:, None])
+        k = ctx[..., :wk].reshape(s, -1, g, d)
+        v = ctx[..., wk:wk + wv].reshape(s, -1, g, v_dim)
+        sc = jnp.einsum("sgrd,stgd->sgrt", q, k,
+                        preferred_element_type=f32) * scale
+        sc = jnp.where(seen[:, None, None], sc, -1e30)
+        m = jnp.max(sc, axis=-1)
+        p = jnp.where(seen[:, None, None],
+                      jnp.exp(sc - m[..., None]), 0.0)
+        l = jnp.sum(p, axis=-1)
+        o = jnp.einsum("sgrt,stgd->sgrd", p.astype(q.dtype), v,
+                       preferred_element_type=f32)
+    new = new_row.astype(f32)
+    k_new = new[:, :wk].reshape(s, g, 1, d)
+    v_new = jnp.broadcast_to(
+        new[:, wk:wk + wv].reshape(s, g, 1, v_dim), (s, g, r, v_dim))
+    s_new = jnp.sum(q.astype(f32) * k_new, axis=-1) * scale
+    flat = lambda x: x.reshape((s, g * r) + x.shape[3:])
+    out = merge_decode_partials(
+        flat(o), flat(m), flat(l), flat(s_new), flat(v_new), writes,
+        sink=None if sink is None else sink.reshape(-1),
+        dtype=q.dtype)
+    return out.reshape(s, g, r, v_dim)
 
 
 @jax.named_scope("zoo:decode/chunk_attention")

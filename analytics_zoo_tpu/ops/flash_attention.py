@@ -24,7 +24,10 @@ the exact kernel code path.
 Beside the family, `paged_decode_partial` (`zoo_paged_decode`):
 single-query decode attention straight from the live pages of a paged
 K/V pool, sharing the family's softmax recursion
-(`_softmax_accumulate`).
+(`_softmax_accumulate`), and `paged_gqa_decode_partial`
+(`zoo_paged_gqa_decode`): the same loop (`_paged_attend`) for
+grouped-query heads over one pool of ``[K | V]`` rows, from a lower
+edge on for a sliding layer's ring.
 """
 
 from __future__ import annotations
@@ -722,13 +725,141 @@ def paged_decode_supported(page_size: int, dtype) -> bool:
         _PAGED_BLOCK % page_size == 0
 
 
+def _paged_attend(table_ref, lens_ref, first_ref, layer, pools, bufs,
+                  sems, state, acc_ref, m_ref, l_ref, prepare, q_of,
+                  kv_of, *, scale: float, pages_per_slot: int):
+    """The body the paged decode kernels share: one grid step = one
+    slot's single-query attention over the pages it holds, a loop of
+    blocks of whole pages copied HBM -> double-buffered VMEM where
+    only live pages are fetched, folded into ``acc_ref`` / ``m_ref``
+    / ``l_ref`` by `_softmax_accumulate`.
+
+    ``table_ref`` (S * pages_per_slot,) and ``lens_ref`` (S,) in
+    SMEM; ``first_ref`` (S,) or None: slot s attends to positions
+    ``[first[s], lens[s])`` of its table row (None: from 0), pages
+    wholly outside them are never fetched and the loop starts at the
+    block that holds ``first[s]``. ``pools``: the HBM pools
+    (L, P, page, W_i) a page is copied from, ``bufs`` their
+    (2, pages a block, page, W_i) buffers, ``sems`` DMA
+    (len(pools), 2). ``state`` SMEM (2,) = (buffer the next block is
+    in, whether the previous slot already started this slot's first
+    copies), carried from one grid step to the next. ``prepare()``
+    runs once before a slot's loop; ``q_of()`` gives the (rows, Wk)
+    query matrix and ``kv_of(buffer)`` a block's ``(K (keys, Wk),
+    V (keys, Wv))`` inside it."""
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    n_pool = pools[0].shape[1]
+    _, ppb, page, _ = bufs[0].shape
+    bk = ppb * page
+    hp = acc_ref.shape[0]
+    n_tok = lens_ref[s]
+    n_blk = jax.lax.div(n_tok + (bk - 1), bk)
+    first_of = (lambda slot: first_ref[slot]) if first_ref is not None \
+        else (lambda slot: 0)
+    lo = first_of(s)
+
+    def copies(slot, blk, buf):
+        # block `blk` of `slot`: (is the page live, its copies) a
+        # page; pages outside the slot's positions are never fetched
+        tok, low = lens_ref[slot], first_of(slot)
+        out = []
+        for i in range(ppb):
+            j = blk * ppb + i
+            pid = table_ref[slot * pages_per_slot +
+                            jnp.minimum(j, pages_per_slot - 1)]
+            pid = jnp.clip(pid, 0, n_pool - 1)
+            live = j * page < tok
+            if first_ref is not None:
+                live = jnp.logical_and(live, (j + 1) * page > low)
+            out.append((live, [
+                pltpu.make_async_copy(pool.at[layer, pid],
+                                      b.at[buf, i], sems.at[n, buf])
+                for n, (pool, b) in enumerate(zip(pools, bufs))]))
+        return out
+
+    def start(slot, blk, buf):
+        for live, cs in copies(slot, blk, buf):
+            @pl.when(live)
+            def _go():
+                for c in cs:
+                    c.start()
+
+    def wait(slot, blk, buf):
+        for live, cs in copies(slot, blk, buf):
+            @pl.when(live)
+            def _arrived():
+                for c in cs:
+                    c.wait()
+
+    @pl.when(s == 0)
+    def _first():
+        state[0] = 0
+        state[1] = 0
+        # a value page that is never fetched multiplies probabilities
+        # that are exactly 0: it must hold numbers, whatever they are
+        bufs[-1][...] = jnp.zeros_like(bufs[-1])
+
+    acc_ref[:] = jnp.zeros_like(acc_ref)
+    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+    l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(n_tok > lo)
+    def _attend():
+        @pl.when(state[1] == 0)
+        def _cold():
+            start(s, lo // bk, state[0])
+
+        prepare()
+
+        def block(b, cur):
+            nxt = 1 - cur
+
+            @pl.when(b + 1 < n_blk)
+            def _next_block():
+                start(s, b + 1, nxt)
+
+            @pl.when(b + 1 == n_blk)
+            def _next_slot():
+                # the first later slot that holds tokens, if any
+                s2 = jax.lax.fori_loop(
+                    s + 1, n_slots,
+                    lambda i, c: jnp.where(
+                        jnp.logical_and(c == n_slots,
+                                        lens_ref[i] > first_of(i)),
+                        i, c),
+                    n_slots)
+
+                @pl.when(s2 < n_slots)
+                def _prefetch():
+                    start(s2, first_of(s2) // bk, nxt)
+                state[1] = (s2 < n_slots).astype(jnp.int32)
+
+            wait(s, b, cur)
+            k, v = kv_of(cur)
+            sc = jax.lax.dot_general(
+                q_of(), k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            pos = b * bk + jax.lax.broadcasted_iota(
+                jnp.int32, (hp, bk), 1)
+            seen = pos < n_tok
+            if first_ref is not None:
+                seen = jnp.logical_and(seen, pos >= lo)
+            sc = jnp.where(seen, sc, _NEG_INF)
+            _softmax_accumulate(sc, v, acc_ref, m_ref, l_ref)
+            return nxt
+
+        state[0] = jax.lax.fori_loop(lo // bk, n_blk, block, state[0])
+
+
 def _paged_decode_kernel(table_ref, lens_ref, layer_ref,
                          q_ref, k_hbm, v_hbm,
                          o_ref, m_out_ref, l_out_ref,
                          k_buf, v_buf, sems, q_bd, acc_ref, m_ref,
                          l_ref, state, *, scale: float, head_dim: int,
                          pages_per_slot: int):
-    """One slot's attention over its cached pages, heads side by side.
+    """One slot's attention over its cached pages, heads side by side
+    (`_paged_attend` over a K and a V pool of one shape).
 
     Scalar prefetch (SMEM): the page table flattened
     (S * pages_per_slot,), ``seq_lens`` (S,), the layer (1,). ``q_ref``
@@ -745,114 +876,57 @@ def _paged_decode_kernel(table_ref, lens_ref, layer_ref,
 
     Scratch: ``k_buf``/``v_buf`` (2, pages a block, page, W), ``sems``
     DMA (2, 2) = (pool, buffer), ``q_bd`` (HP, W), the accumulators,
-    and ``state`` SMEM (2,) = (buffer the next block is in, whether
-    the previous slot already started its copies), carried from one
-    grid step to the next."""
-    s = pl.program_id(0)
-    n_slots = pl.num_programs(0)
-    n_pool = k_hbm.shape[1]
+    and ``state`` SMEM (2,)."""
     _, ppb, page, w = k_buf.shape
     bk = ppb * page
     hp = q_bd.shape[0]
-    layer = layer_ref[0]
-    n_tok = lens_ref[s]
-    n_blk = jax.lax.div(n_tok + (bk - 1), bk)
-
-    def copies(slot, blk, buf):
-        # block `blk` of `slot`: (is the page live, its K copy, its V
-        # copy) a page; pages past the slot's length are never fetched
-        tok = lens_ref[slot]
-        out = []
-        for i in range(ppb):
-            j = blk * ppb + i
-            pid = table_ref[slot * pages_per_slot +
-                            jnp.minimum(j, pages_per_slot - 1)]
-            pid = jnp.clip(pid, 0, n_pool - 1)
-            out.append((
-                j * page < tok,
-                pltpu.make_async_copy(k_hbm.at[layer, pid],
-                                      k_buf.at[buf, i], sems.at[0, buf]),
-                pltpu.make_async_copy(v_hbm.at[layer, pid],
-                                      v_buf.at[buf, i], sems.at[1, buf])))
-        return out
-
-    def start(slot, blk, buf):
-        for live, kc, vc in copies(slot, blk, buf):
-            @pl.when(live)
-            def _go():
-                kc.start()
-                vc.start()
-
-    def wait(slot, blk, buf):
-        for live, kc, vc in copies(slot, blk, buf):
-            @pl.when(live)
-            def _arrived():
-                kc.wait()
-                vc.wait()
-
-    @pl.when(s == 0)
-    def _first():
-        state[0] = 0
-        state[1] = 0
-        # a value page that is never fetched multiplies probabilities
-        # that are exactly 0: it must hold numbers, whatever they are
-        v_buf[...] = jnp.zeros_like(v_buf)
-
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
-    l_ref[:] = jnp.zeros_like(l_ref)
-
     rows = jax.lax.broadcasted_iota(jnp.int32, (hp, w), 0) * head_dim
     cols = jax.lax.broadcasted_iota(jnp.int32, (hp, w), 1)
     diag = jnp.logical_and(cols >= rows, cols < rows + head_dim)
 
-    @pl.when(n_blk > 0)
-    def _attend():
-        @pl.when(state[1] == 0)
-        def _cold():
-            start(s, 0, state[0])
-
+    def prepare():
         q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (hp, w))
         q_bd[:] = jnp.where(diag, q, 0.0).astype(q_bd.dtype)
 
-        def block(b, cur):
-            nxt = 1 - cur
-
-            @pl.when(b + 1 < n_blk)
-            def _next_block():
-                start(s, b + 1, nxt)
-
-            @pl.when(b + 1 == n_blk)
-            def _next_slot():
-                # the first later slot that holds tokens, if any
-                s2 = jax.lax.fori_loop(
-                    s + 1, n_slots,
-                    lambda i, c: jnp.where(
-                        jnp.logical_and(c == n_slots, lens_ref[i] > 0),
-                        i, c),
-                    n_slots)
-
-                @pl.when(s2 < n_slots)
-                def _prefetch():
-                    start(s2, 0, nxt)
-                state[1] = (s2 < n_slots).astype(jnp.int32)
-
-            wait(s, b, cur)
-            k = k_buf[cur].reshape(bk, w)
-            v = v_buf[cur].reshape(bk, w)
-            sc = jax.lax.dot_general(
-                q_bd[:], k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            pos = b * bk + jax.lax.broadcasted_iota(
-                jnp.int32, (hp, bk), 1)
-            sc = jnp.where(pos < n_tok, sc, _NEG_INF)
-            _softmax_accumulate(sc, v, acc_ref, m_ref, l_ref)
-            return nxt
-
-        state[0] = jax.lax.fori_loop(0, n_blk, block, state[0])
-
+    _paged_attend(
+        table_ref, lens_ref, None, layer_ref[0], (k_hbm, v_hbm),
+        (k_buf, v_buf), sems, state, acc_ref, m_ref, l_ref, prepare,
+        lambda: q_bd[:],
+        lambda cur: (k_buf[cur].reshape(bk, w),
+                     v_buf[cur].reshape(bk, w)),
+        scale=scale, pages_per_slot=pages_per_slot)
     o_ref[0] = jnp.sum(jnp.where(diag, acc_ref[:], 0.0), axis=0,
                        keepdims=True)
+    m_out_ref[0] = m_ref[:]
+    l_out_ref[0] = l_ref[:]
+
+
+def _paged_gqa_kernel(table_ref, lens_ref, first_ref, layer_ref,
+                      q_ref, rows_hbm, o_ref, m_out_ref, l_out_ref,
+                      buf, sems, acc_ref, m_ref, l_ref, state, *,
+                      scale: float, k_width: int,
+                      pages_per_slot: int):
+    """`_paged_attend` for grouped-query heads over ONE pool whose
+    row is ``[K of the G heads (k_width) | V of the G heads | pad]``.
+    ``q_ref`` (1, HP, k_width): the slot's queries laid out
+    block-diagonally outside (row j holds query head j's values in
+    the columns of its K/V head), so ``Q @ K_blk^T`` is every query
+    head's scores against the rows' K part and ``P @ V_blk`` an
+    (HP, v_width) partial in which head j's output is its K/V head's
+    columns: written whole, ``o_ref`` (1, HP, v_width) f32, and cut
+    by the caller. ``first_ref``: a sliding layer's lower edge."""
+    _, ppb, page, _ = buf.shape
+    bk = ppb * page
+    wv = acc_ref.shape[1]
+    _paged_attend(
+        table_ref, lens_ref, first_ref, layer_ref[0], (rows_hbm,),
+        (buf,), sems, state, acc_ref, m_ref, l_ref, lambda: None,
+        lambda: q_ref[0],
+        lambda cur: (
+            buf[cur, :, :, :k_width].reshape(bk, k_width),
+            buf[cur, :, :, k_width:k_width + wv].reshape(bk, wv)),
+        scale=scale, pages_per_slot=pages_per_slot)
+    o_ref[0] = acc_ref[:]
     m_out_ref[0] = m_ref[:]
     l_out_ref[0] = l_ref[:]
 
@@ -939,6 +1013,100 @@ def paged_decode_partial(q_rows: jnp.ndarray, k_pages: jnp.ndarray,
       q_rows.astype(dtype)[:, None, :], k_pages, v_pages)
     o = o[:, 0, :heads * head_dim].reshape(s, heads, head_dim)
     return o, m[:, :heads, 0], l[:, :heads, 0]
+
+
+def paged_gqa_decode_partial(q: jnp.ndarray, rows: jnp.ndarray,
+                             page_table: jnp.ndarray,
+                             seq_lens: jnp.ndarray,
+                             first: jnp.ndarray, layer, *,
+                             k_dim: int, v_dim: int, scale: float,
+                             interpret: Optional[bool] = None):
+    """:func:`paged_decode_partial` for grouped-query heads over a
+    pool of ``[K | V]`` rows (`zoo_paged_gqa_decode`).
+
+    ``q`` (S, G, R, k_dim): R query heads to each of G K/V heads;
+    ``rows`` (L, P, page_size, W) the stacked pool, a token's row
+    ``[k of the G heads (G * k_dim) | v of the G heads (G * v_dim) |
+    padding]``, never copied; ``page_table`` (S, n) the pages of each
+    slot in order (a context pool's table, or the pages of a ring
+    that hold a window), ``seq_lens`` and ``first`` (S,): slot s
+    attends to positions ``[first[s], seq_lens[s])`` of its table
+    row. Returns the partials ``o`` (S, G, R, v_dim) f32
+    unnormalised, ``m`` and ``l`` (S, G, R); a slot with no such
+    position gives o = 0, m = -1e30, l = 0. Needs
+    `paged_gqa_supported`."""
+    global invocations
+    invocations += 1
+    if interpret is None:
+        interpret = not on_tpu()
+    s, g, r, _ = q.shape
+    _, _, page, w = rows.shape
+    wk, wv = g * k_dim, g * v_dim
+    if not paged_gqa_supported(page, rows.dtype, w, wk, wv):
+        raise ValueError(
+            f"paged_gqa_decode_partial: pool {rows.shape} "
+            f"{rows.dtype} against {g} K/V heads of {k_dim} + "
+            f"{v_dim} (see paged_gqa_supported)")
+    dtype = rows.dtype
+    ppb = _PAGED_BLOCK // page
+    tile = 32 // jnp.dtype(dtype).itemsize
+    heads = g * r
+    hp = -(-heads // tile) * tile
+    # row j = (g, r) holds its query in K/V head g's columns
+    eye = jnp.eye(g, dtype=q.dtype)
+    q_bd = jnp.einsum("sgrd,gh->sgrhd", q, eye).reshape(s, heads, wk)
+    q_bd = jnp.pad(q_bd.astype(dtype), [(0, 0), (0, hp - heads),
+                                        (0, 0)])
+    stat = pl.BlockSpec((1, hp, 128), lambda i, *_: (i, 0, 0))
+    o, m, l = pl.pallas_call(
+        functools.partial(
+            _paged_gqa_kernel, scale=float(scale), k_width=wk,
+            pages_per_slot=page_table.shape[1]),
+        name="zoo_paged_gqa_decode",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(s,),
+            in_specs=[pl.BlockSpec((1, hp, wk),
+                                   lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=[pl.BlockSpec((1, hp, wv),
+                                    lambda i, *_: (i, 0, 0)),
+                       stat, stat],
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb, page, w), dtype),
+                pltpu.SemaphoreType.DMA((1, 2)),
+                pltpu.VMEM((hp, wv), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.VMEM((hp, 128), jnp.float32),
+                pltpu.SMEM((2,), jnp.int32),
+            ]),
+        out_shape=[
+            jax.ShapeDtypeStruct((s, hp, wv), jnp.float32),
+            jax.ShapeDtypeStruct((s, hp, 128), jnp.float32),
+            jax.ShapeDtypeStruct((s, hp, 128), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(page_table.reshape(-1).astype(jnp.int32),
+      seq_lens.astype(jnp.int32), first.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q_bd, rows)
+    o = jnp.einsum("sgrhd,gh->sgrd",
+                   o[:, :heads].reshape(s, g, r, g, v_dim),
+                   jnp.eye(g, dtype=jnp.float32))
+    return o, m[:, :heads, 0].reshape(s, g, r), \
+        l[:, :heads, 0].reshape(s, g, r)
+
+
+def paged_gqa_supported(page_size: int, dtype, row_width: int,
+                        k_width: int, v_width: int) -> bool:
+    """Whether `paged_gqa_decode_partial` takes a pool of this
+    geometry: `paged_decode_supported`'s pages, and a row whose K
+    part and V part each fill whole 128-lane tiles, so that both are
+    cut out of a page in VMEM with no relayout."""
+    return paged_decode_supported(page_size, dtype) and \
+        k_width % 128 == 0 and v_width % 128 == 0 and \
+        row_width % 128 == 0 and k_width + v_width <= row_width
 
 
 def as_key_mask(mask, b: int, tk: int):

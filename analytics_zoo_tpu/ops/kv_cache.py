@@ -67,32 +67,37 @@ attention — roughly 2x resident-sequence capacity for a bounded,
 tested accuracy cost (tests/test_generate.py's kv-dtype conformance
 matrix).
 
-Latent pages (:class:`LatentPagedCache`, multi-head latent
-attention): a token's row is ONE vector a layer, shared by every
-head — the normalised KV latent with the rotated key part beside it
-— so there is one pool, not a K and a V pool, and no per-head
-anything: :func:`init_latent_cache`, :func:`latent_decode_view`,
-:func:`append_latent_rows` and :func:`write_latent_prompt` are the
-four operations above over that one pool, through the same
-coordinates, gather and in-place scatters. Int8 latent pages are
-refused by name (the scales are per (token, head) and a latent row
-has no heads), and so is the handoff codec, which carries K and V.
+Row pages (:class:`RowPagedCache`, `PatternDecoder`'s cache): a
+token's row is ONE vector a layer, whatever the layer's attention
+part makes it (`part.row_width` values): multi-head latent
+attention's normalised KV latent with the rotated key part beside it,
+shared by every head; a grouped-query part's ``[k of its G heads | v
+of its G heads]``. So there is one pool, not a K and a V pool, and
+nothing here knows what a row holds: :func:`init_row_cache`,
+:func:`row_decode_view`, :func:`append_pool_rows` and
+:func:`write_prompt_rows` are the four operations above over that one
+pool, through the same coordinates, gather and in-place scatters.
+Int8 row pages are refused by name (the scales are per (token, head)
+and the pool does not know a row's heads; a latent row has none), and
+so is the handoff codec, which carries K and V.
 
-A latent cache may hold layers of two kinds side by side, each with
-its own pool, row width and rule for which positions of a slot are
-live. *Context* layers keep every position: ``pages`` (and, for
-layers whose attention chooses its keys, ``index``: the index key of
-each token in a pool of the same page geometry, so that scoring a
-context reads 128 values a token and not the row), addressed through
-the page table the allocator fills. *Window* layers keep the last
-``window`` positions and the chunk being written: ``window`` is a
-ring of pages a slot (logical page j of slot s lives in ring page
-``s * ring + j % ring``), so a page that has slid out of the window
-is the page the next tokens are written to, the pool's size does not
-depend on ``max_context``, and a slot's ring comes and goes with the
-slot (nothing to allocate or release). :func:`window_view`,
-:func:`write_window_rows` and :func:`gather_rows` are their
-primitives.
+A row cache may hold layers of two kinds side by side, each with its
+own pool, row width and rule for which positions of a slot are live
+(two geometries under one page table and one allocator: a model's
+full layers may have 4 K/V heads and its sliding layers 8). *Context*
+layers keep every position: ``pages`` (and, for layers whose
+attention chooses its keys, ``index``: the index key of each token in
+a pool of the same page geometry, so that scoring a context reads 128
+values a token and not the row), addressed through the page table the
+allocator fills. *Window* layers keep the last ``window`` positions
+and the chunk being written: ``window`` is a ring of pages a slot
+(logical page j of slot s lives in ring page ``s * ring + j % ring``),
+so a page that has slid out of the window is the page the next tokens
+are written to, the pool's size does not depend on ``max_context``,
+and a slot's ring comes and goes with the slot (nothing to allocate
+or release). :func:`window_view`, :func:`window_rows`,
+:func:`window_table`, :func:`write_window_rows` and
+:func:`gather_rows` are their primitives.
 
 The host-side :class:`PageAllocator` is the bookkeeping half: a free
 list of physical page ids for the continuous batcher, which assigns
@@ -158,11 +163,13 @@ class PagedKVCache(NamedTuple):
         return self.k_pages.dtype
 
 
-class LatentPagedCache(NamedTuple):
-    """A paged cache whose rows are latent vectors: ``pages``
-    (num_layers, max_pages, page_size, W), W the latent width
-    rounded up to ``ROW_ALIGN``; ``page_table`` and ``seq_lens`` as
-    in :class:`PagedKVCache`, whose geometry properties it shares so
+class RowPagedCache(NamedTuple):
+    """A paged cache whose rows are one vector a token and layer,
+    laid out by the layer's attention part (a latent, or the K and V
+    of a few shared heads side by side): ``pages`` (num_layers,
+    max_pages, page_size, W), W the row's width rounded up to
+    ``ROW_ALIGN``; ``page_table`` and ``seq_lens`` as in
+    :class:`PagedKVCache`, whose geometry properties it shares so
     that the engine's allocator and programs take either."""
 
     pages: jnp.ndarray
@@ -234,25 +241,26 @@ def _pool_geometry(num_layers, max_slots, max_context, width,
     return shape, jnp.asarray(table.reshape(max_slots, pages_per_slot))
 
 
-def init_latent_cache(num_layers: int, max_slots: int,
-                      max_context: int, width: int,
-                      page_size: int = 16, max_pages: int = 0,
-                      dtype=jnp.float32, index_layers: int = 0,
-                      index_width: int = 0, window_layers: int = 0,
-                      window_width: int = 0, window_tokens: int = 0
-                      ) -> LatentPagedCache:
-    """Allocate a latent pool of ``width``-value rows (the KV latent
-    and the rotated key part side by side) for ``num_layers`` context
-    layers; geometry and table as :func:`init_cache`. With
+def init_row_cache(num_layers: int, max_slots: int,
+                   max_context: int, width: int,
+                   page_size: int = 16, max_pages: int = 0,
+                   dtype=jnp.float32, index_layers: int = 0,
+                   index_width: int = 0, window_layers: int = 0,
+                   window_width: int = 0, window_tokens: int = 0
+                   ) -> RowPagedCache:
+    """Allocate a pool of ``width``-value rows (whatever the
+    attention part caches of a token: `part.row_width`) for
+    ``num_layers`` context layers; geometry and table as
+    :func:`init_cache`. With
     ``index_layers`` an index pool of ``index_width`` beside it, and
     with ``window_layers`` the window pool: rows of ``window_width``
     in a ring a slot that holds ``window_tokens`` consecutive
     positions wherever they start (one page more than they fill)."""
     if jnp.dtype(dtype) == jnp.dtype(jnp.int8):
         raise ValueError(
-            "int8 pages for a latent cache (LatentPagedCache): the "
-            "int8 scales are per (token, head) and a latent row has "
-            "no heads; use f32 or bf16")
+            "int8 pages for a row cache (RowPagedCache; a latent cache "
+            "among them): the int8 scales are per (token, head) and "
+            "the pool does not know a row's heads; use f32 or bf16")
     shape, table = _pool_geometry(num_layers, max_slots, max_context,
                                   width, page_size, max_pages)
     index = window = None
@@ -264,7 +272,7 @@ def init_latent_cache(num_layers: int, max_slots: int,
         window = jnp.zeros(
             (int(window_layers), int(max_slots) * ring, shape[2],
              -(-int(window_width) // ROW_ALIGN) * ROW_ALIGN), dtype)
-    return LatentPagedCache(
+    return RowPagedCache(
         pages=jnp.zeros(shape, dtype), page_table=table,
         seq_lens=jnp.zeros((int(max_slots),), jnp.int32),
         index=index, window=window)
@@ -445,21 +453,20 @@ def _put_rows(pages, phys, offset, rows):
         rows, mode="drop")
 
 
-def _latent_rows(pages, x):
-    """Latent rows ``(…, width)`` as the pool stores them: its dtype,
+def _padded_rows(pages, x):
+    """Rows ``(…, width)`` as the pool stores them: its dtype,
     zero-padded to its row."""
     pad = [(0, 0)] * (x.ndim - 1) + [(0, pages.shape[-1] - x.shape[-1])]
     return jnp.pad(x.astype(pages.dtype), pad)
 
 
-def latent_decode_view(cache: LatentPagedCache, layer, new,
-                       active=None):
-    """:func:`decode_view` for a latent pool. ``new``: (S, width),
-    the new token's latent row of every slot. Returns ``(ctx, row)``:
+def row_decode_view(cache: RowPagedCache, layer, new, active=None):
+    """:func:`decode_view` for a row pool. ``new``: (S, width),
+    the new token's row of every slot. Returns ``(ctx, row)``:
     the layer's (S, T, W) context in the pool's dtype with each
     writing slot's row at position ``seq_lens[s]``, and the (S, W)
-    row :func:`append_latent_rows` writes."""
-    row = _latent_rows(cache.pages, new)
+    row :func:`append_pool_rows` writes."""
+    row = _padded_rows(cache.pages, new)
     ctx = _lay_rows(
         gather_layer(cache.pages, cache.page_table, cache.max_context,
                      layer),
@@ -468,9 +475,9 @@ def latent_decode_view(cache: LatentPagedCache, layer, new,
 
 
 @jax.named_scope("zoo:kv_cache/append")
-def append_latent_rows(cache: LatentPagedCache, rows, active=None,
-                       index_rows=None, window_rows=None):
-    """:func:`append_rows` for a latent pool: ``rows`` (L, S, W),
+def append_pool_rows(cache: RowPagedCache, rows, active=None,
+                     index_rows=None, window_rows=None):
+    """:func:`append_rows` for a row cache: ``rows`` (L, S, W),
     every context layer's new row, scattered in place at
     ``seq_lens[s]``; ``index_rows`` and ``window_rows`` likewise into
     the index and the window pool."""
@@ -492,7 +499,7 @@ def append_latent_rows(cache: LatentPagedCache, rows, active=None,
     return cache
 
 
-def _window_coords(cache: LatentPagedCache, slots, positions, active):
+def _window_coords(cache: RowPagedCache, slots, positions, active):
     """(ring page, in-page offset) of ``positions`` (A, C) of slots
     ``slots`` (A,) in the window pool; inactive ones out of range."""
     ring, page = cache.window_ring, cache.page_size
@@ -502,7 +509,7 @@ def _window_coords(cache: LatentPagedCache, slots, positions, active):
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
-def write_window_rows(cache: LatentPagedCache, slots, positions,
+def write_window_rows(cache: RowPagedCache, slots, positions,
                       active, rows):
     """The window pool with ``rows`` (L, A, C, width) written at
     ``positions`` (A, C) of slots ``slots`` (A,) where ``active``.
@@ -511,11 +518,11 @@ def write_window_rows(cache: LatentPagedCache, slots, positions,
     or two of them would fall on one row."""
     phys, offset = _window_coords(cache, slots, positions, active)
     return _put_rows(cache.window, phys, offset,
-                     _latent_rows(cache.window, rows))
+                     _padded_rows(cache.window, rows))
 
 
 @jax.named_scope("zoo:kv_cache/gather")
-def window_view(cache: LatentPagedCache, layer, slots, first_page,
+def window_view(cache: RowPagedCache, layer, slots, first_page,
                 n_pages: int):
     """``n_pages`` consecutive logical pages of window layer
     ``layer`` from ``first_page`` (A,) on, for slots ``slots`` (A,):
@@ -531,6 +538,37 @@ def window_view(cache: LatentPagedCache, layer, slots, first_page,
     positions = (logical[:, :, None] * page + jnp.arange(
         page, dtype=jnp.int32)).reshape(len(slots), n_pages * page)
     return picked.reshape(len(slots), n_pages * page, -1), positions
+
+
+@jax.named_scope("zoo:kv_cache/gather")
+def window_rows(cache: RowPagedCache, layer, slots, positions):
+    """Single rows of window layer ``layer`` out of the ring:
+    ``positions`` (A, K) of slots ``slots`` (A,) -> (A, K, W). A row
+    holds its position only if that position was written within the
+    last turn of the ring and is not negative: the caller's mask owns
+    validity."""
+    ring, page = cache.window_ring, cache.page_size
+    at = jnp.maximum(positions, 0)
+    return cache.window.at[
+        layer, slots[:, None] * ring + (at // page) % ring,
+        at % page].get(mode="clip")
+
+
+def window_table(cache: RowPagedCache, window: int):
+    """What a decode step's sliding layers read of the ring, as a
+    page table: ``(table (S, n), lens (S,), first (S,))``. Row s
+    lists the ring pages that hold slot s's last ``window - 1``
+    cached positions, in order; of the positions of that row, counted
+    from its first page's first, ``[first[s], lens[s])`` are the
+    window (the new token's own position is the caller's to add)."""
+    ring, page = cache.window_ring, cache.page_size
+    n = max(window - 2, 0) // page + 2
+    low = jnp.maximum(cache.seq_lens - (window - 1), 0)
+    page0 = low // page
+    slots = jnp.arange(cache.max_slots, dtype=jnp.int32)
+    table = slots[:, None] * ring + (
+        page0[:, None] + jnp.arange(n, dtype=jnp.int32)[None]) % ring
+    return table, cache.seq_lens - page0 * page, low - page0 * page
 
 
 @jax.named_scope("zoo:kv_cache/gather")
@@ -555,9 +593,9 @@ def prompt_seq_lens(seq_lens, slots, prompt_lens):
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
-def write_latent_prompt(pages, page_table, prompt_lens, rows,
-                        start=None):
-    """:func:`write_prompt_layer` for a latent pool: ``rows``
+def write_prompt_rows(pages, page_table, prompt_lens, rows,
+                      start=None):
+    """:func:`write_prompt_layer` for a row pool: ``rows``
     (L, A, T, width) hold every layer's (right-padded) prompt rows,
     ``page_table`` (A, pages_per_slot) the table row of each;
     positions past ``prompt_lens[a]`` are dropped. With ``start``
@@ -574,7 +612,7 @@ def write_latent_prompt(pages, page_table, prompt_lens, rows,
         positions < page_table.shape[1] * page_size)
     phys, offset = _scatter_coords(page_table, prompt_lens,
                                    positions, page_size, active)
-    return _put_rows(pages, phys, offset, _latent_rows(pages, rows))
+    return _put_rows(pages, phys, offset, _padded_rows(pages, rows))
 
 
 @jax.named_scope("zoo:kv_cache/write_prompt")
@@ -663,14 +701,15 @@ def length_mask(seq_lens, t: int):
 # once and reuses it for every handoff regardless of sequence length.
 
 
-def refuse_latent_handoff(cache):
+def refuse_row_handoff(cache):
     """Raise, by name, for a cache the handoff codec does not
     carry."""
-    if isinstance(cache, LatentPagedCache):
+    if isinstance(cache, RowPagedCache):
         raise TypeError(
             "the KV-page handoff carries K and V pools (blob version "
-            f"{HANDOFF_VERSION}); a latent cache (LatentPagedCache) "
-            "is not carried: serve it with role='both'")
+            f"{HANDOFF_VERSION}); a row cache (RowPagedCache; a "
+            "latent cache among them) is not carried: serve it with "
+            "role='both'")
 
 
 def gather_slot_pages(cache: PagedKVCache, page_ids):
@@ -682,7 +721,7 @@ def gather_slot_pages(cache: PagedKVCache, page_ids):
     ``(k, v, k_scales, v_scales)`` with k/v shaped
     ``(num_layers, P, page_size, W)`` and scales
     ``(num_layers, P, page_size, heads)`` (None for float pools)."""
-    refuse_latent_handoff(cache)
+    refuse_row_handoff(cache)
     k = jnp.take(cache.k_pages, page_ids, axis=1, mode="clip")
     v = jnp.take(cache.v_pages, page_ids, axis=1, mode="clip")
     if cache.k_scales is None:
@@ -706,7 +745,7 @@ def scatter_slot_pages(cache: PagedKVCache, page_ids, active, slot,
     int8 pools) are the :func:`gather_slot_pages` outputs, zero-padded
     to width P. Returns the updated cache; the caller owns writing the
     destination page-table row (host-side bookkeeping)."""
-    refuse_latent_handoff(cache)
+    refuse_row_handoff(cache)
     max_pages = cache.k_pages.shape[1]
     phys = jnp.where(active, page_ids, max_pages + 2 ** 20)
     k_pages = cache.k_pages.at[:, phys].set(k_rows, mode="drop")

@@ -32,8 +32,9 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.noise import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
     MoE, GatedMLP, GroupLimitedMoE)
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder import (
-    YarnRope, LatentAttention, SparseIndexer, PatternDecoder,
-    deepseek_v2_decoder, dots3_note_decoder)
+    YarnRope, LatentAttention, GroupedQueryAttention, SparseIndexer,
+    PatternDecoder, deepseek_v2_decoder, dots3_note_decoder,
+    mimo_v2_flash_decoder)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
     MultiHeadAttention, TransformerLayer, BERT)
 from analytics_zoo_tpu.pipeline.api.keras.layers.elementwise import (
@@ -85,7 +86,8 @@ __all__ = [
     "MultiHeadAttention", "TransformerLayer", "MoE", "BERT",
     "GatedMLP", "GroupLimitedMoE", "YarnRope", "LatentAttention",
     "SparseIndexer", "PatternDecoder", "deepseek_v2_decoder",
-    "dots3_note_decoder",
+    "dots3_note_decoder", "mimo_v2_flash_decoder",
+    "GroupedQueryAttention",
     # elementwise / tensor utilities
     "AddConstant", "MulConstant", "CAdd", "CMul", "Mul", "Scale", "Power",
     "Negative", "Exp", "Log", "Sqrt", "Square", "Identity",

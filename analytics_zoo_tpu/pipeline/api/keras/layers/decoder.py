@@ -8,6 +8,8 @@ the attention part and the feed-forward part given PER LAYER (the
 *pattern*: DeepSeek-V2 is one attention for all layers, one dense
 SwiGLU layer, then expert layers; dots3-note is full layers whose
 attention chooses its keys among sliding layers of other widths), a
+MiMo-V2 is full grouped-query layers among sliding ones with twice
+the K/V heads and a sink), a
 final norm and an untied head. `prefill`, `decode_step`,
 `forward_chunk` and `generate` are written once over the pattern, and
 the surface is the one `GenerationEngine` drives
@@ -19,7 +21,11 @@ rotate-half convention), :class:`LatentAttention` (multi-head latent
 attention: low-rank queries, one KV latent a token shared by all
 heads; expanded per-head K/V for prompts and chunks, the absorbed
 form against the latent page pool for a decode step; optionally
-windowed, gated, or sparse through a :class:`SparseIndexer`).
+windowed, gated, or sparse through a :class:`SparseIndexer`),
+:class:`GroupedQueryAttention` (a few K/V heads shared by groups of
+query heads, keys wider than values, a partial rotary, optionally a
+window and a learned sink; a step reads the live pages of its pool
+through the Pallas kernel `zoo_paged_gqa_decode`).
 Feed-forward parts are `layers.moe.GatedMLP` and
 `layers.moe.GroupLimitedMoE`.
 
@@ -41,7 +47,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from analytics_zoo_tpu.ops.attention import (
-    dot_product_attention, index_scores, latent_decode_attention,
+    banded_attention, dot_product_attention, gqa_decode_attention,
+    grouped_attention, index_scores, latent_decode_attention,
     masked_attention, mla_decode_attention, resolve_attention_impl,
     topk_mask)
 from analytics_zoo_tpu.pipeline.api.keras.engine import (KerasLayer,
@@ -186,6 +193,9 @@ class LatentAttention:
     a head, on the attention output before ``o`` (Gated Attention,
     arXiv:2505.06708). ``lora_rescale``: the normed latents times
     ``sqrt(hidden / rank)`` (LongCat-Flash's scale correction)."""
+
+    # a window part's chunk is handed the whole ring and masks it
+    banded = False
 
     def __init__(self, hidden_size: int, n_head: int,
                  q_lora_rank: int, kv_lora_rank: int,
@@ -375,7 +385,7 @@ class LatentAttention:
         if self.window or self.indexer:
             cache, at, active = view.cache, view.at, view.active
         if self.window:
-            rows["row"] = new = kvc._latent_rows(cache.window, row)
+            rows["row"] = new = kvc._padded_rows(cache.window, row)
             with jax.named_scope("zoo:decode/swa_attention"):
                 first = jnp.maximum(
                     cache.seq_lens - self.window + 1, 0) // \
@@ -395,8 +405,8 @@ class LatentAttention:
                         axis=1), self.scale)
         elif self.indexer:
             q_i, w_i, k_i = self._index_parts(p, x, c_q, positions)
-            rows["row"] = new = kvc._latent_rows(cache.pages, row)
-            rows["index"] = k_new = kvc._latent_rows(cache.index, k_i)
+            rows["row"] = new = kvc._padded_rows(cache.pages, row)
+            rows["index"] = k_new = kvc._padded_rows(cache.index, k_i)
             writes = kvc._decode_writes(cache, active)
             t = cache.max_context
             with jax.named_scope("zoo:decode/dsa_index"):
@@ -514,20 +524,248 @@ class LatentAttention:
         return out, rows
 
 
+class GroupedQueryAttention:
+    """Grouped-query attention (Ainslie et al. 2023) as a part of
+    `PatternDecoder`: ``n_head`` query heads over ``n_kv_head`` K/V
+    heads (query head j reads K/V head ``j // (n_head / n_kv_head)``),
+    queries and keys ``head_dim`` wide, values ``v_head_dim`` (MiMo-V2
+    has 192 and 128). ``rope`` rotates the first ``rope.dim`` values
+    of every query and key head (rotate-half) and passes the rest
+    (a partial rotary); values are multiplied by ``value_scale``
+    before the product.
+
+    The cache row of a token is ``[k of the G heads | v of the G
+    heads]``, :attr:`row_width` = ``n_kv_head * (head_dim +
+    v_head_dim)`` values: a pool of rows, not a K and a V pool, so a
+    model whose full and sliding layers differ in K/V heads keeps
+    both kinds under one page table.
+
+    Which keys a query sees is the part's *kind*, as for
+    :class:`LatentAttention`: ``window = W`` the last ``W`` positions,
+    its own among them (rows in the cache's ring), else every key
+    before it. ``sink``: a learned logit a query head in the
+    softmax's denominator, with no value (gpt-oss's attention sink;
+    MiMo-V2's ``add_swa_attention_sink_bias``).
+
+    :meth:`prefill` is the causal product over a whole prompt (the
+    flash kernel where `ops.attention.dot_product_attention` routes
+    to it, K/V heads repeated); :meth:`chunk` a chunk against what
+    the cache holds (`grouped_attention`, or for a window the band
+    `banded_attention` bounds by the window); :meth:`decode` one
+    token a slot from the live pages (`gqa_decode_attention`)."""
+
+    indexer, index_width = None, 0
+    # a window part's chunk reads the ``window`` positions before it
+    # (`PatternDecoder.forward_chunk`), not the ring
+    banded = True
+
+    def __init__(self, hidden_size: int, n_head: int, n_kv_head: int,
+                 head_dim: int, v_head_dim: int, rope: YarnRope,
+                 value_scale: float = 1.0, window: int = 0,
+                 sink: bool = False,
+                 attention_impl: Optional[str] = None):
+        if n_head % n_kv_head:
+            raise ValueError(f"{n_head} query heads do not divide "
+                             f"over {n_kv_head} K/V heads")
+        if rope.dim > head_dim or rope.dim % 2:
+            raise ValueError(f"rotary dims {rope.dim} of a head of "
+                             f"{head_dim}")
+        self.hidden_size, self.n_head = int(hidden_size), int(n_head)
+        self.n_kv, self.rep = int(n_kv_head), n_head // n_kv_head
+        self.k_dim, self.v_dim = int(head_dim), int(v_head_dim)
+        self.rope, self.value_scale = rope, float(value_scale)
+        self.window, self.sink = int(window), bool(sink)
+        self.attention_impl = attention_impl
+        self.scale = self.k_dim ** -0.5
+        self.k_width = self.n_kv * self.k_dim
+        self.row_width = self.n_kv * (self.k_dim + self.v_dim)
+        self.kind = "window" if self.window else "context"
+        self.scope = "swa_attention" if self.window else \
+            "gqa_attention"
+        self.plain = not (self.window or self.sink)
+
+    def build(self, rng, stddev: float) -> dict:
+        h, nh, g = self.hidden_size, self.n_head, self.n_kv
+        k = jax.random.split(rng, 5)
+        out = {"q": _normal(k[0], (h, nh * self.k_dim), stddev),
+               "k": _normal(k[1], (h, g * self.k_dim), stddev),
+               "v": _normal(k[2], (h, g * self.v_dim), stddev),
+               "o": _normal(k[3], (nh * self.v_dim, h), stddev)}
+        if self.sink:
+            out["sink"] = jnp.zeros((nh,), jnp.float32)
+        return out
+
+    def _rotate(self, x, positions):
+        """The rotary part on the first ``rope.dim`` values of each
+        head of ``x`` (..., heads, head_dim) at ``positions`` (...)."""
+        d = self.rope.dim
+        return jnp.concatenate(
+            [self.rope(x[..., :d], positions[..., None]), x[..., d:]],
+            axis=-1)
+
+    def _project(self, p, x, positions):
+        """``x`` (..., hidden) at ``positions`` (...): the queries
+        (..., G, R, D), rotated, and the cache row (..., row_width)
+        = rotated keys and scaled values, heads side by side."""
+        dt, lead = x.dtype, x.shape[:-1]
+        q = self._rotate((x @ p["q"].astype(dt)).reshape(
+            lead + (self.n_head, self.k_dim)), positions)
+        k = self._rotate((x @ p["k"].astype(dt)).reshape(
+            lead + (self.n_kv, self.k_dim)), positions)
+        v = x @ p["v"].astype(dt)
+        if self.value_scale != 1.0:
+            v = (v * self.value_scale).astype(dt)
+        row = jnp.concatenate(
+            [k.reshape(lead + (self.k_width,)), v], axis=-1)
+        return q.reshape(lead + (self.n_kv, self.rep, self.k_dim)), row
+
+    def _split(self, rows):
+        """Rows (..., >= row_width) as ``(K (..., G, D), V (..., G,
+        Dv))``."""
+        lead = rows.shape[:-1]
+        return (rows[..., :self.k_width].reshape(
+                    lead + (self.n_kv, self.k_dim)),
+                rows[..., self.k_width:self.row_width].reshape(
+                    lead + (self.n_kv, self.v_dim)))
+
+    def _sink(self, p):
+        return p["sink"].reshape(self.n_kv, self.rep) \
+            if self.sink else None
+
+    def _out(self, p, o):
+        """Head outputs (..., G, R, Dv) through ``o``."""
+        return o.reshape(o.shape[:-3] + (self.n_head * self.v_dim,)) \
+            @ p["o"].astype(o.dtype)
+
+    def prefill(self, p, x, impl=None):
+        """Causal self-attention of (S, T, hidden) prompts at
+        positions 0..T-1, for a part that sees every key and has no
+        sink (the others go through :meth:`chunk`). Returns ``(out
+        (S, T, hidden), rows (S, T, row_width))``."""
+        s, t, _ = x.shape
+        pos = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32)[None],
+                               (s, t))
+        q, rows = self._project(p, x, pos)
+        k, v = self._split(rows)
+        # one head count and one head size for the attention kernels:
+        # K/V heads repeated, values zero-padded to the keys' width
+        k = jnp.repeat(k, self.rep, axis=2)
+        v = jnp.pad(jnp.repeat(v, self.rep, axis=2), [(0, 0)] * 3 +
+                    [(0, max(0, self.k_dim - self.v_dim))])
+        with jax.named_scope(f"zoo:prefill/{self.scope}"):
+            a = dot_product_attention(
+                q.reshape(s, t, self.n_head, self.k_dim), k, v,
+                causal=True, scale=self.scale, impl=impl)
+        a = a[..., :self.v_dim].reshape(
+            s, t, self.n_kv, self.rep, self.v_dim)
+        return self._out(p, a), rows
+
+    def chunk(self, p, x, q_pos, valid, cached=None, phase="prefill"):
+        """A chunk of new tokens a row, as
+        :meth:`LatentAttention.chunk`: ``x`` (A, C, hidden) at
+        ``q_pos`` (A, C) against ``cached`` = ``(rows (A, T, W),
+        positions (A, T), valid (A, T), None)`` and the chunk's own
+        rows in flight. For a window part ``cached`` holds the
+        ``window`` positions before the chunk (None: nothing before
+        it) and the product is banded. Returns ``(out, {"row": rows
+        (A, C, row_width)})``."""
+        dt = x.dtype
+        q, new = self._project(p, x, q_pos)
+        if self.window:
+            o = self._banded(q, new, q_pos, valid, cached, phase,
+                             self._sink(p))
+            return self._out(p, o), {"row": new}
+        rows, k_pos, k_valid = new, q_pos, valid
+        if cached is not None:
+            rows = jnp.concatenate(
+                [cached[0][..., :self.row_width].astype(dt), new],
+                axis=1)
+            k_pos = jnp.concatenate([cached[1], q_pos], axis=1)
+            k_valid = jnp.concatenate([cached[2], valid], axis=1)
+        mask = jnp.logical_and(
+            k_valid[:, None, :],
+            k_pos[:, None, :] <= q_pos[:, :, None])
+        k, v = self._split(rows)
+        with jax.named_scope(f"zoo:{phase}/{self.scope}"):
+            o = grouped_attention(q, k, v, mask, self.scale,
+                                  sink=self._sink(p))
+        return self._out(p, o), {"row": new}
+
+    def _banded(self, q, new, q_pos, valid, cached, phase, sink):
+        """The window part's chunk product: a chunk of at least a
+        window is padded to whole blocks of ``window`` queries behind
+        the one block of cached positions and multiplied band by band
+        (`banded_attention`); a shorter one is one block with them."""
+        a, c = q_pos.shape
+        dt, w = q.dtype, self.window
+        if cached is None:
+            n = w if c >= w else 0
+            before = (jnp.zeros((a, n, self.row_width), dt),
+                      jnp.zeros((a, n), jnp.int32),
+                      jnp.zeros((a, n), jnp.bool_))
+        else:
+            before = (cached[0][..., :self.row_width].astype(dt),
+                      cached[1], cached[2])
+        pad = -c % w if c >= w else 0
+        tail = lambda x, fill: jnp.pad(
+            x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2),
+            constant_values=fill)
+        k, v = self._split(jnp.concatenate(
+            [before[0], tail(new, 0)], axis=1))
+        k_pos = jnp.concatenate([before[1], tail(q_pos, 0)], axis=1)
+        k_ok = jnp.concatenate([before[2], tail(valid, False)], axis=1)
+        with jax.named_scope(f"zoo:{phase}/{self.scope}"):
+            if c >= w:
+                return banded_attention(
+                    tail(q, 0), k, v, tail(q_pos, 0), k_pos, k_ok, w,
+                    self.scale, sink=sink)[:, :c]
+            back = q_pos[:, :, None] - k_pos[:, None, :]
+            mask = jnp.logical_and(
+                k_ok[:, None, :],
+                jnp.logical_and(back >= 0, back < w))
+            return grouped_attention(q, k, v, mask, self.scale,
+                                     sink=sink)
+
+    def decode(self, p, x, positions, view, lens_after):
+        """One new token a slot, as :meth:`LatentAttention.decode`:
+        the queries attend from the pool's live pages (a full layer:
+        the slot's pages of the context pool; a sliding layer: the
+        ring pages of its last ``window - 1`` positions) and to the
+        token's own row. Returns ``(out (S, hidden), pool row)``."""
+        from analytics_zoo_tpu.ops import kv_cache as kvc
+        cache, at, active = view.cache, view.at, view.active
+        q, row = self._project(p, x, positions)
+        writes = kvc._decode_writes(cache, active)
+        if self.window:
+            pool = cache.window
+            table, lens, first = kvc.window_table(cache, self.window)
+        else:
+            pool, table, lens = cache.pages, cache.page_table, \
+                cache.seq_lens
+            first = jnp.zeros_like(lens)
+        new = kvc._padded_rows(pool, row)
+        with jax.named_scope(f"zoo:decode/{self.scope}"):
+            o = gqa_decode_attention(
+                q, new, pool, at[0], table, lens, first, writes,
+                v_dim=self.v_dim, scale=self.scale,
+                sink=self._sink(p), impl=self.attention_impl)
+        return self._out(p, o), new
+
+
 class _DecodeView:
     """What one layer's attention reads of the cache in a decode
     step: called with the new token's row, the gathered context of a
-    layer that sees every key (`ops.kv_cache.latent_decode_view`);
-    ``cache``, ``at`` and ``active`` for the kinds that gather for
-    themselves."""
+    latent layer that sees every key (`ops.kv_cache.row_decode_view`);
+    ``cache``, ``at`` and ``active`` for the parts that read the
+    pools themselves (a window, an indexer, grouped-query heads)."""
 
     def __init__(self, cache, at, active):
         self.cache, self.at, self.active = cache, at, active
 
     def __call__(self, row):
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        return kvc.latent_decode_view(self.cache, self.at[0], row,
-                                      active=self.active)
+        return kvc.row_decode_view(self.cache, self.at[0], row,
+                                   active=self.active)
 
 
 # per call, as int32: over the layers whose attention chooses its
@@ -557,9 +795,10 @@ def _record_attention(counts):
 
 
 class PatternDecoder(KerasLayer):
-    """Pre-norm decoder over a layer pattern: ``attention`` (a
-    :class:`LatentAttention` for every layer, or one a layer: full
-    and sliding layers side by side) and ``feed_forward[i]`` (a
+    """Pre-norm decoder over a layer pattern: ``attention`` (one
+    part, a :class:`LatentAttention` or a
+    :class:`GroupedQueryAttention`, for every layer, or one a layer:
+    full and sliding layers side by side) and ``feed_forward[i]`` (a
     `GatedMLP` or a `GroupLimitedMoE`) in layer i, RMSNorm, a final
     norm and an untied head over ``vocab`` rows. ``seq_len`` is the
     most positions the model declares (there is no position table).
@@ -567,7 +806,8 @@ class PatternDecoder(KerasLayer):
     Input (seq_len,) int token ids; ``call`` returns logits
     (B, T, vocab). The decode surface is `TransformerLayer`'s,
     ``forward_chunk`` included. The cache is one
-    `ops.kv_cache.LatentPagedCache`: the layers that keep their
+    `ops.kv_cache.RowPagedCache` of the parts' rows (``row_width``
+    values a token, whatever they hold): the layers that keep their
     whole context share its page pool (and an index pool, if their
     attention chooses its keys), the window layers its ring of
     ``window - 1 + max_chunk`` positions a slot, ``max_chunk`` being
@@ -704,13 +944,13 @@ class PatternDecoder(KerasLayer):
 
     def _prompt(self, params, token_ids, valid):
         """(hidden (S, T, hidden), rows: one dict a layer of
-        (S, T, width) arrays) of right-padded prompts; ``valid``
-        (S, T) marks real tokens."""
+        (S, T, width) arrays, the feed-forward parts' counts) of
+        right-padded prompts; ``valid`` (S, T) marks real tokens."""
         x = jnp.take(params["tok_embed"],
                      token_ids.astype(jnp.int32), axis=0)
         pos = jnp.broadcast_to(jnp.arange(
             x.shape[1], dtype=jnp.int32)[None], x.shape[:2])
-        rows = []
+        rows, counts = [], []
         for p, att, ffn in zip(params["layers"], self.attentions,
                                self.feed_forward):
             with jax.named_scope("zoo:prefill/layer"):
@@ -721,20 +961,23 @@ class PatternDecoder(KerasLayer):
                     r = {"row": r}
                 else:
                     a, r = att.chunk(p["attn"], y, pos, valid)
-                x, _ = self._ffn(ffn, p, x + a, valid, "prefill")
+                x, cnt = self._ffn(ffn, p, x + a, valid, "prefill")
                 rows.append(r)
-        return x, rows
+                if cnt is not None:
+                    counts.append(cnt)
+        return x, rows, counts
 
     def call(self, params, x, *, training=False, rng=None):
         del training, rng
-        h, _ = self._prompt(params, x, jnp.ones(x.shape, jnp.bool_))
+        h, *_ = self._prompt(params, x, jnp.ones(x.shape, jnp.bool_))
         return self._logits(params, h)
 
     # -- decode surface ------------------------------------------------
     def init_kv_cache(self, max_slots: int, max_context: int,
                       page_size: int = 16, dtype=None,
                       max_chunk: int = 1):
-        """A fresh latent cache sized for this stack: the page pool
+        """A fresh row cache sized for this stack from the parts'
+        ``row_width``: the page pool
         of the layers that keep their context, the index pool, and
         the window layers' ring, which holds a window and the
         ``max_chunk`` tokens one :meth:`forward_chunk` call may write
@@ -742,7 +985,7 @@ class PatternDecoder(KerasLayer):
         from analytics_zoo_tpu.ops import kv_cache as kvc
         by_kind = {a.kind: a for a in self.attentions}
         n, win = self._pool_layers, by_kind.get("window")
-        return kvc.init_latent_cache(
+        return kvc.init_row_cache(
             n["context"], int(max_slots), int(max_context),
             by_kind["context"].row_width if "context" in by_kind
             else 1, page_size=int(page_size),
@@ -770,11 +1013,11 @@ class PatternDecoder(KerasLayer):
         table = cache.page_table[slots]
         ctx = self._stacked(rows, "row", "context")
         if ctx is not None:
-            cache = cache._replace(pages=kvc.write_latent_prompt(
+            cache = cache._replace(pages=kvc.write_prompt_rows(
                 cache.pages, table, total, ctx, start=starts))
         idx = self._stacked(rows, "index")
         if idx is not None:
-            cache = cache._replace(index=kvc.write_latent_prompt(
+            cache = cache._replace(index=kvc.write_prompt_rows(
                 cache.index, table, total, idx, start=starts))
         win = self._stacked(rows, "row", "window")
         if win is not None:
@@ -787,11 +1030,13 @@ class PatternDecoder(KerasLayer):
         return cache
 
     def prefill(self, params, cache, token_ids, prompt_lens,
-                slots=None):
-        """`TransformerLayer.prefill`'s contract over the latent
-        pool: the rows are the prompts being admitted, ``slots``
+                slots=None, stats: bool = False):
+        """`TransformerLayer.prefill`'s contract over the row
+        pools: the rows are the prompts being admitted, ``slots``
         says which cache slot each is, every other slot is
-        untouched, and no padding reaches an expert."""
+        untouched, and no padding reaches an expert. With ``stats``
+        also the sums of ``step_counters``, as :meth:`forward_chunk`
+        counts a chunk from position 0."""
         from analytics_zoo_tpu.ops import kv_cache as kvc
         a, t = token_ids.shape
         prompt_lens = jnp.asarray(prompt_lens, jnp.int32)
@@ -800,7 +1045,7 @@ class PatternDecoder(KerasLayer):
         q_pos = jnp.broadcast_to(
             jnp.arange(t, dtype=jnp.int32)[None, :], (a, t))
         valid = q_pos < prompt_lens[:, None]
-        final, rows = self._prompt(params, token_ids, valid)
+        final, rows, counts = self._prompt(params, token_ids, valid)
         cache = self._write_chunk(
             cache, rows, slots, None, prompt_lens, q_pos,
             valid)._replace(seq_lens=kvc.prompt_seq_lens(
@@ -808,7 +1053,10 @@ class PatternDecoder(KerasLayer):
         with jax.named_scope("zoo:prefill/lm_head"):
             logits = self._logits(params, final[
                 jnp.arange(a), jnp.maximum(prompt_lens - 1, 0)])
-        return cache, logits
+        if not stats:
+            return cache, logits
+        return cache, logits, self._counts(
+            counts, jnp.zeros_like(prompt_lens), prompt_lens, cache)
 
     def _ctx_ladder(self, cache, chunk: int) -> "tuple[int, ...]":
         """The cached-context lengths a chunk program of ``chunk``
@@ -828,7 +1076,7 @@ class PatternDecoder(KerasLayer):
                       all_logits: bool = False, slots=None,
                       stats: bool = False):
         """`TransformerLayer.forward_chunk`'s contract over the
-        latent cache: row a holds the next ``n_new[a]`` tokens of
+        row cache: row a holds the next ``n_new[a]`` tokens of
         slot ``slots[a]`` (every slot in order when None) from
         position ``starts[a]`` on. Each layer attends from the chunk's
         queries to the rows the cache holds before ``starts`` and to
@@ -861,6 +1109,13 @@ class PatternDecoder(KerasLayer):
 
         def cached(att, at, t_ctx):
             """What the cache holds before the chunk, for ``att``."""
+            if att.kind == "window" and att.banded:
+                # the window before the chunk, row by row: a banded
+                # part multiplies no more of the ring
+                pos = starts[:, None] - att.window + jnp.arange(
+                    att.window, dtype=jnp.int32)[None]
+                return (kvc.window_rows(cache, at[0], slots, pos),
+                        pos, pos >= 0, None)
             if att.kind == "window":
                 first = jnp.maximum(starts - att.window + 1, 0) // \
                     cache.page_size
@@ -943,7 +1198,7 @@ class PatternDecoder(KerasLayer):
         tally = self._counts(counts, cache.seq_lens,
                              active.astype(jnp.int32), cache) \
             if stats else None
-        cache = kvc.append_latent_rows(
+        cache = kvc.append_pool_rows(
             cache, self._stacked(rows, "row", "context"),
             active=active, index_rows=self._stacked(rows, "index"),
             window_rows=self._stacked(rows, "row", "window")
@@ -956,6 +1211,43 @@ class PatternDecoder(KerasLayer):
 
     # written against init_kv_cache / prefill / decode_step alone
     generate = TransformerLayer.generate
+
+
+def _feed_forward(config: dict, experts_held, **moe):
+    """``ffn(i)``, layer i's feed-forward part from the keys the
+    expert configurations share: a dense SwiGLU of
+    ``intermediate_size`` in the first ``first_k_dense_replace``
+    layers and wherever ``moe_layer_freq`` says (a stride, every
+    n-th layer an expert layer, or a list with one 0/1 a layer), a
+    `GroupLimitedMoE` elsewhere. A key the config holds as ``null``
+    (MiMo-V2's ``n_shared_experts`` and ``routed_scaling_factor``)
+    reads as its default. ``moe`` are further arguments of the expert
+    layers."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
+        GatedMLP, GroupLimitedMoE)
+    c = config
+    get = lambda key, default: default if c.get(key) is None \
+        else c[key]
+    h, freq = c["hidden_size"], get("moe_layer_freq", 1)
+
+    def dense(i):
+        if i < get("first_k_dense_replace", 0):
+            return True
+        return not freq[i] if isinstance(freq, (list, tuple)) \
+            else bool(i % freq)
+
+    def ffn(i):
+        if dense(i):
+            return GatedMLP(h, c["intermediate_size"])
+        return GroupLimitedMoE(
+            h, c["moe_intermediate_size"], c["n_routed_experts"],
+            c["num_experts_per_tok"], n_group=get("n_group", 1),
+            topk_group=get("topk_group", 1),
+            n_shared=get("n_shared_experts", 0),
+            routed_scaling=get("routed_scaling_factor", 1.0),
+            experts_held=experts_held, **moe)
+
+    return ffn
 
 
 def deepseek_v2_decoder(config: dict, *, n_layer: Optional[int] = None,
@@ -973,8 +1265,6 @@ def deepseek_v2_decoder(config: dict, *, n_layer: Optional[int] = None,
     ``experts_held = (first, count)`` of the ``n_routed_experts``
     the router scores (default all), ``vocab`` rows of the vocabulary
     (default ``vocab_size``). ``kwargs`` go to `PatternDecoder`."""
-    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
-        GatedMLP, GroupLimitedMoE)
     c = config
     sc = c.get("rope_scaling") or {}
     rope = YarnRope(
@@ -992,18 +1282,7 @@ def deepseek_v2_decoder(config: dict, *, n_layer: Optional[int] = None,
         c["kv_lora_rank"], c["qk_nope_head_dim"],
         c["qk_rope_head_dim"], c["v_head_dim"], rope, rms_eps=eps)
     n_layer = c["num_hidden_layers"] if n_layer is None else n_layer
-
-    def ffn(i):
-        if i < c.get("first_k_dense_replace", 0) or \
-                i % c.get("moe_layer_freq", 1):
-            return GatedMLP(h, c["intermediate_size"])
-        return GroupLimitedMoE(
-            h, c["moe_intermediate_size"], c["n_routed_experts"],
-            c["num_experts_per_tok"], n_group=c.get("n_group", 1),
-            topk_group=c.get("topk_group", 1),
-            n_shared=c.get("n_shared_experts", 0),
-            routed_scaling=c.get("routed_scaling_factor", 1.0),
-            experts_held=experts_held)
+    ffn = _feed_forward(c, experts_held)
 
     return PatternDecoder(
         c["vocab_size"] if vocab is None else vocab, h, attention,
@@ -1032,8 +1311,6 @@ def dots3_note_decoder(config: dict, *, n_layer: Optional[int] = None,
     ``n_layer``, ``experts_held`` and ``vocab`` state one chip's
     share, as for :func:`deepseek_v2_decoder`; ``kwargs`` go to
     `PatternDecoder`."""
-    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
-        GatedMLP, GroupLimitedMoE)
     c = config
     if c.get("rope_scaling"):
         raise ValueError("dots3_note: rope_scaling is null in the "
@@ -1059,24 +1336,71 @@ def dots3_note_decoder(config: dict, *, n_layer: Optional[int] = None,
         gate=gated("swa_attention_gate_type"), lora_rescale=rescale)
     n_layer = c["num_hidden_layers"] if n_layer is None else n_layer
     kinds = {"full_attention": full, "sliding_attention": sliding}
-
-    def ffn(i):
-        if i < c.get("first_k_dense_replace", 0) or \
-                i % c.get("moe_layer_freq", 1):
-            return GatedMLP(h, c["intermediate_size"])
-        return GroupLimitedMoE(
-            h, c["moe_intermediate_size"], c["n_routed_experts"],
-            c["num_experts_per_tok"], n_group=c.get("n_group", 1),
-            topk_group=c.get("topk_group", 1),
-            n_shared=c.get("n_shared_experts", 0),
-            routed_scaling=c.get("routed_scaling_factor", 1.0),
-            experts_held=experts_held,
-            scoring=c.get("scoring_func", "sigmoid"),
-            norm_topk=c.get("norm_topk_prob", True))
+    ffn = _feed_forward(c, experts_held,
+                        scoring=c.get("scoring_func", "sigmoid"),
+                        norm_topk=c.get("norm_topk_prob", True))
 
     return PatternDecoder(
         c["vocab_size"] if vocab is None else vocab, h,
         [kinds[c["layer_types"][i]] for i in range(n_layer)],
+        [ffn(i) for i in range(n_layer)],
+        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        initializer_range=c.get("initializer_range", 0.02), **kwargs)
+
+
+def mimo_v2_flash_decoder(config: dict, *, n_layer: Optional[int] = None,
+                          experts_held: "Optional[tuple]" = None,
+                          vocab: Optional[int] = None,
+                          **kwargs) -> PatternDecoder:
+    """A `PatternDecoder` from the keys of a ``config.json`` of
+    ``model_type`` ``mimo_v2_flash`` (the language model only): layer
+    i is full where ``hybrid_layer_pattern[i]`` is 0 and sliding
+    where it is 1. Both are grouped-query attention over
+    ``num_attention_heads`` query heads of ``head_dim`` with values
+    of ``v_head_dim`` times ``attention_value_scale`` and a rotary on
+    the first ``floor(partial_rotary_factor * head_dim)`` values
+    (rounded down to even); a full layer has ``num_key_value_heads``
+    K/V heads and the base ``rope_theta``, a sliding one
+    ``swa_num_key_value_heads``, ``swa_rope_theta``, a window of
+    ``sliding_window`` positions and, where
+    ``add_swa_attention_sink_bias``, a learned sink a head. A dense
+    SwiGLU where ``moe_layer_freq[i]`` is 0, else an ungrouped
+    sigmoid-scored expert layer with a selection bias
+    (``noaux_tc``), renormalised weights and no shared expert.
+
+    ``n_layer``, ``experts_held`` and ``vocab`` state one chip's
+    share, as for :func:`deepseek_v2_decoder`; ``kwargs`` go to
+    `PatternDecoder`."""
+    c = config
+    if (c.get("rope_scaling") or {}).get("rope_type", "default") \
+            != "default":
+        raise ValueError("mimo_v2_flash: no rope scaling is "
+                         "published; none is implemented")
+    h, eps = c["hidden_size"], c.get("layernorm_epsilon", 1e-5)
+    impl = kwargs.get("attention_impl")
+
+    def part(pre: str, theta: float, window: int, sink: bool):
+        d = c[pre + "head_dim"]
+        rotary = int(c.get("partial_rotary_factor", 1.0) * d) // 2 * 2
+        return GroupedQueryAttention(
+            h, c[pre + "num_attention_heads"],
+            c[pre + "num_key_value_heads"], d, c[pre + "v_head_dim"],
+            YarnRope(rotary, theta=theta),
+            value_scale=c.get("attention_value_scale") or 1.0,
+            window=window, sink=sink, attention_impl=impl)
+
+    kinds = {
+        0: part("", c["rope_theta"], 0,
+                bool(c.get("add_full_attention_sink_bias"))),
+        1: part("swa_", c["swa_rope_theta"], c["sliding_window"],
+                bool(c.get("add_swa_attention_sink_bias")))}
+    n_layer = c["num_hidden_layers"] if n_layer is None else n_layer
+    ffn = _feed_forward(c, experts_held,
+                        scoring=c.get("scoring_func", "sigmoid"),
+                        norm_topk=c.get("norm_topk_prob", True))
+    return PatternDecoder(
+        c["vocab_size"] if vocab is None else vocab, h,
+        [kinds[c["hybrid_layer_pattern"][i]] for i in range(n_layer)],
         [ffn(i) for i in range(n_layer)],
         seq_len=c["max_position_embeddings"], rms_eps=eps,
         initializer_range=c.get("initializer_range", 0.02), **kwargs)

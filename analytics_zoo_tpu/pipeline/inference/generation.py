@@ -148,10 +148,11 @@ class GenerationEngine:
     / generate`` and ``seq_len`` (the most positions it takes) /
     ``vocab`` attributes (duck-typed — any net with those methods
     serves: `TransformerLayer` with its K/V pools, `PatternDecoder`
-    with its latent pools). A net without ``forward_chunk`` serves
+    with its row pools). A net without ``forward_chunk`` serves
     whole-prompt prefill only: ``prefill_chunk > 0`` and
     ``spec_k > 0`` are refused for it here. A net that names
-    ``step_counters`` has ``decode_step(..., stats=True)`` and
+    ``step_counters`` has ``decode_step(..., stats=True)``,
+    ``prefill(..., stats=True)`` and
     ``forward_chunk(..., stats=True)`` return their counts, which
     come back in the tokens' fetch and go to its
     ``record_step_counts``. A ``drafter`` (same
@@ -243,7 +244,7 @@ class GenerationEngine:
             page_table=jax.numpy.asarray(self._table))
         self.allocator = kvc.PageAllocator(cache.num_pages)
         if role != "both":
-            kvc.refuse_latent_handoff(cache)
+            kvc.refuse_row_handoff(cache)
         self._slot_pages: "dict[int, list]" = {}
         self.free_slots = set(range(self.max_slots))
 
@@ -329,12 +330,16 @@ class GenerationEngine:
                     rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
-        cache, logits = self.net.prefill(params, cache, ids, plens,
-                                         slots)
+        counted = bool(getattr(self.net, "step_counters", ()))
+        cache, logits, *counts = self.net.prefill(
+            params, cache, ids, plens, slots,
+            **({"stats": True} if counted else {}))
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        return cache, nxt
+        # as a chunk's: the prompt's counts ride behind its token
+        return cache, jax.numpy.concatenate([nxt] + counts) \
+            if counts else nxt
 
     def _abstract(self, tree):
         import jax
@@ -773,6 +778,8 @@ class GenerationEngine:
         self.prefill_counts = (len(firsts), rows)
         out = []
         for slot, tok in zip(admitted, jax.device_get(firsts)):
+            if len(tok) > 1:
+                self.net.record_step_counts(tok[1:])
             self._last_tok[slot] = tok[0]
             out.append((slot, int(tok[0])))
         return out
@@ -975,7 +982,7 @@ class GenerationEngine:
         resume decode token-exactly with NO forward pass."""
         import jax
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        kvc.refuse_latent_handoff(self.cache)
+        kvc.refuse_row_handoff(self.cache)
         if slot in self._pending_prompts:
             raise ValueError(
                 f"slot {slot} is still mid-chunked-prefill")
@@ -1010,7 +1017,7 @@ class GenerationEngine:
 
     def _check_handoff_blob(self, blob: dict):
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        kvc.refuse_latent_handoff(self.cache)
+        kvc.refuse_row_handoff(self.cache)
         if int(blob.get("version", -1)) != kvc.HANDOFF_VERSION:
             raise ValueError(
                 f"handoff version {blob.get('version')!r} != "

@@ -14,7 +14,7 @@ against a plain reference:
                then one Estimator step at T=1024 through the flash
                kernel, forward and backward
   4. kernels   every Pallas entry point (the flash attention family,
-               the paged decode kernel)
+               the two paged decode kernels)
                compiled (not interpreted), run at T=4096 shapes and
                compared with its XLA reference
   5. four chips (only with ``--chips 4``, which runs nothing else):
@@ -722,6 +722,34 @@ def phase_kernels(sz: Sizes, seed: int, clock: _CompileClock,
     run("paged_decode_partial bf16", paged,
         lambda q, k, v: att.decode_attention(q, k, v, lens,
                                              impl="xla"),
+        dq, dk, dv)
+
+    # -- paged decode for grouped-query heads: two K/V heads shared by
+    # the query heads, a token's K and V side by side in one pool row,
+    # from a lower edge on (a sliding layer's window) --------------------
+    g, rep = 2, d["h"] // 2
+    low = jnp.maximum(lens - d["t"] // 4, 0)
+
+    def paged_gqa(q, k, v):
+        rows = jnp.concatenate(
+            [k[:, :, :g].reshape(d["s"], d["t"], -1),
+             v[:, :, :g].reshape(d["s"], d["t"], -1)], axis=-1)
+        o, _, l = fa.paged_gqa_decode_partial(
+            q.reshape(d["s"], g, rep, d["d"]),
+            rows.reshape(1, -1, page, rows.shape[-1]), table, lens,
+            low, 0, k_dim=d["d"], v_dim=d["d"], scale=dscale,
+            interpret=interp)
+        return (o / l[..., None]).astype(q.dtype).reshape(q.shape)
+
+    def gqa_ref(q, k, v):
+        shared = lambda x: jnp.repeat(x[:, :, :g], rep, axis=2)
+        s = jnp.einsum("shd,sthd->sht", q, shared(k)).astype(f32)
+        at = jnp.arange(d["t"])[None, None, :]
+        seen = (at >= low[:, None, None]) & (at < lens[:, None, None])
+        p = jax.nn.softmax(jnp.where(seen, s * dscale, -1e30), -1)
+        return jnp.einsum("sht,sthd->shd", p.astype(q.dtype),
+                          shared(v))
+    run("paged_gqa_decode_partial bf16", paged_gqa, gqa_ref,
         dq, dk, dv)
 
     bad = [c["kernel"] for c in cases if not c["passed"]]

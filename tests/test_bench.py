@@ -338,9 +338,9 @@ def test_chip_smoke_rehearsal_lines_say_what_ran(rehearsal):
     assert gen["train_step"]["attention"] == "flash"
     kern = by["kernels"]
     assert kern["interpret"] is True      # the Pallas interpreter
-    # the flash attention family and the paged decode kernel are
-    # every Pallas kernel there is
-    assert len(kern["cases"]) == 7
+    # the flash attention family and the two paged decode kernels
+    # are every Pallas kernel there is
+    assert len(kern["cases"]) == 8
     assert all(c["passed"] for c in kern["cases"])
     assert sorted({c["kernel"].split("_")[0] for c in kern["cases"]}
                   ) == ["flash", "paged"]

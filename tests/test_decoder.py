@@ -290,19 +290,19 @@ def test_tokens_not_valid_reach_no_expert():
 def test_latent_pool_is_one_padded_pool():
     net = _net(_share(0, 8))
     cache = net.init_kv_cache(4, 30, page_size=4)
-    assert isinstance(cache, kvc.LatentPagedCache)
+    assert isinstance(cache, kvc.RowPagedCache)
     assert cache.pages.shape == (3, 4 * 8, 4, kvc.ROW_ALIGN)
     assert (cache.max_context, cache.max_slots, cache.page_size,
             cache.num_pages) == (32, 4, 4, 32)
-    wide = kvc.init_latent_cache(5, 2, 64, 576, dtype=jnp.bfloat16)
+    wide = kvc.init_row_cache(5, 2, 64, 576, dtype=jnp.bfloat16)
     assert wide.pages.shape[-1] == 640
     assert wide.pool_dtype == jnp.bfloat16
 
 
 def test_int8_latent_cache_is_refused_by_name():
-    with pytest.raises(ValueError, match="latent cache"):
+    with pytest.raises(ValueError, match="row cache"):
         _net(_share(0, 8)).init_kv_cache(2, 16, dtype=jnp.int8)
-    with pytest.raises(ValueError, match="latent cache"):
+    with pytest.raises(ValueError, match="row cache"):
         GenerationEngine(_net(_share(0, 8)), {}, max_slots=2,
                          max_context=16, cache_dtype="int8")
 
@@ -328,12 +328,12 @@ def test_engine_refuses_what_the_decoder_lacks():
     with pytest.raises(ValueError, match="forward_chunk"):
         GenerationEngine(_NoChunk(), {}, spec_k=2,
                          drafter=_net(cfg), drafter_params={})
-    with pytest.raises(TypeError, match="latent cache"):
+    with pytest.raises(TypeError, match="row cache"):
         _engine(cfg, role="prefill")
     eng = _engine(cfg)
-    with pytest.raises(TypeError, match="latent cache"):
+    with pytest.raises(TypeError, match="row cache"):
         eng.export_handoff(0)
-    with pytest.raises(TypeError, match="latent cache"):
+    with pytest.raises(TypeError, match="row cache"):
         eng.admit_from_handoff({"version": kvc.HANDOFF_VERSION}, 4)
     with pytest.raises(ValueError, match="positions"):
         _engine(cfg, max_context=512)
@@ -343,7 +343,8 @@ def test_batcher_serves_the_decoder_with_slots_joining_and_leaving():
     """Nine greedy requests of unequal lengths over three slots, run
     alone: every answer is what the whole-loop `generate` gives for
     that prompt by itself, nothing compiles after warm-up, and the
-    step's expert counts reach the counters with the tokens."""
+    expert counts of the steps and of the whole-prompt prefills
+    reach the counters with the tokens."""
     from analytics_zoo_tpu.common import observability as obs
     from jax import monitoring
     cfg = _share(0, 8)
@@ -391,5 +392,6 @@ def test_batcher_serves_the_decoder_with_slots_joining_and_leaving():
                             for n, b in zip(names, before))
     decoded = counter("zoo_tpu_serving_gen_tokens_total") - steps0
     assert decoded == sum(m - 1 for _, m in sizes)
-    assert total == decoded * 3 * 2      # top-3, two expert layers
+    # top-3, two expert layers; a prompt's tokens count as a chunk's
+    assert total == (decoded + sum(n for n, _ in sizes)) * 3 * 2
     assert 0 < busiest <= held < total

@@ -322,7 +322,7 @@ def test_window_pool_does_not_grow_with_the_context(max_context):
                                                     max_context)
     # the published sizes: 704 values a token in the context pool,
     # 1088 in the window pool, 513 - 1 + 2048 positions a slot
-    big = kvc.init_latent_cache(
+    big = kvc.init_row_cache(
         3, 8, 32768, 576, dtype=jnp.bfloat16, index_layers=3,
         index_width=128, window_layers=3, window_width=1088,
         window_tokens=512 + 2048)

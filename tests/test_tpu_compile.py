@@ -591,3 +591,147 @@ def test_dots3_note_programs_fit_the_chip_and_leave_the_pools(
     want = s * cfg["num_experts_per_tok"] if program == "step" \
         else eng["prefill_chunk"] * cfg["num_experts_per_tok"]
     assert rows == {str(want)}, rows
+
+
+# -- MiMo-V2-Flash: grouped-query rows of two geometries -----------------
+
+def _mimo(one_chip):
+    from analytics_zoo_tpu.pipeline.api.keras.layers import \
+        mimo_v2_flash_decoder
+    cfg = _config("mimo-v2-flash-ep16")
+    eng = cfg["engine"]
+    first, end = cfg["held"]["experts"]
+    net = mimo_v2_flash_decoder(
+        dict(cfg, n_routed_experts=cfg["published"]["n_routed_experts"]),
+        n_layer=cfg["n_layer"], experts_held=(first, end - first),
+        vocab=cfg["vocab_size"])
+
+    built = jax.eval_shape(lambda: net.build(jax.random.key(0), (16,)))
+    # matrices and norm gains in bfloat16; the routers' selection
+    # biases and the sink biases stay float32, as the weights' maker
+    # leaves them
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: jax.ShapeDtypeStruct(
+            a.shape, F32 if any(
+                getattr(k, "key", None) in ("router_bias", "sink")
+                for k in path) else BF, sharding=one_chip), built)
+    cache = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip),
+        jax.eval_shape(lambda: net.init_kv_cache(
+            eng["max_slots"], eng["max_context"],
+            page_size=eng["page_size"], dtype=BF,
+            max_chunk=eng["prefill_chunk"])))
+    return cfg, net, params, cache
+
+
+def _mimo_program(one_chip, program, bucket=None, lower_only=False):
+    cfg, net, params, cache = _mimo(one_chip)
+    eng = cfg["engine"]
+    s = eng["max_slots"]
+    i32 = lambda *d: jax.ShapeDtypeStruct(d, jnp.int32,
+                                          sharding=one_chip)
+    if program == "step":
+        def fn(cache, params, tok, active):
+            return net.decode_step(params, cache, tok, active=active,
+                                   stats=True)
+        args = [i32(s), jax.ShapeDtypeStruct((s,), jnp.bool_,
+                                             sharding=one_chip)]
+    elif program == "chunk":
+        def fn(cache, params, ids, starts, n_new, slots):
+            return net.forward_chunk(params, cache, ids, starts, n_new,
+                                     slots=slots, stats=True)
+        args = [i32(1, eng["prefill_chunk"]), i32(1), i32(1), i32(1)]
+    else:
+        def fn(cache, params, ids, plens, slots):
+            return net.prefill(params, cache, ids, plens, slots,
+                               stats=True)
+        args = [i32(1, bucket), i32(1), i32(1)]
+    lowered = jax.jit(fn, donate_argnums=(0,)).lower(
+        cache, params, *args)
+    return cfg, params, cache, \
+        lowered if lower_only else lowered.compile()
+
+
+@pytest.mark.parametrize("program, bucket", [
+    ("step", None), ("chunk", None), ("prefill", 2048),
+    ("prefill", 64)])
+def test_mimo_v2_flash_programs_fit_the_chip_and_leave_the_pools(
+        one_chip, as_on_the_chip, program, bucket):
+    """At the published widths and the configuration's depth, slots,
+    context and chunk: 6.86 GB of weights, the program's arguments,
+    results and temporaries inside 15.75 GB; the context pool (two
+    full layers' rows of 4 x (192 + 128) = 1280) and the ring (five
+    sliding layers' rows of 8 x 320 = 2560, 137 pages a slot)
+    row-major, aliased to their inputs, nothing of a pool's shape
+    copied, transposed or sliced; the step reads both through the
+    paged kernel and holds no dense view of a slot's context."""
+    import re
+    cfg, params, cache, compiled = _mimo_program(one_chip, program,
+                                                 bucket)
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(params))
+    assert abs(weights - 6.86e9) < 0.005e9, weights
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.output_size_in_bytes - \
+        mem.alias_size_in_bytes + mem.temp_size_in_bytes
+    assert held < _V5E_BYTES, held
+    assert cache.pages.shape == (2, 32768, 16, 1280)
+    assert cache.window.shape == (5, 16 * 137, 16, 2560)
+    assert cache.index is None
+    hlo = compiled.as_text()
+    entry = hlo.split("entry_computation_layout={(", 1)[1]
+    assert entry.startswith(
+        "bf16[2,32768,16,1280]{3,2,1,0:"), entry[:80]
+    shapes = []
+    for pool in (cache.pages, cache.window):
+        shapes.append(",".join(map(str, pool.shape)))
+        shapes.append(",".join(map(str, pool.shape[1:])))
+        assert "bf16[%s]{3,2,1,0:" % shapes[-2] in entry
+    made = re.findall(
+        r"= bf16\[(?:1,)?(?:%s)\]\S* ([\w\-]+)\(" % "|".join(shapes),
+        hlo)
+    moved = [op for op in made if op in (
+        "copy", "transpose", "dynamic-slice", "dynamic-update-slice")]
+    assert not moved, moved
+    # donated and written in place: the four leaves of the cache
+    for leaf in range(4):
+        assert "{%d}: (%d, {}, may-alias)" % (leaf, leaf) in hlo
+    eng = cfg["engine"]
+    rows = set(re.findall(r"%ragged-dot[\w\-.]* = \w+\[(\d+),", hlo))
+    tokens = {"step": eng["max_slots"], "chunk": eng["prefill_chunk"],
+              "prefill": bucket}[program]
+    assert rows == {str(tokens * cfg["num_experts_per_tok"])}, rows
+    if program == "step":
+        assert hlo.count("zoo_paged_gqa_decode") >= 7
+        # no slot's whole context as a view: 16 x 32768 positions
+        _no_shape_leads_with(hlo, eng["max_slots"], eng["max_context"])
+        assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+    if program == "prefill" and bucket == 2048:
+        assert "zoo_flash_fwd" in hlo
+
+
+def test_mimo_v2_flash_prefill_ladder_never_looks_like_a_decode_step(
+        one_chip):
+    """`benchmark/reduce/moe.py` tells a decode step's grouped
+    products by their rows: 16 slots x 8 experts a token = 128, which
+    a 16-token prompt bucket would give too. The engine's ladder
+    starts at 32, and no bucket up to the chunk has 128 rows."""
+    import re
+
+    from analytics_zoo_tpu.pipeline.inference.generation import \
+        prompt_ladder
+    cfg = _config("mimo-v2-flash-ep16")
+    eng, per_token = cfg["engine"], cfg["num_experts_per_tok"]
+    decode_rows = eng["max_slots"] * per_token
+    assert decode_rows == 128
+    ladder = [b for b in prompt_ladder(eng["max_context"])
+              if b <= eng["prefill_chunk"]]
+    assert ladder[0] >= 32 and ladder[-1] == eng["prefill_chunk"]
+    for bucket in ladder:
+        assert bucket * per_token != decode_rows
+    lowered = _mimo_program(one_chip, "prefill", bucket=ladder[0],
+                            lower_only=True)[3]
+    rows = set(re.findall(r"ragged_dot.*?tensor<(\d+)x",
+                          lowered.as_text()))
+    assert rows == {str(ladder[0] * per_token)}, rows
