@@ -644,7 +644,7 @@ class _GenEntry:
     __slots__ = ("ids", "max_new", "temperature", "eos_id", "future",
                  "t_enq", "t_enq_wall", "trace", "slot", "tokens",
                  "t_first", "prefilling", "handoff", "blob",
-                 "prompt_len")
+                 "prompt_len", "ahead")
 
     def __init__(self, ids, max_new, temperature, eos_id):
         self.ids = ids
@@ -668,6 +668,10 @@ class _GenEntry:
         # handoff-in entry that never sees the prompt — the blob's
         # cached position
         self.prompt_len = len(ids)
+        # tokens dispatched for it that the host has not fetched yet
+        # (its first, or a decode step's): counted, never waited for,
+        # when the next step's mask is built
+        self.ahead = 0
 
 
 class ContinuousBatcher:
@@ -691,9 +695,25 @@ class ContinuousBatcher:
     (`GenerationEngine.can_admit`), so an admitted sequence always
     runs to completion.
 
+    The loop runs ONE decode step ahead of its fetches: the slots'
+    last tokens stay on the device, so a pass dispatches step k —
+    its mask built from counts alone, a slot being in it while
+    tokens emitted + tokens in flight < ``max_new`` — and only then
+    fetches step k - 1 and the pass's first tokens, hands them out,
+    retires and pops the queue, all while the device runs step k.
+    The device executes in dispatch order, so a slot retired with a
+    row still in flight (a request that met its ``eos_id``: the row
+    is discarded and counted) may be re-admitted at once: the next
+    prefill into its pages is ordered behind that row. Under
+    speculation (``engine.spec_k > 0``: a round's emission count is
+    data the next mask needs) and whenever nothing is in flight the
+    same loop is the synchronous order.
+
     Telemetry (docs/observability.md): one `decode/iteration` trace a
-    pass of the loop, whose children `decode/prefill`, `decode/step`
-    and `decode/release` time the engine calls; per request, the
+    pass of the loop, whose children `decode/prefill` (the wait for
+    an admission's first tokens), `decode/step` (the dispatch of
+    step k and the fetch of step k - 1: the loop's period) and
+    `decode/release` time the engine calls; per request, the
     already-timed `decode/queue_wait`, `decode/admit` (submit to
     first token) and `decode/retire` (submit to last) records under
     the request's own trace; slot-occupancy + free-page gauges, a
@@ -717,6 +737,9 @@ class ContinuousBatcher:
         self.max_new_cap = int(max_new_cap)
         self._q: "deque[_GenEntry]" = deque()
         self._active: "list[_GenEntry]" = []
+        # the decode step the last pass left running, its tokens
+        # unfetched: (handle, the entries in its mask), or None
+        self._flight = None
         self._cond = threading.Condition()
         self._stop = False
         self._draining = False
@@ -773,6 +796,7 @@ class ContinuousBatcher:
             pending = list(self._q) + list(self._active)
             self._q.clear()
             self._active = []
+            self._flight = None     # its requests fail below
         for e in pending:
             if e.slot >= 0:
                 self.engine.release(e.slot)
@@ -1042,7 +1066,7 @@ class ContinuousBatcher:
         while True:
             with self._cond:
                 while not self._q and not self._active \
-                        and not self._stop:
+                        and self._flight is None and not self._stop:
                     self._cond.wait(timeout=0.1)
                 if self._stop:
                     return
@@ -1064,74 +1088,117 @@ class ContinuousBatcher:
                             engine.release(e.slot)
                         _fail_entry(e, exc)
                     self._active = []
+                    self._flight = None
                     logger.warning("generation batcher error: %s",
                                    exc)
             self._slots_gauge().set(engine.slots_active)
             self._pages_gauge().set(engine.free_pages)
 
-    def _prefill_span(self, entries, bucket: int):
-        """The ``decode/prefill`` span round one admission call;
-        ``bucket``: the longest padded length its programs run at.
-        ``calls`` programs held ``rows`` prompt rows between them
-        (none yet for a chunked admission): ``rows / n`` is 1 when
-        only admitted prompts are computed."""
+    def _prefill_span(self, entries, bucket: int, calls: int = 0,
+                      rows: int = 0):
+        """The ``decode/prefill`` span of one admission: round the
+        wait for its first tokens, or round ``admit_partial`` for a
+        chunked one; ``bucket``: the longest padded length its
+        programs run at. ``calls`` programs held ``rows`` prompt rows
+        between them (none yet for a chunked admission): ``rows / n``
+        is 1 when only admitted prompts are computed."""
         return obs.span(
             "decode/prefill", n=len(entries), bucket=bucket,
             prompt_tokens=sum(len(e.ids) for e in entries),
-            calls=0, rows=0)
+            calls=calls, rows=rows)
+
+    def _tokens_in(self, pairs, now: float, done) -> int:
+        """Hand fetched tokens, ``(entry, token)`` pairs, to their
+        requests; one that finishes leaves the active set (to
+        ``done``, or straight out as a handoff blob). A row that ran
+        for a request which had already met its ``eos_id`` is
+        discarded and counted. Returns the tokens emitted."""
+        emitted = 0
+        for e, tok in pairs:
+            e.ahead -= 1
+            if e.future.done():
+                obs.counter(
+                    "zoo_tpu_decode_rows_discarded_total",
+                    help="rows a decode step ran for a request that "
+                         "had already met its eos_id").inc()
+                continue
+            emitted += 1
+            if e.handoff == "out":
+                self._token_out(e, tok, now)
+                self._active.remove(e)
+                self._finish_handoff_out(e, now)
+            elif self._token_out(e, tok, now):
+                self._active.remove(e)
+                done.append(e)
+        return emitted
+
+    def _land(self, flight, done):
+        """Fetch what this pass's admission and chunk left on the
+        device — ``(span, handle, entries)`` each, the wait under
+        its span (the programs' time on the device once what ran
+        before them has ended) — and hand the first tokens to their
+        requests; a request whose first token this is, and which was
+        not admitted chunk by chunk, gets its ``decode/admit``."""
+        for span, h, entries in flight:
+            with span:
+                toks = self.engine.collect(h)
+            now = time.monotonic()
+            if span.name == "decode/prefill":
+                for e in entries:
+                    tracing.record_span(
+                        e.trace, "decode/admit", e.t_enq_wall,
+                        now - e.t_enq, slot=e.slot,
+                        prompt_len=len(e.ids))
+            self._tokens_in(zip(entries, toks.tolist()), now, done)
+
+    def _retire(self, done) -> int:
+        """Finish the requests in ``done`` and empty it; how many."""
+        now = time.monotonic()
+        n = len(done)
+        while done:
+            self._finish(done.pop(0), now)
+        return n
 
     def _iterate(self, fresh: "list[_GenEntry]", it):
-        """One pass: admit ``fresh``, advance chunked prefills, step
-        the resident slots, retire what finished."""
-        engine = self.engine
-        chunked = getattr(engine, "prefill_chunk", 0) > 0
-        spec_k = int(getattr(engine, "spec_k", 0))
-        now = time.monotonic()
+        """One pass of the loop (:meth:`_advance`); what finished in
+        it is retired even when the pass ends in an error."""
         done: "list[_GenEntry]" = []
+        emitted = retired = 0
+        try:
+            emitted, retired = self._advance(fresh, done)
+        finally:
+            retired += self._retire(done)
+        it.annotate(admitted=len(fresh), active=len(self._active),
+                    emitted=emitted, retired=retired)
+
+    def _advance(self, fresh: "list[_GenEntry]", done
+                 ) -> "tuple[int, int]":
+        """Admit ``fresh`` and advance a chunked prefill
+        (programs dispatched, their first tokens left on the
+        device), dispatch the resident slots' step from counts
+        alone, THEN fetch what was in flight before it — the step of
+        the pass before, whose finished requests are retired at once,
+        and this pass's first tokens — all while the device runs the
+        step. With nothing in flight (the first pass, after a drain,
+        under speculation) that is the synchronous order. Returns the
+        tokens the steps emitted and the requests retired before the
+        first tokens were waited for (the rest are left in
+        ``done``)."""
+        engine = self.engine
+        chunked = engine.prefill_chunk > 0
+        spec_k = engine.spec_k
+        now = time.monotonic()
         for e in fresh:
             tracing.record_span(e.trace, "decode/queue_wait",
                                 e.t_enq_wall, now - e.t_enq)
-
-        def chunk_step():
-            # advance the mid-prefill slot whose turn it is by
-            # one chunk and emit the first token if that was
-            # its prompt's last
-            with obs.span(
-                    "decode/prefill_chunk",
-                    n=len(engine.prefilling_slots),
-                    tokens=0, context=0) as sp:
-                firsts = engine.prefill_step()
-                # the tokens the chunk wrote, and the cached
-                # context it wrote them behind
-                work = getattr(engine, "chunk_work", None)
-                if work:
-                    sp.annotate(tokens=work[2], context=work[1])
-            t = time.monotonic()
-            obs.counter(
-                "zoo_tpu_serving_gen_prefill_chunks_total",
-                help="prompt chunks written by chunked "
-                     "prefill").inc()
-            if firsts:
-                by_slot = {e.slot: e
-                           for e in self._active}
-                for slot, tok in firsts:
-                    e = by_slot[slot]
-                    e.prefilling = False
-                    if e.handoff == "out":
-                        self._token_out(e, tok, t)
-                        self._active.remove(e)
-                        self._finish_handoff_out(e, t)
-                    elif self._token_out(e, tok, t):
-                        done.append(e)
-                        self._active.remove(e)
-        admitted = len(fresh)
-        if fresh:
-            hand_in = [e for e in fresh
-                       if e.handoff == "in"]
-            if hand_in:
-                fresh = [e for e in fresh
-                         if e.handoff != "in"]
-                self._admit_handoffs(hand_in, done)
+        # the step the pass before left running, if any
+        older, self._flight = self._flight, None
+        # what this pass's admission and chunk leave on the device
+        flight = []
+        hand_in = [e for e in fresh if e.handoff == "in"]
+        if hand_in:
+            fresh = [e for e in fresh if e.handoff != "in"]
+            self._admit_handoffs(hand_in, done)
         if fresh:
             # chunked admission only pays off past one
             # chunk: a prompt that fits in a single chunk
@@ -1163,39 +1230,53 @@ class ContinuousBatcher:
             if short_p:
                 reqs = [(e.ids, e.max_new, e.temperature)
                         for e in short_p]
-                with self._prefill_span(
-                        short_p, engine.prompt_bucket(
-                            max(len(e.ids) for e in short_p))) as sp:
-                    first = engine.admit(reqs)
-                    calls, rows = engine.prefill_counts
-                    sp.annotate(calls=calls, rows=rows)
-                now = time.monotonic()
-                for e, (slot, tok) in zip(short_p, first):
+                h = engine.admit_dispatch(reqs)
+                calls, rows = engine.prefill_counts
+                for e, slot in zip(short_p, h.slots):
                     e.slot = slot
-                    tracing.record_span(
-                        e.trace, "decode/admit",
-                        e.t_enq_wall, now - e.t_enq,
-                        slot=slot, prompt_len=len(e.ids))
-                    if e.handoff == "out":
-                        self._token_out(e, tok, now)
-                        self._finish_handoff_out(e, now)
-                    elif self._token_out(e, tok, now):
-                        done.append(e)
-                    else:
-                        self._active.append(e)
+                    e.ahead = 1     # its first token
+                    self._active.append(e)
+                flight.append((self._prefill_span(
+                    short_p, engine.prompt_bucket(
+                        max(len(e.ids) for e in short_p)),
+                    calls=calls, rows=rows), h, short_p))
         if chunked and engine.prefilling_slots:
-            chunk_step()
-            now = time.monotonic()
+            # advance the mid-prefill slot whose turn it is by one
+            # chunk; its prompt's last leaves the first token on the
+            # device and the slot decodes from this pass's step on
+            n_mid = len(engine.prefilling_slots)
+            h = engine.prefill_dispatch()
+            obs.counter(
+                "zoo_tpu_serving_gen_prefill_chunks_total",
+                help="prompt chunks written by chunked "
+                     "prefill").inc()
+            last_of = [e for e in self._active if e.slot in h.slots]
+            for e in last_of:
+                e.prefilling = False
+                e.ahead = 1
+            # the tokens the chunk wrote, and the cached context it
+            # wrote them behind
+            _slot, context, tokens = engine.chunk_work
+            flight.append((obs.span(
+                "decode/prefill_chunk", n=n_mid, tokens=tokens,
+                context=context), h, last_of))
+        emitted = 0
+        if spec_k > 0:
+            # a round's emission count is data the host reads before
+            # it can build the next mask: nothing runs ahead
+            self._land(flight, done)
+            flight = []
         spec: "list[_GenEntry]" = []
         regular: "list[_GenEntry]" = []
         for e in self._active:
-            if e.prefilling:
+            if e.prefilling or e.handoff == "out":
                 continue
             if spec_k > 0 and self._spec_eligible(e):
                 spec.append(e)
-            else:
+            elif len(e.tokens) + e.ahead < e.max_new:
+                # from counts alone: a slot whose last token is in
+                # flight is not in the step
                 regular.append(e)
-        emitted = 0
         if spec:
             active = np.zeros((engine.max_slots,),
                               np.bool_)
@@ -1227,6 +1308,8 @@ class ContinuousBatcher:
                 if fin:
                     done.append(e)
                     self._active.remove(e)
+        # the step whose tokens this pass waits for
+        waited = toks = None
         if regular:
             active = np.zeros((engine.max_slots,),
                               np.bool_)
@@ -1234,37 +1317,38 @@ class ContinuousBatcher:
                 active[e.slot] = True
             # what of the page table the step's slots hold, from the
             # lengths kept here (a slot has cached its prompt and all
-            # but the last of its tokens): the share a step that
-            # reads live pages only has to read
+            # but the last of its tokens, those in flight among
+            # them): the share a step that reads live pages only has
+            # to read
             page = engine.page_size
             pages_live = sum(
-                -(-(e.prompt_len + len(e.tokens) - 1) // page)
-                for e in regular)
+                -(-(e.prompt_len + len(e.tokens) + e.ahead - 1)
+                  // page) for e in regular)
             pages_table = engine.max_slots * engine.pages_per_slot
             with obs.span("decode/step", n=len(regular),
                           pages_live=pages_live,
-                          pages_table=pages_table) as sp:
-                toks = engine.step(active)
-                # the step's two waits, timed inside the engine:
-                # the compiled call returning, then the tokens
-                dispatch_s, fetch_s = engine.step_times
-                sp.annotate(dispatch_s=round(dispatch_s, 6),
-                            fetch_s=round(fetch_s, 6))
-            now = time.monotonic()
-            for e in regular:
-                emitted += 1
-                if self._token_out(e, int(toks[e.slot]),
-                                   now):
-                    done.append(e)
-                    self._active.remove(e)
-        if spec or regular:
-            obs.counter(
-                "zoo_tpu_serving_gen_tokens_total",
-                help="tokens generated").inc(emitted)
-            obs.counter(
-                "zoo_tpu_serving_gen_steps_total",
-                help="decode iterations executed").inc()
-        if regular:
+                          pages_table=pages_table,
+                          ahead=int(older is not None)) as sp:
+                h = engine.dispatch(active)
+                for e in regular:
+                    e.ahead += 1
+                # wait for the step before while this one runs behind
+                # it; under speculation, for this step itself
+                waited, self._flight = ((h, regular), None) \
+                    if spec_k > 0 else (older, (h, regular))
+                if waited is not None:
+                    toks = engine.collect(waited[0])
+                # the span's two waits: the compiled call returning,
+                # then the tokens
+                sp.annotate(
+                    dispatch_s=round(h.dispatch_s, 6),
+                    fetch_s=round(waited[0].fetch_s, 6)
+                    if waited is not None else 0.0)
+            if older is not None:
+                obs.counter(
+                    "zoo_tpu_decode_steps_ahead_total",
+                    help="decode steps dispatched while an earlier "
+                         "step's tokens were unfetched").inc()
             obs.counter(
                 "zoo_tpu_decode_pages_live_total",
                 help="pages the active slots of decode steps held "
@@ -1274,10 +1358,26 @@ class ContinuousBatcher:
                 "zoo_tpu_decode_pages_table_total",
                 help="pages the page table of decode steps spans "
                      "(slots x pages a slot)").inc(pages_table)
-        for e in done:
-            self._finish(e, now)
-        it.annotate(admitted=admitted, active=len(self._active),
-                    emitted=emitted, retired=len(done))
+        elif older is not None:
+            # nothing to run behind it: the last step's tokens
+            waited, toks = older, engine.collect(older[0])
+        if waited is not None:
+            emitted += self._tokens_in(
+                [(e, int(toks[e.slot])) for e in waited[1]],
+                time.monotonic(), done)
+        # answers leave before the wait for this pass's prompt
+        # programs (a chunk runs for 0.1-0.8 s): the client's next
+        # request is in the queue when the pass ends
+        retired = self._retire(done)
+        self._land(flight, done)
+        if spec or regular:
+            obs.counter(
+                "zoo_tpu_serving_gen_steps_total",
+                help="decode iterations executed").inc()
+        obs.counter(
+            "zoo_tpu_serving_gen_tokens_total",
+            help="tokens generated").inc(emitted)
+        return emitted, retired
 
     # -- introspection ------------------------------------------------------
     def stats(self) -> dict:

@@ -10,7 +10,10 @@ this module owns everything a *server* needs around it:
   `PageAllocator` assigning physical pages to slots at admission and
   reclaiming them at retirement — the vLLM bookkeeping half;
 - ONE compiled decode-step program (shape-static over the full slot
-  array, inactive slots frozen by the ``active`` mask) plus one
+  array, inactive slots frozen by the ``active`` mask; it takes and
+  returns the slots' last tokens as a device array, so step k + 1 is
+  dispatched with no host value of step k: :meth:`dispatch` returns
+  a handle, :meth:`collect` fetches its tokens) plus one
   compiled prefill program per prompt-length bucket (powers of two
   from ``PROMPT_BUCKET_FLOOR`` up), each of ONE prompt row addressed
   to its slot: an admission runs the prompts it admits and no other
@@ -89,7 +92,7 @@ from analytics_zoo_tpu.common import faults
 from analytics_zoo_tpu.common import observability as obs
 from analytics_zoo_tpu.pipeline.inference.batching import bucket_ladder
 
-__all__ = ["GenerationEngine", "resolve_kv_dtype"]
+__all__ = ["Dispatched", "GenerationEngine", "resolve_kv_dtype"]
 
 # chaos hook: armed via ZOO_TPU_FAULTS or tests (docs/robustness.md);
 # a "kill" here simulates the device/replica dying mid-decode with
@@ -137,6 +140,24 @@ def resolve_kv_dtype(cache_dtype=None):
                 f"{_KV_DTYPES}")
         return named[cache_dtype]
     return cache_dtype
+
+
+class Dispatched:
+    """What a dispatched call left on the device for the host to
+    fetch (:meth:`GenerationEngine.collect`): in ``outs`` one token
+    vector a program — a step's over every slot, a prefill's or a
+    chunk's of its one row — with the program's counts behind its
+    first ``width`` entries. ``slots``: whose first tokens a
+    prefill's or a prompt's last chunk's are, in the order of
+    ``outs`` (empty for a step, and for a chunk with prompt left).
+    ``dispatch_s``: a step's compiled call returning; ``fetch_s``:
+    the wait for the tokens, once collected."""
+
+    __slots__ = ("outs", "width", "slots", "dispatch_s", "fetch_s")
+
+    def __init__(self, outs, width, slots=(), dispatch_s=0.0):
+        self.outs, self.width, self.slots = outs, width, slots
+        self.dispatch_s, self.fetch_s = dispatch_s, 0.0
 
 
 class GenerationEngine:
@@ -274,7 +295,12 @@ class GenerationEngine:
 
         # per-slot sampling state (traced per call — no recompiles)
         self._temps = np.zeros((self.max_slots,), np.float32)
-        self._last_tok = np.zeros((self.max_slots,), np.int32)
+        # each slot's last sampled token, ON THE DEVICE: the step
+        # program takes and returns it, and the programs that bring
+        # a slot in (prefill, a prompt's last chunk, handoff import)
+        # write its entry, so no step waits for a host value
+        self._last_tok = jax.numpy.zeros((self.max_slots,),
+                                         jax.numpy.int32)
         self._rng = jax.random.key(int(rng_seed))
         self._step_id = 0
 
@@ -294,9 +320,6 @@ class GenerationEngine:
         self.prompt_buckets = prompt_ladder(
             min(self.max_context, int(net.seq_len)))
 
-        # (dispatch_s, fetch_s) of the latest `step`: the compiled call
-        # returning, then blocked on the tokens (`decode/step` fields)
-        self.step_times = (0.0, 0.0)
         # (calls, rows) of the latest `admit`: prefill programs run,
         # and the prompt rows they held (`decode/prefill` fields)
         self.prefill_counts = (0, 0)
@@ -322,12 +345,13 @@ class GenerationEngine:
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        # the step's counts ride behind the tokens: one fetch
-        return cache, jax.numpy.concatenate([nxt] + counts) \
-            if counts else nxt
+        # the last tokens stay on the device for the next step; the
+        # step's counts ride behind the fetched tokens: one fetch
+        return cache, jax.numpy.where(active, nxt, tok), \
+            jax.numpy.concatenate([nxt] + counts) if counts else nxt
 
-    def _prefill_fn(self, cache, params, ids, plens, slots, temps,
-                    rng, step):
+    def _prefill_fn(self, cache, params, last, ids, plens, slots,
+                    temps, rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
         counted = bool(getattr(self.net, "step_counters", ()))
@@ -337,9 +361,10 @@ class GenerationEngine:
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        # as a chunk's: the prompt's counts ride behind its token
-        return cache, jax.numpy.concatenate([nxt] + counts) \
-            if counts else nxt
+        # the first token goes to its slot's entry on the device;
+        # as a chunk's, the prompt's counts ride behind the fetched one
+        return cache, last.at[slots].set(nxt), \
+            jax.numpy.concatenate([nxt] + counts) if counts else nxt
 
     def _abstract(self, tree):
         import jax
@@ -349,8 +374,8 @@ class GenerationEngine:
             if not hasattr(a, "aval") else
             jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
 
-    def _chunk_fn(self, cache, params, ids, starts, n_new, slots,
-                  temps, rng, step):
+    def _chunk_fn(self, cache, params, last, ids, starts, n_new,
+                  slots, temps, rng, step):
         import jax
         from analytics_zoo_tpu.ops.sampling import sample_tokens
         counted = bool(getattr(self.net, "step_counters", ()))
@@ -360,8 +385,10 @@ class GenerationEngine:
         nxt = sample_tokens(jax.random.fold_in(rng, step),
                             logits.astype(jax.numpy.float32), temps,
                             self.top_k)
-        return cache, jax.numpy.concatenate([nxt] + counts) \
-            if counts else nxt
+        # every chunk writes its slot's entry: the slot decodes only
+        # after its prompt's last chunk, whose token then stands there
+        return cache, last.at[slots].set(nxt), \
+            jax.numpy.concatenate([nxt] + counts) if counts else nxt
 
     def _draft_prefill_fn(self, dcache, dparams, ids, plens, slots):
         dcache, _ = self.drafter.prefill(dparams, dcache, ids, plens,
@@ -412,7 +439,8 @@ class GenerationEngine:
         drafter caches stay row-for-row in lockstep with no resync
         pass, and a full acceptance leaves ``dk`` as the pending
         token. Returns (cache, dcache, out_tokens (S, K), n_accept,
-        n_emit, next_tok)."""
+        n_emit, the slots' last tokens with the active slots' pending
+        ones in)."""
         import jax
         import jax.numpy as jnp
         from analytics_zoo_tpu.ops.sampling import (sampling_probs,
@@ -444,7 +472,8 @@ class GenerationEngine:
             seq_lens=jnp.where(active, new_len, cache.seq_lens))
         dcache = dcache._replace(
             seq_lens=jnp.where(active, new_len, dcache.seq_lens))
-        return cache, dcache, out, n_acc, n_emit, nxt
+        return cache, dcache, out, n_acc, n_emit, \
+            jnp.where(active, nxt, t0)
 
     def _compile(self, fn, structs, program, bucket=None,
                  donate=(0,)):
@@ -483,7 +512,7 @@ class GenerationEngine:
                 self._shape(),
             )
             self._compiled_step = self._compile(
-                self._step_fn, structs, "step")
+                self._step_fn, structs, "step", donate=(0, 2))
         return self._compiled_step
 
     def _get_prefill(self, tp: int):
@@ -494,6 +523,7 @@ class GenerationEngine:
             structs = (
                 self._abstract(self.cache),
                 self._abstract(self.params),
+                self._shape(self.max_slots),
                 self._shape(1, tp),
                 self._shape(1),
                 self._shape(1),
@@ -502,7 +532,7 @@ class GenerationEngine:
                 self._shape(),
             )
             fn = self._compile(self._prefill_fn, structs, "prefill",
-                               bucket=tp)
+                               bucket=tp, donate=(0, 2))
             self._compiled_prefill[tp] = fn
         return fn
 
@@ -513,6 +543,7 @@ class GenerationEngine:
             structs = (
                 self._abstract(self.cache),
                 self._abstract(self.params),
+                self._shape(self.max_slots),
                 self._shape(1, self.prefill_chunk),
                 self._shape(1),
                 self._shape(1),
@@ -522,7 +553,7 @@ class GenerationEngine:
                 self._shape(),
             )
             self._compiled_chunk = self._compile(
-                self._chunk_fn, structs, "chunk")
+                self._chunk_fn, structs, "chunk", donate=(0, 2))
         return self._compiled_chunk
 
     def _get_draft_prefill(self, tp: int):
@@ -594,13 +625,16 @@ class GenerationEngine:
         from analytics_zoo_tpu.ops import kv_cache as kvc
         return kvc.gather_slot_pages(cache, page_ids)
 
-    def _handoff_import_fn(self, cache, page_ids, active, slot,
-                           seq_len, k_rows, v_rows, k_srows,
-                           v_srows):
+    def _handoff_import_fn(self, cache, last, page_ids, active, slot,
+                           seq_len, last_token, k_rows, v_rows,
+                           k_srows, v_srows):
         from analytics_zoo_tpu.ops import kv_cache as kvc
-        return kvc.scatter_slot_pages(cache, page_ids, active, slot,
-                                      seq_len, k_rows, v_rows,
-                                      k_srows, v_srows)
+        cache = kvc.scatter_slot_pages(cache, page_ids, active, slot,
+                                       seq_len, k_rows, v_rows,
+                                       k_srows, v_srows)
+        # the token the prefill side sampled, which the host alone
+        # knows, goes to the slot's entry as a prefill's own does
+        return cache, last.at[slot].set(last_token)
 
     def _handoff_row_structs(self):
         """(k/v rows, scale rows) ShapeDtypeStructs at the FIXED
@@ -637,14 +671,17 @@ class GenerationEngine:
             rows, srows = self._handoff_row_structs()
             structs = (
                 self._abstract(self.cache),
+                self._shape(self.max_slots),
                 self._shape(p),
                 self._shape(p, dtype=np.bool_),
+                self._shape(),
                 self._shape(),
                 self._shape(),
                 rows, rows, srows, srows,
             )
             self._compiled_handoff_import = self._compile(
-                self._handoff_import_fn, structs, "handoff_import")
+                self._handoff_import_fn, structs, "handoff_import",
+                donate=(0, 1))
         return self._compiled_handoff_import
 
     def _warmed(self) -> int:
@@ -734,21 +771,30 @@ class GenerationEngine:
         return next(b for b in self.prompt_buckets if b >= prompt_len)
 
     def admit(self, requests: "Sequence[tuple]") -> "list[tuple]":
+        """:meth:`admit_dispatch`, then the first tokens fetched
+        together: ``[(slot, first_token), ...]``."""
+        if not requests:
+            return []
+        h = self.admit_dispatch(requests)
+        return list(zip(h.slots, self.collect(h).tolist()))
+
+    def admit_dispatch(self, requests: "Sequence[tuple]"
+                       ) -> Dispatched:
         """Admit ``[(prompt_ids, max_new, temperature), ...]`` into
         free slots of the LIVE batch: assign pages, write the table
         rows (one push), then run ONE one-row prefill a request, at
         that request's own bucket and addressed to its slot — the
         programs compute the admitted prompts and nothing else, and
         touch no other slot (the property `prefill` guarantees) —
-        and sample each new slot's first token; the first tokens are
-        fetched together, after the last dispatch. Returns
-        ``[(slot, first_token), ...]`` and leaves ``(calls, rows)``
-        of this admission in :attr:`prefill_counts`. Raises
-        MemoryError when slots/pages run out mid-list (callers gate
-        with :meth:`can_admit` per request first)."""
-        import jax
-        if not requests:
-            return []
+        and sample each new slot's first token, which the program
+        leaves in the slot's entry of the device's last tokens: a
+        step dispatched next consumes it with no fetch between.
+        Returns the handle whose ``slots`` are the admitted slots
+        and whose :meth:`collect` gives their first tokens, and
+        leaves ``(calls, rows)`` of this admission in
+        :attr:`prefill_counts`. Raises MemoryError when slots/pages
+        run out mid-list (callers gate with :meth:`can_admit` per
+        request first)."""
         for prompt_ids, _, _ in requests:
             if not 1 <= len(prompt_ids) <= self.max_context - 1:
                 raise ValueError(
@@ -764,9 +810,11 @@ class GenerationEngine:
             ids[0, :n] = np.asarray(prompt_ids, np.int32)
             plens = np.full((1,), n, np.int32)
             at = np.full((1,), slot, np.int32)
-            self.cache, tok = self._get_prefill(tp)(
-                self.cache, self.params, ids, plens, at,
-                self._temps[slot:slot + 1], self._rng,
+            # (a copy of the temperature: the host's vector is
+            # rewritten by later admissions while programs are queued)
+            self.cache, self._last_tok, tok = self._get_prefill(tp)(
+                self.cache, self.params, self._last_tok, ids, plens,
+                at, self._temps[slot:slot + 1].copy(), self._rng,
                 np.int32(self._step_id))
             self._step_id += 1
             firsts.append(tok)
@@ -776,13 +824,7 @@ class GenerationEngine:
                     self._draft_cache, self.drafter_params, ids,
                     plens, at)
         self.prefill_counts = (len(firsts), rows)
-        out = []
-        for slot, tok in zip(admitted, jax.device_get(firsts)):
-            if len(tok) > 1:
-                self.net.record_step_counts(tok[1:])
-            self._last_tok[slot] = tok[0]
-            out.append((slot, int(tok[0])))
-        return out
+        return Dispatched(firsts, 1, admitted)
 
     def _claim_slot(self, prompt_ids, max_new, temperature) -> int:
         """Allocate pages + a slot + its table row for one request
@@ -857,20 +899,30 @@ class GenerationEngine:
         self._pending_prompts.pop(slot, None)
 
     def prefill_step(self) -> "list[tuple]":
+        """:meth:`prefill_dispatch`, then the chunk's token (and
+        counts) fetched: ``[(slot, first_token)]`` if the chunk was
+        its prompt's last (the slot then decodes), else []."""
+        h = self.prefill_dispatch()
+        if h is None:
+            return []
+        return list(zip(h.slots, self.collect(h).tolist()))
+
+    def prefill_dispatch(self) -> "Dispatched | None":
         """Advance ONE prefilling slot by one chunk (at most
         ``prefill_chunk`` prompt tokens): the compiled one-row chunk
         program, addressed to the slot, so a chunk computes the
         tokens it writes and no idle slot's padding. The slots take
         turns in the order they were admitted, a slot with prompt
         left going to the back, so a call costs one chunk program
-        however many prompts are mid-prefill. Returns ``[(slot,
-        first_token)]`` if the chunk was its prompt's last (the slot
-        then decodes), else []. Leaves ``(slot, start, tokens)`` of
-        the chunk in :attr:`chunk_work` (None when nothing is
-        prefilling: a no-op)."""
+        however many prompts are mid-prefill. Returns the chunk's
+        handle — ``slots`` holds the slot if the chunk was its
+        prompt's last (its first token then stands in the device's
+        last tokens and the slot decodes), else nothing — and leaves
+        ``(slot, start, tokens)`` of the chunk in :attr:`chunk_work`;
+        None, and ``chunk_work`` None, when nothing is prefilling."""
         self.chunk_work = None
         if not self._pending_prompts:
-            return []
+            return None
         c = self.prefill_chunk
         slot = next(iter(self._pending_prompts))
         ids, off = st = self._pending_prompts.pop(slot)
@@ -880,9 +932,9 @@ class GenerationEngine:
         starts = np.full((1,), off, np.int32)
         n_new = np.full((1,), n, np.int32)
         at = np.full((1,), slot, np.int32)
-        self.cache, tok = self._get_chunk()(
-            self.cache, self.params, row, starts, n_new, at,
-            self._temps[slot:slot + 1], self._rng,
+        self.cache, self._last_tok, tok = self._get_chunk()(
+            self.cache, self.params, self._last_tok, row, starts,
+            n_new, at, self._temps[slot:slot + 1].copy(), self._rng,
             np.int32(self._step_id))
         self._step_id += 1
         if self._draft_cache is not None:
@@ -890,39 +942,51 @@ class GenerationEngine:
                 self._draft_cache, self.drafter_params, row, starts,
                 n_new, at)
         self.chunk_work = (slot, off, n)
-        tok = np.asarray(tok)
-        if len(tok) > 1:
-            self.net.record_step_counts(tok[1:])
         if off + n < len(ids):
             st[1] = off + n
             self._pending_prompts[slot] = st    # to the back
-            return []
-        self._last_tok[slot] = tok[0]
-        return [(slot, int(tok[0]))]
+            return Dispatched([tok], 1)
+        return Dispatched([tok], 1, [slot])
 
     def step(self, active: np.ndarray) -> np.ndarray:
-        """One decode iteration over the WHOLE slot array: append each
-        active slot's last token to the cache, attend, sample. Slots
-        with ``active == False`` are frozen (nothing written, lengths
-        unchanged). Returns the ``(max_slots,)`` sampled tokens —
-        meaningful only at active slots."""
+        """One decode iteration over the WHOLE slot array, start to
+        tokens: :meth:`dispatch`, then :meth:`collect`. Returns the
+        ``(max_slots,)`` sampled tokens — meaningful only at active
+        slots."""
+        return self.collect(self.dispatch(active))
+
+    def dispatch(self, active: np.ndarray) -> Dispatched:
+        """Start one decode iteration over the WHOLE slot array:
+        append each active slot's last token to the cache, attend,
+        sample. Slots with ``active == False`` are frozen (nothing
+        written, lengths unchanged, last token kept). The sampled
+        tokens become the slots' last tokens ON THE DEVICE, so the
+        next dispatch needs nothing this one produces: a caller may
+        dispatch step k + 1 before it collects step k."""
         _STEP_FAULT.fire()
         fn = self._get_step()
         active = np.asarray(active, np.bool_)
         t0 = time.perf_counter()
-        self.cache, toks = fn(self.cache, self.params,
-                              self._last_tok, active, self._temps,
-                              self._rng, np.int32(self._step_id))
-        t1 = time.perf_counter()
+        self.cache, self._last_tok, toks = fn(
+            self.cache, self.params, self._last_tok, active,
+            self._temps.copy(), self._rng, np.int32(self._step_id))
         self._step_id += 1
-        toks = np.asarray(toks)
-        self.step_times = (t1 - t0, time.perf_counter() - t1)
-        if len(toks) > self.max_slots:
-            self.net.record_step_counts(toks[self.max_slots:])
-            toks = toks[:self.max_slots]
-        self._last_tok = np.where(active, toks, self._last_tok
-                                  ).astype(np.int32)
-        return toks
+        return Dispatched([toks], self.max_slots,
+                          dispatch_s=time.perf_counter() - t0)
+
+    def collect(self, h: Dispatched) -> np.ndarray:
+        """Fetch a handle's tokens (blocking until its programs have
+        run): a step's ``(max_slots,)`` vector, or one first token a
+        program of an admission or a chunk. The counts that ride
+        behind them go to the net's ``record_step_counts``."""
+        import jax
+        t0 = time.perf_counter()
+        got = jax.device_get(h.outs)
+        h.fetch_s = time.perf_counter() - t0
+        for a in got:
+            if len(a) > h.width:
+                self.net.record_step_counts(a[h.width:])
+        return np.concatenate([a[:h.width] for a in got])
 
     def spec_step(self, active: np.ndarray):
         """One speculative round over the active slots: draft
@@ -936,19 +1000,19 @@ class GenerationEngine:
         _STEP_FAULT.fire()
         active = np.asarray(active, np.bool_)
         dfn, vfn = self._get_draft(), self._get_verify()
+        temps = self._temps.copy()
         self._draft_cache, drafts, qprobs = dfn(
             self._draft_cache, self.drafter_params, self._last_tok,
-            active, self._temps, self._rng, np.int32(self._step_id))
+            active, temps, self._rng, np.int32(self._step_id))
         self._step_id += 1
         (self.cache, self._draft_cache, out, n_acc, n_emit,
-         nxt) = vfn(self.cache, self._draft_cache, self.params,
-                    self._last_tok, drafts, qprobs, active,
-                    self._temps, self._rng, np.int32(self._step_id))
+         self._last_tok) = vfn(
+            self.cache, self._draft_cache, self.params,
+            self._last_tok, drafts, qprobs, active, temps, self._rng,
+            np.int32(self._step_id))
         self._step_id += 1
-        out, nxt = np.asarray(out), np.asarray(nxt)
+        out = np.asarray(out)
         n_emit = np.where(active, np.asarray(n_emit), 0)
-        self._last_tok = np.where(active, nxt, self._last_tok
-                                  ).astype(np.int32)
         n_active = int(active.sum())
         self.spec_proposed += self.spec_k * n_active
         self.spec_accepted += int(
@@ -1003,7 +1067,7 @@ class GenerationEngine:
             "kv_dtype": np.dtype(self.cache.k_pages.dtype).name,
             "num_layers": int(self.cache.k_pages.shape[0]),
             "row_width": int(self.cache.k_pages.shape[3]),
-            "last_token": int(self._last_tok[slot]),
+            "last_token": int(np.asarray(self._last_tok)[slot]),
             "temperature": float(self._temps[slot]),
             "k": np.asarray(k)[:, :n_used].copy(),
             "v": np.asarray(v)[:, :n_used].copy(),
@@ -1082,12 +1146,12 @@ class GenerationEngine:
             return out
 
         fn = self._get_handoff_import()
-        self.cache = fn(self.cache, jax.numpy.asarray(row), active,
-                        np.int32(slot), np.int32(seq_len),
-                        pad(blob["k"]), pad(blob["v"]),
-                        pad(blob["k_scales"]),
-                        pad(blob["v_scales"]))
-        self._last_tok[slot] = int(blob["last_token"])
+        self.cache, self._last_tok = fn(
+            self.cache, self._last_tok, jax.numpy.asarray(row),
+            active, np.int32(slot), np.int32(seq_len),
+            np.int32(blob["last_token"]), pad(blob["k"]),
+            pad(blob["v"]), pad(blob["k_scales"]),
+            pad(blob["v_scales"]))
         return slot
 
     @property

@@ -996,3 +996,399 @@ def test_shuffled_page_table_same_tokens(kv):
             tok = logits.argmax(-1).astype(np.int32)
         streams.append(np.stack(out))
     np.testing.assert_array_equal(streams[0], streams[1])
+
+
+# -- one decode step dispatched ahead ---------------------------------------
+# The batcher dispatches step k before it fetches step k - 1: the
+# slots' last tokens stay on the device and the mask is built from
+# counts. Whatever it serves must be, token for token, what the same
+# sequence of engine calls gives when every call is fetched at once
+# (`admit`, `prefill_step`, plain `step`): each test logs the calls
+# the loop makes and replays them on a twin engine.
+
+def _log_engine_calls(eng):
+    log = []
+
+    def wrap(name):
+        fn = getattr(eng, name)
+
+        def logged(*args):
+            log.append((name, tuple(
+                a.copy() if isinstance(a, np.ndarray) else a
+                for a in args)))
+            return fn(*args)
+        setattr(eng, name, logged)
+
+    for name in ("admit_dispatch", "admit_partial",
+                 "prefill_dispatch", "admit_from_handoff",
+                 "dispatch", "release"):
+        wrap(name)
+    return log
+
+
+def _replay_plain(twin, log):
+    """The logged calls on ``twin``, each fetched before the next:
+    every stream a slot's occupants produced, in admission order."""
+    streams, at = [], {}
+    for name, args in log:
+        if name == "admit_dispatch":
+            for slot, tok in twin.admit(args[0]):
+                at[slot] = [tok]
+                streams.append(at[slot])
+        elif name == "admit_partial":
+            for slot in twin.admit_partial(args[0]):
+                at[slot] = []
+                streams.append(at[slot])
+        elif name == "prefill_dispatch":
+            for slot, tok in twin.prefill_step():
+                at[slot].append(tok)
+        elif name == "admit_from_handoff":
+            slot = twin.admit_from_handoff(*args)
+            at[slot] = [int(args[0]["last_token"])]
+            streams.append(at[slot])
+        elif name == "dispatch":
+            toks = twin.step(args[0])
+            for slot in np.flatnonzero(args[0]):
+                at[slot].append(int(toks[slot]))
+        else:
+            twin.release(args[0])
+    return streams
+
+
+def _counter(name):
+    from analytics_zoo_tpu.common import observability as obs
+    return obs.counter(name).value
+
+
+def _serve_logged(jobs, temperature=0.0, eos_id=None, **kw):
+    """``jobs`` of (prompt, max_new) through a started batcher, all
+    queued before the loop starts (so the order of admission is the
+    order of ``jobs``); the served streams beside the twin's replay
+    of the same calls."""
+    eng, twin = _engine(**kw), _engine(**kw)
+    log = _log_engine_calls(eng)
+    cb = ContinuousBatcher(eng, queue_depth=32)
+    futs = [cb.submit(p, max_new_tokens=m, temperature=temperature,
+                      eos_id=eos_id) for p, m in jobs]
+    cb.start()
+    try:
+        outs = [[int(t) for t in f.result(timeout=120)]
+                for f in futs]
+    finally:
+        cb.stop()
+    assert eng.slots_active == 0
+    assert eng.free_pages == eng.allocator.max_pages
+    return outs, _replay_plain(twin, log), eng
+
+
+_AHEAD_JOBS = [(3, 6), (7, 1), (2, 9), (5, 2), (4, 7), (6, 1), (9, 5)]
+
+
+def _jobs(seed=4):
+    rs = np.random.RandomState(seed)
+    return [(rs.randint(1, VOCAB, size=n).tolist(), m)
+            for n, m in _AHEAD_JOBS]
+
+
+def test_ahead_greedy_staggered_matches_plain_steps():
+    """Seven requests over two slots, budgets of 1, 2 and many:
+    each answer is exactly its budget long, equals the twin's plain
+    `step()` sequence and the whole-loop reference, and steps did
+    run ahead of their fetches."""
+    jobs = _jobs()
+    ref_eng = _engine(max_slots=2)
+    refs = [[int(t) for t in ref_eng.generate(p, max_new_tokens=m)[0]]
+            for p, m in jobs]
+    outs, plain, _ = _serve_logged(jobs, max_slots=2)
+    assert [len(o) for o in outs] == [m for _, m in jobs]
+    assert outs == plain == refs
+    assert _counter("zoo_tpu_decode_steps_ahead_total") > 0
+    assert _counter("zoo_tpu_decode_steps_ahead_total") < \
+        _counter("zoo_tpu_serving_gen_steps_total")
+    assert _counter("zoo_tpu_decode_rows_discarded_total") == 0
+
+
+def test_ahead_sampled_fixed_seed_matches_plain_steps():
+    """Temperature 0.9 under the engine's fixed seed: the step ids
+    that seed sampling advance in the order of dispatch, so deferred
+    fetches change no draw."""
+    jobs = _jobs(seed=9)
+    outs, plain, _ = _serve_logged(jobs, temperature=0.9,
+                                   max_slots=2)
+    assert [len(o) for o in outs] == [m for _, m in jobs]
+    assert outs == plain
+    greedy, _, _ = _serve_logged(jobs, max_slots=2)
+    assert outs != greedy       # the temperature did sample
+
+
+@pytest.mark.parametrize("max_new", [1, 2, 11])
+def test_ahead_budget_met_by_count(max_new):
+    """A lone request of budget 1 (its first token is its last: no
+    step is dispatched for it), 2 and many."""
+    prompt = [4, 19, 7]
+    ref = [int(t) for t in _engine().generate(
+        prompt, max_new_tokens=max_new)[0]]
+    outs, plain, _ = _serve_logged([(prompt, max_new)])
+    assert outs == plain == [ref]
+    assert _counter("zoo_tpu_serving_gen_steps_total") == max_new - 1
+    assert _counter("zoo_tpu_decode_steps_ahead_total") == \
+        max(0, max_new - 2)
+
+
+def test_ahead_eos_mid_stream_discards_the_row_in_flight():
+    """A request that meets its `eos_id` while its next row is
+    already running: the answer ends at the eos, the row is
+    discarded and counted, and the neighbour's stream is whole."""
+    other, ref_eng = [9, 2, 33, 5], _engine()
+    rs = np.random.RandomState(2)
+    for _ in range(40):     # a stream with a fresh token mid-way
+        prompt = rs.randint(1, VOCAB, size=4).tolist()
+        full = [int(t) for t in ref_eng.generate(
+            prompt, max_new_tokens=12)[0]]
+        k = next((i for i in range(2, 10)
+                  if full[i] not in full[:i]), None)
+        if k is not None:
+            break
+    assert k is not None
+    eng, twin = _engine(max_slots=2), _engine(max_slots=2)
+    log = _log_engine_calls(eng)
+    cb = ContinuousBatcher(eng, queue_depth=8)
+    f0 = cb.submit(prompt, max_new_tokens=12, eos_id=full[k])
+    f1 = cb.submit(other, max_new_tokens=12)
+    cb.start()
+    try:
+        out0 = [int(t) for t in f0.result(timeout=60)]
+        out1 = [int(t) for t in f1.result(timeout=60)]
+    finally:
+        cb.stop()
+    plain0, plain1 = _replay_plain(twin, log)
+    assert out0 == full[:k + 1]          # eos included, nothing after
+    assert plain0 == full[:k + 2]        # the row that ran ahead
+    assert out1 == plain1 and len(out1) == 12
+    assert _counter("zoo_tpu_decode_rows_discarded_total") == 1
+    assert eng.slots_active == 0
+    assert eng.free_pages == eng.allocator.max_pages
+
+
+def test_ahead_chunked_admission_matches_plain_steps():
+    """Prompts past one chunk are written a chunk a pass beside the
+    running steps; the last chunk's first token reaches the step
+    that consumes it without a fetch between."""
+    rs = np.random.RandomState(11)
+    jobs = [(rs.randint(1, VOCAB, size=n).tolist(), m)
+            for n, m in [(3, 8), (14, 5), (5, 1), (11, 2), (2, 6)]]
+    ref_eng = _engine(max_slots=2)
+    refs = [[int(t) for t in ref_eng.generate(p, max_new_tokens=m)[0]]
+            for p, m in jobs]
+    outs, plain, eng = _serve_logged(jobs, max_slots=2,
+                                     prefill_chunk=4)
+    assert outs == refs
+    # `admit_partial` lists a stream at its admission, `admit` at
+    # its own: compare as sets of streams
+    assert sorted(outs) == sorted(plain)
+    assert _counter("zoo_tpu_serving_gen_prefill_chunks_total") == \
+        4 + 2 + 3   # 14 tokens in chunks of 4, 5 and 11
+    assert not eng.prefilling_slots
+
+
+def test_ahead_answer_leaves_before_the_wait_for_a_chunk():
+    """A request whose last token a step's fetch brings is answered
+    before the pass waits for its chunk program (0.1-0.8 s on the
+    chip at 2048 tokens): the future is done when that wait starts,
+    not a pass later."""
+    eng = _engine(max_slots=2, prefill_chunk=4)
+    cb = ContinuousBatcher(eng, queue_depth=8)
+    short = cb.submit([4, 19, 7], max_new_tokens=3)
+    long_ = cb.submit(list(range(1, 15)), max_new_tokens=2)
+    seen, collect = [], eng.collect
+
+    def watched(h):
+        # (a step's handle?, is the short request answered?)
+        seen.append((h.width == eng.max_slots, short.done()))
+        return collect(h)
+    eng.collect = watched
+    cb.start()
+    try:
+        assert len(short.result(timeout=60)) == 3
+        assert len(long_.result(timeout=60)) == 2
+    finally:
+        cb.stop()
+    first = next(i for i, (_, done) in enumerate(seen) if done)
+    assert seen[first][0] is False          # a chunk's wait ...
+    assert seen[first - 1] == (True, False)  # ... behind its step's
+
+
+def test_ahead_handoff_import_matches_plain_steps():
+    """A decode-pool engine takes the token the prefill side
+    sampled, which the host alone knows, into the device's last
+    tokens through the import program: the resumed stream equals
+    the twin's plain steps and the monolithic reference."""
+    prompt, max_new = [4, 19, 7, 3, 12], 7
+    ref = [int(t) for t in _engine().generate(
+        prompt, max_new_tokens=max_new)[0]]
+    pre = _engine(role="prefill")
+    (slot, first), = pre.admit([(prompt, max_new, 0.0)])
+    blob = pre.export_handoff(slot)
+    assert blob["last_token"] == first == ref[0]
+    dec, twin = _engine(role="decode"), _engine(role="decode")
+    log = _log_engine_calls(dec)
+    cb = ContinuousBatcher(dec, queue_depth=4).start()
+    try:
+        out = [int(t) for t in cb.submit_handoff(
+            blob, max_new_tokens=max_new).result(timeout=60)]
+    finally:
+        cb.stop()
+    plain, = _replay_plain(twin, log)
+    assert out == plain == ref
+    assert dec.free_pages == dec.allocator.max_pages
+
+
+def _resident(eng, n, timeout=20.0):
+    """Wait until ``n`` slots are resident and two steps have been
+    dispatched behind their prefills: from then on every dispatch
+    finds the step before it unfetched."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if eng.slots_active == n and eng._step_id >= n + 2:
+            return
+        time.sleep(0.002)
+    raise AssertionError("the loop never ran ahead")
+
+
+def test_stop_with_a_step_in_flight_resolves_and_frees():
+    from analytics_zoo_tpu.common import faults
+    eng = _engine(max_slots=2)
+    cb = ContinuousBatcher(eng, queue_depth=8,
+                           max_new_cap=4096).start()
+    try:
+        faults.arm("generation/decode_step", "delay", seconds=0.05)
+        futs = [cb.submit([4, 19, 7], max_new_tokens=25),
+                cb.submit([9, 2], max_new_tokens=25)]
+        _resident(eng, 2)
+        queued = cb.submit([5], max_new_tokens=4)
+        cb.stop(timeout=0.3)    # the drain cannot finish in time
+    finally:
+        faults.disarm_all()
+        cb.stop()
+    for f in futs:
+        with pytest.raises(RuntimeError, match="stopped"):
+            f.result(5)
+    with pytest.raises(RuntimeError):
+        queued.result(5)
+    assert cb._flight is None
+    assert eng.slots_active == 0
+    assert eng.free_pages == eng.allocator.max_pages
+
+
+def test_drain_with_a_step_in_flight_finishes_the_residents():
+    from analytics_zoo_tpu.common import faults
+    eng = _engine(max_slots=2)
+    jobs = [([4, 19, 7], 20), ([9, 2], 17)]
+    refs = [[int(t) for t in eng.generate(p, max_new_tokens=m)[0]]
+            for p, m in jobs]
+    cb = ContinuousBatcher(eng, queue_depth=8)
+    futs = [cb.submit(p, max_new_tokens=m) for p, m in jobs]
+    cb.start()
+    try:
+        faults.arm("generation/decode_step", "delay", seconds=0.03)
+        _resident(eng, 2)
+        assert cb.drain(timeout=30) is True
+        assert [[int(t) for t in f.result(5)] for f in futs] == refs
+        assert cb._flight is None
+        assert eng.slots_active == 0
+        assert eng.free_pages == eng.allocator.max_pages
+    finally:
+        faults.disarm_all()
+        cb.stop()
+
+
+def test_decode_fault_with_a_step_in_flight_fails_and_frees():
+    """A `generation/decode_step` kill at the dispatch of step k,
+    while step k - 1 is unfetched: every resident request fails,
+    the handle is dropped, no slot or page stays claimed, and the
+    loop serves the next request exactly."""
+    from analytics_zoo_tpu.common import faults
+    from analytics_zoo_tpu.common.faults import InjectedKillError
+    eng = _engine(max_slots=2)
+    ref = [int(t) for t in eng.generate([4, 19, 7],
+                                        max_new_tokens=4)[0]]
+    cb = ContinuousBatcher(eng, queue_depth=8).start()
+    try:
+        faults.arm("generation/decode_step", "delay", seconds=0.03)
+        futs = [cb.submit([4, 19, 7], max_new_tokens=25),
+                cb.submit([9, 2], max_new_tokens=25)]
+        _resident(eng, 2)
+        faults.arm("generation/decode_step", "kill", times=1)
+        for f in futs:
+            with pytest.raises(InjectedKillError):
+                f.result(timeout=30)
+        deadline = time.monotonic() + 10
+        while time.monotonic() < deadline and eng.slots_active:
+            time.sleep(0.005)
+        assert cb._flight is None
+        assert eng.slots_active == 0
+        assert eng.free_pages == eng.allocator.max_pages
+        out = cb.submit([4, 19, 7], max_new_tokens=4).result(30)
+        assert [int(t) for t in out] == ref
+    finally:
+        faults.disarm_all()
+        cb.stop()
+
+
+def test_step_chains_on_the_device_token_vector():
+    """The compiled step takes the slots' last tokens as a device
+    array and returns them, donated like the cache; two dispatches
+    in a row need no host value of the first, and give what two
+    fetched steps give."""
+    import jax
+    eng, twin = _engine(), _engine()
+    reqs = [([4, 19, 7], 8, 0.0), ([9, 2], 8, 0.0)]
+    h0 = eng.admit_dispatch(reqs)           # first tokens unfetched
+    want0 = [t for _, t in twin.admit(reqs)]
+    assert isinstance(eng._last_tok, jax.Array)
+    active = np.zeros((eng.max_slots,), np.bool_)
+    active[list(h0.slots)] = True
+    before = eng._last_tok
+    h1 = eng.dispatch(active)
+    assert before.is_deleted()              # donated to the step
+    h2 = eng.dispatch(active)
+    assert not eng._last_tok.is_deleted()
+    want1, want2 = twin.step(active), twin.step(active)
+    # fetched late and out of order: the handles own their tokens
+    got2, got1 = eng.collect(h2), eng.collect(h1)
+    assert eng.collect(h0).tolist() == want0
+    assert got1.tolist() == want1.tolist()
+    assert got2.tolist() == want2.tolist()
+    assert np.asarray(eng._last_tok)[active].tolist() == \
+        want2[active].tolist()
+    # an inactive slot keeps its entry
+    assert np.asarray(eng._last_tok)[~active].tolist() == \
+        np.asarray(twin._last_tok)[~active].tolist()
+
+
+def test_only_the_step_program_is_named_step_fn():
+    """`benchmark/readers/device.py::find_module` reads the step by
+    the one traced program whose name holds `_step_fn`: of every
+    program an engine can compile, chunked, speculating and on both
+    sides of a handoff, exactly the step's does."""
+    dnet, dparams = _toy_drafter()
+    engines = [
+        _engine(prefill_chunk=4, spec_k=2, drafter=dnet,
+                drafter_params=dparams),
+        _engine(role="prefill"), _engine(role="decode")]
+    names = []
+    for eng in engines:
+        eng.warm()
+        progs = [eng._compiled_step, eng._compiled_chunk,
+                 eng._compiled_draft, eng._compiled_verify,
+                 eng._compiled_draft_chunk,
+                 eng._compiled_handoff_export,
+                 eng._compiled_handoff_import,
+                 *eng._compiled_prefill.values(),
+                 *eng._compiled_draft_prefill.values()]
+        mine = [p.as_text().split("\n", 1)[0].split()[1].rstrip(",")
+                for p in progs if p is not None]
+        assert sum("_step_fn" in n for n in mine) == \
+            (eng.role != "prefill"), mine
+        names += mine
+    assert len(names) >= 10

@@ -206,8 +206,10 @@ def test_admit_runs_each_request_at_its_own_bucket(kind, kv):
     assert eng.prefill_counts == (2, 2)
     for tp, fn in eng._compiled_prefill.items():
         shapes = [a.shape for a in jax.tree_util.tree_leaves(
-            fn.args_info[0][2:6])]
-        assert shapes == [(1, tp), (1,), (1,), (1,)], shapes
+            fn.args_info[0][2:7])]
+        # the slots' last tokens, then the one prompt row
+        assert shapes == [(eng.max_slots,), (1, tp), (1,), (1,),
+                          (1,)], shapes
     more = _decode(eng, (s0, s1), 3)
     assert [t0] + more[s0] == want[0]
     assert [t1] + more[s1] == want[1]

@@ -275,6 +275,46 @@ def _xl_program(one_chip, program, cache_dtype=BF):
     return cache, compiled
 
 
+def test_engine_step_keeps_the_last_tokens_on_the_chip(
+        one_chip, as_on_the_chip):
+    """The engine's own step program, as `_get_step` compiles it:
+    the slots' last tokens go in as a device array and come out
+    aliased in place, donated like the cache, beside the vector the
+    host fetches, so the next step is dispatched with no host value
+    of this one; the program's name is what the readers look for."""
+    import re
+    from analytics_zoo_tpu.pipeline.api.keras.layers.transformer \
+        import TransformerLayer
+    from analytics_zoo_tpu.pipeline.inference import GenerationEngine
+    net = TransformerLayer(n_block=2, hidden_size=256, n_head=2,
+                           seq_len=256, vocab=512, hidden_p_drop=0.0,
+                           attn_p_drop=0.0, embed_p_drop=0.0)
+    params = jax.tree_util.tree_map(
+        lambda a: a.astype(BF), net.build(jax.random.key(0), (256,)))
+    eng = GenerationEngine(net, params, max_slots=8, max_context=256,
+                           page_size=16, cache_dtype=BF)
+    on_chip = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                       sharding=one_chip), tree)
+    structs = on_chip((
+        eng._abstract(eng.cache), eng._abstract(eng.params),
+        eng._shape(8), eng._shape(8, dtype=np.bool_),
+        eng._shape(8, dtype=np.float32), eng._abstract(eng._rng),
+        eng._shape()))
+    compiled = jax.jit(eng._step_fn, donate_argnums=(0, 2)).lower(
+        *structs).compile()
+    header = compiled.as_text().split("\n", 1)[0]
+    assert header.split()[1].rstrip(",") == "jit__step_fn"
+    n_cache = len(jax.tree_util.tree_leaves(eng.cache))
+    n_params = len(jax.tree_util.tree_leaves(eng.params))
+    # outputs: the cache's leaves, the last tokens, the fetched ones
+    aliased = dict(re.findall(r"\{(\d+)\}: \((\d+),", header))
+    assert aliased[str(n_cache)] == str(n_cache + n_params), header
+    out = compiled.output_shardings
+    assert len(jax.tree_util.tree_leaves(out)) == n_cache + 2
+    assert "zoo_paged_decode" in compiled.as_text()
+
+
 def _no_shape_leads_with(hlo, *dims):
     """No operand or result of the program has a shape whose leading
     dimensions are ``dims``, or their product (the same rows

@@ -220,9 +220,18 @@ def test_serving_single_trace_id_end_to_end(rng):
         resp = _post_predict(srv.port, x, trace_id="req-abc")
         assert json.loads(resp.read())["outputs"]
         assert resp.headers[tracing.TRACE_HEADER] == "req-abc"
-        dbg = json.loads(urllib.request.urlopen(
-            f"http://127.0.0.1:{srv.port}/debug/traces?n=50"
-        ).read())
+        # the batcher records `serving/scatter` after it has resolved
+        # the request's future: the answer can overtake the record
+        deadline = time.monotonic() + 5.0
+        while True:
+            dbg = json.loads(urllib.request.urlopen(
+                f"http://127.0.0.1:{srv.port}/debug/traces?n=50"
+            ).read())
+            if time.monotonic() > deadline or any(
+                    s["name"] == "serving/scatter"
+                    for t in dbg["traces"] for s in t["spans"]):
+                break
+            time.sleep(0.01)
     finally:
         srv.stop()
     assert dbg["enabled"] is True
@@ -475,7 +484,7 @@ def decode_records():
     ("decode/prefill", {"n", "bucket", "prompt_tokens", "calls",
                         "rows"}),
     ("decode/step", {"n", "dispatch_s", "fetch_s", "pages_live",
-                     "pages_table"}),
+                     "pages_table", "ahead"}),
     ("decode/release", {"slot", "tokens"}),
     ("decode/queue_wait", set()),
     ("decode/admit", {"slot", "prompt_len"}),
@@ -526,6 +535,43 @@ def test_decode_step_counts_the_pages_its_slots_hold(decode_records):
                for n, m in [(3, 6), (7, 4), (2, 8), (5, 5), (4, 7)]
                for j in range(m - 1))
     assert sum(f["pages_live"] for f in steps) == want
+
+
+def test_decode_step_says_when_it_ran_ahead(decode_records):
+    """`ahead` is 1 on a step dispatched while the step before was
+    unfetched (whose wait is then that span's `fetch_s`), 0 on one
+    dispatched with nothing in flight, which waits for nothing."""
+    steps = sorted((r for r in decode_records
+                    if r["name"] == "decode/step"),
+                   key=lambda r: r["t_start"])
+    flags = [r["fields"]["ahead"] for r in steps]
+    assert set(flags) == {0, 1} and flags[0] == 0
+    assert sum(flags) >= len(flags) - 3     # the loop stays ahead
+    for r in steps:
+        if not r["fields"]["ahead"]:
+            assert r["fields"]["fetch_s"] == 0.0
+
+
+def test_steps_ahead_counter_follows_the_span():
+    from analytics_zoo_tpu.common import observability as obs
+    from analytics_zoo_tpu.pipeline.inference.batching import \
+        ContinuousBatcher
+    before = {n: obs.counter(n).value for n in (
+        "zoo_tpu_decode_steps_ahead_total",
+        "zoo_tpu_serving_gen_steps_total",
+        "zoo_tpu_decode_rows_discarded_total")}
+    cb = ContinuousBatcher(_toy_engine(), queue_depth=4).start()
+    try:
+        cb.submit([1, 2, 3, 4, 5, 6, 7], max_new_tokens=6).result(
+            timeout=60)
+    finally:
+        cb.stop()
+    got = {n: obs.counter(n).value - v for n, v in before.items()}
+    # five steps behind the prefill's token; all but the first were
+    # dispatched with the one before unfetched
+    assert got == {"zoo_tpu_decode_steps_ahead_total": 4,
+                   "zoo_tpu_serving_gen_steps_total": 5,
+                   "zoo_tpu_decode_rows_discarded_total": 0}
 
 
 def test_decode_page_counters_follow_the_span():
@@ -766,7 +812,8 @@ def test_prefill_program_carries_its_scopes():
     eng = _toy_engine()
     ids = np.zeros((1, 32), np.int32)
     text = jax.jit(eng._prefill_fn).lower(
-        eng.cache, eng.params, ids, np.array([3], np.int32),
+        eng.cache, eng.params, eng._last_tok, ids,
+        np.array([3], np.int32),
         np.array([1], np.int32), eng._temps[:1], eng._rng,
         np.int32(0)).as_text(debug_info=True)
     for scope in ("zoo:kv_cache/write_prompt", "zoo:prefill/layer",
