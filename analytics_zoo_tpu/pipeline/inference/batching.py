@@ -639,12 +639,13 @@ class DynamicBatcher:
 class _GenEntry:
     """One queued generation request: prompt tokens, decode budget,
     sampling knobs, completion future, clocks, and — once admitted —
-    its slot and the tokens emitted so far."""
+    its slot, the tokens emitted so far and the times between them."""
 
     __slots__ = ("ids", "max_new", "temperature", "eos_id", "future",
                  "t_enq", "t_enq_wall", "trace", "slot", "tokens",
-                 "t_first", "prefilling", "handoff", "blob",
-                 "prompt_len", "ahead")
+                 "queue_s", "first_s", "chunks", "t_last", "gap_sum",
+                 "gap_max", "gaps_behind", "prefilling", "handoff",
+                 "blob", "prompt_len", "ahead")
 
     def __init__(self, ids, max_new, temperature, eos_id):
         self.ids = ids
@@ -657,7 +658,16 @@ class _GenEntry:
         self.trace = tracing.current()
         self.slot = -1
         self.tokens: "list[int]" = []
-        self.t_first = 0.0  # monotonic time of the first token
+        self.queue_s = 0.0  # submit to popped by the loop
+        self.first_s = 0.0  # submit to the first token handed out
+        self.chunks = 0     # chunk programs that wrote the prompt
+        # the gaps between its tokens: the time of the last hand-out,
+        # their sum and longest (there are ``len(tokens) - 1``), and
+        # how many closed behind another request's prompt program
+        self.t_last = 0.0
+        self.gap_sum = 0.0
+        self.gap_max = 0.0
+        self.gaps_behind = 0
         self.prefilling = False  # admitted, prompt not fully cached
         # disaggregation: None = ordinary request; "out" = prefill
         # side (future resolves to a handoff blob at first token);
@@ -672,6 +682,22 @@ class _GenEntry:
         # (its first, or a decode step's): counted, never waited for,
         # when the next step's mask is built
         self.ahead = 0
+
+
+class _Pass:
+    """What one pass of the loop dispatched and waited for, summed
+    from the handles' own times (`decode/iteration`'s ``dispatch_s``,
+    ``wait_s``, ``programs``)."""
+
+    __slots__ = ("dispatch_s", "wait_s", "programs")
+
+    def __init__(self):
+        self.dispatch_s = self.wait_s = 0.0
+        self.programs = 0
+
+    def sent(self, dispatch_s: float, programs: int = 1):
+        self.dispatch_s += dispatch_s
+        self.programs += programs
 
 
 class ContinuousBatcher:
@@ -710,14 +736,21 @@ class ContinuousBatcher:
     same loop is the synchronous order.
 
     Telemetry (docs/observability.md): one `decode/iteration` trace a
-    pass of the loop, whose children `decode/prefill` (the wait for
-    an admission's first tokens), `decode/step` (the dispatch of
-    step k and the fetch of step k - 1: the loop's period) and
-    `decode/release` time the engine calls; per request, the
-    already-timed `decode/queue_wait`, `decode/admit` (submit to
-    first token) and `decode/retire` (submit to last) records under
-    the request's own trace; slot-occupancy + free-page gauges, a
-    tokens counter and a time-to-first-token histogram.
+    pass of the loop (split into ``dispatch_s``, the compiled calls
+    returning, ``wait_s``, the blocking fetches, and the host's own
+    rest), whose children `decode/prefill` (the wait for an
+    admission's first tokens) and `decode/step` (the dispatch of
+    step k and the fetch of step k - 1: the loop's period) time the
+    engine calls; per request, the already-timed `decode/queue_wait`,
+    `decode/admit` (submit to the admission: the first token on the
+    one-row path, the slot claimed on the chunked one),
+    `decode/first_token` (submit to the first token handed out, on
+    every path) and `decode/retire` (submit to last, with the mean
+    and the longest gap between its tokens) records under the
+    request's own trace; slot-occupancy + free-page gauges, a tokens
+    counter, a time-to-first-token histogram, a histogram of the gaps
+    between a request's tokens and a counter of the gap seconds
+    spent behind other requests' prompt programs.
     ``ZOO_TPU_GEN_QUEUE_DEPTH`` bounds the wait queue (default 64;
     full → :class:`QueueFullError` → 503),
     ``ZOO_TPU_GEN_MAX_NEW`` caps any request's decode budget
@@ -738,7 +771,8 @@ class ContinuousBatcher:
         self._q: "deque[_GenEntry]" = deque()
         self._active: "list[_GenEntry]" = []
         # the decode step the last pass left running, its tokens
-        # unfetched: (handle, the entries in its mask), or None
+        # unfetched: (handle, the entries in its mask, whether its
+        # pass dispatched a prompt program before it), or None
         self._flight = None
         self._cond = threading.Condition()
         self._stop = False
@@ -760,6 +794,12 @@ class ContinuousBatcher:
     def _depth_gauge(self):
         return obs.gauge("zoo_tpu_serving_gen_queue_depth",
                          help="generation requests waiting for a slot")
+
+    def _gap_hist(self):
+        return obs.histogram(
+            "zoo_tpu_serving_gen_token_gap_seconds",
+            help="time between two tokens handed to one request",
+            buckets=obs.DEFAULT_BUCKETS[:13])   # 1 ms to 10 s
 
     # -- lifecycle ----------------------------------------------------------
     def start(self) -> "ContinuousBatcher":
@@ -944,14 +984,19 @@ class ContinuousBatcher:
         return entry.future
 
     # -- the decode loop ----------------------------------------------------
-    def _finish(self, e: "_GenEntry", now: float):
-        with obs.span("decode/release", slot=e.slot,
-                      tokens=len(e.tokens)):
-            self.engine.release(e.slot)
-        dur = now - e.t_enq
+    def _finish(self, e: "_GenEntry"):
+        self.engine.release(e.slot)
+        # submit to the last token's hand-out: with the time to first
+        # token, the gaps sum to it exactly
+        dur = e.t_last - e.t_enq
         self._ema_req_s = 0.8 * self._ema_req_s + 0.2 * dur
-        tracing.record_span(e.trace, "decode/retire", e.t_enq_wall,
-                            dur, slot=e.slot, tokens=len(e.tokens))
+        gaps = len(e.tokens) - 1
+        tracing.record_span(
+            e.trace, "decode/retire", e.t_enq_wall, dur, slot=e.slot,
+            tokens=len(e.tokens), first_token_s=round(e.first_s, 6),
+            gap_mean_s=round(e.gap_sum / gaps, 6) if gaps else 0.0,
+            gap_max_s=round(e.gap_max, 6),
+            gaps_behind_prompt=e.gaps_behind)
         e.future.set_result(np.asarray(e.tokens, np.int32))
 
     def _finish_handoff_out(self, e: "_GenEntry", now: float):
@@ -988,6 +1033,7 @@ class ContinuousBatcher:
                 continue
             now = time.monotonic()
             e.slot = slot
+            e.t_last = now  # its first gap here runs from the splice
             e.blob = None  # drop the host copy once spliced
             obs.histogram(
                 "zoo_tpu_serving_gen_handoff_seconds",
@@ -1011,15 +1057,32 @@ class ContinuousBatcher:
             else:
                 self._active.append(e)
 
-    def _token_out(self, e: "_GenEntry", tok: int, now: float
-                   ) -> bool:
-        """Record one emitted token; True when the request is done."""
+    def _token_out(self, e: "_GenEntry", tok: int, now: float,
+                   gaps) -> bool:
+        """Hand one token out: a request's first leaves its
+        ``decode/first_token`` record, whichever path admitted it;
+        every later one closes a gap, kept on the entry and observed
+        into ``gaps`` (:meth:`_gap_hist`, looked up by the caller).
+        True when the request is done."""
         if not e.tokens:
-            e.t_first = now
+            e.first_s = now - e.t_enq
             obs.histogram(
                 "zoo_tpu_serving_gen_ttft_seconds",
                 help="time from submit to first generated token"
-            ).observe(now - e.t_enq)
+            ).observe(e.first_s)
+            tracing.record_span(
+                e.trace, "decode/first_token", e.t_enq_wall,
+                e.first_s, slot=e.slot, prompt_len=len(e.ids),
+                path="handoff_out" if e.handoff == "out"
+                else "chunked" if e.chunks else "prefill",
+                chunks=e.chunks, queue_s=round(e.queue_s, 6))
+        else:
+            gap = now - e.t_last
+            e.gap_sum += gap
+            if gap > e.gap_max:
+                e.gap_max = gap
+            gaps.observe(gap)
+        e.t_last = now
         e.tokens.append(tok)
         if e.eos_id is not None and tok == e.eos_id:
             return True
@@ -1107,13 +1170,35 @@ class ContinuousBatcher:
             prompt_tokens=sum(len(e.ids) for e in entries),
             calls=calls, rows=rows)
 
-    def _tokens_in(self, pairs, now: float, done) -> int:
+    @staticmethod
+    def _gap_behind(e: "_GenEntry", now: float) -> float:
+        """The gap ``e``'s next token closes, counted on the entry as
+        spent behind another request's prompt program; 0.0 for its
+        first gap, which starts where its own prompt program ended."""
+        if len(e.tokens) < 2:
+            return 0.0
+        e.gaps_behind += 1
+        return now - e.t_last
+
+    def _count_behind(self, seconds: float):
+        obs.counter(
+            "zoo_tpu_serving_gen_token_gap_behind_prompt_seconds_total",
+            help="time between tokens that resident requests spent "
+                 "behind other requests' prompt programs"
+        ).inc(seconds)
+
+    def _tokens_in(self, pairs, now: float, done,
+                   behind: bool = False) -> int:
         """Hand fetched tokens, ``(entry, token)`` pairs, to their
         requests; one that finishes leaves the active set (to
         ``done``, or straight out as a handoff blob). A row that ran
         for a request which had already met its ``eos_id`` is
-        discarded and counted. Returns the tokens emitted."""
-        emitted = 0
+        discarded and counted. ``behind``: the step they come from
+        was dispatched behind a prompt program, and the gaps they
+        close are counted as such (one ``inc`` a step). Returns the
+        tokens emitted."""
+        emitted, behind_s = 0, 0.0
+        gaps = self._gap_hist()
         for e, tok in pairs:
             e.ahead -= 1
             if e.future.done():
@@ -1123,16 +1208,26 @@ class ContinuousBatcher:
                          "had already met its eos_id").inc()
                 continue
             emitted += 1
+            if behind:
+                behind_s += self._gap_behind(e, now)
             if e.handoff == "out":
-                self._token_out(e, tok, now)
+                self._token_out(e, tok, now, gaps)
                 self._active.remove(e)
                 self._finish_handoff_out(e, now)
-            elif self._token_out(e, tok, now):
+            elif self._token_out(e, tok, now, gaps):
                 self._active.remove(e)
                 done.append(e)
+        if behind:
+            self._count_behind(behind_s)
         return emitted
 
-    def _land(self, flight, done):
+    def _collect(self, h, ps: "_Pass"):
+        """``engine.collect`` with its wait added to the pass's."""
+        toks = self.engine.collect(h)
+        ps.wait_s += h.fetch_s
+        return toks
+
+    def _land(self, flight, done, ps: "_Pass"):
         """Fetch what this pass's admission and chunk left on the
         device — ``(span, handle, entries)`` each, the wait under
         its span (the programs' time on the device once what ran
@@ -1141,7 +1236,7 @@ class ContinuousBatcher:
         not admitted chunk by chunk, gets its ``decode/admit``."""
         for span, h, entries in flight:
             with span:
-                toks = self.engine.collect(h)
+                toks = self._collect(h, ps)
             now = time.monotonic()
             if span.name == "decode/prefill":
                 for e in entries:
@@ -1153,25 +1248,29 @@ class ContinuousBatcher:
 
     def _retire(self, done) -> int:
         """Finish the requests in ``done`` and empty it; how many."""
-        now = time.monotonic()
         n = len(done)
         while done:
-            self._finish(done.pop(0), now)
+            self._finish(done.pop(0))
         return n
 
     def _iterate(self, fresh: "list[_GenEntry]", it):
         """One pass of the loop (:meth:`_advance`); what finished in
         it is retired even when the pass ends in an error."""
         done: "list[_GenEntry]" = []
+        ps = _Pass()
         emitted = retired = 0
         try:
-            emitted, retired = self._advance(fresh, done)
+            emitted, retired = self._advance(fresh, done, ps)
         finally:
             retired += self._retire(done)
+        # the pass split: the span's length less `wait_s` is the
+        # host's own time, overlapped by the device or not
         it.annotate(admitted=len(fresh), active=len(self._active),
-                    emitted=emitted, retired=retired)
+                    emitted=emitted, retired=retired,
+                    dispatch_s=round(ps.dispatch_s, 6),
+                    wait_s=round(ps.wait_s, 6), programs=ps.programs)
 
-    def _advance(self, fresh: "list[_GenEntry]", done
+    def _advance(self, fresh: "list[_GenEntry]", done, ps: "_Pass"
                  ) -> "tuple[int, int]":
         """Admit ``fresh`` and advance a chunked prefill
         (programs dispatched, their first tokens left on the
@@ -1180,7 +1279,8 @@ class ContinuousBatcher:
         the pass before, whose finished requests are retired at once,
         and this pass's first tokens — all while the device runs the
         step. With nothing in flight (the first pass, after a drain,
-        under speculation) that is the synchronous order. Returns the
+        under speculation) that is the synchronous order. ``ps``
+        sums what the pass dispatched and waited for. Returns the
         tokens the steps emitted and the requests retired before the
         first tokens were waited for (the rest are left in
         ``done``)."""
@@ -1189,8 +1289,9 @@ class ContinuousBatcher:
         spec_k = engine.spec_k
         now = time.monotonic()
         for e in fresh:
+            e.queue_s = now - e.t_enq
             tracing.record_span(e.trace, "decode/queue_wait",
-                                e.t_enq_wall, now - e.t_enq)
+                                e.t_enq_wall, e.queue_s)
         # the step the pass before left running, if any
         older, self._flight = self._flight, None
         # what this pass's admission and chunk leave on the device
@@ -1232,6 +1333,7 @@ class ContinuousBatcher:
                         for e in short_p]
                 h = engine.admit_dispatch(reqs)
                 calls, rows = engine.prefill_counts
+                ps.sent(h.dispatch_s, calls)
                 for e, slot in zip(short_p, h.slots):
                     e.slot = slot
                     e.ahead = 1     # its first token
@@ -1246,25 +1348,32 @@ class ContinuousBatcher:
             # device and the slot decodes from this pass's step on
             n_mid = len(engine.prefilling_slots)
             h = engine.prefill_dispatch()
+            ps.sent(h.dispatch_s)
             obs.counter(
                 "zoo_tpu_serving_gen_prefill_chunks_total",
                 help="prompt chunks written by chunked "
                      "prefill").inc()
-            last_of = [e for e in self._active if e.slot in h.slots]
+            # whose chunk, the tokens it wrote, and the cached
+            # context it wrote them behind
+            slot, context, tokens = engine.chunk_work
+            mid = [e for e in self._active if e.slot == slot]
+            for e in mid:
+                e.chunks += 1
+            last_of = mid if h.slots else []
             for e in last_of:
                 e.prefilling = False
                 e.ahead = 1
-            # the tokens the chunk wrote, and the cached context it
-            # wrote them behind
-            _slot, context, tokens = engine.chunk_work
             flight.append((obs.span(
                 "decode/prefill_chunk", n=n_mid, tokens=tokens,
                 context=context), h, last_of))
         emitted = 0
+        # the step below runs behind this pass's prompt programs, and
+        # so do the tokens it brings
+        behind = bool(flight)
         if spec_k > 0:
             # a round's emission count is data the host reads before
             # it can build the next mask: nothing runs ahead
-            self._land(flight, done)
+            self._land(flight, done, ps)
             flight = []
         spec: "list[_GenEntry]" = []
         regular: "list[_GenEntry]" = []
@@ -1286,6 +1395,8 @@ class ContinuousBatcher:
             with obs.span("decode/spec_step",
                           n=len(spec)):
                 out, n_emit = engine.spec_step(active)
+            ps.sent(engine.spec_times[0], 2)    # draft and verify
+            ps.wait_s += engine.spec_times[1]
             now = time.monotonic()
             obs.counter(
                 "zoo_tpu_serving_gen_spec_proposed_total",
@@ -1297,17 +1408,25 @@ class ContinuousBatcher:
                 help="draft tokens accepted by the "
                      "target model").inc(
                 engine.spec_accepted - prev_acc)
+            # a round's tokens are handed out at one instant: the
+            # first closes the gap since the round before, the rest
+            # gaps of 0
+            gaps, behind_s = self._gap_hist(), 0.0
             for e in spec:
                 fin = False
+                if behind:
+                    behind_s += self._gap_behind(e, now)
                 for j in range(int(n_emit[e.slot])):
                     emitted += 1
                     if self._token_out(
-                            e, int(out[e.slot, j]), now):
+                            e, int(out[e.slot, j]), now, gaps):
                         fin = True
                         break
                 if fin:
                     done.append(e)
                     self._active.remove(e)
+            if behind:
+                self._count_behind(behind_s)
         # the step whose tokens this pass waits for
         waited = toks = None
         if regular:
@@ -1330,14 +1449,16 @@ class ContinuousBatcher:
                           pages_table=pages_table,
                           ahead=int(older is not None)) as sp:
                 h = engine.dispatch(active)
+                ps.sent(h.dispatch_s)
                 for e in regular:
                     e.ahead += 1
                 # wait for the step before while this one runs behind
                 # it; under speculation, for this step itself
-                waited, self._flight = ((h, regular), None) \
-                    if spec_k > 0 else (older, (h, regular))
+                mine = (h, regular, behind)
+                waited, self._flight = (mine, None) \
+                    if spec_k > 0 else (older, mine)
                 if waited is not None:
-                    toks = engine.collect(waited[0])
+                    toks = self._collect(waited[0], ps)
                 # the span's two waits: the compiled call returning,
                 # then the tokens
                 sp.annotate(
@@ -1360,16 +1481,16 @@ class ContinuousBatcher:
                      "(slots x pages a slot)").inc(pages_table)
         elif older is not None:
             # nothing to run behind it: the last step's tokens
-            waited, toks = older, engine.collect(older[0])
+            waited, toks = older, self._collect(older[0], ps)
         if waited is not None:
             emitted += self._tokens_in(
                 [(e, int(toks[e.slot])) for e in waited[1]],
-                time.monotonic(), done)
+                time.monotonic(), done, behind=waited[2])
         # answers leave before the wait for this pass's prompt
         # programs (a chunk runs for 0.1-0.8 s): the client's next
         # request is in the queue when the pass ends
         retired = self._retire(done)
-        self._land(flight, done)
+        self._land(flight, done, ps)
         if spec or regular:
             obs.counter(
                 "zoo_tpu_serving_gen_steps_total",

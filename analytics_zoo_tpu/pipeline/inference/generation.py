@@ -150,8 +150,9 @@ class Dispatched:
     first ``width`` entries. ``slots``: whose first tokens a
     prefill's or a prompt's last chunk's are, in the order of
     ``outs`` (empty for a step, and for a chunk with prompt left).
-    ``dispatch_s``: a step's compiled call returning; ``fetch_s``:
-    the wait for the tokens, once collected."""
+    ``dispatch_s``: the compiled calls returning (a step's one, an
+    admission's one a request, a chunk's); ``fetch_s``: the wait for
+    the tokens, once collected."""
 
     __slots__ = ("outs", "width", "slots", "dispatch_s", "fetch_s")
 
@@ -314,6 +315,9 @@ class GenerationEngine:
         # speculative acceptance accounting (bench + /health)
         self.spec_proposed = 0
         self.spec_accepted = 0
+        # (dispatch_s, fetch_s) of the latest `spec_step`: its two
+        # compiled calls returning, then the wait for what they hold
+        self.spec_times = (0.0, 0.0)
 
         # prompt-length buckets: powers of two from the floor up,
         # capped at what the position table and the cache can hold
@@ -803,6 +807,7 @@ class GenerationEngine:
         admitted = [self._claim_slot(*r) for r in requests]
         self._push_table()
         firsts, rows = [], 0
+        t0 = time.perf_counter()
         for slot, (prompt_ids, _, _) in zip(admitted, requests):
             n = len(prompt_ids)
             tp = self.prompt_bucket(n)
@@ -824,7 +829,8 @@ class GenerationEngine:
                     self._draft_cache, self.drafter_params, ids,
                     plens, at)
         self.prefill_counts = (len(firsts), rows)
-        return Dispatched(firsts, 1, admitted)
+        return Dispatched(firsts, 1, admitted,
+                          dispatch_s=time.perf_counter() - t0)
 
     def _claim_slot(self, prompt_ids, max_new, temperature) -> int:
         """Allocate pages + a slot + its table row for one request
@@ -932,6 +938,7 @@ class GenerationEngine:
         starts = np.full((1,), off, np.int32)
         n_new = np.full((1,), n, np.int32)
         at = np.full((1,), slot, np.int32)
+        t0 = time.perf_counter()
         self.cache, self._last_tok, tok = self._get_chunk()(
             self.cache, self.params, self._last_tok, row, starts,
             n_new, at, self._temps[slot:slot + 1].copy(), self._rng,
@@ -942,11 +949,12 @@ class GenerationEngine:
                 self._draft_cache, self.drafter_params, row, starts,
                 n_new, at)
         self.chunk_work = (slot, off, n)
+        dispatch_s = time.perf_counter() - t0
         if off + n < len(ids):
             st[1] = off + n
             self._pending_prompts[slot] = st    # to the back
-            return Dispatched([tok], 1)
-        return Dispatched([tok], 1, [slot])
+            return Dispatched([tok], 1, dispatch_s=dispatch_s)
+        return Dispatched([tok], 1, [slot], dispatch_s=dispatch_s)
 
     def step(self, active: np.ndarray) -> np.ndarray:
         """One decode iteration over the WHOLE slot array, start to
@@ -1001,6 +1009,7 @@ class GenerationEngine:
         active = np.asarray(active, np.bool_)
         dfn, vfn = self._get_draft(), self._get_verify()
         temps = self._temps.copy()
+        t0 = time.perf_counter()
         self._draft_cache, drafts, qprobs = dfn(
             self._draft_cache, self.drafter_params, self._last_tok,
             active, temps, self._rng, np.int32(self._step_id))
@@ -1011,12 +1020,14 @@ class GenerationEngine:
             self._last_tok, drafts, qprobs, active, temps, self._rng,
             np.int32(self._step_id))
         self._step_id += 1
+        t1 = time.perf_counter()
         out = np.asarray(out)
         n_emit = np.where(active, np.asarray(n_emit), 0)
         n_active = int(active.sum())
         self.spec_proposed += self.spec_k * n_active
         self.spec_accepted += int(
             np.asarray(n_acc)[active].sum()) if n_active else 0
+        self.spec_times = (t1 - t0, time.perf_counter() - t1)
         return out, n_emit
 
     def release(self, slot: int):

@@ -480,15 +480,19 @@ def decode_records():
 
 @pytest.mark.parametrize("name,fields", [
     ("decode/iteration", {"admitted", "active", "emitted",
-                          "retired"}),
+                          "retired", "dispatch_s", "wait_s",
+                          "programs"}),
     ("decode/prefill", {"n", "bucket", "prompt_tokens", "calls",
                         "rows"}),
     ("decode/step", {"n", "dispatch_s", "fetch_s", "pages_live",
                      "pages_table", "ahead"}),
-    ("decode/release", {"slot", "tokens"}),
+    ("decode/first_token", {"slot", "prompt_len", "path", "chunks",
+                            "queue_s"}),
     ("decode/queue_wait", set()),
     ("decode/admit", {"slot", "prompt_len"}),
-    ("decode/retire", {"slot", "tokens"}),
+    ("decode/retire", {"slot", "tokens", "first_token_s",
+                       "gap_mean_s", "gap_max_s",
+                       "gaps_behind_prompt"}),
 ])
 def test_decode_span_in_store_with_fields(decode_records, name,
                                           fields):
@@ -497,8 +501,15 @@ def test_decode_span_in_store_with_fields(decode_records, name,
     for r in recs:
         assert fields <= set(r["fields"]), r
     if name in ("decode/queue_wait", "decode/admit", "decode/retire",
-                "decode/release"):
+                "decode/first_token"):
         assert len(recs) == 5       # one a request
+    if name == "decode/first_token":
+        # the one-row path: the admission's fetch brings the token
+        admits = {r["trace_id"]: r["dur_s"] for r in decode_records
+                  if r["name"] == "decode/admit"}
+        for r in recs:
+            assert r["fields"]["path"] == "prefill"
+            assert r["dur_s"] == admits[r["trace_id"]]
     if name == "decode/step":
         for r in recs:
             f = r["fields"]
@@ -593,7 +604,9 @@ def test_decode_page_counters_follow_the_span():
 def test_decode_children_of_one_iteration(decode_records):
     its = {r["span_id"]: r for r in decode_records
            if r["name"] == "decode/iteration"}
-    for name in ("decode/prefill", "decode/step", "decode/release"):
+    assert not [r for r in decode_records
+                if r["name"] == "decode/release"]
+    for name in ("decode/prefill", "decode/step"):
         for r in decode_records:
             if r["name"] == name:
                 parent = its[r["parent_id"]]
