@@ -299,15 +299,71 @@ def topk_mask(scores: jnp.ndarray, visible: jnp.ndarray, k: int
     return jnp.logical_and(jnp.logical_or(above, take), visible)
 
 
+def masked_flash_blocks(q, k, v):
+    """The (block_q, block_k) with which :func:`masked_attention`
+    takes the chunk kernel (`ops.flash_attention.
+    masked_chunk_attention`) for these operands, or None where it
+    keeps the XLA body. Decided by what the call can observe, nothing
+    to set: the backend runs the Pallas kernels (`flash_backend_ok`),
+    ``ZOO_TPU_ATTENTION`` does not force XLA, the operands are floating point
+    of one dtype, keys and values are at most 256 wide, and the
+    queries a row are a whole number of the kernel's query blocks
+    (a multiple of 128)."""
+    if resolve_attention_impl(None) == "xla" or not flash_backend_ok():
+        return None
+    dt = jnp.dtype(q.dtype)
+    if not jnp.issubdtype(dt, jnp.floating) or dt.itemsize > 4 or \
+            k.dtype != dt or v.dtype != dt or \
+            max(q.shape[-1], v.shape[-1]) > 256:
+        return None
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    return fa.chunk_blocks(q.shape[1], k.shape[1])
+
+
+def mask_tile_counts(q, k, v, mask) -> jnp.ndarray:
+    """int32 ``[tiles, tiles run]`` of one :func:`masked_attention`
+    call with these operands: the size and the sum of the table of
+    occupied tiles the chunk kernel is handed; zeros where the call
+    keeps the XLA body, which has no tiles."""
+    blocks = masked_flash_blocks(q, k, v)
+    if blocks is None:
+        return jnp.zeros((2,), jnp.int32)
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    occ = fa.mask_tiles(mask, *blocks)
+    return jnp.stack([jnp.asarray(occ.size, jnp.int32), jnp.sum(occ)])
+
+
 def masked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                      mask: jnp.ndarray, scale: float,
                      q_block: int = 256) -> jnp.ndarray:
-    """Dense attention of a chunk's queries ``q`` (A, C, H, D) over
-    keys ``k`` (A, T, H, D) and values ``v`` (A, T, H, Dv) under an
+    """Attention of a chunk's queries ``q`` (A, C, H, D) over keys
+    ``k`` (A, T, H, D) and values ``v`` (A, T, H, Dv) under an
     arbitrary ``mask`` (A, C, T) (1 = attend: causality, a window, an
-    indexer's choice), ``q_block`` queries at a time so that the f32
-    scores are (A, H, q_block, T). A query with no key gets a uniform
-    softmax, never NaN: the caller drops it. Returns (A, C, H, Dv)."""
+    indexer's choice), softmax in f32, the probabilities rounded to
+    the operands' dtype before the second product. A query with no
+    key gets a finite row, never NaN: the caller drops it. Returns
+    (A, C, H, Dv).
+
+    Where :func:`masked_flash_blocks` allows, one pass of the chunk
+    kernel that keeps the scores on the chip and runs no tile of the
+    mask that is empty; else (the CPU, ``ZOO_TPU_ATTENTION=xla``, a C
+    that is no multiple of 128, as a whole prompt under
+    `PatternDecoder.prefill` may be) the dense XLA body under it, the
+    kernel's reference: ``q_block`` queries at a time, so that the
+    f32 scores are (A, H, q_block, T)."""
+    blocks = masked_flash_blocks(q, k, v)
+    if blocks is not None:
+        from analytics_zoo_tpu.ops import flash_attention as fa
+        return fa.masked_chunk_attention(
+            q, k, v, mask, scale, block_q=blocks[0], block_k=blocks[1])
+    return _masked_attention_xla(q, k, v, mask, scale, q_block)
+
+
+def _masked_attention_xla(q, k, v, mask, scale: float,
+                          q_block: int = 256) -> jnp.ndarray:
+    """:func:`masked_attention` as dense products: every query with
+    every key, the mask applied to the scores. A query with no key
+    gets a uniform softmax."""
     a, c, h, d = q.shape
     qb = q_block if c % q_block == 0 else c
 

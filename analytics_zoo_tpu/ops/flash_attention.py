@@ -1109,6 +1109,189 @@ def paged_gqa_supported(page_size: int, dtype, row_width: int,
         row_width % 128 == 0 and k_width + v_width <= row_width
 
 
+# -- a chunk's attention under an arbitrary mask ----------------------------
+# `ops.attention.masked_attention` on the chip: the flash recursion over
+# (block_q, block_k) tiles of a mask that no rule describes (an indexer's
+# top-k, a window over a ring), with a table of the tiles that hold a 1.
+# One grid step is one tile of the mask against ALL the heads of the
+# call: the mask tile is read and turned into its additive form once for
+# the H heads, which is what makes an int8 tile (as many bytes as a
+# head's K and V tiles together) cheap enough to carry.
+
+
+def chunk_blocks(c: int, t: int) -> "Optional[tuple[int, int]]":
+    """(block_q, block_k) of `masked_chunk_attention` for ``c``
+    queries a row against ``t`` keys: the largest block of queries
+    among 512, 256 and 128 that divides ``c`` (none does: None, the
+    kernel does not take the call) against 1024 keys, or all of
+    fewer rounded up to a lane tile.
+
+    Read on the v5e at dots3-note's shapes, 2048 queries of 16 bf16
+    heads a call, milliseconds a call with the transposes and the
+    mask's preparation (my chip run, PR 39,
+    `scripts/chunk_attention_sweep.py`), behind 32768 / 8192 / 0
+    cached keys on a full layer (keys 192 wide) and on a sliding one
+    (4624 keys, 256 wide), where the XLA body takes 23.95 / 5.93 /
+    0.60 and 2.82: 256x256 18.10 / 5.25 / 1.01 and 1.08; 512x512
+    10.46 / 3.12 / 0.72 and 1.04; 512x1024 7.22 / 2.43 / 0.59 and
+    1.01; 1024x1024 6.70 / 2.15 / 0.59 and 1.01; 512x2048 7.32 /
+    2.39 / 0.61 and 1.26. A step's fixed cost (16 heads' statistics
+    read and written) is paid a tile, so wide tiles win until the
+    skip's grain costs what they save; past 512x1024 nothing is left
+    to win and VMEM doubles."""
+    bq = next((b for b in (512, 256, 128) if c % b == 0), None)
+    return bq and (bq, min(1024, -(-t // 128) * 128))
+
+
+def mask_tiles(mask: jnp.ndarray, block_q: int, block_k: int
+               ) -> jnp.ndarray:
+    """``occupied`` (A, C / block_q, ceil(T / block_k)) int32 of a
+    mask (A, C, T): 1 where the tile holds a key some query of the
+    block sees."""
+    a, c, t = mask.shape
+    nk = -(-t // block_k)
+    m = jnp.pad(mask.astype(jnp.bool_),
+                [(0, 0), (0, 0), (0, nk * block_k - t)])
+    return jnp.any(m.reshape(a, c // block_q, block_q, nk, block_k),
+                   axis=(2, 4)).astype(jnp.int32)
+
+
+def _chunk_kernel(occ_ref, idx_ref, q_ref, k_ref, v_ref, mask_ref,
+                  o_ref, acc_ref, m_ref, l_ref, *, scale: float):
+    """Grid (A, nq, nk): one (block_q, block_k) tile of the mask
+    against every head. Scalar prefetch: ``occ_ref`` and ``idx_ref``
+    (A * nq * nk,), whether the tile holds a 1 and the key block the
+    index maps fetched for it (its own where occupied, else the
+    block already there). ``q_ref`` (1, H, block_q, D), ``k_ref``
+    (1, H, block_k, D), ``v_ref`` (1, H, block_k, Dv), ``mask_ref``
+    (1, block_q, block_k) int8, ``o_ref`` (1, H, block_q, Dv).
+    Scratch, one a head: ``acc_ref`` (H, block_q, Dv) f32,
+    ``m_ref``/``l_ref`` (H, block_q, 128) f32."""
+    del idx_ref
+    nq, nk = pl.num_programs(1), pl.num_programs(2)
+    ki = pl.program_id(2)
+    heads = q_ref.shape[1]
+
+    @pl.when(ki == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+
+    @pl.when(occ_ref[(pl.program_id(0) * nq + pl.program_id(1)) * nk
+                     + ki] > 0)
+    def _tile():
+        # s * scale + (0 | -1e30) is where(mask, s * scale, -1e30) to
+        # the bit: a score is nowhere near 1e30 * 2^-24
+        bias = jnp.where(mask_ref[0].astype(jnp.int32) != 0, 0.0,
+                         _NEG_INF)
+
+        def head(h, carry):
+            s = jax.lax.dot_general(
+                q_ref[0, h], k_ref[0, h], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale + bias
+            _softmax_accumulate(s, v_ref[0, h], acc_ref.at[h],
+                                m_ref.at[h], l_ref.at[h])
+            return carry
+
+        jax.lax.fori_loop(0, heads, head, 0)
+
+    @pl.when(ki == nk - 1)
+    def _final():
+        # a block of queries with no tile at all: 0 / 1e-30 of zeros
+        o_ref[0] = (acc_ref[:] / jnp.maximum(
+            l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
+
+
+def masked_chunk_attention(q: jnp.ndarray, k: jnp.ndarray,
+                           v: jnp.ndarray, mask: jnp.ndarray,
+                           scale: float, *, block_q: int,
+                           block_k: int, skip: bool = True,
+                           interpret: Optional[bool] = None
+                           ) -> jnp.ndarray:
+    """Softmax attention of queries ``q`` (A, C, H, D) over keys ``k``
+    (A, T, H, D) and values ``v`` (A, T, H, Dv) under an arbitrary
+    ``mask`` (A, C, T) shared by the heads, in one pass
+    (`zoo_flash_chunk`): the flash recursion, f32 statistics in VMEM,
+    the probabilities rounded to the operands' dtype before the
+    second product, and no tile of the mask run that holds no 1
+    (`mask_tiles`; ``skip=False`` runs every tile, to the same
+    result). Any T: keys, values and mask are padded to ``block_k``
+    with keys no query sees, and values to whole lane tiles of 128.
+    C a multiple of ``block_q``. A query with no key comes out
+    finite (the mean of the values of the tiles its block ran, or
+    0). Returns (A, C, H, Dv)."""
+    global invocations
+    invocations += 1
+    if interpret is None:
+        interpret = not on_tpu()
+    a, c, h, d = q.shape
+    t, dv_in = k.shape[1], v.shape[-1]
+    dv = -(-dv_in // 128) * 128       # a head's accumulator: whole tiles
+    nq, nk = c // block_q, -(-t // block_k)
+    if c % block_q:
+        raise ValueError(f"masked_chunk_attention: {c} queries in "
+                         f"blocks of {block_q}")
+    pad = (0, nk * block_k - t)
+    mask = jnp.pad(mask.astype(jnp.int8), [(0, 0), (0, 0), pad])
+    occ = mask_tiles(mask, block_q, block_k)
+    if not skip:
+        occ = jnp.ones_like(occ)
+    # the block an unoccupied tile leaves in place: the last occupied
+    # one before it, or the first to come
+    ks = jnp.arange(nk, dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(occ > 0, ks, -1), axis=2)
+    idx = jnp.where(last >= 0, last,
+                    jnp.argmax(occ, axis=2).astype(jnp.int32)[..., None])
+    heads_major = lambda x, w: jnp.transpose(
+        jnp.pad(x, [(0, 0), pad, (0, 0), (0, w - x.shape[-1])]),
+        (0, 2, 1, 3))
+    at = lambda i, j, n, idx_ref: idx_ref[(i * nq + j) * nk + n]
+    # what a step holds in VMEM: the blocks of q, out, k, v and the
+    # mask twice (the pipeline's two buffers), the heads' accumulators
+    # and statistics, and a handful of f32 tiles of scores in flight;
+    # at 512 x 1024 and 16 heads of 192 + 128 about 58 MB of the
+    # chip's 128
+    item = jnp.dtype(q.dtype).itemsize
+    vmem = 2 * (h * (block_q + block_k) * (d + dv) * item
+                + block_q * block_k) \
+        + h * block_q * (dv + 256) * 4 + 6 * block_q * block_k * 4
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, scale=float(scale)),
+        name="zoo_flash_chunk",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(a, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, h, block_q, d),
+                             lambda i, j, n, *_: (i, 0, j, 0)),
+                pl.BlockSpec((1, h, block_k, d),
+                             lambda i, j, n, _, idx_ref:
+                             (i, 0, at(i, j, n, idx_ref), 0)),
+                pl.BlockSpec((1, h, block_k, dv),
+                             lambda i, j, n, _, idx_ref:
+                             (i, 0, at(i, j, n, idx_ref), 0)),
+                pl.BlockSpec((1, block_q, block_k),
+                             lambda i, j, n, _, idx_ref:
+                             (i, j, at(i, j, n, idx_ref))),
+            ],
+            out_specs=pl.BlockSpec((1, h, block_q, dv),
+                                   lambda i, j, n, *_: (i, 0, j, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((h, block_q, dv), jnp.float32),
+                pltpu.VMEM((h, block_q, 128), jnp.float32),
+                pltpu.VMEM((h, block_q, 128), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((a, h, c, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=int(vmem) + (8 << 20)),
+        interpret=interpret,
+    )(occ.reshape(-1), idx.reshape(-1), jnp.transpose(q, (0, 2, 1, 3)),
+      heads_major(k, d), heads_major(v, dv), mask)
+    return jnp.transpose(out, (0, 2, 1, 3))[..., :dv_in]
+
+
 def as_key_mask(mask, b: int, tk: int):
     """Reduce an attention mask (broadcastable to (B, H, Tq, Tk)) to
     the kernel-native (B, Tk) key-validity form, or None if it varies

@@ -49,8 +49,8 @@ import numpy as np
 from analytics_zoo_tpu.ops.attention import (
     banded_attention, dot_product_attention, gqa_decode_attention,
     grouped_attention, index_scores, latent_decode_attention,
-    masked_attention, mla_decode_attention, resolve_attention_impl,
-    topk_mask)
+    mask_tile_counts, masked_attention, mla_decode_attention,
+    resolve_attention_impl, topk_mask)
 from analytics_zoo_tpu.pipeline.api.keras.engine import (KerasLayer,
                                                          ShapeLike)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
@@ -195,6 +195,7 @@ class LatentAttention:
     ``sqrt(hidden / rank)`` (LongCat-Flash's scale correction)."""
 
     # a window part's chunk is handed the whole ring and masks it
+    # (`masked_attention` runs the tiles the window reaches)
     banded = False
 
     def __init__(self, hidden_size: int, n_head: int,
@@ -453,7 +454,8 @@ class LatentAttention:
         flight. The mask is the part's kind: causal, and the window
         or the indexer's exact top-k over causal keys. Returns
         ``(out (A, C, hidden), rows)`` as :meth:`decode`, rows
-        (A, C, width) unpadded."""
+        (A, C, width) unpadded, and under ``rows["tiles"]`` the
+        int32 :data:`CHUNK_TILE_COUNTERS` of its attention calls."""
         a, c, _ = x.shape
         dt = x.dtype
         nb, nh = self.head_block, self.n_head
@@ -487,6 +489,10 @@ class LatentAttention:
                 mask = topk_mask(scores, mask, self.indexer.top_k)
         c_kv, k_pe = lat[..., :self.kv_rank], lat[..., self.kv_rank:]
         t = lat.shape[1]
+        # one table of tiles a head block, all of them this mask's
+        heads = lambda n, w: jax.ShapeDtypeStruct((a, n, nb, w), dt)
+        rows["tiles"] = (nh // nb) * mask_tile_counts(
+            heads(c, qk), heads(t, qk), heads(t, self.v_dim), mask)
         q_b = p["q_b"].astype(dt).reshape(self.q_rank, nh // nb,
                                           nb * qk)
         kv_b = p["kv_b"].astype(dt).reshape(
@@ -776,10 +782,28 @@ ATTENTION_COUNTERS = ("zoo_tpu_dsa_keys_visible_total",
                       "zoo_tpu_window_pages_recycled_total")
 
 
+# per call, as int32: over the attention calls of the latent layers'
+# chunks (one a head block and layer), the (query block, key block)
+# tiles of their masks and those that held a key and were run; zeros
+# where `ops.attention.masked_attention` keeps its XLA body
+CHUNK_TILE_COUNTERS = ("zoo_tpu_chunk_attn_tiles_total",
+                       "zoo_tpu_chunk_attn_tiles_run_total")
+
+
 def _record_attention(counts):
-    """Add one call's three counts to :data:`ATTENTION_COUNTERS`."""
+    """Add one call's counts to :data:`ATTENTION_COUNTERS` and, where
+    the call has them, to :data:`CHUNK_TILE_COUNTERS` behind them."""
     from analytics_zoo_tpu.common import observability as obs
-    visible, kept, recycled = (int(c) for c in counts)
+    visible, kept, recycled, *tiles = (int(c) for c in counts)
+    if tiles:
+        obs.counter(
+            "zoo_tpu_chunk_attn_tiles_total",
+            help="mask tiles (query block x key block) of the chunk "
+            "attention kernel's calls").inc(tiles[0])
+        obs.counter(
+            "zoo_tpu_chunk_attn_tiles_run_total",
+            help="those that held a key a query of the block sees, "
+            "the only ones the kernel runs").inc(tiles[1])
     obs.counter(
         "zoo_tpu_dsa_keys_visible_total",
         help="keys visible to the queries of the sparse-attention "
@@ -814,7 +838,8 @@ class PatternDecoder(KerasLayer):
     the most tokens one chunk may write (:meth:`init_kv_cache`'s
     argument: the engine passes its own). A part that counts (an
     expert layer's assignments, a sparse attention's keys, a window's
-    recycled pages) names its counts in ``step_counters``;
+    recycled pages, the mask tiles a latent layer's chunk runs) names
+    its counts in ``step_counters``;
     ``decode_step`` and ``forward_chunk`` with ``stats=True`` then
     also return their sums over the layers as one int32 vector, which
     :meth:`record_step_counts` adds to the counters of those
@@ -864,9 +889,13 @@ class PatternDecoder(KerasLayer):
         self._plain = all(a.plain for a in self.attentions)
         self._counting = next(
             (f for f in self.feed_forward if f.step_counters), None)
+        # the parts whose chunks go through `masked_attention`
+        self._tiled = any(isinstance(a, LatentAttention) and not a.plain
+                          for a in self.attentions)
         self.step_counters = (
             self._counting.step_counters if self._counting else ()) + (
-            () if self._plain else ATTENTION_COUNTERS)
+            () if self._plain else ATTENTION_COUNTERS) + (
+            CHUNK_TILE_COUNTERS if self._tiled else ())
 
     def build(self, rng, input_shape: ShapeLike) -> dict:
         r, h = self.initializer_range, self.hidden_size
@@ -1056,7 +1085,8 @@ class PatternDecoder(KerasLayer):
         if not stats:
             return cache, logits
         return cache, logits, self._counts(
-            counts, jnp.zeros_like(prompt_lens), prompt_lens, cache)
+            counts, jnp.zeros_like(prompt_lens), prompt_lens, cache,
+            rows)
 
     def _ctx_ladder(self, cache, chunk: int) -> "tuple[int, ...]":
         """The cached-context lengths a chunk program of ``chunk``
@@ -1160,15 +1190,20 @@ class PatternDecoder(KerasLayer):
         if not stats:
             return cache, logits
         return cache, logits, self._counts(
-            counts, starts, n_new, cache)
+            counts, starts, n_new, cache, rows)
 
-    def _counts(self, ffn_counts, first, n_new, cache):
+    def _counts(self, ffn_counts, first, n_new, cache, rows=()):
+        """The sums of ``step_counters`` of one call; ``rows``: what
+        the layers' chunks returned (a step has no tiles)."""
         out = [sum(ffn_counts)] if ffn_counts else []
         if not self._plain:
             out.append(self._attention_counts(
                 first, n_new,
                 cache.window_ring if cache.window is not None else 0,
                 cache.page_size))
+        if self._tiled:
+            out.append(sum((r["tiles"] for r in rows if "tiles" in r),
+                           jnp.zeros((2,), jnp.int32)))
         return jnp.concatenate(out) if out else \
             jnp.zeros((0,), jnp.int32)
 

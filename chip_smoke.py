@@ -677,6 +677,25 @@ def phase_kernels(sz: Sizes, seed: int, clock: _CompileClock,
             interpret=interp),
         _by_batch(partial_ref), q[:, :half], k[:, :half], v[:, :half])
 
+    # a chunk's attention under a mask no rule describes: the last
+    # half of the positions as queries over all the keys but their
+    # last 40, values half as wide as the keys; a window of a quarter
+    # of the context and every third key before it, so that tiles
+    # above the diagonal are skipped and those below it are not
+    tc = a["t"] - 40
+    back = (half + np.arange(half))[:, None] - np.arange(tc)[None, :]
+    cmask = jnp.asarray(np.broadcast_to(
+        (back >= 0) & ((back < a["t"] // 4) | (back % 3 == 0)),
+        (a["b"], half, tc)))
+    bq, bk = fa.chunk_blocks(half, tc)
+    run("masked_chunk_attention",
+        lambda q, k, v, m: fa.masked_chunk_attention(
+            q, k, v, m, scale, block_q=bq, block_k=bk,
+            interpret=interp),
+        _by_batch(lambda q, k, v, m: att._masked_attention_xla(
+            q, k, v, m, scale)),
+        q[:, half:], k[:, :tc], v[:, :tc, :, :a["d"] // 2], cmask)
+
     # -- flash decode (bf16 and int8 cache) ------------------------------
     d = sz.decode
     dq = rnd(d["s"], d["h"], d["d"])

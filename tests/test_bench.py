@@ -338,10 +338,11 @@ def test_chip_smoke_rehearsal_lines_say_what_ran(rehearsal):
     assert gen["train_step"]["attention"] == "flash"
     kern = by["kernels"]
     assert kern["interpret"] is True      # the Pallas interpreter
-    # the flash attention family and the two paged decode kernels
-    # are every Pallas kernel there is
-    assert len(kern["cases"]) == 8
+    # the flash attention family, the chunk kernel under
+    # `masked_attention` and the two paged decode kernels are every
+    # Pallas kernel there is
+    assert len(kern["cases"]) == 9
     assert all(c["passed"] for c in kern["cases"])
     assert sorted({c["kernel"].split("_")[0] for c in kern["cases"]}
-                  ) == ["flash", "paged"]
+                  ) == ["flash", "masked", "paged"]
     assert "fused_resnet50_step" not in kern

@@ -450,3 +450,159 @@ def test_batcher_serves_long_prompts_through_both_pools(ids):
         whole.release(slot)
         assert list(got[[0, 1, 2].index(r)]) == toks
     assert eng.free_pages == eng.allocator.max_pages
+
+
+# -- the chunk attention kernel against the XLA body it sits on -------
+
+def _chunk_case(name):
+    """(q, k, v, mask, block_q, block_k, tiles by hand or None) of one
+    case at the interpreter's size: 256 queries of 2 heads."""
+    rs = np.random.RandomState(11)
+    c, h = 256, 2
+    rand = lambda *s: jnp.asarray(rs.randn(*s), F32)
+    if name in ("topk_behind_an_unfilled_bucket", "bfloat16"):
+        # a bucket of 1024 cached keys of which 300 are written, the
+        # chunk's own 256 behind them, the 64 of largest score kept
+        t_ctx, starts, d = 1024, 300, 192
+        q_pos = starts + np.arange(c)
+        k_pos = np.concatenate([np.arange(t_ctx), q_pos])
+        ok = np.concatenate([np.arange(t_ctx) < starts,
+                             np.ones(c, bool)])
+        vis = ok[None] & (k_pos[None] <= q_pos[:, None])
+        mask = topk_mask(rand(1, c, t_ctx + c), jnp.asarray(vis[None]),
+                         64)
+        # key blocks of 256: block 0 and the 44 written keys of block
+        # 1 for both query blocks; blocks 2 and 3 hold nothing; of
+        # the chunk's own block 4 every query sees its half or more
+        tiles, bq, bk = [[1, 1, 0, 0, 1]] * 2, 128, 256
+    elif name == "window_over_a_ragged_ring":
+        # a ring of 400 positions of which the last 100 before the
+        # chunk are written, then the chunk: 656 keys, no multiple of
+        # 128; a window of 65
+        ring, starts, d = 400, 500, 256
+        q_pos = starts + np.arange(c)
+        k_pos = np.concatenate([starts - 100 + np.arange(ring), q_pos])
+        ok = np.concatenate([np.arange(ring) < 100, np.ones(c, bool)])
+        back = q_pos[:, None] - k_pos[None]
+        mask = jnp.asarray((ok[None] & (back >= 0) & (back < 65))[None])
+        # key blocks of 128 over 656 keys padded to 768: the written
+        # keys are 0-99 (block 0), of which query block 0 sees the
+        # last 64; the chunk's own are 400-655: query block 0 (400-527)
+        # sees blocks 3 and 4 (512-527), query block 1 (own keys
+        # 528-655, and back to 464) blocks 3, 4 and 5
+        tiles, bq, bk = [[1, 0, 0, 1, 1, 0], [0, 0, 0, 1, 1, 1]], \
+            128, 128
+    else:
+        assert name == "a_query_block_with_no_key"
+        # rows of padding behind a short chunk: no key at all, and
+        # the rows before them see keys 0 to 255 of 384
+        d = 192
+        t = 384
+        m = np.tril(np.ones((c, t), bool), k=t - c)
+        m[128:] = False
+        mask = jnp.asarray(m[None])
+        tiles, bq, bk = [[1, 1, 0], [0, 0, 0]], 128, 128
+    t = mask.shape[-1]
+    q, k, v = rand(1, c, h, d), rand(1, t, h, d), rand(1, t, h, 128)
+    if name == "bfloat16":
+        q, k, v = (x.astype(jnp.bfloat16) for x in (q, k, v))
+    return q, k, v, mask, bq, bk, np.asarray([tiles])
+
+
+@pytest.mark.parametrize("case", [
+    "topk_behind_an_unfilled_bucket", "window_over_a_ragged_ring",
+    "a_query_block_with_no_key", "bfloat16"])
+def test_chunk_kernel_is_the_masked_attention_it_replaces(case):
+    """`zoo_flash_chunk` under the interpreter against
+    `masked_attention`'s XLA body: keys 192 and 256 wide against
+    values of 128, any number of keys, the table of occupied tiles as
+    counted by hand, the same output with and without the skip, and
+    a finite row for a query that sees nothing."""
+    from analytics_zoo_tpu.ops import attention as att
+    from analytics_zoo_tpu.ops import flash_attention as fa
+    q, k, v, mask, bq, bk, tiles = _chunk_case(case)
+    np.testing.assert_array_equal(
+        np.asarray(fa.mask_tiles(mask, bq, bk)), tiles)
+    want = np.asarray(att._masked_attention_xla(
+        q, k, v, mask, 0.11).astype(F32))
+    run = lambda skip: np.asarray(fa.masked_chunk_attention(
+        q, k, v, mask, 0.11, block_q=bq, block_k=bk, skip=skip,
+        interpret=True).astype(F32))
+    got, dense = run(True), run(False)
+    assert got.shape == want.shape == (1, 256, 2, 128)
+    assert np.isfinite(got).all() and np.isfinite(dense).all()
+    seen = np.asarray(mask.any(-1))[0]
+    assert seen.sum() == (128 if "no_key" in case else 256)
+    tol = 2e-2 if case == "bfloat16" else 2e-5
+    np.testing.assert_allclose(got[0][seen], want[0][seen], atol=tol,
+                               rtol=tol)
+    # a tile with no 1 adds exp(-1e30 - m) = 0 to a row that has a key
+    np.testing.assert_array_equal(got[0][seen], dense[0][seen])
+
+
+def test_masked_attention_takes_the_kernel_by_what_it_observes(
+        monkeypatch):
+    from analytics_zoo_tpu.ops import attention as att
+    sds = lambda n, w, dt=jnp.bfloat16: jax.ShapeDtypeStruct(
+        (1, n, 16, w), dt)
+    q, k, v = sds(2048, 192), sds(34816, 192), sds(34816, 128)
+    assert att.masked_flash_blocks(q, k, v) is None       # the CPU
+    monkeypatch.setenv("ZOO_TPU_FLASH_FORCE_INTERPRET", "1")
+    assert att.masked_flash_blocks(q, k, v) == (512, 1024)
+    assert att.masked_flash_blocks(
+        sds(2048, 256), sds(4624, 256), sds(4624, 128)) == (512, 1024)
+    assert att.masked_flash_blocks(sds(384, 192), sds(600, 192),
+                                   sds(600, 128)) == (128, 640)
+    # a whole prompt of any length, other dtypes, wider heads
+    assert att.masked_flash_blocks(sds(72, 192), k, v) is None
+    assert att.masked_flash_blocks(q, k, sds(34816, 128, F32)) is None
+    assert att.masked_flash_blocks(
+        sds(2048, 192, jnp.int8), sds(128, 192, jnp.int8),
+        sds(128, 128, jnp.int8)) is None
+    assert att.masked_flash_blocks(sds(2048, 320), sds(128, 320),
+                                   v) is None
+    monkeypatch.setenv("ZOO_TPU_ATTENTION", "xla")
+    assert att.masked_flash_blocks(q, k, v) is None
+
+
+def test_chunk_programs_count_the_tiles_they_run(monkeypatch):
+    """`forward_chunk(..., stats=True)` of 128-token chunks with the
+    kernel under the interpreter: the logits of the XLA body, and the
+    two tile counters behind the tokens. The second chunk starts
+    behind 1000 tokens in a bucket of 2048: of a full layer's three
+    key blocks of 1024 the middle one holds no written key."""
+    cfg = dict(_share(0, 8), max_position_embeddings=4096)
+    net = _net(cfg)
+    net.ctx_bucket_floor = 2048
+    assert net.step_counters[-2:] == L.decoder.CHUNK_TILE_COUNTERS
+    params = wd.weights(cfg, SEED, F32)
+    c = 128
+    toks = np.random.RandomState(3).randint(0, 100, (1, c))
+
+    def run():
+        cache = net.init_kv_cache(1, 4096, page_size=16, dtype=F32,
+                                  max_chunk=c)
+        fn = jax.jit(lambda cache, s: net.forward_chunk(
+            params, cache, toks, s, np.array([c], np.int32),
+            stats=True))
+        out = []
+        for start in (0, 1000):
+            _, logits, counts = fn(cache, np.array([start], np.int32))
+            out.append((np.asarray(logits), np.asarray(counts)))
+        return out
+
+    plain = run()
+    monkeypatch.setenv("ZOO_TPU_FLASH_FORCE_INTERPRET", "1")
+    tiled = run()
+    for (want, none), (got, counts) in zip(plain, tiled):
+        np.testing.assert_allclose(got, want, atol=LOGIT_TOL,
+                                   rtol=LOGIT_TOL)
+        assert none[-2:].tolist() == [0, 0]
+    # one head block a layer. From 0: one tile a layer, six layers
+    assert tiled[0][1][-2:].tolist() == [6, 6]
+    # from 1000: three tiles a full layer and two of them run; a
+    # sliding layer's ring and chunk are one tile
+    assert tiled[1][1][-2:].tolist() == [3 * 3 + 3, 3 * 2 + 3]
+    before = {n: _counter(n) for n in L.decoder.CHUNK_TILE_COUNTERS}
+    net.record_step_counts(tiled[1][1])
+    assert [_counter(n) - before[n] for n in before] == [12, 9]
