@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 
 from analytics_zoo_tpu.ops import activations, initializers
+from analytics_zoo_tpu.ops.grouped_matmul import grouped_matmul
 from analytics_zoo_tpu.pipeline.api.keras.engine import KerasLayer, Shape
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import \
     _normal
@@ -104,24 +105,25 @@ class GroupLimitedMoE:
     each token, only what its chosen experts in ``[first, first +
     count)`` give; what the others would add is left out. The routed
     part is a grouped matrix product over the held experts
-    (`jax.lax.ragged_dot` on the assignments sorted by expert): an
-    expert no token chose is not read, and tokens marked not
-    ``valid`` (padding, idle slots) choose none.
+    (`ops/grouped_matmul.py`'s kernel on the assignments sorted by
+    expert): an expert no token chose is not read, and tokens marked
+    not ``valid`` (padding, idle slots) choose none.
     """
 
     # per call, as int32: (token, expert) assignments of valid
     # tokens, those that fell on held experts, the busiest held
-    # expert's
+    # expert's, and the held experts they reached
     step_counters = ("zoo_tpu_moe_assignments_total",
                      "zoo_tpu_moe_assignments_held_total",
-                     "zoo_tpu_moe_expert_load_max_total")
+                     "zoo_tpu_moe_expert_load_max_total",
+                     "zoo_tpu_moe_experts_reached_total")
 
     @staticmethod
     def record(counts):
-        """Add a decode step's three counts (summed over the expert
+        """Add a decode step's four counts (summed over the expert
         layers) to the counters of :attr:`step_counters`."""
         from analytics_zoo_tpu.common import observability as obs
-        total, held, busiest = (int(c) for c in counts)
+        total, held, busiest, reached = (int(c) for c in counts)
         obs.counter(
             "zoo_tpu_moe_assignments_total",
             help="(token, expert) assignments of active slots in "
@@ -134,6 +136,11 @@ class GroupLimitedMoE:
             "zoo_tpu_moe_expert_load_max_total",
             help="the busiest held expert's assignments, a layer "
             "and step").inc(busiest)
+        obs.counter(
+            "zoo_tpu_moe_experts_reached_total",
+            help="held experts that a valid row reached, a layer and "
+            "token block: the expert weight sets the grouped product "
+            "streams").inc(reached)
 
     def __init__(self, hidden_size: int, width: int, n_experts: int,
                  top_k: int, n_group: int = 1, topk_group: int = 1,
@@ -225,7 +232,7 @@ class GroupLimitedMoE:
 
     def routed(self, p, x, experts, weights, valid):
         """The held experts' part for tokens ``x`` (N, hidden) and
-        the three counts of :attr:`step_counters`."""
+        the four counts of :attr:`step_counters`."""
         n, k = experts.shape
         local = experts - self.first
         held = jnp.logical_and(local >= 0, local < self.n_held)
@@ -237,17 +244,13 @@ class GroupLimitedMoE:
         sizes = jnp.zeros((self.n_held + 1,), jnp.int32).at[key].add(
             1)[:self.n_held]
         rows = jnp.take(x, order // k, axis=0)
-        dt = x.dtype
+        product = lambda a, name: grouped_matmul(a, p[name], sizes)
         if self.activation == "relu2":
-            act = jnp.square(jax.nn.relu(jax.lax.ragged_dot(
-                rows, p["experts_up"].astype(dt), sizes)))
+            act = jnp.square(jax.nn.relu(product(rows, "experts_up")))
         else:
-            act = jax.nn.silu(jax.lax.ragged_dot(
-                rows, p["experts_gate"].astype(dt), sizes)) * \
-                jax.lax.ragged_dot(rows, p["experts_up"].astype(dt),
-                                   sizes)
-        out = jax.lax.ragged_dot(act, p["experts_down"].astype(dt),
-                                 sizes)
+            act = jax.nn.silu(product(rows, "experts_gate")) * \
+                product(rows, "experts_up")
+        out = product(act, "experts_down")
         # back to (token, choice) order; a row past the last group is
         # whatever the kernel left there, and counts for nothing
         back = jnp.argsort(order)
@@ -256,14 +259,17 @@ class GroupLimitedMoE:
         y = (out.astype(jnp.float32) * w).reshape(n, k, -1).sum(axis=1)
         counts = jnp.stack([k * jnp.sum(valid, dtype=jnp.int32),
                             jnp.sum(held, dtype=jnp.int32),
-                            jnp.max(sizes, initial=0)])
-        return y.astype(dt), counts
+                            jnp.max(sizes, initial=0),
+                            jnp.sum(sizes > 0, dtype=jnp.int32)])
+        return y.astype(x.dtype), counts
 
     def __call__(self, p, x, valid=None, scope="decode"):
         """``x``: (N, hidden) tokens; ``valid`` (N,) bool. Returns
-        ``(y (N, hidden), counts (3,) int32)``. Past ``token_block``
+        ``(y (N, hidden), counts (4,) int32)``. Past ``token_block``
         tokens the routed part runs block by block, so that its
-        sorted copies of the tokens stay small."""
+        sorted copies of the tokens stay small; each block streams the
+        experts it reaches once, and the reached count is their sum
+        over the blocks."""
         n = x.shape[0]
         if valid is None:
             valid = jnp.ones((n,), jnp.bool_)
@@ -281,7 +287,7 @@ class GroupLimitedMoE:
                                       valid.reshape(n // tb, tb)))
             y = y.reshape(n, -1)
             counts = jnp.stack([counts[:, 0].sum(), counts[:, 1].sum(),
-                                counts[:, 2].max()])
+                                counts[:, 2].max(), counts[:, 3].sum()])
         else:
             y, counts = block(x, valid)
         if self.n_shared:

@@ -369,7 +369,7 @@ def test_batcher_serves_the_decoder_with_slots_joining_and_leaving():
     monitoring.register_event_duration_secs_listener(listener)
     cb = ContinuousBatcher(eng, queue_depth=32)
     names = eng.net.step_counters
-    assert len(names) == 3 and names[0].startswith("zoo_tpu_moe_")
+    assert len(names) == 4 and names[0].startswith("zoo_tpu_moe_")
     before = [counter(n) for n in names]
     steps0 = counter("zoo_tpu_serving_gen_tokens_total")
     try:
@@ -388,10 +388,12 @@ def test_batcher_serves_the_decoder_with_slots_joining_and_leaving():
     for p, (_, m), out in zip(prompts, sizes, outs):
         alone = eng.generate([p], max_new_tokens=m)[0]
         assert list(out) == list(alone), (len(p), m)
-    total, held, busiest = (counter(n) - b
-                            for n, b in zip(names, before))
+    total, held, busiest, reached = (counter(n) - b
+                                     for n, b in zip(names, before))
     decoded = counter("zoo_tpu_serving_gen_tokens_total") - steps0
     assert decoded == sum(m - 1 for _, m in sizes)
     # top-3, two expert layers; a prompt's tokens count as a chunk's
     assert total == (decoded + sum(n for n, _ in sizes)) * 3 * 2
     assert 0 < busiest <= held < total
+    # an expert a call reached took at least one of its rows
+    assert 0 < reached <= held

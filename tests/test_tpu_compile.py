@@ -20,6 +20,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from analytics_zoo_tpu.ops import flash_attention as fa
+from analytics_zoo_tpu.ops import grouped_matmul as gm
 from analytics_zoo_tpu.ops import kv_cache as kvc
 from analytics_zoo_tpu.perf import autotune
 
@@ -58,8 +59,10 @@ def one_chip(topo):
 def _v5e_tile_tables(monkeypatch):
     """Tile decisions as the chip makes them: the code asks
     `jax.devices()`, which is the CPU here, so point the autotuner at
-    the committed v5e table."""
+    the committed v5e table, and compile the routed experts' grouped
+    product as its kernel rather than its interpreter."""
     monkeypatch.setattr(autotune, "_device", "v5e")
+    monkeypatch.setattr(gm, "on_tpu", lambda: True)
     autotune.reset_cache()
     yield
     autotune.reset_cache()
@@ -70,6 +73,10 @@ def _v5e_tile_tables(monkeypatch):
 # ---------------------------------------------------------------------
 
 _ATT = (4, 4096, 16, 64)          # B, T, H, D
+# the routed experts' grouped product: the rows of its call sites as
+# lowered and of its kernel as compiled
+_GROUPED_LOWERED = r"call @_grouped_matmul\w*\(.*?\) : \(tensor<(\d+)x"
+_GROUPED_COMPILED = r"%zoo_grouped_matmul[\w.]* = \w+\[(\d+),"
 _DEC = dict(s=8, t=4096, h=12, d=64)
 
 
@@ -110,6 +117,11 @@ def _flash_chunk(q, k, v, mask):
                                      block_k=bk, interpret=False)
 
 
+def _grouped_shapes(rows, k, n, experts):
+    return [((rows, k), BF), ((experts, k, n), BF),
+            ((experts,), jnp.int32)]
+
+
 def _chunk_shapes(keys, width):
     """dots3-note's chunk attention: 2048 queries of a head block of
     16 against ``keys`` keys ``width`` wide and values of 128."""
@@ -142,6 +154,15 @@ KERNELS = {
     # 2576 and the chunk, padded to whole key blocks inside
     "flash_chunk_full": (_flash_chunk, _chunk_shapes(34816, 192)),
     "flash_chunk_window": (_flash_chunk, _chunk_shapes(4624, 256)),
+    # the routed experts: Nemotron-H's step (96 slots x 6, its 16 held
+    # relu^2 experts, up and down) and a 2048-token block of dots3-note
+    # (x 8, 32 held SwiGLU experts)
+    "grouped_matmul_nemotron_up": (
+        gm.grouped_matmul, _grouped_shapes(576, 2688, 1856, 16)),
+    "grouped_matmul_nemotron_down": (
+        gm.grouped_matmul, _grouped_shapes(576, 1856, 2688, 16)),
+    "grouped_matmul_chunk_block": (
+        gm.grouped_matmul, _grouped_shapes(16384, 5120, 1536, 32)),
 }
 
 
@@ -153,6 +174,10 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), \
         f"{name}: the Pallas kernel is not in the compiled program"
+    if name.startswith("grouped_matmul"):
+        # the experts are read where they lie: no copy of them first
+        mem = compiled.memory_analysis()
+        assert mem.temp_size_in_bytes < 8 << 20, mem.temp_size_in_bytes
 
 
 def test_int8_decode_operands_match_the_cache_codec():
@@ -506,9 +531,9 @@ def test_deepseek_v2_programs_fit_the_chip_and_leave_the_pool(
         "copy", "transpose", "dynamic-slice", "dynamic-update-slice")]
     assert not moved, moved
     assert "{0}: (0, {}, may-alias)" in hlo
-    # the routed experts are the compiler's grouped matrix product:
-    # an expert no token chose is not read
-    assert "ragged-dot" in hlo
+    # the routed experts are the grouped product's kernel: an expert
+    # no token chose is not read
+    assert "zoo_grouped_matmul" in hlo
     if program == "prefill":
         assert "zoo_flash_fwd" in hlo
         # one prompt row: nothing has `max_slots x bucket` rows, and
@@ -521,10 +546,11 @@ def test_deepseek_v2_programs_fit_the_chip_and_leave_the_pool(
 
 def test_deepseek_v2_prefill_ladder_never_looks_like_a_decode_step(
         one_chip):
-    """`benchmark/reduce/moe.py` tells a decode step's grouped
-    products from a prefill's by their rows alone: `max_slots x
-    experts a token` = 96. A prefill program holds one prompt, so its
-    grouped products have `bucket x 6` rows: no bucket of the
+    """A decode step's grouped products have `max_slots x experts a
+    token` = 96 rows (the rows by which `benchmark/reduce/moe.py` put
+    the compiler's own grouped products back under their scope; the
+    kernel keeps its scope). A prefill program holds one prompt, so
+    its grouped products have `bucket x 6` rows: no bucket of the
     engine's ladder may be as short as the engine has slots. Every
     program of the ladder as lowered, and the shortest as the v5e
     compiler names it."""
@@ -541,13 +567,11 @@ def test_deepseek_v2_prefill_ladder_never_looks_like_a_decode_step(
     for bucket in ladder:
         _, _, lowered = _ds_program(one_chip, "prefill", bucket=bucket,
                                     lower_only=True)
-        rows = set(re.findall(r"ragged_dot.*?tensor<(\d+)x",
-                              lowered.as_text()))
+        rows = set(re.findall(_GROUPED_LOWERED, lowered.as_text()))
         assert rows == {str(bucket * per_token)}, (bucket, rows)
         assert bucket * per_token != decode_rows
     compiled = _ds_program(one_chip, "prefill", bucket=ladder[0])[2]
-    named = set(re.findall(r"%ragged-dot-none\.\d+ = \w+\[(\d+),",
-                           compiled.as_text()))
+    named = set(re.findall(_GROUPED_COMPILED, compiled.as_text()))
     assert named == {str(ladder[0] * per_token)}, named
 
 
@@ -646,10 +670,9 @@ def test_dots3_note_programs_fit_the_chip_and_leave_the_pools(
     # donated and written in place: all five leaves of the cache
     for leaf in range(5):
         assert "{%d}: (%d, {}, may-alias)" % (leaf, leaf) in hlo
-    # the routed experts are the compiler's grouped product, and its
-    # rows tell the phases apart (`benchmark/reduce/moe.py`): 8 slots
+    # the routed experts are the grouped product's kernel, of 8 slots
     # x 8 experts a token in a step, 2048 x 8 in a chunk
-    rows = set(re.findall(r"%ragged-dot[\w\-.]* = \w+\[(\d+),", hlo))
+    rows = set(re.findall(_GROUPED_COMPILED, hlo))
     want = s * cfg["num_experts_per_tok"] if program == "step" \
         else eng["prefill_chunk"] * cfg["num_experts_per_tok"]
     assert rows == {str(want)}, rows
@@ -767,7 +790,7 @@ def test_mimo_v2_flash_programs_fit_the_chip_and_leave_the_pools(
     for leaf in range(4):
         assert "{%d}: (%d, {}, may-alias)" % (leaf, leaf) in hlo
     eng = cfg["engine"]
-    rows = set(re.findall(r"%ragged-dot[\w\-.]* = \w+\[(\d+),", hlo))
+    rows = set(re.findall(_GROUPED_COMPILED, hlo))
     tokens = {"step": eng["max_slots"], "chunk": eng["prefill_chunk"],
               "prefill": bucket}[program]
     assert rows == {str(tokens * cfg["num_experts_per_tok"])}, rows
@@ -782,10 +805,11 @@ def test_mimo_v2_flash_programs_fit_the_chip_and_leave_the_pools(
 
 def test_mimo_v2_flash_prefill_ladder_never_looks_like_a_decode_step(
         one_chip):
-    """`benchmark/reduce/moe.py` tells a decode step's grouped
-    products by their rows: 16 slots x 8 experts a token = 128, which
-    a 16-token prompt bucket would give too. The engine's ladder
-    starts at 32, and no bucket up to the chunk has 128 rows."""
+    """A decode step's grouped products have 16 slots x 8 experts
+    a token = 128 rows (by which `benchmark/reduce/moe.py` told the
+    compiler's own grouped products apart), which a 16-token prompt
+    bucket would give too. The engine's ladder starts at 32, and no
+    bucket up to the chunk has 128 rows."""
     import re
 
     from analytics_zoo_tpu.pipeline.inference.generation import \
@@ -801,8 +825,7 @@ def test_mimo_v2_flash_prefill_ladder_never_looks_like_a_decode_step(
         assert bucket * per_token != decode_rows
     lowered = _mimo_program(one_chip, "prefill", bucket=ladder[0],
                             lower_only=True)[3]
-    rows = set(re.findall(r"ragged_dot.*?tensor<(\d+)x",
-                          lowered.as_text()))
+    rows = set(re.findall(_GROUPED_LOWERED, lowered.as_text()))
     assert rows == {str(ladder[0] * per_token)}, rows
 
 
@@ -889,7 +912,13 @@ def test_nemotron_h_programs_fit_the_chip_and_update_the_state_in_place(
     if program == "step":
         assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
         assert hlo.count("zoo_paged_gqa_decode") >= 3
-    rows = set(re.findall(r"%ragged-dot[\w\-.]* = \w+\[(\d+),", hlo))
+    # the grouped product's kernel reads the 16 held experts where they
+    # lie (the up weights' last dim, 1856, is no whole lane tile: the
+    # compiler keeps them 2688-minor, and the kernel takes that view)
+    made = re.findall(r"= bf16\[16,(?:2688,1856|1856,2688)\]\S* "
+                      r"([\w\-]+)\(", hlo)
+    assert not [op for op in made if op in ("copy", "transpose")], made
+    rows = set(re.findall(_GROUPED_COMPILED, hlo))
     tokens = cfg["engine"]["max_slots"] if program == "step" \
         else cfg["engine"]["prefill_chunk"]
     assert rows == {str(tokens * cfg["num_experts_per_tok"])}, rows
