@@ -34,7 +34,8 @@ from analytics_zoo_tpu.pipeline.api.keras.layers.moe import (
 from analytics_zoo_tpu.pipeline.api.keras.layers.decoder import (
     YarnRope, LatentAttention, GroupedQueryAttention, SparseIndexer,
     MambaMixer, PatternDecoder, deepseek_v2_decoder,
-    dots3_note_decoder, mimo_v2_flash_decoder, nemotron_h_decoder)
+    dots3_note_decoder, mimo_v2_flash_decoder, nemotron_h_decoder,
+    command_a_decoder)
 from analytics_zoo_tpu.pipeline.api.keras.layers.transformer import (
     MultiHeadAttention, TransformerLayer, BERT)
 from analytics_zoo_tpu.pipeline.api.keras.layers.elementwise import (
@@ -88,6 +89,7 @@ __all__ = [
     "SparseIndexer", "PatternDecoder", "deepseek_v2_decoder",
     "dots3_note_decoder", "mimo_v2_flash_decoder",
     "GroupedQueryAttention", "MambaMixer", "nemotron_h_decoder",
+    "command_a_decoder",
     # elementwise / tensor utilities
     "AddConstant", "MulConstant", "CAdd", "CMul", "Mul", "Scale", "Power",
     "Negative", "Exp", "Log", "Sqrt", "Square", "Identity",

@@ -70,6 +70,9 @@ class YarnRope:
     """Rotary position embedding over ``dim`` values with YaRN
     context scaling (Peng et al. 2023, as DeepSeek-V2's
     ``rope_scaling`` block states it). ``factor == 1`` is plain RoPE.
+    The pairs turned together are values (i, i + dim / 2), the
+    rotate-half convention, or with ``interleaved`` values (2i, 2i + 1),
+    GPT-J's (Cohere's ``rope_gptj``).
 
     Frequencies: ``inv_freq = inter / factor * (1 - m) + extra * m``,
     ``extra = theta^(-2i/dim)`` and ``m`` one minus the linear ramp
@@ -78,15 +81,16 @@ class YarnRope:
     are scaled by ``mscale(factor, mscale) / mscale(factor,
     mscale_all_dim)``; :attr:`attention_mscale` =
     ``mscale(factor, mscale_all_dim)`` multiplies the softmax scale,
-    squared. Rotate-half convention: the two halves of the vector are
-    the pairs' first and second members."""
+    squared."""
 
     def __init__(self, dim: int, theta: float = 10000.0,
                  factor: float = 1.0,
                  original_max_position: int = 4096,
                  beta_fast: float = 32.0, beta_slow: float = 1.0,
-                 mscale: float = 1.0, mscale_all_dim: float = 0.0):
+                 mscale: float = 1.0, mscale_all_dim: float = 0.0,
+                 interleaved: bool = False):
         self.dim, self.theta = int(dim), float(theta)
+        self.interleaved = bool(interleaved)
         self.factor = float(factor)
         self.original_max_position = int(original_max_position)
         self.beta_fast, self.beta_slow = float(beta_fast), \
@@ -135,7 +139,14 @@ class YarnRope:
             jnp.asarray(self.inv_freq(), jnp.float32)
         cos = jnp.cos(ang) * self.cos_sin_scale
         sin = jnp.sin(ang) * self.cos_sin_scale
-        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+        xf = x.astype(jnp.float32)
+        if self.interleaved:
+            pairs = xf.reshape(x.shape[:-1] + (self.dim // 2, 2))
+            x1, x2 = pairs[..., 0], pairs[..., 1]
+            return jnp.stack(
+                [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                axis=-1).reshape(x.shape).astype(x.dtype)
+        x1, x2 = jnp.split(xf, 2, axis=-1)
         return jnp.concatenate(
             [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
             axis=-1).astype(x.dtype)
@@ -157,12 +168,25 @@ INDEX_NORM_EPS = 1e-6
 
 
 def _layer_norm(x, gain, bias, eps: float):
+    """Mean-centred LayerNorm with float32 statistics, in ``x``'s
+    dtype; ``bias`` None: a gain alone."""
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * gain.astype(jnp.float32) +
-            bias.astype(jnp.float32)).astype(x.dtype)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps) * gain.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(x.dtype)
+
+
+def layer_norm(x, gain, eps: float):
+    """LayerNorm with a gain and no bias (Cohere's), float32
+    statistics, in ``x``'s dtype."""
+    return _layer_norm(x, gain, None, eps)
+
+
+# a `PatternDecoder`'s norm kinds
+NORMS = {"rms": rms_norm, "layer": layer_norm}
 
 
 class LatentAttention:
@@ -539,10 +563,11 @@ class GroupedQueryAttention:
     heads (query head j reads K/V head ``j // (n_head / n_kv_head)``),
     queries and keys ``head_dim`` wide, values ``v_head_dim`` (MiMo-V2
     has 192 and 128). ``rope`` rotates the first ``rope.dim`` values
-    of every query and key head (rotate-half) and passes the rest
-    (a partial rotary; None: no rotary at all, as Nemotron-H's
-    attention layers have none); values are multiplied by
-    ``value_scale`` before the product.
+    of every query and key head (in its convention, rotate-half or
+    interleaved) and passes the rest (a partial rotary; None: no
+    rotary at all, as Nemotron-H's attention layers and Command A's
+    full layers have none); values are multiplied by ``value_scale``
+    before the product.
 
     The cache row of a token is ``[k of the G heads | v of the G
     heads]``, :attr:`row_width` = ``n_kv_head * (head_dim +
@@ -562,7 +587,12 @@ class GroupedQueryAttention:
     to it, K/V heads repeated); :meth:`chunk` a chunk against what
     the cache holds (`grouped_attention`, or for a window the band
     `banded_attention` bounds by the window); :meth:`decode` one
-    token a slot from the live pages (`gqa_decode_attention`)."""
+    token a slot from the live pages (`gqa_decode_attention`).
+
+    :attr:`q_block`, the queries `grouped_attention` scores at a time,
+    follows the head count: 256 up to 64 heads, ``2**20 / n_head**2``
+    past them, so that a block's f32 scores (n_head x q_block x keys)
+    at 128 heads and 28672 keys are 0.94 GB and not 3.76."""
 
     indexer, index_width = None, 0
     # a window part's chunk reads the ``window`` positions before it
@@ -589,6 +619,7 @@ class GroupedQueryAttention:
         self.window, self.sink = int(window), bool(sink)
         self.attention_impl = attention_impl
         self.scale = self.k_dim ** -0.5
+        self.q_block = min(256, 2 ** 20 // self.n_head ** 2)
         self.k_width = self.n_kv * self.k_dim
         self.row_width = self.n_kv * (self.k_dim + self.v_dim)
         self.kind = "window" if self.window else "context"
@@ -702,7 +733,8 @@ class GroupedQueryAttention:
         k, v = self._split(rows)
         with jax.named_scope(f"zoo:{phase}/{self.scope}"):
             o = grouped_attention(q, k, v, mask, self.scale,
-                                  sink=self._sink(p))
+                                  sink=self._sink(p),
+                                  q_block=self.q_block)
         return self._out(p, o), {"row": new}
 
     def _banded(self, q, new, q_pos, valid, cached, phase, sink):
@@ -738,7 +770,7 @@ class GroupedQueryAttention:
                 k_ok[:, None, :],
                 jnp.logical_and(back >= 0, back < w))
             return grouped_attention(q, k, v, mask, self.scale,
-                                     sink=sink)
+                                     sink=sink, q_block=self.q_block)
 
     def decode(self, p, x, positions, view, lens_after):
         """One new token a slot, as :meth:`LatentAttention.decode`:
@@ -913,9 +945,13 @@ class _DecodeView:
 
 # per call, as int32: over the layers whose attention chooses its
 # keys, the keys its queries could see and those they kept; over the
-# window layers, the pages of the window pool written over
+# window layers, the keys their queries attend (min(t + 1, window) a
+# query at position t), the keys they would attend with no window
+# (t + 1), and the pages of the window pool written over
 ATTENTION_COUNTERS = ("zoo_tpu_dsa_keys_visible_total",
                       "zoo_tpu_dsa_keys_selected_total",
+                      "zoo_tpu_window_keys_read_total",
+                      "zoo_tpu_window_keys_causal_total",
                       "zoo_tpu_window_pages_recycled_total")
 
 
@@ -952,7 +988,8 @@ def _record_attention(counts):
     """Add one call's counts to :data:`ATTENTION_COUNTERS` and, where
     the call has them, to :data:`CHUNK_TILE_COUNTERS` behind them."""
     from analytics_zoo_tpu.common import observability as obs
-    visible, kept, recycled, *tiles = (int(c) for c in counts)
+    visible, kept, read, causal, recycled, *tiles = (
+        int(c) for c in counts)
     if tiles:
         obs.counter(
             "zoo_tpu_chunk_attn_tiles_total",
@@ -971,6 +1008,14 @@ def _record_attention(counts):
         help="keys the indexer kept for them (its top-k of the "
         "visible)").inc(kept)
     obs.counter(
+        "zoo_tpu_window_keys_read_total",
+        help="keys the queries of the window layers attend, steps "
+        "and chunks: min(t + 1, window) a query at position t").inc(read)
+    obs.counter(
+        "zoo_tpu_window_keys_causal_total",
+        help="keys the same queries would attend with no window: "
+        "t + 1 a query at position t").inc(causal)
+    obs.counter(
         "zoo_tpu_window_pages_recycled_total",
         help="window-pool pages written over by positions one turn "
         "of the ring later").inc(recycled)
@@ -982,12 +1027,20 @@ class PatternDecoder(KerasLayer):
     :class:`GroupedQueryAttention`, for every layer, or one a layer:
     full and sliding layers side by side, or a :class:`MambaMixer`
     beside them) and ``feed_forward[i]`` (a `GatedMLP` or a
-    `GroupLimitedMoE`) in layer i, RMSNorm, a final norm and an
-    untied head over ``vocab`` rows. A layer may hold one of the two
-    alone (the other None: Nemotron-H's layers are a mixer or a
-    feed-forward part); each part present sits behind its own norm,
-    ``norm1`` before the mixer (under the part's ``key``: ``attn`` or
-    ``ssm``) and ``norm2`` before ``ffn``. ``seq_len`` is the
+    `GroupLimitedMoE`) in layer i, a final norm and a head over
+    ``vocab`` rows. A layer may hold one of the two alone (the other
+    None: Nemotron-H's layers are a mixer or a feed-forward part);
+    each part present sits behind its own norm, ``norm1`` before the
+    mixer (under the part's ``key``: ``attn`` or ``ssm``) and
+    ``norm2`` before ``ffn``: ``x + mixer(norm1(x))``, then that plus
+    ``ffn(norm2(...))``. ``parallel`` (Cohere's parallel block): ONE
+    norm, both parts reading it, ``x + mixer(u) + ffn(u)`` with ``u =
+    norm1(x)``, and no ``norm2``. ``norm``: ``"rms"`` (RMSNorm) or
+    ``"layer"`` (mean-centred LayerNorm with a gain and no bias), for
+    the layers and the final norm alike, of ``norm_eps``.
+    ``tie_head``: the logits are the final norm's output times the
+    token embedding's transpose (no ``lm_head`` leaf); either head's
+    logits are multiplied by ``logit_scale``. ``seq_len`` is the
     most positions the model declares (there is no position table).
 
     Input (seq_len,) int token ids; ``call`` returns logits
@@ -1016,9 +1069,11 @@ class PatternDecoder(KerasLayer):
 
     def __init__(self, vocab: int, hidden_size: int,
                  attention, feed_forward: Sequence, seq_len: int,
-                 rms_eps: float = 1e-6,
+                 norm_eps: float = 1e-6,
                  initializer_range: float = 0.02,
                  attention_impl: Optional[str] = None,
+                 norm: str = "rms", parallel: bool = False,
+                 tie_head: bool = False, logit_scale: float = 1.0,
                  input_shape=None, name=None, **kwargs):
         super().__init__(input_shape=input_shape or (seq_len,),
                          name=name, **kwargs)
@@ -1035,8 +1090,18 @@ class PatternDecoder(KerasLayer):
                              "all of them")
         mixers = [a for a in self.attentions if a is not None]
         self.attention = mixers[0]
+        if norm not in NORMS:
+            raise ValueError(f"norm {norm!r} not one of {sorted(NORMS)}")
+        self.parallel = bool(parallel)
+        if self.parallel and any(
+                a is None or f is None
+                for a, f in zip(self.attentions, self.feed_forward)):
+            raise ValueError("a parallel layer holds a mixer and a "
+                             "feed-forward part both")
         self.seq_len = int(seq_len)
-        self.rms_eps = float(rms_eps)
+        self.norm, self.norm_eps = norm, float(norm_eps)
+        self.tie_head, self.logit_scale = bool(tie_head), \
+            float(logit_scale)
         self.initializer_range = float(initializer_range)
         # where each layer's rows or state live: (index in its pool or
         # state arrays, index in the index pool); None: no mixer
@@ -1084,13 +1149,16 @@ class PatternDecoder(KerasLayer):
                 layer.update({"norm1": jnp.ones((h,), jnp.float32),
                               att.key: att.build(k_a, r)})
             if ffn is not None:
-                layer.update({"norm2": jnp.ones((h,), jnp.float32),
-                              "ffn": ffn.build(k_f, r)})
+                if not self.parallel:
+                    layer["norm2"] = jnp.ones((h,), jnp.float32)
+                layer["ffn"] = ffn.build(k_f, r)
             layers.append(layer)
-        return {"tok_embed": _normal(k_tok, (self.vocab, h), r),
-                "layers": layers,
-                "norm_f": jnp.ones((h,), jnp.float32),
-                "lm_head": _normal(k_head, (h, self.vocab), r)}
+        out = {"tok_embed": _normal(k_tok, (self.vocab, h), r),
+               "layers": layers,
+               "norm_f": jnp.ones((h,), jnp.float32)}
+        if not self.tie_head:
+            out["lm_head"] = _normal(k_head, (h, self.vocab), r)
+        return out
 
     def compute_output_shape(self, input_shape: ShapeLike):
         return (input_shape[0], self.vocab)
@@ -1109,32 +1177,61 @@ class PatternDecoder(KerasLayer):
         if self.state_layers:
             _record_state(counts[n:n + len(SSM_COUNTERS)])
 
-    # -- the pattern, once for prompts and once for a step -------------
-    def _ffn(self, ffn, p, x, valid, scope):
-        """``x`` (..., hidden) through a feed-forward part that takes
-        flat tokens."""
-        y, counts = ffn(p["ffn"], rms_norm(
-            x, p["norm2"], self.rms_eps).reshape(-1, x.shape[-1]),
-            valid.reshape(-1), scope)
-        return x + y.reshape(x.shape), counts
+    # -- one layer, written once for prompts, chunks and steps ---------
+    def _norm(self, x, gain):
+        return NORMS[self.norm](x, gain, self.norm_eps)
+
+    def _layer(self, p, att, ffn, x, valid, scope, mix):
+        """Layer ``p`` on ``x`` (..., hidden), ``valid`` (...) its
+        real tokens: ``mix(u)`` gives the mixer's ``(out, rows)`` of
+        its normed input, the feed-forward part takes flat tokens.
+        Sequential or parallel, as the decoder is. Returns ``(x,
+        rows, the feed-forward part's counts or None)``."""
+        rows, counts = {}, None
+        if att is not None:
+            u = self._norm(x, p["norm1"])
+            a, rows = mix(u)
+            if not self.parallel:
+                x = x + a
+        if ffn is not None:
+            if not self.parallel:
+                u = self._norm(x, p["norm2"])
+            y, counts = ffn(p["ffn"], u.reshape(-1, x.shape[-1]),
+                            valid.reshape(-1), scope)
+            y = y.reshape(x.shape)
+            x = x + a + y if self.parallel else x + y
+        return x, rows, counts
 
     def _logits(self, params, h):
-        h = rms_norm(h, params["norm_f"], self.rms_eps)
-        return h @ params["lm_head"].astype(h.dtype)
+        h = self._norm(h, params["norm_f"])
+        if self.tie_head:
+            out = jnp.einsum("...h,vh->...v", h,
+                             params["tok_embed"].astype(h.dtype))
+        else:
+            out = h @ params["lm_head"].astype(h.dtype)
+        return out if self.logit_scale == 1.0 else \
+            (out * self.logit_scale).astype(out.dtype)
 
     def _attention_counts(self, first, n_new, ring_pages: int,
                           page: int):
-        """The three :data:`ATTENTION_COUNTERS` of queries at
-        positions ``first[a] .. first[a] + n_new[a] - 1``: a query at
-        position t sees t + 1 keys and keeps ``min(t + 1, top_k)``;
-        a logical page past the ring's first turn lands on a page
-        that held an older one."""
+        """The :data:`ATTENTION_COUNTERS` of queries at positions
+        ``first[a] .. first[a] + n_new[a] - 1``: a query at position
+        t sees t + 1 keys, keeps ``min(t + 1, top_k)`` and in a window
+        layer attends ``min(t + 1, window)``; a logical page past the
+        ring's first turn lands on a page that held an older one."""
         first, n = first.astype(jnp.int32), n_new.astype(jnp.int32)
         last = first + n
         # sum of (t + 1) over the n positions from `first` on
         seen = n * first + n * (n + 1) // 2
-        visible = kept = jnp.zeros((), jnp.int32)
+        visible = kept = read = causal = jnp.zeros((), jnp.int32)
         for att in self.attentions:
+            if att is not None and att.window:
+                # the positions up to window - 1 see t + 1 keys, the
+                # others the window
+                m = jnp.clip(att.window - first, 0, n)
+                read = read + jnp.sum(m * first + m * (m + 1) // 2 +
+                                      (n - m) * att.window)
+                causal = causal + jnp.sum(seen)
             if att is None or att.indexer is None:
                 continue
             # what the positions past the top_k-th see beyond it
@@ -1150,7 +1247,7 @@ class PatternDecoder(KerasLayer):
             up(last) - jnp.maximum(up(first), ring_pages), 0)) * \
             self._pool_layers["window"] if ring_pages \
             else jnp.zeros((), jnp.int32)
-        return jnp.stack([visible, kept,
+        return jnp.stack([visible, kept, read, causal,
                           recycled.astype(jnp.int32)])
 
     def _prompt(self, params, token_ids, valid):
@@ -1164,23 +1261,21 @@ class PatternDecoder(KerasLayer):
         rows, counts = [], []
         for p, att, ffn in zip(params["layers"], self.attentions,
                                self.feed_forward):
+
+            def mix(u, p=p, att=att):
+                if att.kind == "state":
+                    return att.chunk(p[att.key], u, valid)
+                if att.plain:
+                    a, r = att.prefill(p[att.key], u,
+                                       impl=self.attention_impl)
+                    return a, {"row": r}
+                return att.chunk(p[att.key], u, pos, valid)
+
             with jax.named_scope("zoo:prefill/layer"):
-                r = {}
-                if att is not None:
-                    y = rms_norm(x, p["norm1"], self.rms_eps)
-                    if att.kind == "state":
-                        a, r = att.chunk(p[att.key], y, valid)
-                    elif att.plain:
-                        a, r = att.prefill(p[att.key], y,
-                                           impl=self.attention_impl)
-                        r = {"row": r}
-                    else:
-                        a, r = att.chunk(p[att.key], y, pos, valid)
-                    x = x + a
-                if ffn is not None:
-                    x, cnt = self._ffn(ffn, p, x, valid, "prefill")
-                    if cnt is not None:
-                        counts.append(cnt)
+                x, r, cnt = self._layer(p, att, ffn, x, valid,
+                                        "prefill", mix)
+                if cnt is not None:
+                    counts.append(cnt)
                 rows.append(r)
         return x, rows, counts
 
@@ -1375,26 +1470,22 @@ class PatternDecoder(KerasLayer):
         rows, counts = [], []
         for p, att, at, ffn in zip(params["layers"], self.attentions,
                                    self._at, self.feed_forward):
+
+            def mix(u, p=p, att=att, at=at):
+                run = lambda t: att.chunk(p[att.key], u, q_pos, valid,
+                                          cached(att, at, t))
+                if att.kind == "state":
+                    return att.chunk(p[att.key], u, valid, carried(at))
+                if att.kind == "window":
+                    return run(0)
+                return jax.lax.switch(
+                    bucket, [functools.partial(run, t) for t in ladder])
+
             with jax.named_scope("zoo:prefill/chunk_layer"):
-                r = {}
-                if att is not None:
-                    y = rms_norm(x, p["norm1"], self.rms_eps)
-                    run = lambda t, p=p, att=att, at=at, y=y: att.chunk(
-                        p[att.key], y, q_pos, valid, cached(att, at, t))
-                    if att.kind == "state":
-                        o, r = att.chunk(p[att.key], y, valid,
-                                         carried(at))
-                    elif att.kind == "window":
-                        o, r = run(0)
-                    else:
-                        o, r = jax.lax.switch(
-                            bucket, [functools.partial(run, t)
-                                     for t in ladder])
-                    x = x + o
-                if ffn is not None:
-                    x, cnt = self._ffn(ffn, p, x, valid, "prefill")
-                    if cnt is not None:
-                        counts.append(cnt)
+                x, r, cnt = self._layer(p, att, ffn, x, valid,
+                                        "prefill", mix)
+                if cnt is not None:
+                    counts.append(cnt)
                 rows.append(r)
         cache = self._write_chunk(
             cache, rows, slots, starts, total, q_pos, valid)._replace(
@@ -1449,31 +1540,31 @@ class PatternDecoder(KerasLayer):
         conv, ssm = cache.conv, cache.ssm
         for p, att, at, ffn in zip(params["layers"], self.attentions,
                                    self._at, self.feed_forward):
+
+            def mix(u, p=p, att=att, at=at):
+                nonlocal conv, ssm
+                if att.kind != "state":
+                    a, *row = att.decode(
+                        p[att.key], u, pos,
+                        _DecodeView(cache, at, active), lens_after)
+                    return a, dict(zip(("row", "index"), row))
+                # the layer's slab of each state array is read and
+                # written back in place (the step program donates the
+                # cache); no other layer reads it
+                with jax.named_scope("zoo:decode/ssm_scan"):
+                    state = (conv[at[0]], ssm[at[0]])
+                a, (tail, s) = att.decode(p[att.key], u, state, active)
+                with jax.named_scope("zoo:decode/ssm_scan"):
+                    conv = conv.at[at[0]].set(tail)
+                    ssm = ssm.at[at[0]].set(s)
+                return a, {}
+
             with jax.named_scope("zoo:decode/layer"):
-                row = []
-                if att is not None:
-                    y = rms_norm(x, p["norm1"], self.rms_eps)
-                    if att.kind == "state":
-                        # the layer's slab of each state array is read
-                        # and written back in place (the step program
-                        # donates the cache); no other layer reads it
-                        with jax.named_scope("zoo:decode/ssm_scan"):
-                            state = (conv[at[0]], ssm[at[0]])
-                        a, (tail, s) = att.decode(p[att.key], y, state,
-                                                  active)
-                        with jax.named_scope("zoo:decode/ssm_scan"):
-                            conv = conv.at[at[0]].set(tail)
-                            ssm = ssm.at[at[0]].set(s)
-                    else:
-                        a, *row = att.decode(
-                            p[att.key], y, pos,
-                            _DecodeView(cache, at, active), lens_after)
-                    x = x + a
-                if ffn is not None:
-                    x, c = self._ffn(ffn, p, x, active, "decode")
-                    if c is not None:
-                        counts.append(c)
-                rows.append(dict(zip(("row", "index"), row)))
+                x, r, c = self._layer(p, att, ffn, x, active, "decode",
+                                      mix)
+                if c is not None:
+                    counts.append(c)
+                rows.append(r)
         tally = self._counts(counts, cache.seq_lens,
                              active.astype(jnp.int32), cache) \
             if stats else None
@@ -1567,7 +1658,7 @@ def deepseek_v2_decoder(config: dict, *, n_layer: Optional[int] = None,
     return PatternDecoder(
         c["vocab_size"] if vocab is None else vocab, h, attention,
         [ffn(i) for i in range(n_layer)],
-        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        seq_len=c["max_position_embeddings"], norm_eps=eps,
         initializer_range=c.get("initializer_range", 0.02), **kwargs)
 
 
@@ -1624,7 +1715,7 @@ def dots3_note_decoder(config: dict, *, n_layer: Optional[int] = None,
         c["vocab_size"] if vocab is None else vocab, h,
         [kinds[c["layer_types"][i]] for i in range(n_layer)],
         [ffn(i) for i in range(n_layer)],
-        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        seq_len=c["max_position_embeddings"], norm_eps=eps,
         initializer_range=c.get("initializer_range", 0.02), **kwargs)
 
 
@@ -1682,7 +1773,7 @@ def mimo_v2_flash_decoder(config: dict, *, n_layer: Optional[int] = None,
         c["vocab_size"] if vocab is None else vocab, h,
         [kinds[c["hybrid_layer_pattern"][i]] for i in range(n_layer)],
         [ffn(i) for i in range(n_layer)],
-        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        seq_len=c["max_position_embeddings"], norm_eps=eps,
         initializer_range=c.get("initializer_range", 0.02), **kwargs)
 
 
@@ -1745,5 +1836,90 @@ def nemotron_h_decoder(config: dict, *, n_layer: Optional[int] = None,
         c["vocab_size"] if vocab is None else vocab, h,
         [{"M": mamba, "*": attention}.get(k) for k in pattern],
         [experts if k == "E" else None for k in pattern],
-        seq_len=c["max_position_embeddings"], rms_eps=eps,
+        seq_len=c["max_position_embeddings"], norm_eps=eps,
+        initializer_range=c.get("initializer_range", 0.02), **kwargs)
+
+
+def command_a_decoder(config: dict, *, n_layer: Optional[int] = None,
+                      experts_held: "Optional[tuple]" = None,
+                      vocab: Optional[int] = None,
+                      **kwargs) -> PatternDecoder:
+    """A `PatternDecoder` from the keys of a ``config.json`` of
+    ``model_type`` ``cohere2_moe`` (Command A+; the language model
+    only): layer i is ``layer_types[i]``. Both kinds are grouped-query
+    attention of ``num_attention_heads`` over ``num_key_value_heads``
+    heads of ``head_dim`` with no bias and no qk-norm; a
+    ``sliding_attention`` layer rotates the first ``rotary_pct`` of
+    each head in the ``position_embedding_type`` ``rope_gptj``
+    (interleaved) convention at ``rope_theta`` and sees the last
+    ``sliding_window`` positions, its own among them; a
+    ``full_attention`` layer has no positional encoding at all and
+    sees every key before it. Every layer's feed-forward part is
+    ``num_experts`` SwiGLU experts of ``intermediate_size``, the top
+    ``num_experts_per_tok`` by their sigmoid scores with no selection
+    bias, weights renormalised (``norm_topk_prob``), beside
+    ``num_shared_experts`` shared ones (one SwiGLU of their summed
+    width), the two paths joined as
+    ``shared_expert_combination_strategy`` says. ``use_parallel_block``:
+    attention and experts read one LayerNorm (``layer_norm_eps``, no
+    bias) and both add to the residual; ``tie_word_embeddings``: the
+    head is the embedding's transpose, times ``logit_scale``.
+
+    A value of these keys that is not implemented is refused by name.
+    ``n_layer``, ``experts_held`` and ``vocab`` state one chip's
+    share, as for :func:`deepseek_v2_decoder`; ``kwargs`` go to
+    `PatternDecoder`."""
+    from analytics_zoo_tpu.pipeline.api.keras.layers.moe import \
+        GroupLimitedMoE
+    c = config
+    for key, want in (("position_embedding_type", "rope_gptj"),
+                      ("expert_selection_fn", "sigmoid"),
+                      ("hidden_act", "silu"),
+                      ("use_gated_activation", True),
+                      ("use_qk_norm", False), ("attention_bias", False),
+                      ("first_k_dense_replace", 0),
+                      ("use_parallel_embedding", False)):
+        if c.get(key, want) != want:
+            raise ValueError(f"cohere2_moe: {key} {c[key]!r} is not "
+                             f"implemented (only {want!r})")
+    if (c.get("rope_parameters") or {}).get("rope_type",
+                                             "default") != "default":
+        raise ValueError("cohere2_moe: rope_type "
+                         f"{c['rope_parameters']['rope_type']!r} is not "
+                         "implemented (only 'default')")
+    combine = c.get("shared_expert_combination_strategy", "sum")
+    if combine not in ("sum", "average"):
+        raise ValueError("cohere2_moe: shared_expert_combination_"
+                         f"strategy {combine!r} is not implemented")
+    n_layer = c["num_hidden_layers"] if n_layer is None else n_layer
+    types = c["layer_types"][:n_layer]
+    unknown = set(types) - {"sliding_attention", "full_attention"}
+    if unknown:
+        raise ValueError(f"cohere2_moe: layer types {sorted(unknown)} "
+                         "are not implemented")
+    h, d = c["hidden_size"], c["head_dim"]
+    impl = kwargs.get("attention_impl")
+    rotary = int(c.get("rotary_pct", 1.0) * d) // 2 * 2
+    part = lambda rope, window: GroupedQueryAttention(
+        h, c["num_attention_heads"], c["num_key_value_heads"], d, d,
+        rope, window=window, attention_impl=impl)
+    kinds = {
+        "sliding_attention": part(
+            YarnRope(rotary, theta=c["rope_theta"], interleaved=True),
+            c["sliding_window"]),
+        "full_attention": part(None, 0)}
+    experts = GroupLimitedMoE(
+        h, c["intermediate_size"], c["num_experts"],
+        c["num_experts_per_tok"], n_shared=c["num_shared_experts"],
+        experts_held=experts_held, scoring="sigmoid",
+        selection_bias=False, norm_topk=c.get("norm_topk_prob", True),
+        shared_combine=combine)
+    return PatternDecoder(
+        c["vocab_size"] if vocab is None else vocab, h,
+        [kinds[k] for k in types], [experts] * n_layer,
+        seq_len=c["max_position_embeddings"],
+        norm_eps=c["layer_norm_eps"], norm="layer",
+        parallel=bool(c.get("use_parallel_block", False)),
+        tie_head=bool(c.get("tie_word_embeddings", False)),
+        logit_scale=c.get("logit_scale", 1.0),
         initializer_range=c.get("initializer_range", 0.02), **kwargs)

@@ -89,13 +89,19 @@ class GroupLimitedMoE:
     chosen among the ``topk_group`` best groups (a group's score is
     its best expert's), weights the scores themselves times
     ``routed_scaling``; ``n_shared`` shared experts (one SwiGLU of
-    ``n_shared * width``) always on.
+    ``n_shared * width``) always on, added to the routed mix or
+    averaged with it (``shared_combine``).
 
     ``scoring``: ``"softmax"`` over the router's outputs
     (DeepSeek-V2), or ``"sigmoid"`` of each (DeepSeek-V3's
     ``noaux_tc``): a ``router_bias`` a expert is then added to the
-    scores for CHOOSING and takes no part in the weights.
+    scores for CHOOSING and takes no part in the weights (with
+    ``selection_bias=False`` there is no such leaf, and the scores
+    choose alone: Command A's router).
     ``norm_topk``: the chosen experts' weights divided by their sum.
+    ``shared_combine``: ``"sum"``, ``r + s`` of the routed mix ``r``
+    and the shared path ``s``, or ``"average"``, ``(r + s) / 2``
+    (Command A's ``shared_expert_combination_strategy``).
     ``n_group=1, scoring="sigmoid", norm_topk=True`` is the ungrouped
     router (every expert is a candidate for every token): the class
     keeps its name, the group limit is then no limit.
@@ -148,14 +154,23 @@ class GroupLimitedMoE:
                  experts_held: "Optional[tuple]" = None,
                  token_block: int = 2048, scoring: str = "softmax",
                  norm_topk: bool = False, activation: str = "swiglu",
-                 shared_width: Optional[int] = None):
+                 shared_width: Optional[int] = None,
+                 selection_bias: bool = True,
+                 shared_combine: str = "sum"):
         if scoring not in ("softmax", "sigmoid"):
             raise ValueError(f"scoring {scoring!r} not 'softmax' or "
                              "'sigmoid'")
+        if shared_combine not in ("sum", "average") or (
+                shared_combine == "average" and not n_shared):
+            raise ValueError(f"shared_combine {shared_combine!r}: "
+                             "'sum', or 'average' of a shared path")
         if activation not in ACTIVATIONS:
             raise ValueError(f"activation {activation!r} not one of "
                              f"{ACTIVATIONS}")
         self.scoring, self.norm_topk = scoring, bool(norm_topk)
+        self.selection_bias = scoring == "sigmoid" and bool(
+            selection_bias)
+        self.shared_combine = shared_combine
         self.activation = activation
         self.hidden_size, self.width = int(hidden_size), int(width)
         self.n_experts, self.top_k = int(n_experts), int(top_k)
@@ -188,7 +203,7 @@ class GroupLimitedMoE:
             out["experts_gate"] = n(k[1], (e, h, m))
         out.update(experts_up=n(k[2], (e, h, m)),
                    experts_down=n(k[3], (e, m, h)))
-        if self.scoring == "sigmoid":
+        if self.selection_bias:
             out["router_bias"] = jnp.zeros((self.n_experts,),
                                            jnp.float32)
         if self.n_shared:
@@ -208,8 +223,9 @@ class GroupLimitedMoE:
             x.astype(jnp.float32), p["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
         if self.scoring == "sigmoid":
-            scores = jax.nn.sigmoid(logits)
-            choice = scores + p["router_bias"].astype(jnp.float32)
+            scores = choice = jax.nn.sigmoid(logits)
+            if self.selection_bias:
+                choice = scores + p["router_bias"].astype(jnp.float32)
         else:
             scores = choice = jax.nn.softmax(logits, axis=-1)
         n, g = scores.shape[0], self.n_group
@@ -292,10 +308,13 @@ class GroupLimitedMoE:
             y, counts = block(x, valid)
         if self.n_shared:
             with jax.named_scope(f"zoo:{scope}/moe_shared"):
-                y = y + (relu2_mlp(x, p["shared_up"], p["shared_down"])
-                         if self.activation == "relu2" else
-                         gated_mlp(x, p["shared_gate"], p["shared_up"],
-                                   p["shared_down"]))
+                s = relu2_mlp(x, p["shared_up"], p["shared_down"]) \
+                    if self.activation == "relu2" else \
+                    gated_mlp(x, p["shared_gate"], p["shared_up"],
+                              p["shared_down"])
+                y = y + s if self.shared_combine == "sum" else \
+                    (0.5 * (y.astype(jnp.float32) + s.astype(
+                        jnp.float32))).astype(y.dtype)
         return y, counts
 
 

@@ -415,12 +415,16 @@ def test_engine_chunked_prefill_serves_the_whole_prompt_tokens(ids):
     got = serve(_engine(cfg, prefill_chunk=CHUNK), True)
     counted = {n: _counter(n) - before[n] for n in before}
     assert got == serve(_engine(cfg), False)
-    vis, kept, recycled = counted.values()
+    vis, kept, read, causal, recycled = counted.values()
     # three full layers; a query at position t sees t + 1 keys and
     # keeps min(t + 1, 16)
     tri = lambda n: n * (n + 1) // 2
     assert vis == 3 * (tri(61) + tri(38))
     assert kept == 3 * (tri(61) - tri(45) + tri(38) - tri(22))
+    # three window layers of 9: a query attends min(t + 1, 9) keys of
+    # the t + 1 it would attend with no window
+    win = lambda n: sum(min(t + 1, 9) for t in range(n))
+    assert causal == vis and read == 3 * (win(61) + win(38))
     # three window layers, a ring of 5 pages of 4: the 61 and 38
     # positions begin 16 and 10 pages, all past the fifth recycled
     assert recycled == 3 * ((16 - 5) + (10 - 5))
